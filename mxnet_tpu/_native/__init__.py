@@ -12,6 +12,8 @@ pure-Python fallback so the framework works without a compiler.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -30,6 +32,40 @@ _tried = False
 # marker recording a failed -ljpeg link (so a reader-only .so is not
 # mistaken for up-to-date once libjpeg appears later)
 _NOJPEG_MARKER = _LIB_PATH + ".nojpeg"
+# hash of the sources the library beside it was built from.  The library is
+# ignored by git yet travels with a copied tree, and a copy does not keep
+# mtimes: freshness is decided by content, so a stale binary is never loaded
+_HASH_PATH = _LIB_PATH + ".src-sha256"
+
+
+def _src_hash():
+    h = hashlib.sha256()
+    for path in sorted(glob.glob(os.path.join(os.path.dirname(_SRC),
+                                              "*.cc"))):
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _built_from():
+    try:
+        with open(_HASH_PATH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _commit(tmp, src_hash):
+    """Install a freshly linked library and record what it was built from
+    (the hash goes first: a crash between the two leaves a rebuild, never
+    a stale library taken for fresh)."""
+    if os.path.exists(_HASH_PATH):
+        os.remove(_HASH_PATH)
+    os.replace(tmp, _LIB_PATH)
+    with open(f"{_HASH_PATH}.tmp.{os.getpid()}", "w") as f:
+        f.write(src_hash + "\n")
+    os.replace(f.name, _HASH_PATH)
 
 
 def _build():
@@ -37,6 +73,7 @@ def _build():
     # place would truncate an inode that may still be mapped in-process
     # (the staleness probe dlopens it), risking SIGBUS / a stale mapping.
     tmp = f"{_LIB_PATH}.tmp.{os.getpid()}"
+    src_hash = _src_hash()
     # jpeg_decode.cc needs libjpeg; try with it first, fall back to the
     # reader-only library when the dev package is absent (decode then uses
     # the cv2 Python path)
@@ -46,18 +83,18 @@ def _build():
                "-o", tmp, "-ljpeg"]
         try:
             subprocess.run(cmd, check=True, capture_output=True)
-            os.replace(tmp, _LIB_PATH)
+            _commit(tmp, src_hash)
             if os.path.exists(_NOJPEG_MARKER):
                 os.remove(_NOJPEG_MARKER)
             return
         except subprocess.CalledProcessError:
             with open(_NOJPEG_MARKER, "w") as f:
-                f.write("libjpeg link failed; delete this file (or touch "
-                        "src/io/*.cc) after installing libjpeg to retry\n")
+                f.write("libjpeg link failed; delete this file after "
+                        "installing libjpeg to retry\n")
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
            os.path.abspath(_SRC), "-o", tmp]
     subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(tmp, _LIB_PATH)
+    _commit(tmp, src_hash)
 
 
 def load():
@@ -68,11 +105,8 @@ def load():
             return _lib
         _tried = True
         try:
-            srcs = [_SRC] + ([_SRC_JPEG] if os.path.exists(_SRC_JPEG)
-                             else [])
-            newest_src = max(os.path.getmtime(p) for p in srcs)
             stale = not os.path.exists(_LIB_PATH) or \
-                os.path.getmtime(_LIB_PATH) < newest_src
+                _built_from() != _src_hash()
             if not stale and os.path.exists(_SRC_JPEG):
                 # a reader-only .so from a failed -ljpeg link must retry
                 # once the marker is gone (e.g. libjpeg installed later)

@@ -156,20 +156,14 @@ def record_op(fn, attrs, input_ndarrays, raw_inputs, output_ndarrays,
         o._ag_node = (node, i)
 
 
-_TYPEOF = getattr(jax, "typeof", None)   # probed once: jax.__getattr__ on
-#                                          a missing name raises internally
-
-
 def _aval_of(x):
-    """Shape/dtype abstract value of an array or tracer.  ``jax.typeof``
-    only exists in newer JAX; ``ShapeDtypeStruct`` carries the two fields
-    the backward pass reads and works on every version."""
-    if _TYPEOF is not None:
-        try:
-            return _TYPEOF(x)
-        except Exception:
-            pass
-    return jax.ShapeDtypeStruct(x.shape, x.dtype)
+    """Shape/dtype abstract value of an array or tracer.  A pending engine
+    handle is not a type jax knows: ``ShapeDtypeStruct`` carries the two
+    fields the backward pass reads."""
+    try:
+        return jax.typeof(x)
+    except TypeError:
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -340,8 +340,19 @@ class Parameter:
                                         grad_reqs=self.grad_req)
 
     def reset_ctx(self, ctx):
+        """Re-assign the Parameter to other contexts (reference
+        ``parameter.py:440``): the data moves, in place so every handle on
+        it stays valid, and the gradient buffer is rebuilt beside it."""
+        ctx_list = [ctx] if isinstance(ctx, Context) else list(ctx)
         if self._data is not None:
-            self._ctx_list = [ctx] if isinstance(ctx, Context) else list(ctx)
+            import jax
+            self._ctx_list = ctx_list
+            self._data._data = jax.device_put(self._data._materialize(),
+                                              ctx_list[0].jax_device())
+            self._init_grad()
+        elif self._deferred_init:
+            init, _, default_init, data = self._deferred_init
+            self._deferred_init = (init, ctx_list, default_init, data)
 
 
 class Constant(Parameter):

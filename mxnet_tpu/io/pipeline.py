@@ -2,8 +2,8 @@
 
 The reference decodes JPEGs on an OMP thread pool inside one process
 (``src/io/iter_image_recordio_2.cc``); Python threads can only take that so
-far — BENCH_r04/r05 measured the end-to-end ResNet step host-input-bound with
-one decode core busy.  This module moves decode across *processes*:
+far — an end-to-end ResNet step was host-input-bound with one decode core
+busy.  This module moves decode across *processes*:
 
 - :class:`DecodeSpec` is the pickleable decode recipe shared by the in-process
   thread path and the worker processes — one code path, so

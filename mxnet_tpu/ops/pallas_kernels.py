@@ -20,23 +20,25 @@ import math
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is TPU/interpret-only; degrade gracefully elsewhere
-    from jax.experimental import pallas as pl
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    pl = None
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 __all__ = ["flash_attention"]
 
 _NEG = -1e30
 
+# Mosaic's default scoped-VMEM budget for one kernel on a v5e core; the
+# chip's compiler refuses a kernel whose blocks need more
+_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
-               seq_len):
+               seq_len, kv_len):
     """One (batch*head, q-block) program: stream K/V blocks, online softmax.
 
-    Block shapes: q (1, BQ, D), k/v (1, T, D), o (1, BQ, D).
+    Block shapes: q (1, BQ, D), k/v (1, T, D), o (1, BQ, D).  ``seq_len``
+    is the padded T, ``kv_len`` the real one: keys at or past it are the
+    zero padding and are masked out (causal masking already hides them
+    from every real query row).
     """
     qi = pl.program_id(1)
     bq = q_ref.shape[1]
@@ -55,10 +57,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
         k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (BQ, BK)
-        if causal:
+        if causal or kv_len < seq_len:
             k_pos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG)
+            keep = q_pos >= k_pos if causal else k_pos < kv_len
+            s = jnp.where(keep, s, _NEG)
         new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - new_m)
         corr = jnp.exp(m - new_m)
@@ -80,16 +83,35 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
 def _fa_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     b, h, t, d = q.shape
     orig_t, orig_d = t, d
-    # pad D to the 128-lane tile and T to the block size; zero K-padding
-    # contributes exp(-inf)=... no — zero scores, handled by length masking
+    # pad D to the 128-lane tile and T to the block size; the kernel masks
+    # the padded key positions by length
     pad_d = (-d) % 128
-    pad_t = (-t) % max(block_q, block_k)
+    block = max(block_q, block_k)
+    pad_t = (-t) % block
     if pad_d or pad_t:
         cfg = [(0, 0), (0, 0), (0, pad_t), (0, pad_d)]
         q = jnp.pad(q, cfg)
         k = jnp.pad(k, cfg)
         v = jnp.pad(v, cfg)
         t, d = t + pad_t, d + pad_d
+    # K and V ride as whole-sequence blocks, double-buffered like the q/o
+    # blocks, beside the kernel's f32 scores, probabilities and accumulator.
+    # Past the budget the chip's compiler refuses the kernel ("scoped vmem
+    # limit"), so say so here with the numbers.  The estimate matches the
+    # v5e compiler's verdict at 128x128 blocks: bf16 passes to 16,000
+    # tokens, f32 to 7,936, at head_dim <= 128.
+    itemsize = jnp.dtype(q.dtype).itemsize
+    fixed = 4 * block_q * d * itemsize + \
+        4 * (2 * block_q * block_k + block_q * d)
+    if 4 * t * d * itemsize + fixed > _VMEM_LIMIT_BYTES:
+        max_t = (_VMEM_LIMIT_BYTES - fixed) // (4 * d * itemsize)
+        raise ValueError(
+            f"flash_attention: {orig_t} tokens (padded {t}) at head_dim "
+            f"{orig_d} (padded {d}) {q.dtype} need "
+            f"{4 * t * d * itemsize + fixed} bytes of VMEM for the "
+            f"whole-sequence K/V blocks, over the kernel's "
+            f"{_VMEM_LIMIT_BYTES}-byte budget; the limit at this width and "
+            f"dtype is {max_t // block * block} tokens")
     bh = b * h
     qf = q.reshape(bh, t, d)
     kf = k.reshape(bh, t, d)
@@ -97,7 +119,7 @@ def _fa_forward(q, k, v, causal, scale, block_q, block_k, interpret):
 
     grid = (bh, t // block_q)
     kernel = functools.partial(_fa_kernel, block_k=block_k, causal=causal,
-                               scale=scale, seq_len=t)
+                               scale=scale, seq_len=t, kv_len=orig_t)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
@@ -131,19 +153,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
                     block_k=128, interpret=None):
     """Blockwise attention, (B, H, T, D) → (B, H, T, D).
 
-    ``interpret=None`` auto-selects: real kernel on TPU, pallas interpreter
-    elsewhere (tests on the CPU mesh).  T is padded to the block size and D
-    to 128 lanes internally.
+    ``interpret=None`` auto-selects: the pallas interpreter on the CPU
+    backend (the tests), the compiled kernel everywhere else — a kernel the
+    chip's compiler refuses raises, it is never swapped for the dense
+    reference.  T is padded to the block size and D to 128 lanes
+    internally; sequences past the kernel's VMEM budget raise ``ValueError``.
     """
     scale_v = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
-    if not _HAS_PALLAS:
-        return _reference(q, k, v, causal, scale_v)
-    # padded (non-causal) key positions would attend with score 0; guard by
-    # requiring T % block == 0 when non-causal, else fall back
-    if not causal and q.shape[2] % max(block_q, block_k) != 0:
-        return _reference(q, k, v, causal, scale_v)
+        interpret = jax.default_backend() == "cpu"
     return _fa_forward(q, k, v, causal, scale_v, block_q, block_k, interpret)
 
 

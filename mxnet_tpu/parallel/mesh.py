@@ -15,16 +15,6 @@ import numpy as np
 _current = []
 
 
-def shard_map_fn():
-    """The shard_map entry point across jax versions (one shim, used by
-    ring_attention/pipeline/moe)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def device_mesh(axes, devices=None):
     """Build a ``jax.sharding.Mesh`` from ``{axis_name: size}``.
 
@@ -66,12 +56,13 @@ def make_mesh(n_devices=None, dp=None, tp=1, sp=1, pp=1):
 
 
 def current_mesh():
-    """Innermost mesh entered via ``with mesh:`` or our helpers."""
+    """Innermost mesh entered via :func:`use_mesh`, else the one set with
+    ``jax.set_mesh``; ``None`` outside both."""
+    if _current:
+        return _current[-1]
     import jax
-    env = getattr(jax.interpreters.pxla, "thread_resources", None)
-    if env is not None and env.env.physical_mesh.devices.size > 0:
-        return env.env.physical_mesh
-    return _current[-1] if _current else None
+    mesh = jax.sharding.get_mesh()
+    return None if mesh.empty else mesh
 
 
 @contextlib.contextmanager

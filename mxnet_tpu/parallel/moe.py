@@ -63,8 +63,7 @@ def moe_layer(expert_fn, gate_w, expert_params, x, mesh, ep_axis="ep",
     """SPMD entry: x (B, D) sharded over ``ep`` (token-parallel), experts
     sharded one-per-device; returns (B, D) with the same sharding."""
     from jax.sharding import PartitionSpec as P
-    from .mesh import shard_map_fn
-    shard_map = shard_map_fn()
+    from jax import shard_map
 
     if _san.collectives:
         _div.record("moe.all_to_all", axis=ep_axis, shape=tuple(x.shape),

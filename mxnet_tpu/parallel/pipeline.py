@@ -107,8 +107,7 @@ def gpipe(stage_fn, stacked_params, x, mesh, n_microbatches, pp_axis="pp"):
     - ``x``: (batch, ...); batch must divide by ``n_microbatches``
     """
     from jax.sharding import PartitionSpec as P
-    from .mesh import shard_map_fn
-    shard_map = shard_map_fn()
+    from jax import shard_map
 
     if _faults.active:
         # resilience drill site: fails before the schedule dispatches, so
@@ -258,8 +257,7 @@ def pipeline_train_1f1b(stage_fn, loss_fn, stacked_params, x, y, mesh,
     the returned loss is the mean over microbatches.
     """
     from jax.sharding import PartitionSpec as P
-    from .mesh import shard_map_fn
-    shard_map = shard_map_fn()
+    from jax import shard_map
 
     if _faults.active:
         _faults.check("pipeline.schedule")
@@ -361,8 +359,7 @@ def gpipe_interleaved(stage_fn, stacked_params, x, mesh, n_microbatches,
     """
     import numpy as _np
     from jax.sharding import PartitionSpec as P
-    from .mesh import shard_map_fn
-    shard_map = shard_map_fn()
+    from jax import shard_map
 
     if _faults.active:
         _faults.check("pipeline.schedule")

@@ -95,8 +95,7 @@ def ring_self_attention(q, k, v, mesh, sp_axis="sp", dp_axis="dp",
     """SPMD entry point: (B, H, T, D) arrays, T sharded over ``sp`` and B
     over ``dp``.  Returns attention output with the same sharding."""
     from jax.sharding import PartitionSpec as P
-    from .mesh import shard_map_fn
-    shard_map = shard_map_fn()
+    from jax import shard_map
 
     spec = P(dp_axis, None, sp_axis, None)
     fn = functools.partial(ring_attention, axis_name=sp_axis, causal=causal,

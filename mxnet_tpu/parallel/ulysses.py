@@ -52,8 +52,7 @@ def ulysses_self_attention(q, k, v, mesh, sp_axis="sp", dp_axis="dp",
     """SPMD entry point, drop-in alternative to ``ring_self_attention``:
     (B, H, T, D) arrays with T sharded over ``sp`` and B over ``dp``."""
     from jax.sharding import PartitionSpec as P
-    from .mesh import shard_map_fn
-    shard_map = shard_map_fn()
+    from jax import shard_map
 
     n_sp = mesh.shape[sp_axis]
     if q.shape[1] % n_sp != 0:

@@ -116,38 +116,28 @@ def _xplane_aggregate(logdir):
     key = frozenset(files)
     if key in _parse_cache:             # a finished trace is immutable
         return _parse_cache[key]
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except Exception as e:                      # pragma: no cover
-        warnings.warn(f"xplane parser unavailable ({e}); falling back to "
-                      "wall-clock aggregates")
-        return None
+    from jax.profiler import ProfileData
     agg, rt_agg = {}, {}
     for path in files:
-        space = xplane_pb2.XSpace()
-        with open(path, "rb") as f:
-            space.ParseFromString(f.read())
-        for plane in space.planes:
+        for plane in ProfileData.from_file(path).planes:
             plane_is_device = "/device:" in plane.name.lower()
-            meta = {m_id: m.name or m.display_name
-                    for m_id, m in plane.event_metadata.items()}
             for line in plane.lines:
-                lname = (line.name or line.display_name).lower()
+                lname = line.name.lower()
                 if plane_is_device:
                     if "step" in lname:
                         continue        # step-number markers, not ops
-                    target = agg        # TPU/GPU: lines are XLA ops/modules
+                    target = agg        # TPU: lines are XLA ops/modules
                 elif lname.startswith("tf_xlapjrt"):
                     target = rt_agg     # host runtime executing XLA thunks
                 else:
                     continue            # python frames, codegen, metadata
                 for ev in line.events:
-                    name = meta.get(ev.metadata_id, "")
+                    name = ev.name
                     # drop region markers and C++ runtime internals — keep
                     # the op/fusion executions the table is about
                     if not name or name.startswith("end: ") or "::" in name:
                         continue
-                    dur = ev.duration_ps / 1e12
+                    dur = ev.duration_ns / 1e9
                     row = target.setdefault(name, [0, 0.0, float("inf"),
                                                    0.0])
                     row[0] += 1

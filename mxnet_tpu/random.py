@@ -17,7 +17,12 @@ import threading
 import jax
 
 _lock = threading.Lock()
-_key = [jax.random.PRNGKey(0)]
+# the root key is made from the seed on first use, never at import or in
+# seed(): a process that only imports the framework (a gateway front end,
+# a supervisor) must not initialise a backend — on a chip machine that
+# takes the chip away from the child that needs it
+_seed = [0]
+_key = [None]
 # host-side stream for initializers (reference initializers run on mxnet's
 # seeded RNG ops, so mx.random.seed must determinize them here too)
 import numpy as _np
@@ -32,11 +37,19 @@ _pool = {"keys": None, "i": 0, "last": None}
 def seed(seed_state, ctx="all"):
     """Reset the global key (reference ``mx.random.seed``)."""
     with _lock:
-        _key[0] = jax.random.PRNGKey(int(seed_state))
+        _seed[0] = int(seed_state)
+        _key[0] = None
         _pool["keys"] = None
         _pool["i"] = 0
         _pool["last"] = None
         np_rng.seed(int(seed_state))
+
+
+def _root_key():
+    """The global key (call under ``_lock``)."""
+    if _key[0] is None:
+        _key[0] = jax.random.PRNGKey(_seed[0])
+    return _key[0]
 
 
 _tls = threading.local()
@@ -78,7 +91,7 @@ def next_key():
         return sub
     with _lock:
         if _pool["keys"] is None or _pool["i"] >= _POOL:
-            ks = jax.random.split(_key[0], _POOL + 1)
+            ks = jax.random.split(_root_key(), _POOL + 1)
             _key[0] = ks[0]
             # host copy: a numpy row IS a valid key and slices for free —
             # a device-array __getitem__ costs a full eager dispatch
@@ -102,7 +115,7 @@ def get_state():
     init happens before training, which is what checkpoints bracket."""
     with _lock:
         return {
-            "key": _np.asarray(_key[0]).copy(),
+            "key": _np.asarray(_root_key()).copy(),
             "pool_keys": None if _pool["keys"] is None
             else _pool["keys"].copy(),
             "pool_i": _pool["i"],
@@ -139,7 +152,7 @@ def current_key():
     with _lock:
         if _pool["last"] is not None:
             return _pool["last"]
-        return _key[0]
+        return _root_key()
 
 
 # The user-facing sampling functions (mx.random.uniform etc.) are installed by

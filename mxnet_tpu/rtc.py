@@ -50,7 +50,7 @@ def compile_pallas(source, kernel_name, out_shape):
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct(out_shape[0], out_shape[1]),
-            interpret=jax.default_backend() not in ("tpu",),
+            interpret=jax.default_backend() == "cpu",
         )(*arrays)
 
     return fn

@@ -220,8 +220,16 @@ class ProgramCache:
         except Exception:
             return "unpickle"
         try:
+            import jax
             from jax.experimental import serialize_executable as _se
-            fn = _se.deserialize_and_load(payload, in_tree, out_tree)
+            # load onto the devices the program was compiled for — the
+            # default is every device of the backend, and a one-device
+            # program then refuses its arguments on a multi-device host
+            ids = header.get("device_ids")
+            devices = [d for i in ids or () for d in jax.devices()
+                       if d.id == i] or None
+            fn = _se.deserialize_and_load(payload, in_tree, out_tree,
+                                          execution_devices=devices)
         except Exception:
             return "deserialize"
         return fn, extra
@@ -238,9 +246,13 @@ class ProgramCache:
             blob = pickle.dumps((payload, in_tree, out_tree, extra or {}),
                                 protocol=pickle.HIGHEST_PROTOCOL)
             header = dict(self._env)
+            import jax
+            shardings = jax.tree_util.tree_leaves(compiled.input_shardings)
             header.update(format=AOT_FORMAT, model_key=self.model_key,
                           name=str(name), payload_len=len(blob),
-                          crc32=zlib.crc32(blob) & 0xffffffff)
+                          crc32=zlib.crc32(blob) & 0xffffffff,
+                          device_ids=sorted({d.id for sh in shardings
+                                             for d in sh.device_set}))
             hjson = json.dumps(header, sort_keys=True).encode()
             data = _MAGIC + struct.pack("<I", len(hjson)) + hjson + blob
             os.makedirs(self.dir, exist_ok=True)

@@ -40,7 +40,7 @@ from ...analysis import sanitizer as _san
 from ...gluon.block import io_signature
 from ...telemetry import bus as _tel
 from ..aot import as_program_cache
-from ..runtime import default_buckets
+from ..runtime import default_buckets, place_block
 from .kv_cache import PagedKVCache
 
 __all__ = ["DecodeRuntime", "seq_bucket_ladder"]
@@ -157,7 +157,7 @@ class DecodeRuntime:
                 f"spec bucket cap {self.spec_buckets[-1]} exceeds the "
                 f"cache context ({cache.context_length} tokens)")
         self.max_spec_k = self.spec_buckets[-1] if self.spec_buckets else 0
-        self._params = block.param_leaves()
+        import jax
         # sharded cache: the page pools live distributed over the mesh,
         # while the block's params (and the CachedOp prefill outputs) are
         # committed to one device — jit refuses mixed committed placements.
@@ -165,11 +165,20 @@ class DecodeRuntime:
         # boundary; everything downstream is then mesh-consistent.
         self._replicate = None
         if getattr(cache, "mesh", None) is not None:
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec
             rep = NamedSharding(cache.mesh, PartitionSpec())
-            self._params = [jax.device_put(p, rep) for p in self._params]
+            self.device = cache.mesh.devices.flat[0]
+            self._params = [jax.device_put(p, rep)
+                            for p in block.param_leaves()]
             self._replicate = lambda x: jax.device_put(x, rep)
+        else:
+            # one device for parameters, page pools and every program:
+            # committing the pools beside the placed block leaves jit no
+            # choice of where to run
+            self.device = place_block(block)
+            self._params = block.param_leaves()
+            cache.set_pools(jax.device_put(p, self.device)
+                            for p in cache.pools)
         self._step_fns = {}       # batch_bucket -> donated jit
         self._commit_fns = {}     # (batch_bucket, seq_bucket) -> donated jit
         self._verify_fns = {}     # (batch_bucket, spec_k) -> donated jit
@@ -236,8 +245,8 @@ class DecodeRuntime:
         with _tel.span("decode.warmup", model=self.name,
                        grid=len(grid), steps=len(self.batch_buckets)):
             def make_example(b, s):
-                return [nd.array(np.zeros((b, s), "int32")),
-                        nd.array(np.ones((b,), "int32"))]
+                return [nd.array(np.zeros((b, s), "int32"), ctx=self.device),
+                        nd.array(np.ones((b,), "int32"), ctx=self.device)]
 
             with autograd.pause(train_mode=False):
                 self._prefill_sigs.update(
@@ -482,8 +491,8 @@ class DecodeRuntime:
         can skip this whole call).  The page pools are functionally
         updated in place (donated)."""
         b, s = tokens.shape
-        tok_nd = nd.array(tokens)
-        len_nd = nd.array(lengths)
+        tok_nd = nd.array(tokens, ctx=self.device)
+        len_nd = nd.array(lengths, ctx=self.device)
         sig = io_signature([tok_nd, len_nd])
         if sig not in self._prefill_sigs:
             if sig in self._block.compiled_signatures(training=False):

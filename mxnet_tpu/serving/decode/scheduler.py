@@ -45,6 +45,7 @@ from ...telemetry import flight as _flight
 from ...telemetry import http as _http
 from ...telemetry import trace as _trace
 from ..batcher import RequestRejected
+from ..runtime import device_info
 from .kv_cache import KVCacheExhausted, pages_needed
 from .runtime import DecodeRuntime
 from .speculate import SpecState, resolve_drafter
@@ -449,6 +450,11 @@ class DecodeScheduler:
         """Sequences currently in the decode batch (approximate — read
         without joining the step boundary)."""
         return len(self._active)
+
+    @property
+    def device(self):
+        """The ``jax.Device`` this scheduler's runtime runs on."""
+        return self._runtime.device
 
     @property
     def healthy(self):
@@ -1152,6 +1158,10 @@ class DecodeSession:
         return self.scheduler.stream(prompt, **kwargs)
 
     @property
+    def device(self):
+        return self.runtime.device
+
+    @property
     def healthy(self):
         return self.scheduler.healthy
 
@@ -1163,6 +1173,9 @@ class DecodeSession:
         s = self.cache.stats()
         s["pending"] = self.scheduler.pending()
         s["active"] = self.scheduler.active()
+        s.update(device_info(self.device))
+        if self.runtime.aot_cache is not None:
+            s["aot_cache"] = self.runtime.aot_cache.stats()
         return s
 
     def close(self, drain=True, timeout=60.0):

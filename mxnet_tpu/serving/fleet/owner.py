@@ -46,6 +46,7 @@ from ...telemetry import bus as _tel
 from ...telemetry import flight as _flight
 from ...telemetry import trace as _trace
 from ..batcher import RequestRejected
+from ..runtime import device_info
 from .transport import RPCServer
 
 __all__ = ["OwnerService", "load_builder", "serve", "main"]
@@ -90,11 +91,15 @@ class OwnerService:
         self.generation = int(generation)
         self.started_at = time.time()
         self._draining = threading.Event()
+        # this process IS the device owner: say which device it holds, so a
+        # front-end (which never touches one) can report and assert it
+        import jax
+        self.device = device_info(jax.local_devices()[0])
 
     # ----------------------------------------------------------- dispatch
     def pong(self):
         return {"pid": os.getpid(), "generation": self.generation,
-                "draining": self._draining.is_set()}
+                "draining": self._draining.is_set(), **self.device}
 
     def handle(self, method, params, deadline_ms, trace, emit,
                register_cancel):
@@ -182,7 +187,8 @@ class OwnerService:
     def stats(self):
         out = {"pid": os.getpid(), "generation": self.generation,
                "uptime_s": round(time.time() - self.started_at, 3),
-               "draining": self._draining.is_set(), "decode": {}}
+               "draining": self._draining.is_set(), "decode": {},
+               **self.device}
         for name, sess in self.decode.items():
             try:
                 out["decode"][name] = sess.stats()
@@ -271,6 +277,11 @@ def main(argv=None):
     p.add_argument("--ready-fd", type=int, default=None,
                    help="fd to write one byte to once serving")
     args = p.parse_args(argv)
+    # a restart is only warm if every incarnation finds what the last one
+    # compiled: JAX's persistent cache, placed from outside (or at its one
+    # fixed path), beside the AOT program cache
+    from ...runtime import compile_cache
+    compile_cache()
     return serve(args.spec, args.socket, aot_cache=args.aot_cache,
                  generation=args.generation, ready_fd=args.ready_fd)
 

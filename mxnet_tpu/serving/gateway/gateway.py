@@ -55,6 +55,7 @@ from ...telemetry import flight as _flight
 from ...telemetry import http as _http
 from ...telemetry import trace as _trace
 from ..batcher import RequestRejected
+from ..runtime import device_info
 from .qos import AdmissionController
 
 __all__ = ["Gateway"]
@@ -187,6 +188,22 @@ class Gateway:
     @property
     def draining(self):
         return self._draining.is_set()
+
+    @property
+    def devices(self):
+        """Where the models behind this gateway run — ``{model:
+        {"platform", "device_kind"}}`` in process, ``{"owner": ...}`` as
+        the device-owner reports it in proxy mode (empty while it is
+        down).  Rides ``/healthz``."""
+        if self.owner is None:
+            return {name: device_info(s.device)
+                    for name, s in self._decode.items()}
+        try:
+            pong = self.owner.ping(timeout=1.0)
+        except (OSError, TimeoutError):
+            return {}
+        return {"owner": {k: pong.get(k)
+                          for k in ("platform", "device_kind")}}
 
     # ---------------------------------------------------------------- drain
     def drain(self):

@@ -25,7 +25,40 @@ from ..ndarray import NDArray
 from ..telemetry import bus as _tel
 from .aot import as_program_cache
 
-__all__ = ["ModelRuntime", "default_buckets"]
+__all__ = ["ModelRuntime", "default_buckets", "place_block",
+           "device_info"]
+
+
+def place_block(block):
+    """Decide where a runtime runs and put ``block`` there — the serving
+    stack's one placement rule; returns the ``jax.Device``.
+
+    A runtime runs on the accelerator its block's parameters are committed
+    to.  A block left on the host — ``net.initialize()`` with no ``ctx``
+    lands on ``cpu(0)``, the MXNet default — moves to the process's first
+    device: the chip where there is one, the CPU in the tests.  Without
+    this, a jit over host-committed parameters and uncommitted inputs runs
+    on the host while the chip sits idle, and nothing says so."""
+    import jax
+    from ..context import context_from_jax_device
+    params = block.collect_params()
+    devices = set()
+    for p in params.values():
+        if p._data is not None:
+            devices |= p.data()._data.devices()
+    if len(devices) == 1 and next(iter(devices)).platform != "cpu":
+        return next(iter(devices))
+    device = jax.local_devices()[0]
+    if devices != {device}:
+        params.reset_ctx(context_from_jax_device(device))
+    return device
+
+
+def device_info(device):
+    """``{"platform", "device_kind"}`` of a ``jax.Device`` — what every
+    serving ``stats()`` and the gateway's health route report, so a
+    deployment that landed on the host says so."""
+    return {"platform": device.platform, "device_kind": device.device_kind}
 
 
 def default_buckets(max_batch):
@@ -78,6 +111,7 @@ class ModelRuntime:
         if not getattr(block, "_active", False):
             block.hybridize()
         self._block = block
+        self.device = place_block(block)
         self.name = name or getattr(block, "name", "model")
         self.max_batch = int(max_batch)
         self.buckets = tuple(sorted(set(
@@ -133,7 +167,7 @@ class ModelRuntime:
         After this, any micro-batch padded to a bucket replays a compiled
         executable — zero steady-state XLA recompiles."""
         def make_example(b):
-            return [nd.array(np.zeros((b,) + shp, dt))
+            return [nd.array(np.zeros((b,) + shp, dt), ctx=self.device)
                     for shp, dt in zip(self._item_shapes, self._dtypes)]
 
         with _tel.span("serving.warmup", model=self.name,
@@ -191,7 +225,7 @@ class ModelRuntime:
             if bucket > n:
                 stacked = np.concatenate(
                     [stacked, np.zeros((bucket - n,) + shp, stacked.dtype)])
-            ins.append(nd.array(stacked, dtype=dt))
+            ins.append(nd.array(stacked, dtype=dt, ctx=self.device))
         sig = io_signature(ins)
         miss = sig not in self._compiled_sigs
         if miss and sig in self._block.compiled_signatures(training=False):

@@ -48,7 +48,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from . import exporters
 
 __all__ = ["start_server", "stop_server", "server_port",
-           "register_health", "unregister_health", "health",
+           "register_health", "unregister_health", "health", "devices",
            "register_ready", "unregister_ready", "readiness",
            "register_route", "unregister_route", "routes"]
 
@@ -126,6 +126,23 @@ def health():
     return _report(_health, ("healthy",))
 
 
+def devices():
+    """``{name: ...}`` for every liveness probe that exposes ``.devices``
+    (property or nullary method): where that component's models run, by
+    jax ``platform``/``device_kind``.  A server that landed on the host
+    says so on the probe an operator already watches."""
+    with _health_lock:
+        items = list(_health.items())
+    out = {}
+    for name, ref in items:
+        d = getattr(ref(), "devices", None)
+        if callable(d):
+            d = d()
+        if d:
+            out[name] = d
+    return out
+
+
 def register_ready(name, obj):
     """Register a **readiness** probe under ``name``: ``obj.ready`` is
     consulted, falling back to ``obj.healthy`` (so breaker-bearing
@@ -184,7 +201,8 @@ def _route_metrics(h):
 
 def _route_healthz(h):
     ok, report = health()
-    body = json.dumps({"ok": ok, "components": report}) + "\n"
+    body = json.dumps({"ok": ok, "components": report,
+                       "devices": devices()}) + "\n"
     h._send(200 if ok else 503, body, "application/json")
 
 

@@ -44,6 +44,7 @@ def main():
             "token_ids": res.token_ids,
             "finish_reason": res.finish_reason,
             "cache": pc.stats() if pc is not None else None,
+            "platform": sess.stats()["platform"],
         }))
     finally:
         sess.close(drain=False)

@@ -243,8 +243,10 @@ def _spd(A, n):
     """Deterministic symmetric positive-definite matrix."""
     m = A(n, n)
     import mxnet_tpu as _mx
+    # the identity goes where m lives: with no ctx it lands on the host,
+    # and on the chip the sum then refuses its mixed devices
     return _mx.nd.dot(m, m, transpose_b=True) + _mx.nd.array(
-        np.eye(n, dtype="float32") * n)
+        np.eye(n, dtype="float32") * n, ctx=m.context)
 
 
 _POSITIVE_OPS = {

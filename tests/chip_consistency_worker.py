@@ -1,16 +1,9 @@
-"""Chip-side half of the CPU↔TPU consistency suite (the reference's
+"""The curated op batch of the CPU↔TPU consistency suite (the reference's
 ``check_consistency`` role, ``python/mxnet/test_utils.py`` — same ops on
-two backends, outputs must agree).
-
-Run WITHOUT the suite's CPU pin so ``mx.gpu(0)`` resolves to the real
-accelerator; writes every op output to the npz given in argv[1].
-The op batch is defined HERE so both sides import one list.
+two backends, outputs must agree).  ``chip_smoke.py --ops`` runs it, with
+the generated sweep, on the chip and on the CPU and compares; the batch is
+defined HERE so both sides import one list.
 """
-import os
-import sys
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
 import numpy as np
 
 
@@ -75,29 +68,3 @@ def op_batch(mx, ctx):
                                           target_shape=(4, 4))
     out["instance_norm"] = mx.nd.InstanceNorm(x, A(3), A(3), eps=1e-4)
     return out
-
-
-def main():
-    out_path = sys.argv[1]
-    import jax
-    import mxnet_tpu as mx
-
-    accel = [d for d in jax.devices() if d.platform != "cpu"]
-    if not accel:
-        print("NO_ACCELERATOR")
-        return 0
-    ctx = mx.gpu(0)
-    from chip_consistency_sweep import sweep_batch
-    with jax.default_matmul_precision("highest"):
-        outs = op_batch(mx, ctx)
-        arrays = {k: v.asnumpy() for k, v in outs.items()}
-        if os.environ.get("CHIP_SWEEP", "1") != "0":
-            for k, v in sweep_batch(mx, ctx).items():
-                arrays[f"sweep:{k}"] = v.asnumpy()
-    np.savez(out_path, **arrays)
-    print(f"CHIP_OK n={len(arrays)} device={accel[0].device_kind!r}")
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -72,3 +72,22 @@ def test_image_record_iter_uses_native(tmp_path):
         assert b.data[0].shape == (4, 3, 16, 16)
         labels.extend(b.label[0].asnumpy().tolist())
     assert len(labels) == 12
+
+
+def test_library_freshness_is_keyed_on_the_source_hash(monkeypatch):
+    """The .so is ignored by git yet travels with a copied tree, and a copy
+    keeps no mtimes: a library whose recorded source hash differs from
+    ``src/io/*.cc`` is rebuilt, never loaded; a matching one is kept however
+    old its mtime."""
+    import os
+    assert _native._built_from() == _native._src_hash()
+    builds = []
+    monkeypatch.setattr(_native, "_build", lambda: builds.append(1))
+    monkeypatch.setattr(_native, "_lib", None)
+    monkeypatch.setattr(_native, "_tried", False)
+    os.utime(_native._LIB_PATH, (0, 0))           # older than every source
+    assert _native.load() is not None and builds == []
+    monkeypatch.setattr(_native, "_lib", None)
+    monkeypatch.setattr(_native, "_tried", False)
+    monkeypatch.setattr(_native, "_built_from", lambda: "another tree's")
+    assert _native.load() is not None and builds == [1]

@@ -192,8 +192,7 @@ def test_sync_batch_norm_shard_map_moments_are_global():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
     from mxnet_tpu.ops.nn import batch_norm, sync_batch_norm
-    from mxnet_tpu.parallel.mesh import shard_map_fn
-    shard_map = shard_map_fn()
+    from jax import shard_map
 
     rng = np.random.RandomState(0)
     devs = np.array(jax.devices()[:4])

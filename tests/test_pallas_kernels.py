@@ -1,5 +1,6 @@
 """Pallas flash attention kernel vs the full-materialization reference
-(interpret mode on the CPU mesh; the same kernel compiles for real on TPU)."""
+(interpret mode on the CPU mesh; tests/test_chip_compile.py compiles the same
+kernel for a described v5e, chip_smoke.py runs it on the chip)."""
 import numpy as np
 import pytest
 
@@ -25,11 +26,14 @@ def test_flash_matches_reference(causal):
                                atol=2e-5)
 
 
-def test_flash_causal_padded_seq():
-    """T not divisible by the block: causal path pads and slices back."""
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_padded_seq(causal):
+    """T not divisible by the block: the kernel pads, masks the padded keys
+    by length (non-causal rows would otherwise attend to them with score 0)
+    and slices back — it never swaps in the dense reference."""
     q, k, v = _qkv(t=200)
-    out = flash_attention(q, k, v, True, None, 128, 128, True)
-    ref = _reference(q, k, v, True, 1.0 / np.sqrt(q.shape[-1]))
+    out = flash_attention(q, k, v, causal, None, 128, 128, True)
+    ref = _reference(q, k, v, causal, 1.0 / np.sqrt(q.shape[-1]))
     assert out.shape == (2, 2, 200, 64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
