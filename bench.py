@@ -2079,7 +2079,8 @@ def _telemetry_summary():
         "decode_joins": c.get("decode.joins", 0),
         "decode_evictions": c.get("decode.evictions", 0),
         "decode_compile_misses": c.get("decode.compile_miss", 0),
-        "decode_ttft_ms": round(c.get("decode.ttft_ms", 0.0), 1),
+        "decode_ttft_ms": round(
+            snap["histograms"].get("decode.ttft_ms", {}).get("sum", 0.0), 1),
         "decode_rejections": c.get("decode.rejections", 0),
         "decode_kv_occupancy": g.get("decode.kv_occupancy", 0),
         "decode_kv_bytes_per_token": g.get("decode.kv_bytes_per_token", 0),

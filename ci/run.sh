@@ -1042,7 +1042,7 @@ roots = [e for e in bus.events() if e[0] == "I" and e[1] == "decode.submit"]
 assert len(roots) == 3, len(roots)
 lane = (roots[0][6] or {})["trace_id"]
 names = [e[1] for e in bus.events() if e[5] == lane]
-for hop in ("decode.queue_wait", "decode.prefill", "decode.ride_step",
+for hop in ("decode.queue_wait", "decode.ride_prefill", "decode.ride_step",
             "decode.evict"):
     assert hop in names, (hop, names)
 hist = telemetry.snapshot()["histograms"]

@@ -232,13 +232,9 @@ class _Scope:
         self._ann = None
 
     def start(self):
-        import jax
+        from .telemetry import bus
         self._t0 = time.perf_counter()
-        try:
-            self._ann = jax.profiler.TraceAnnotation(self._name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
+        self._ann = bus.annotation(self._name)
 
     def stop(self):
         if self._t0 is None:

@@ -491,38 +491,48 @@ class DecodeRuntime:
         can skip this whole call).  The page pools are functionally
         updated in place (donated)."""
         b, s = tokens.shape
-        tok_nd = nd.array(tokens, ctx=self.device)
-        len_nd = nd.array(lengths, ctx=self.device)
-        sig = io_signature([tok_nd, len_nd])
-        if sig not in self._prefill_sigs:
-            if sig in self._block.compiled_signatures(training=False):
-                self._prefill_sigs.add(sig)
-            elif self._warmed:
-                self._miss("prefill", (b, s))
+        # the span is the whole call as the scheduler sees it: .dispatch
+        # runs from the arguments' staging until both programs have been
+        # handed over, .fetch is where this thread waits for the chip
         with _tel.span("decode.prefill", model=self.name, batch=b, seq=s):
-            with autograd.pause(train_mode=False):
-                logits, kv = self._block(tok_nd, len_nd)
-            self._prefill_sigs.add(sig)
-            commit = self._commit_fn(b, s)
-            cache = self.cache
-            pools = cache.pools
-            kv_raw, logits_raw = kv.data, logits.data
-            if self._replicate is not None:
-                kv_raw = self._replicate(kv_raw)
-                logits_raw = self._replicate(logits_raw)
-            logits_host = (np.asarray(logits_raw, "float32")
-                           if cache.prefix_sharing else None)
-            out = commit(
-                self._params, kv_raw, logits_raw,
-                lengths.astype("int32"), tables.astype("int32"),
-                keys.astype("uint32"), np.zeros((b,), "int32"),
-                temps.astype("float32"), *pools)
-            if _san.donation:
-                # the commit donated the page pools: poison the pre-call
-                # arrays so any stray alias raises naming this site
-                _san.poison(list(pools), "decode.prefill_commit")
-            cache.set_pools(out[1:])
-        return np.asarray(out[0]), logits_host
+            with _tel.span("decode.prefill.dispatch"):
+                tok_nd = nd.array(tokens, ctx=self.device)
+                len_nd = nd.array(lengths, ctx=self.device)
+                sig = io_signature([tok_nd, len_nd])
+                if sig not in self._prefill_sigs:
+                    if sig in self._block.compiled_signatures(
+                            training=False):
+                        self._prefill_sigs.add(sig)
+                    elif self._warmed:
+                        self._miss("prefill", (b, s))
+                with autograd.pause(train_mode=False):
+                    logits, kv = self._block(tok_nd, len_nd)
+                self._prefill_sigs.add(sig)
+                commit = self._commit_fn(b, s)
+                cache = self.cache
+                pools = cache.pools
+                kv_raw, logits_raw = kv.data, logits.data
+                if self._replicate is not None:
+                    kv_raw = self._replicate(kv_raw)
+                    logits_raw = self._replicate(logits_raw)
+                out = commit(
+                    self._params, kv_raw, logits_raw,
+                    lengths.astype("int32"), tables.astype("int32"),
+                    keys.astype("uint32"), np.zeros((b,), "int32"),
+                    temps.astype("float32"), *pools)
+                if _san.donation:
+                    # the commit donated the page pools: poison the
+                    # pre-call arrays so any stray alias raises naming
+                    # this site
+                    _san.poison(list(pools), "decode.prefill_commit")
+                cache.set_pools(out[1:])
+            with _tel.span("decode.prefill.fetch"):
+                # the commit donates the pools only, so the logits are
+                # still readable after it
+                logits_host = (np.asarray(logits_raw, "float32")
+                               if cache.prefix_sharing else None)
+                first = np.asarray(out[0])
+        return first, logits_host
 
     def step(self, tokens, positions, tables, keys, steps, temps):
         """One decode step for a batch padded to a batch bucket (padded
@@ -531,18 +541,21 @@ class DecodeRuntime:
         b = tokens.shape[0]
         fn = self._step_fn(b)
         with _tel.span("decode.step", model=self.name, batch=b):
-            cache = self.cache
-            pools = cache.pools
-            out = fn(
-                self._params, tokens.astype("int32"),
-                positions.astype("int32"), tables.astype("int32"),
-                keys.astype("uint32"), steps.astype("int32"),
-                temps.astype("float32"), *pools)
-            if _san.donation:
-                # the step donated the page pools (see prefill above)
-                _san.poison(list(pools), "decode.step")
-            cache.set_pools(out[1:])
-        return np.asarray(out[0])
+            with _tel.span("decode.step.dispatch"):
+                cache = self.cache
+                pools = cache.pools
+                out = fn(
+                    self._params, tokens.astype("int32"),
+                    positions.astype("int32"), tables.astype("int32"),
+                    keys.astype("uint32"), steps.astype("int32"),
+                    temps.astype("float32"), *pools)
+                if _san.donation:
+                    # the step donated the page pools (see prefill above)
+                    _san.poison(list(pools), "decode.step")
+                cache.set_pools(out[1:])
+            with _tel.span("decode.step.fetch"):
+                nxt = np.asarray(out[0])
+        return nxt
 
     def verify(self, tokens, positions, n_draft, tables, keys, steps,
                temps):
@@ -558,18 +571,22 @@ class DecodeRuntime:
         fn = self._verify_fn(b, k1 - 1)
         with _tel.span("decode.verify", model=self.name, batch=b,
                        k=k1 - 1):
-            cache = self.cache
-            pools = cache.pools
-            out = fn(
-                self._params, tokens.astype("int32"),
-                positions.astype("int32"), n_draft.astype("int32"),
-                tables.astype("int32"), keys.astype("uint32"),
-                steps.astype("int32"), temps.astype("float32"), *pools)
-            if _san.donation:
-                # the verify donated the page pools (see step above)
-                _san.poison(list(pools), "decode.verify")
-            cache.set_pools(out[2:])
-        return np.asarray(out[0]), np.asarray(out[1])
+            with _tel.span("decode.verify.dispatch"):
+                cache = self.cache
+                pools = cache.pools
+                out = fn(
+                    self._params, tokens.astype("int32"),
+                    positions.astype("int32"), n_draft.astype("int32"),
+                    tables.astype("int32"), keys.astype("uint32"),
+                    steps.astype("int32"), temps.astype("float32"),
+                    *pools)
+                if _san.donation:
+                    # the verify donated the page pools (see step above)
+                    _san.poison(list(pools), "decode.verify")
+                cache.set_pools(out[2:])
+            with _tel.span("decode.verify.fetch"):
+                target, n_acc = np.asarray(out[0]), np.asarray(out[1])
+        return target, n_acc
 
     def sample_first(self, logits_row, key, temp):
         """Sample a prefix-hit admission's first token from the cached

@@ -79,9 +79,14 @@ class TokenStream:
         self._exc = None
         self._future = None       # attached by stream()/submit's caller
         self._abort = None        # scheduler abort hook for running requests
+        # perf_counter of the first _put: where the scheduler thread handed
+        # the first token over (the gateway's first-frame span starts here)
+        self.t_first_put = None
 
     # ------------------------------- producer (scheduler worker thread)
     def _put(self, token):
+        if self.t_first_put is None:
+            self.t_first_put = time.perf_counter()
         with self._cond:
             self._pending.append(int(token))
             self._cond.notify_all()
@@ -375,11 +380,21 @@ class DecodeScheduler:
             # worker's boundary sweep
             sink._abort = lambda: self._abort_request(req)
         if _tel.enabled:
-            # trace root: the request's id; its lane carries every hop
-            # from here to eviction (admission, prefill, each ride)
-            req.ctx = _trace.start("decode.submit", model=rt.name,
-                                   prompt_len=int(prompt.size),
-                                   max_new=max_new)
+            # the request's trace: a child of the context active on the
+            # caller's thread (the gateway's wire-side root, a fleet
+            # owner's hop), a root of its own when there is none.  Its
+            # lane carries every hop from here to eviction (admission,
+            # prefill, each ride)
+            parent = _trace.current()
+            if parent is None:
+                req.ctx = _trace.start("decode.submit", model=rt.name,
+                                       prompt_len=int(prompt.size),
+                                       max_new=max_new)
+            else:
+                link = _trace.child(parent)
+                _tel.instant("decode.submit", trace=link, model=rt.name,
+                             prompt_len=int(prompt.size), max_new=max_new)
+                req.ctx = _trace.TraceContext(link[0], link[1])
             req.lane = req.ctx.trace_id
         with self._lock:
             if self._closed:
@@ -507,43 +522,61 @@ class DecodeScheduler:
             self._worker.start()
 
     def _run(self):
-        while True:
+        while self._turn():
+            pass
+        with self._lock:
+            self._not_full.notify_all()
+
+    def _turn(self):
+        """One turn of the worker's loop: wait for work, run one boundary.
+        False once the scheduler has shut down.  Each turn is one trace
+        root, so its phase spans (``decode.idle``, ``decode.boundary`` and
+        everything under it) carry ``parent_id`` and self times add up."""
+        with _trace.use(_trace.start() if _tel.enabled else None):
             with self._lock:
-                while not self._queue and not self._active:
-                    if self._closed:
-                        return
-                    self._not_empty.wait()
+                if not self._queue and not self._active:
+                    with _tel.span("decode.idle"):
+                        while not self._queue and not self._active:
+                            if self._closed:
+                                return False
+                            self._not_empty.wait()
                 if self._closed and not self._drain:
                     self._abort_locked()
-                    break
+                    return False
             self._boundary()
             with self._lock:
                 if self._closed and not self._active and \
                         (not self._drain or not self._queue):
                     self._shed_queue_locked("shutdown")
-                    break
-        with self._lock:
-            self._not_full.notify_all()
+                    return False
+        return True
 
     def _boundary(self):
         """One step boundary — admit under the lock, then prefill the
         joins and step the batch outside it.  The ONE body both the live
         worker and ``close()``'s inline settle run, so the two paths can
         never diverge."""
-        self._sweep_aborted()
-        with self._lock:
-            joining = self._admit_locked()
-            self._not_full.notify_all()
-            if _tel.enabled:
-                _tel.gauge("decode.queue_depth", len(self._queue),
-                           model=self._runtime.name)
-        try:
-            if joining:
-                self._prefill(joining)
-            if self._active:
-                self._step()
-        except BaseException as e:
-            self._fail_active(e, joining)
+        with _tel.span("decode.boundary",
+                       active=len(self._active)) as boundary:
+            # admission, the lock's wait included
+            with _tel.span("decode.admit",
+                           queued=len(self._queue)) as admit:
+                self._sweep_aborted()
+                with self._lock:
+                    joining = self._admit_locked()
+                    self._not_full.notify_all()
+                    if _tel.enabled:
+                        _tel.gauge("decode.queue_depth", len(self._queue),
+                                   model=self._runtime.name)
+                admit.set(admitted=len(joining))
+            boundary.set(joining=len(joining))
+            try:
+                if joining:
+                    self._prefill(joining)
+                if self._active:
+                    self._step()
+            except BaseException as e:
+                self._fail_active(e, joining)
 
     def _sweep_aborted(self):
         """Evict requests whose client gave up (stream cancel / hung-up
@@ -692,9 +725,6 @@ class DecodeScheduler:
         now = time.perf_counter()
         req.ttft_ms = (now - req.t_submit) * 1e3
         if _tel.enabled:
-            _tel.count("decode.ttft_ms", round(req.ttft_ms, 3),
-                       model=rt.name)
-            _tel.record_span("decode.ttft", req.t_submit, now, model=rt.name)
             _tel.observe("decode.ttft_ms", req.ttft_ms)
             _tel.count("decode.tokens", 1, model=rt.name)
             _tel.count("decode.prefill_skips", model=rt.name)
@@ -716,65 +746,66 @@ class DecodeScheduler:
 
     def _prefill_group(self, reqs, s):
         rt, cache = self._runtime, self._cache
-        b = rt.batch_bucket_for(len(reqs))
-        tokens = np.zeros((b, s), "int32")
-        lengths = np.ones((b,), "int32")
-        tables = np.zeros((b, cache.max_pages_per_seq), "int32")
-        keys = np.zeros((b, 2), "uint32")
-        temps = np.zeros((b,), "float32")
-        for r, req in enumerate(reqs):
-            tokens[r, :req.prompt.size] = req.prompt
-            lengths[r] = req.prompt.size
-            # write_table: a partial prefix hit re-runs the full dense
-            # prefill (bitwise the cold computation) but masks its shared
-            # pages to the trash page at commit — their content is
-            # already paged in and possibly read by live sequences
-            tables[r] = req.slot.write_table()
-            keys[r] = req.key
-            temps[r] = req.temp
-        _flight.record("decode.prefill", detail=rt.name, value=len(reqs))
+        with _tel.span("decode.prefill.prepare", rows=len(reqs),
+                       seq_bucket=int(s)):
+            b = rt.batch_bucket_for(len(reqs))
+            tokens = np.zeros((b, s), "int32")
+            lengths = np.ones((b,), "int32")
+            tables = np.zeros((b, cache.max_pages_per_seq), "int32")
+            keys = np.zeros((b, 2), "uint32")
+            temps = np.zeros((b,), "float32")
+            for r, req in enumerate(reqs):
+                tokens[r, :req.prompt.size] = req.prompt
+                lengths[r] = req.prompt.size
+                # write_table: a partial prefix hit re-runs the full dense
+                # prefill (bitwise the cold computation) but masks its
+                # shared pages to the trash page at commit — their content
+                # is already paged in and possibly read by live sequences
+                tables[r] = req.slot.write_table()
+                keys[r] = req.key
+                temps[r] = req.temp
+            _flight.record("decode.prefill", detail=rt.name,
+                           value=len(reqs))
         t_pre = time.perf_counter()
         first, logits = rt.prefill(tokens, lengths, tables, keys, temps)
-        if logits is not None:
-            # publish BEFORE any decode step: each slot's prompt pages
-            # hold exactly the prompt K/V right now (generated tokens
-            # land later), so the index copies/pins clean pages
+        with _tel.span("decode.prefill.fanout", rows=len(reqs),
+                       seq_bucket=int(s)):
+            if logits is not None:
+                # publish BEFORE any decode step: each slot's prompt pages
+                # hold exactly the prompt K/V right now (generated tokens
+                # land later), so the index copies/pins clean pages
+                for r, req in enumerate(reqs):
+                    cache.publish(req.slot, req.prompt, logits[r])
+            now = time.perf_counter()
+            done = []
             for r, req in enumerate(reqs):
-                cache.publish(req.slot, req.prompt, logits[r])
-        now = time.perf_counter()
-        done = []
-        for r, req in enumerate(reqs):
-            req.ttft_ms = (now - req.t_submit) * 1e3
+                req.ttft_ms = (now - req.t_submit) * 1e3
+                if _tel.enabled:
+                    _tel.observe("decode.ttft_ms", req.ttft_ms)
+                    if req.ctx is not None:
+                        # the request's own lane: time queued, then the
+                        # prefill bucket it rode — both linked to its root
+                        _tel.record_span("decode.queue_wait", req.t_submit,
+                                         t_pre, tid=req.lane, trace=req.ctx,
+                                         model=rt.name)
+                        _tel.record_span("decode.ride_prefill", t_pre, now,
+                                         tid=req.lane, trace=req.ctx,
+                                         model=rt.name, seq_bucket=int(s),
+                                         batch_bucket=int(b))
+                req.cur = int(first[r])
+                req.tokens.append(req.cur)
+                if req.sink is not None:
+                    req.sink._put(req.cur)
+                req.step_idx = 1
+                if self._is_finished(req):
+                    done.append(req)
+                else:
+                    self._active.append(req)
             if _tel.enabled:
-                _tel.count("decode.ttft_ms", round(req.ttft_ms, 3),
-                           model=rt.name)
-                _tel.record_span("decode.ttft", req.t_submit, now,
-                                 model=rt.name)
-                _tel.observe("decode.ttft_ms", req.ttft_ms)
-                if req.ctx is not None:
-                    # the request's own lane: time queued, then the
-                    # prefill bucket it rode — both linked to its root
-                    _tel.record_span("decode.queue_wait", req.t_submit,
-                                     t_pre, tid=req.lane, trace=req.ctx,
-                                     model=rt.name)
-                    _tel.record_span("decode.prefill", t_pre, now,
-                                     tid=req.lane, trace=req.ctx,
-                                     model=rt.name, seq_bucket=int(s),
-                                     batch_bucket=int(b))
-            req.cur = int(first[r])
-            req.tokens.append(req.cur)
-            if req.sink is not None:
-                req.sink._put(req.cur)
-            req.step_idx = 1
-            if self._is_finished(req):
-                done.append(req)
-            else:
-                self._active.append(req)
-        if _tel.enabled:
-            _tel.count("decode.tokens", len(reqs), model=rt.name)
-            _tel.count("decode.prefills", len(reqs), model=rt.name)
-        for req in done:
-            self._finish(req)
+                _tel.count("decode.tokens", len(reqs), model=rt.name)
+                _tel.count("decode.prefills", len(reqs), model=rt.name)
+            for req in done:
+                self._finish(req)
         self._consecutive_failures = 0
 
     def _step(self):
@@ -786,15 +817,55 @@ class DecodeScheduler:
         (:meth:`_spec_step`) — non-speculating rows ride along with
         ``n_draft = 0``, which is bitwise the plain step for them."""
         rt, cache = self._runtime, self._cache
-        if _faults.active:
-            _faults.check("decode.step")
-        if _san.slots:
-            for req in self._active:
-                cache.check_slot(req.slot)
-        drafts = self._collect_drafts()
+        n = len(self._active)
+        b = rt.batch_bucket_for(n)
+        with _tel.span("decode.step.prepare", rows=n, batch_bucket=b):
+            if _faults.active:
+                _faults.check("decode.step")
+            if _san.slots:
+                for req in self._active:
+                    cache.check_slot(req.slot)
+            drafts = self._collect_drafts()
+            if drafts is None:
+                args = self._step_args(b)
         if drafts is not None:
             self._spec_step(drafts)
             return
+        _flight.record("decode.step", detail=rt.name, value=n)
+        t0 = time.perf_counter()
+        nxt = rt.step(*args)
+        t1 = time.perf_counter()
+        with _tel.span("decode.step.fanout", rows=n, batch_bucket=b):
+            if _tel.enabled:
+                _tel.count("decode.steps", model=rt.name)
+                _tel.count("decode.tokens", n, model=rt.name)
+                _tel.observe("decode.step_ms", (t1 - t0) * 1e3)
+                for req in self._active:
+                    if req.ctx is not None:
+                        # every step the request rode, on its own lane —
+                        # "which steps served me" is visible per request
+                        _tel.record_span("decode.ride_step", t0, t1,
+                                         tid=req.lane, trace=req.ctx,
+                                         model=rt.name, batch=n)
+            still = []
+            for r, req in enumerate(self._active):
+                req.cur = int(nxt[r])
+                req.tokens.append(req.cur)
+                if req.sink is not None:
+                    req.sink._put(req.cur)
+                req.position += 1
+                req.step_idx += 1
+                if self._is_finished(req):
+                    self._finish(req)
+                else:
+                    still.append(req)
+            self._active = still
+        self._consecutive_failures = 0
+
+    def _step_args(self, b):
+        """The plain step's host arrays for the active batch, padded to
+        batch bucket ``b``, behind the copy-on-write fence."""
+        cache = self._cache
         if cache.prefix_sharing:
             # copy-on-write fence: the page each row is about to write
             # must be exclusively owned.  Admission already privatized
@@ -805,8 +876,6 @@ class DecodeScheduler:
             for req in self._active:
                 cache.ensure_writable(req.slot,
                                       req.position // cache.page_size)
-        n = len(self._active)
-        b = rt.batch_bucket_for(n)
         tokens = np.zeros((b,), "int32")
         positions = np.zeros((b,), "int32")
         tables = np.zeros((b, cache.max_pages_per_seq), "int32")
@@ -820,35 +889,7 @@ class DecodeScheduler:
             keys[r] = req.key
             steps[r] = req.step_idx
             temps[r] = req.temp
-        _flight.record("decode.step", detail=rt.name, value=n)
-        t0 = time.perf_counter()
-        nxt = rt.step(tokens, positions, tables, keys, steps, temps)
-        t1 = time.perf_counter()
-        if _tel.enabled:
-            _tel.count("decode.steps", model=rt.name)
-            _tel.count("decode.tokens", n, model=rt.name)
-            _tel.observe("decode.step_ms", (t1 - t0) * 1e3)
-            for req in self._active:
-                if req.ctx is not None:
-                    # every step the request rode, on its own lane —
-                    # "which steps served me" is visible per request
-                    _tel.record_span("decode.ride_step", t0, t1,
-                                     tid=req.lane, trace=req.ctx,
-                                     model=rt.name, batch=n)
-        still = []
-        for r, req in enumerate(self._active):
-            req.cur = int(nxt[r])
-            req.tokens.append(req.cur)
-            if req.sink is not None:
-                req.sink._put(req.cur)
-            req.position += 1
-            req.step_idx += 1
-            if self._is_finished(req):
-                self._finish(req)
-            else:
-                still.append(req)
-        self._active = still
-        self._consecutive_failures = 0
+        return tokens, positions, tables, keys, steps, temps
 
     def _collect_drafts(self):
         """Per-row draft proposals for this boundary, or ``None`` when
@@ -897,10 +938,26 @@ class DecodeScheduler:
         Rolled-back K/V needs no cleanup — positions past the new
         ``req.position`` stay causally masked until a later boundary
         overwrites them."""
-        rt, cache = self._runtime, self._cache
+        rt = self._runtime
         n = len(self._active)
         kb = rt.spec_bucket_for(max(d.size for d in drafts))
         b = rt.batch_bucket_for(n)
+        # the turn's second prepare span: _step's covered the drafting
+        with _tel.span("decode.step.prepare", rows=n, batch_bucket=b):
+            args = self._verify_args(drafts, b, kb)
+        _flight.record("decode.spec_verify", detail=rt.name, value=n)
+        t0 = time.perf_counter()
+        target, n_acc = rt.verify(*args)
+        t1 = time.perf_counter()
+        with _tel.span("decode.step.fanout", rows=n, batch_bucket=b):
+            self._commit_verified(drafts, target, n_acc, kb, t0, t1)
+        self._consecutive_failures = 0
+
+    def _verify_args(self, drafts, b, kb):
+        """The verify program's host arrays: ``[cur, d_1 .. d_K]`` per
+        active row, padded to batch bucket ``b`` and spec bucket ``kb``,
+        every page the candidate span touches made private first."""
+        cache = self._cache
         tokens = np.zeros((b, kb + 1), "int32")
         positions = np.zeros((b,), "int32")
         n_draft = np.zeros((b,), "int32")
@@ -929,11 +986,14 @@ class DecodeScheduler:
             if _san.slots:
                 _san.check_kv_write_span(cache, req.slot, req.position,
                                          int(d.size) + 1)
-        _flight.record("decode.spec_verify", detail=rt.name, value=n)
-        t0 = time.perf_counter()
-        target, n_acc = rt.verify(tokens, positions, n_draft, tables,
-                                  keys, steps, temps)
-        t1 = time.perf_counter()
+        return tokens, positions, n_draft, tables, keys, steps, temps
+
+    def _commit_verified(self, drafts, target, n_acc, kb, t0, t1):
+        """Fan one verify's result out: per row the accepted drafts plus
+        the target's own token, to the token lists, the sinks and the
+        drafter's windows; finished rows leave the batch."""
+        rt = self._runtime
+        n = len(self._active)
         committed = 0
         still = []
         for r, (req, d) in enumerate(zip(self._active, drafts)):
@@ -982,7 +1042,6 @@ class DecodeScheduler:
                                      model=rt.name, batch=n,
                                      spec_k=int(kb))
         self._active = still
-        self._consecutive_failures = 0
 
     @staticmethod
     def _is_finished(req):

@@ -359,6 +359,8 @@ class Gateway:
         try:
             # the request's trace lane roots HERE, at the socket — the
             # scheduler's submit/prefill/ride spans nest under the wire
+            # (DecodeScheduler.submit hangs decode.submit off the context
+            # active on this thread), and so does the first frame's egress
             ctx = _trace.start("gateway.request", route="generate",
                                model=str(model),
                                stream=stream) if _tel.enabled else None
@@ -379,7 +381,8 @@ class Gateway:
                 _tel.observe("gateway.queue_wait_ms",
                              (time.perf_counter() - t_wire) * 1e3)
             if stream:
-                self._stream_response(h, model, src, t_wire, source=sess)
+                self._stream_response(h, model, src, t_wire, source=sess,
+                                      ctx=ctx)
             else:
                 self._buffered_response(h, model, src, t_wire, source=sess)
         finally:
@@ -441,7 +444,8 @@ class Gateway:
             if _tel.enabled:
                 _tel.observe("gateway.bytes_out", float(bytes_out))
 
-    def _stream_response(self, h, model, sink, t_wire, source=None):
+    def _stream_response(self, h, model, sink, t_wire, source=None,
+                         ctx=None):
         self._start_sse(h, model)
         bytes_out = 0
         first = True
@@ -455,8 +459,16 @@ class Gateway:
                 h.wfile.flush()
                 bytes_out += len(frame)
                 if first and _tel.enabled:
+                    now = time.perf_counter()
                     _tel.observe("gateway.ttft_streamed_ms",
-                                 (time.perf_counter() - t_wire) * 1e3)
+                                 (now - t_wire) * 1e3)
+                    if ctx is not None:
+                        # the hand-over from the scheduler's thread to
+                        # this writer: first token put -> first frame
+                        # flushed, on the request's own lane
+                        _tel.record_span("gateway.first_frame",
+                                         sink.t_first_put, now,
+                                         tid=ctx.trace_id, trace=ctx)
                 first = False
             res = sink.result()
             final = {"done": True, "finish_reason": res.finish_reason,

@@ -31,6 +31,12 @@ Design constraints (mirroring ``profiler.h``'s lock-free ring):
   ``tid``/``pid``/``trace`` lane overrides for scopes measured on behalf
   of another lane (a decode request's ride through the batch, a worker
   process's decode span emitted by the consumer).
+- **On the profiler's clock.** Every :class:`Span` also enters a
+  ``jax.profiler.TraceAnnotation`` (:func:`annotation`, the one place that
+  talks to it), so while a ``jax.profiler`` session runs, each framework
+  span sits in the ``/host:CPU`` plane of the ``.xplane.pb`` under its own
+  name, on the thread that ran it, on the device trace's time axis.  Spans
+  recorded after the fact (``record_span``) stay on the bus clock only.
 
 Enable via ``MXNET_TELEMETRY=1`` in the environment (checked at import) or
 ``mxnet_tpu.telemetry.enable()``.
@@ -47,6 +53,7 @@ __all__ = ["enable", "disable", "is_enabled", "span", "count", "gauge",
            "instant", "counter_sample", "counter_value", "snapshot", "reset",
            "events", "record_span", "observe", "histogram_quantile",
            "histograms", "new_id", "trace_current", "open_spans",
+           "annotation",
            "DEFAULT_CAPACITY", "HIST_BOUNDS"]
 
 DEFAULT_CAPACITY = 65536
@@ -327,6 +334,25 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+_TraceAnnotation = None     # jax.profiler's class, imported at first use
+
+
+def annotation(name, attrs=None):
+    """Enter and return a ``jax.profiler.TraceAnnotation`` called ``name``
+    carrying the scalar ``attrs`` — the ONE place the framework talks to
+    it (:class:`Span` and ``profiler._Scope`` both come here).  The caller
+    leaves it with ``__exit__(None, None, None)``.  With no profiler
+    session running an annotation records nothing."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    scalars = {k: v for k, v in attrs.items()
+               if isinstance(v, (bool, int, float, str))} if attrs else {}
+    ann = _TraceAnnotation(name, **scalars)
+    ann.__enter__()
+    return ann
+
 
 class Span:
     """Timed scope that lands as one complete ('X') trace event on exit
@@ -337,15 +363,20 @@ class Span:
     pushes it, so nested spans form a parent→child chain the exporter can
     render as flow arrows; exit stamps ``trace_id``/``span_id``/
     ``parent_id`` into the attrs.  Open spans are registered for the
-    flight recorder's "what was in flight" post-mortem section."""
+    flight recorder's "what was in flight" post-mortem section.
 
-    __slots__ = ("name", "attrs", "_t0", "_trace")
+    The span is also a profiler annotation (:func:`annotation`) with the
+    attrs it was opened with; ones :meth:`set` later reach the bus event
+    only."""
+
+    __slots__ = ("name", "attrs", "_t0", "_trace", "_ann")
 
     def __init__(self, name, attrs):
         self.name = name
         self.attrs = attrs
         self._t0 = None
         self._trace = None
+        self._ann = None
 
     def set(self, **attrs):
         """Attach attributes mid-span (shows in the trace event args)."""
@@ -362,9 +393,13 @@ class Span:
         self._t0 = time.perf_counter()
         _open_spans[id(self)] = (self.name, self._t0,
                                  threading.get_ident())
+        self._ann = annotation(self.name, self.attrs)
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         # the stack pop must happen even if the bus was disabled mid-span,
         # or the thread's context stack would corrupt for every later span
         if self._trace is not None:

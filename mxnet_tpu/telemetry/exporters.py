@@ -90,10 +90,13 @@ def dump_metrics():
         for labels, val in sorted(
                 snap["counters_by_label"].get(name, {}).items()):
             lines.append(f"{metric}{labels} {val}")
+    typed = set()
     for name in sorted(snap["gauges"]):
         base, _, labels = name.partition("{")
         metric = _prom_name(base)
-        lines.append(f"# TYPE {metric} gauge")
+        if metric not in typed:     # one TYPE line per family, not per
+            typed.add(metric)       # label set
+            lines.append(f"# TYPE {metric} gauge")
         suffix = "{" + labels if labels else ""
         lines.append(f"{metric}{suffix} {snap['gauges'][name]}")
     for name in sorted(snap["spans"]):

@@ -475,7 +475,9 @@ def test_decode_telemetry_counters(runtime):
         == 5
     assert c["decode.tokens"] == 20
     assert c["decode.evictions"] == 5
-    assert c["decode.ttft_ms"] > 0
+    ttft = snap["histograms"]["decode.ttft_ms"]
+    assert ttft["count"] == 5 and ttft["sum"] > 0
+    assert "decode.ttft_ms" not in c      # one type per metric family
     assert c.get("decode.compile_miss") in (None, 0)
     assert "decode.kv_occupancy" in snap["gauges"]
     assert "decode.kv_bytes_per_token" in snap["gauges"]

@@ -152,6 +152,43 @@ def test_generate_buffered_vs_streamed_bitwise(decode_sess):
         assert [t["token"] for t in toks] == buffered["token_ids"]
 
 
+def test_streamed_request_is_one_trace_from_socket_to_first_frame(
+        decode_sess):
+    """One trace id per request: the wire-side root, the scheduler's
+    submit, queue wait and prefill ride, and the first frame's egress, each
+    hop linked to the one before."""
+    from mxnet_tpu.telemetry import bus
+    telemetry.enable()
+    with Gateway() as gw:
+        gw.add_decode("tiny", decode_sess)
+        st, _, raw = _post(gw.port, "/v1/generate",
+                           {"model": "tiny", "prompt": [41, 7, 19, 33, 2, 50],
+                            "max_new_tokens": 5, "stream": True})
+        assert st == 200 and _sse_frames(raw)[-1] == "[DONE]"
+    evs = bus.events()
+    root = [e for e in evs if e[1] == "gateway.request"]
+    assert len(root) == 1
+    trace_id = root[0][6]["trace_id"]
+    assert root[0][6]["span_id"] == trace_id
+    lane = {e[1]: e for e in evs if e[1] != "decode.ride_step"
+            and (e[6] or {}).get("trace_id") == trace_id}
+    assert set(lane) == {"gateway.request", "decode.submit",
+                         "decode.queue_wait", "decode.ride_prefill",
+                         "gateway.first_frame", "decode.evict"}
+    submit = lane["decode.submit"][6]
+    assert submit["parent_id"] == trace_id
+    for hop in ("decode.queue_wait", "decode.ride_prefill", "decode.evict"):
+        assert lane[hop][6]["parent_id"] == submit["span_id"], hop
+        assert lane[hop][5] == trace_id        # the request's own lane
+    first = lane["gateway.first_frame"]
+    assert first[6]["parent_id"] == trace_id and first[5] == trace_id
+    # first token put (end of the prefill ride's fan-out) -> frame flushed
+    ride = lane["decode.ride_prefill"]
+    assert first[3] >= ride[3] + ride[4] - 1e-3 and 0 <= first[4] < 5e6
+    rides = [e for e in evs if e[1] == "decode.ride_step"]
+    assert rides and all(e[6]["trace_id"] == trace_id for e in rides)
+
+
 def test_generate_default_model_and_errors(decode_sess):
     with Gateway() as gw:
         gw.add_decode("tiny", decode_sess)

@@ -152,7 +152,15 @@ def test_dump_metrics_prometheus_format():
 
 
 # ------------------------------------------------------- instrumented paths
-def test_eager_dispatch_counters():
+def test_eager_dispatch_counters(monkeypatch):
+    # the eager jit cache is process-wide: a third distinct scalar seen by
+    # `_mul_scalar` anywhere earlier on this worker moves the op to the
+    # no-jit set, and a warm entry turns the first call into a hit.  Start
+    # from empty caches so the counts do not depend on what ran before.
+    from mxnet_tpu.ndarray import ndarray as nd_mod
+    monkeypatch.setattr(nd_mod, "_EAGER_JIT", {})
+    monkeypatch.setattr(nd_mod, "_EAGER_NOJIT", set())
+    monkeypatch.setattr(nd_mod, "_EAGER_MISSES", {})
     telemetry.enable()
     x = mx.nd.ones((4, 4))
     for _ in range(3):
@@ -162,7 +170,8 @@ def test_eager_dispatch_counters():
     c = snap["counters"]
     assert c["dispatch.op_calls"] >= 3
     # first _mul_scalar call compiles (miss), later ones hit the cache
-    assert c.get("dispatch.jit_cache_hits", 0) >= 1
+    assert c["dispatch.jit_cache_misses"] >= 1
+    assert c.get("dispatch.jit_cache_hits", 0) >= 2
     labeled = snap["counters_by_label"]["dispatch.op_calls"]
     assert any("_mul_scalar" in k for k in labeled)
 
@@ -496,3 +505,77 @@ def test_counter_sampler_all_counters_and_pause():
                     if e[0] == "C"]) > n_disabled
     finally:
         telemetry.stop_counter_sampler()
+
+
+# ------------------------------------------- bus spans on the profiler's clock
+def test_span_off_builds_no_annotation(monkeypatch):
+    """With the bus off ``span()`` hands out the one shared no-op and never
+    reaches the profiler: the untraced hot path constructs nothing."""
+    from mxnet_tpu.telemetry import bus
+    built = []
+    monkeypatch.setattr(bus, "annotation",
+                        lambda *a, **k: built.append(a) or None)
+    sp = telemetry.span("x.off", rows=3)
+    assert sp is bus._NOOP and telemetry.span("x.other") is sp
+    with sp as inner:
+        inner.set(late=1)
+    assert built == [] and bus._NOOP.attrs == {}
+    telemetry.enable()
+    with telemetry.span("x.on", rows=3):
+        pass
+    assert [a[0] for a in built] == ["x.on"]
+
+
+def test_span_is_a_profiler_annotation_on_its_own_thread(tmp_path):
+    """With the bus on and a ``jax.profiler`` session open, a span sits in
+    the ``/host:CPU`` plane under its own name, with its scalar attrs, on
+    the line of the thread that ran it; ``profiler._Scope`` goes through the
+    same helper."""
+    import glob
+    import threading
+    import time
+
+    import jax
+    telemetry.enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        def work():
+            with telemetry.span("toy.outer", rows=3, model="m",
+                                shape=(1, 2)) as sp:
+                sp.set(late=5)
+                with telemetry.span("toy.inner"):
+                    time.sleep(0.005)
+        th = threading.Thread(target=work)
+        th.start()
+        th.join(30)
+        assert not th.is_alive()
+        with telemetry.span("toy.main_thread"):
+            pass
+        with mx.profiler.Event("toy_user_event"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    host = [p for p in jax.profiler.ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"][0]
+    lines = {}
+    for i, line in enumerate(host.lines):
+        for ev in line.events:
+            if ev.name.startswith(("toy.", "toy_")):
+                lines.setdefault(i, {})[ev.name] = (
+                    ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats))
+    by_names = {frozenset(v): v for v in lines.values()}
+    worker = by_names[frozenset({"toy.outer", "toy.inner"})]
+    main = by_names[frozenset({"toy.main_thread", "toy_user_event"})]
+    # attrs the span was opened with, scalars only; later ones stay on
+    # the bus
+    assert worker["toy.outer"][2] == {"rows": 3, "model": "m"}
+    o, i = worker["toy.outer"], worker["toy.inner"]
+    assert o[0] <= i[0] and i[1] <= o[1] and i[1] - i[0] >= 4_000_000
+    assert main["toy.main_thread"][1] <= main["toy_user_event"][0]
+    # the bus event keeps everything, on the bus clock
+    ev = [e for e in telemetry.trace_events() if e["name"] == "toy.outer"][0]
+    assert ev["args"]["late"] == 5 and ev["args"]["rows"] == 3

@@ -307,7 +307,7 @@ class TestDecodeRequestLane:
         assert len(roots) == 1
         lane = _attrs(roots[0])["trace_id"]
         names = [e[1] for e in _lane_events(lane)]
-        for hop in ("decode.queue_wait", "decode.prefill",
+        for hop in ("decode.queue_wait", "decode.ride_prefill",
                     "decode.ride_step", "decode.evict"):
             assert hop in names, f"lane missing {hop}: {names}"
         assert names.count("decode.ride_step") >= 1
@@ -342,7 +342,7 @@ class TestDecodeRequestLane:
         assert lanes[0] != lanes[1]
         for lane in lanes:
             names = [e[1] for e in _lane_events(lane)]
-            for hop in ("decode.queue_wait", "decode.prefill",
+            for hop in ("decode.queue_wait", "decode.ride_prefill",
                         "decode.ride_step", "decode.evict"):
                 assert hop in names, f"lane {lane:#x} missing {hop}"
             ids = {_attrs(e)["trace_id"] for e in _lane_events(lane)}
@@ -358,6 +358,134 @@ class TestDecodeRequestLane:
         hist = snap["histograms"]
         assert hist["decode.ttft_ms"]["count"] == 2
         assert hist["decode.step_ms"]["count"] >= 1
+
+
+# ------------------------------------------------- scheduler phase spans
+def _tree(events):
+    """``{span_id: event}`` and ``{parent_id: [children]}`` of bus spans."""
+    by_id = {_attrs(e)["span_id"]: e for e in events if "span_id" in _attrs(e)}
+    kids = {}
+    for e in by_id.values():
+        kids.setdefault(_attrs(e).get("parent_id"), []).append(e)
+    return by_id, kids
+
+
+class TestSchedulerPhases:
+    PHASES = {"decode.admit", "decode.prefill.prepare", "decode.prefill",
+              "decode.prefill.fanout", "decode.step.prepare", "decode.step",
+              "decode.step.fanout"}
+
+    @pytest.fixture(scope="class")
+    def served(self, runtime):
+        """Two requests through a live scheduler, the second joining the
+        first mid-flight, served once for the class (a repeated prompt
+        would hit the shared-prefix index and skip its prefill).  Returns
+        the worker thread's spans, the histograms and the exposition."""
+        telemetry.reset()
+        telemetry.enable()
+        sched = DecodeScheduler(runtime)
+        try:
+            deadline = time.perf_counter() + 60
+            while not any(n == "decode.idle" for n, _t, _i in
+                          bus.open_spans()) and \
+                    time.perf_counter() < deadline:
+                time.sleep(0.001)
+            a = sched.submit([11, 23, 35, 47, 59], max_new_tokens=12)
+            while not _spans("decode.ride_step") and \
+                    time.perf_counter() < deadline:
+                time.sleep(0.001)
+            b = sched.submit([58, 46, 34], max_new_tokens=3)
+            a.result(timeout=120), b.result(timeout=120)
+            tid = sched._worker.ident
+        finally:
+            sched.close(drain=True, timeout=30.0)
+        telemetry.gauge("t.depth", 1, model="a")
+        telemetry.gauge("t.depth", 2, model="b")
+        out = {"spans": [e for e in _spans() if e[5] == tid],
+               "histograms": telemetry.histograms(),
+               "metrics": telemetry.dump_metrics()}
+        telemetry.disable()
+        telemetry.reset()
+        return out
+
+    def test_turn_phases_nest_and_self_times_add_up(self, served):
+        served = served["spans"]
+        by_id, kids = _tree(served)
+        boundaries = [e for e in served if e[1] == "decode.boundary"]
+        assert len(boundaries) >= 8
+        seen = set()
+        for b in boundaries:
+            # a turn is one trace: its root is the boundary's parent
+            assert _attrs(b)["parent_id"] == _attrs(b)["trace_id"]
+
+            def self_us(e):
+                """Self time of e plus of everything under it, checking
+                that children lie inside e and do not overlap."""
+                mine = sorted(kids.get(_attrs(e)["span_id"], []),
+                              key=lambda c: c[3])
+                end = e[3]
+                for c in mine:
+                    assert c[3] >= end - 1e-3 and \
+                        c[3] + c[4] <= e[3] + e[4] + 1e-3, (e[1], c[1])
+                    end = c[3] + c[4]
+                own = e[4] - sum(c[4] for c in mine)
+                assert own >= -1e-3, (e[1], own)
+                return own + sum(self_us(c) for c in mine)
+
+            names = {c[1] for c in kids.get(_attrs(b)["span_id"], [])}
+            assert "decode.admit" in names and names <= self.PHASES, names
+            seen |= names
+            assert self_us(b) == pytest.approx(b[4], rel=1e-9, abs=1e-3)
+        assert seen == self.PHASES
+        joins = [b for b in boundaries if _attrs(b)["joining"]]
+        assert len(joins) >= 2 and any(_attrs(b)["active"] for b in joins)
+        admits = [e for e in served if e[1] == "decode.admit"]
+        assert sum(_attrs(e)["admitted"] for e in admits) == 2
+
+    def test_idle_wait_is_a_span_of_its_own_turn(self, served):
+        served = served["spans"]
+        idles = [e for e in served if e[1] == "decode.idle"]
+        assert idles, "the worker waited for the first request"
+        by_id, kids = _tree(served)
+        for e in idles:
+            # root of the turn is the parent; the boundary that follows the
+            # wait belongs to the same turn
+            turn = _attrs(e)["trace_id"]
+            assert _attrs(e)["parent_id"] == turn
+            after = [b for b in kids.get(turn, [])
+                     if b[1] == "decode.boundary"]
+            assert all(b[3] >= e[3] + e[4] - 1e-3 for b in after)
+
+    @pytest.mark.parametrize("call", ["decode.step", "decode.prefill"])
+    def test_runtime_span_ends_after_its_fetch(self, served, call):
+        """The runtime's span is the whole call: dispatch, then the fetch
+        of the sampled tokens, both inside it."""
+        hists, served = served["histograms"], served["spans"]
+        by_id, kids = _tree(served)
+        calls = [e for e in served if e[1] == call and "batch" in _attrs(e)]
+        assert calls
+        for e in calls:
+            parts = {c[1]: c for c in kids[_attrs(e)["span_id"]]}
+            assert set(parts) == {call + ".dispatch", call + ".fetch"}
+            d, f = parts[call + ".dispatch"], parts[call + ".fetch"]
+            assert e[3] <= d[3] and d[3] + d[4] <= f[3] + 1e-3
+            assert f[3] + f[4] <= e[3] + e[4] + 1e-3
+            assert e[4] >= d[4] + f[4] - 1e-3
+        # the scheduler's own bracket of the call holds the span
+        if call == "decode.step":
+            steps = hists["decode.step_ms"]
+            assert steps["sum"] >= sum(e[4] for e in calls) / 1e3 - 1e-3
+
+    def test_metrics_exposition_has_one_type_per_family(self, served):
+        text = served["metrics"]
+        typed = [ln.split()[2] for ln in text.splitlines()
+                 if ln.startswith("# TYPE ")]
+        assert len(typed) == len(set(typed)), sorted(
+            t for t in set(typed) if typed.count(t) > 1)
+        assert "# TYPE mxnet_decode_ttft_ms histogram" in text
+        assert "mxnet_decode_ttft_ms_count 2" in text
+        assert "mxnet_decode_ttft_calls" not in text     # the span is gone
+        assert 'mxnet_t_depth{model="b"} 2' in text
 
 
 # --------------------------------------------------------- io worker lanes
