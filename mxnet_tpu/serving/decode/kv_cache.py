@@ -3,12 +3,26 @@ refcounted shared-prefix pages, and optional int8-quantized pools.
 
 The decode batch's attention state lives on device as page-pool arrays
 per cache — ``k_pages`` / ``v_pages`` of shape ``(layers, num_pages,
-page_size, heads, head_dim)``.  A sequence owns a *slot* (its identity in
+page_size, heads * head_dim)``.  A sequence owns a *slot* (its identity in
 the allocator) and a fixed-length page table (``max_pages_per_seq``
 entries, padded with the reserved trash page 0) mapping logical token
 positions to physical pages.  Page 0 is never allocated: padded batch rows
 and padded prompt positions scatter their K/V there, so one compiled
 program per batch bucket serves every batch composition.
+
+**The pool's minor axis is the whole token row** (``heads * head_dim``
+values), not ``head_dim``.  A TPU array lives in (8, 128) tiles of its two
+minor dimensions; with ``head_dim`` = 64 minor-most the client pads the
+resident pool and stores it pages-minor, a layout no scatter or gather
+computes in, so every program that took the donated pools began and ended
+with a copy of each whole pool.  A row that fills whole lane tiles is
+resident unpadded in the layout the programs compute in, and they update
+the donated buffers in place.  **A pool is indexed once**: ``pool[i,
+tables]`` / ``pool.at[i, pages, offsets]``, never ``pool[i][tables]`` —
+the chained form materialises all ``num_pages`` pages of layer ``i``
+before gathering a row's few.  ``tests/test_chip_compile.py`` holds every
+program (step, verify, commit, copy-on-write; fp32, int8, fp8) to this: no
+temporary the size of a pool or of one layer of one.
 
 **Slot-generation discipline** (the ShmRing pattern from the input
 pipeline, generalized): every slot carries a recycle generation, bumped on
@@ -50,7 +64,8 @@ int8 relaxes is fidelity *versus the fp32 pools* (documented in
 ``docs/serving.md``).
 
 Sharding: pass ``mesh`` (+ ``kv_axis``) and the page pools are created
-under a ``NamedSharding`` over the heads axis, so the cache scales with
+under a ``NamedSharding`` over the row axis (a contiguous split of
+``heads * head_dim`` is a split by heads), so the cache scales with
 the mesh without changing any scheduler/runtime code (the SNIPPETS.md [1]
 GSPMD pattern).  Allocation state is host-side and tiny either way.
 
@@ -190,7 +205,8 @@ class PagedKVCache:
         LRU cap on published full-prompt entries.
     mesh : jax Mesh, optional
         When given, page pools are sharded ``NamedSharding(mesh,
-        P(None, None, None, kv_axis, None))`` — heads over the model axis.
+        P(None, None, None, kv_axis))`` — the row axis over the model
+        axis, whose size must divide ``num_heads`` (a split by heads).
     """
 
     def __init__(self, num_layers, num_heads, head_dim, page_size=16,
@@ -226,7 +242,7 @@ class PagedKVCache:
         self.prefix_sharing = bool(prefix_sharing)
         self._prefix_entry_cap = int(prefix_entries)
         shape = (self.num_layers, self.num_pages, self.page_size,
-                 self.num_heads, self.head_dim)
+                 self.num_heads * self.head_dim)
         pool_dtype = {"float32": self.dtype, "int8": "int8",
                       "fp8_e4m3": "float8_e4m3fn"}[kv_dtype]
         k = jnp.zeros(shape, pool_dtype)
@@ -237,8 +253,12 @@ class PagedKVCache:
         if mesh is not None:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec
+            if self.num_heads % mesh.shape[kv_axis]:
+                raise ValueError(
+                    f"num_heads={self.num_heads} is not divisible by the "
+                    f"mesh's {kv_axis!r} axis ({mesh.shape[kv_axis]})")
             sharding = NamedSharding(
-                mesh, PartitionSpec(None, None, None, kv_axis, None))
+                mesh, PartitionSpec(None, None, None, kv_axis))
             k = jax.device_put(k, sharding)
             v = jax.device_put(v, sharding)
             rep = NamedSharding(mesh, PartitionSpec())

@@ -114,12 +114,17 @@ def _kv_scatter(state, i, wp, woff, k, v):
     """Write one layer's new K/V rows into the paged pools at
     ``(page, offset)``, quantizing by sidecar arity: ``None`` = raw fp32,
     2 sidecars = fp8 per-row scale, 4 = int8 per-row scale/mid.  ``wp`` /
-    ``woff`` may be ``(B,)`` (step) or ``(B, K+1)`` (verify); ``k`` / ``v``
-    carry matching leading axes plus trailing ``(H, D)``."""
+    ``woff`` may be ``(B,)`` (step), ``(B, K+1)`` (verify) or ``(B, S)``
+    (the prefill's commit); ``k`` / ``v`` carry matching leading axes plus
+    trailing ``(H, D)``, which the quantizers reduce over before it is
+    flattened to the pool's row."""
+    def put(pool, x):
+        return pool.at[i, wp, woff].set(x.reshape(x.shape[:-2] + (-1,)))
+
     qs = state["q"]
     if qs is None:
-        state["k"] = state["k"].at[i, wp, woff].set(k)
-        state["v"] = state["v"].at[i, wp, woff].set(v)
+        state["k"] = put(state["k"], k)
+        state["v"] = put(state["v"], v)
         return
     if len(qs) == 2:
         kq, ksc = kv_quantize_rows_fp8(k)
@@ -129,20 +134,22 @@ def _kv_scatter(state, i, wp, woff, k, v):
         kq, ksc, kmd = kv_quantize_rows(k)
         vq, vsc, vmd = kv_quantize_rows(v)
         rows = (ksc, kmd, vsc, vmd)
-    state["k"] = state["k"].at[i, wp, woff].set(kq)
-    state["v"] = state["v"].at[i, wp, woff].set(vq)
+    state["k"] = put(state["k"], kq)
+    state["v"] = put(state["v"], vq)
     for j, row in enumerate(rows):
         qs[j] = qs[j].at[i, wp, woff].set(row)
 
 
 def _kv_gather(state, i, tables, B, lctx, H, D):
     """Gather one layer's full paged context ``(B, lctx, H, D)`` for every
-    row, dequantizing through whichever sidecars the pool carries."""
+    row, dequantizing through whichever sidecars the pool carries.  Each
+    pool is indexed ONCE: ``pool[i][tables]`` would copy all of layer
+    ``i``'s pages out of the pool before gathering the rows' few."""
     def g(pool):
-        return pool[i][tables].reshape(B, lctx, H, D)
+        return pool[i, tables].reshape(B, lctx, H, D)
 
     def side(j):
-        return state["q"][j][i][tables].reshape(B, lctx)
+        return state["q"][j][i, tables].reshape(B, lctx)
 
     qs = state["q"]
     if qs is None:

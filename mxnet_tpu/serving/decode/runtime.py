@@ -21,14 +21,18 @@ pre-call arrays are poisoned at sites ``decode.prefill_commit`` /
 ``decode.step`` exactly like the aggregated-optimizer and engine-segment
 donation sites.
 
-With an int8 cache (``kv_dtype="int8"``) the same two surfaces carry the
-quantization: the commit program scatter-*quantizes* the prefill's fp32
-K/V into the int8 pools (+ per-row scale/mid sidecars) and the step
-program gather-*dequantizes* before attending — both fused into the
-already-compiled per-bucket executables, so the dtype costs zero extra
-programs and ``warm()`` covers it exactly like fp32.  The pool argument
-list simply grows from ``(k, v)`` to ``(k, v, k_scale, k_mid, v_scale,
-v_mid)`` (all donated, all poisoned).
+With a quantized cache (``kv_dtype="int8"`` or ``"fp8_e4m3"``) the same
+two surfaces carry the quantization: the commit program scatter-*quantizes*
+the prefill's fp32 K/V into the pools (+ per-row sidecars) through the one
+writer the step and verify programs use (``model._kv_scatter``, a layer
+at a time) and the step program gather-*dequantizes* before attending —
+both fused into the already-compiled per-bucket executables, so the dtype
+costs zero extra programs and ``warm()`` covers it exactly like fp32.  The
+pool argument list simply grows from ``(k, v)`` to ``(k, v, k_scale,
+k_mid, v_scale, v_mid)`` for int8 or ``(k, v, k_scale, v_scale)`` for fp8
+(all donated, all poisoned).  Every program reads and writes only the
+pages its page table names, in the donated buffers (``kv_cache``'s module
+docstring says what that asks of the pools' shape and of their indexing).
 """
 from __future__ import annotations
 
@@ -195,7 +199,8 @@ class DecodeRuntime:
                  f":pg{cache.page_size}:np{cache.num_pages}"
                  f":mp{cache.max_pages_per_seq}:sl{cache.max_slots}"
                  f":kv{cache.kv_dtype}:pfx{cache.prefix_sharing}"
-                 f":spec{self.spec_buckets}")
+                 f":spec{self.spec_buckets}"
+                 f":pools{[(p.shape, str(p.dtype)) for p in cache.pools]}")
         self._warmed = False
         if warm:
             self.warm()
@@ -447,7 +452,7 @@ class DecodeRuntime:
     def _build_commit(self):
         import jax
         import jax.numpy as jnp
-        from .model import kv_quantize_rows
+        from .model import _kv_scatter
         block, page_size = self._block, self.cache.page_size
         quantized = self.cache.quantized
 
@@ -460,20 +465,15 @@ class DecodeRuntime:
                 valid, jnp.take_along_axis(tables, j // page_size, axis=1),
                 0)
             dest_off = jnp.broadcast_to(j % page_size, (B, S))
-            if quantized:
-                # scatter-quantize: per-row (L, B, S) scale/mid sidecars
-                # ride the same dest indices as the int8 values
-                kq, ksc, kmd = kv_quantize_rows(kv[0])
-                vq, vsc, vmd = kv_quantize_rows(kv[1])
-                new = [pools[0].at[:, dest_page, dest_off].set(kq),
-                       pools[1].at[:, dest_page, dest_off].set(vq)]
-                for pool, rows in zip(pools[2:], (ksc, kmd, vsc, vmd)):
-                    new.append(pool.at[:, dest_page, dest_off].set(rows))
-            else:
-                new = [pools[0].at[:, dest_page, dest_off].set(kv[0]),
-                       pools[1].at[:, dest_page, dest_off].set(kv[1])]
+            # one scatter a layer, as the step writes: a scatter over all
+            # layers at once makes the compiler relayout both whole pools
+            state = {"k": pools[0], "v": pools[1],
+                     "q": list(pools[2:]) if quantized else None}
+            for i in range(block.num_layers):
+                _kv_scatter(state, i, dest_page, dest_off,
+                            kv[0, i], kv[1, i])
             first = block.sample_math(logits, keys, steps, temps)
-            return (first,) + tuple(new)
+            return (first, state["k"], state["v"]) + tuple(state["q"] or ())
 
         n = len(self.cache.pools)
         return jax.jit(commit, donate_argnums=tuple(range(8, 8 + n)))

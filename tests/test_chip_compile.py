@@ -7,7 +7,9 @@ would refuse on the machine with the chip, it refuses here, at no chip time.
 Nothing runs, so nothing here says a result is right — ``chip_smoke.py``
 does that on the chip.
 """
+import functools
 import os
+import re
 
 import pytest
 
@@ -119,3 +121,120 @@ def test_decode_base_step_program_compiles_for_v5e(one_chip, kv_dtype):
     pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize
                      for p in rt.cache.pools)
     assert stats.alias_size_in_bytes >= pool_bytes
+
+
+# gpt2_medium's serving geometry (PERF.md section 4): hidden 1024 in 16
+# heads, pages of 16 tokens, 1025 pages a pool, 64 pages a row.  The
+# vocabulary is no part of the pools' geometry and is kept small, so that
+# no weight is as large as one layer of a pool.
+_UNITS, _HEADS, _PAGE, _PAGES, _ROW_PAGES, _SEQ, _SPEC_K = \
+    1024, 16, 16, 1025, 64, 256, 3
+
+
+@functools.lru_cache(maxsize=1)
+def _gpt2_medium_runtime(layers, kv_dtype):
+    """An unwarmed runtime of ``layers`` gpt2_medium layers.  Nothing runs
+    on it, so the weights are zeros and its own cache is two pages: the
+    programs take their pools' size from their arguments."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.serving.decode import DecodeRuntime, PagedKVCache
+    from mxnet_tpu.serving.decode.model import CausalLM
+
+    net = CausalLM(vocab_size=512, units=_UNITS, num_layers=layers,
+                   num_heads=_HEADS, max_length=_ROW_PAGES * _PAGE)
+    for p in net.collect_params().values():
+        p._load_init(mx.nd.zeros(p.shape), None)
+    cache = PagedKVCache(layers, _HEADS, _UNITS // _HEADS, page_size=_PAGE,
+                         num_pages=2, max_pages_per_seq=_ROW_PAGES,
+                         max_slots=8, kv_dtype=kv_dtype)
+    return DecodeRuntime(net, cache=cache, batch_buckets=(1, 8),
+                         seq_buckets=(_SEQ,), spec_buckets=(_SPEC_K,),
+                         warm=False)
+
+
+def _pool_program(rt, kind, b, sds):
+    """``(jitted program, its arguments with the pools last)``."""
+    i32, u32, f32 = "int32", "uint32", "float32"
+    blk = rt.block
+    pools = tuple(sds(p.shape[:1] + (_PAGES,) + p.shape[2:], p.dtype)
+                  for p in rt.cache.pools)
+    if kind == "cow":
+        rt.cache.warm_programs()      # builds the jit; runs on two pages
+        return rt.cache._copy_fn, (sds((), i32), sds((), i32)) + pools
+    params = [sds(p.shape, p.dtype) for p in rt._params]
+    rows = (sds((b, _ROW_PAGES), i32), sds((b, 2), u32), sds((b,), i32),
+            sds((b,), f32))           # tables, keys, steps, temps
+    if kind == "step":
+        return rt._build_step(), \
+            (params, sds((b,), i32), sds((b,), i32)) + rows + pools
+    if kind == "verify":
+        return rt._build_verify(), \
+            (params, sds((b, _SPEC_K + 1), i32), sds((b,), i32),
+             sds((b,), i32)) + rows + pools
+    kv = sds((2, blk.num_layers, b, _SEQ, _HEADS, _UNITS // _HEADS), f32)
+    return rt._build_commit(), \
+        (params, kv, sds((b, blk.vocab_size), f32), sds((b,), i32)) \
+        + rows + pools
+
+
+def _materialised(hlo_text):
+    """``(opcode, dtype, dims)`` of every array-valued instruction outside
+    the fused computations: what the program writes to device memory."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", hlo_text))
+    out, skip = [], False
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            skip = head.group(1) in fused
+        m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and not skip:
+            out.append((m.group(3), m.group(1),
+                        tuple(int(d) for d in m.group(2).split(",") if d)))
+    return out
+
+
+_HLO_DTYPE = {"float32": "f32", "int8": "s8", "float8_e4m3fn": "f8e4m3fn"}
+
+
+@pytest.mark.parametrize("kind,b,kv_dtype,layers", [
+    ("step", 1, "float32", 24), ("step", 8, "float32", 24)] + [
+    (kind, b, kv_dtype, 4)
+    for kv_dtype in ("float32", "int8", "fp8_e4m3")
+    for kind, b in (("step", 1), ("step", 8), ("verify", 4), ("commit", 1),
+                    ("cow", 0))
+    if (kind, kv_dtype) != ("step", "float32")])
+def test_pool_programs_touch_only_their_pages(one_chip, kind, b, kv_dtype,
+                                              layers):
+    """Every program that takes the donated KV pools updates them in the
+    buffers it was given: none copies a pool into another layout, none
+    slices a whole layer out of one, and its temporaries are small beside
+    the pools."""
+    import numpy as np
+
+    rt = _gpt2_medium_runtime(layers, kv_dtype)
+    fn, args = _pool_program(
+        rt, kind, b, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    pools = args[-len(rt.cache.pools):]
+    compiled = fn.lower(*args).compile()
+    what = f"{kind}-b{b} {kv_dtype} x{layers}"
+
+    kv_shapes = {(_HLO_DTYPE[str(p.dtype)], p.shape) for p in pools[:2]}
+    layer = int(np.prod(pools[0].shape[1:]))
+    for op, dtype, dims in _materialised(compiled.as_text()):
+        if int(np.prod(dims)) < layer:
+            continue
+        # as large as a layer of a pool: only the pool itself may be,
+        # updated where it lies (the memory numbers below hold it to that)
+        assert (dtype, dims) in kv_shapes, \
+            f"{what}: {op} writes {dtype}{list(dims)}, a layer of a pool " \
+            f"or more"
+        assert op != "copy", f"{what}: copies a whole pool {dtype}{dims}"
+
+    stats = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    assert stats.alias_size_in_bytes >= pool_bytes, what
+    assert stats.temp_size_in_bytes < pool_bytes / 10, \
+        f"{what}: {stats.temp_size_in_bytes / 1e9:.3f} GB of temporaries " \
+        f"beside {pool_bytes / 1e9:.3f} GB of pools"

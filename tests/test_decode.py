@@ -878,6 +878,66 @@ def test_fp8_pool_geometry_between_fp32_and_int8():
     assert k_pool.dtype == jnp.float8_e4m3fn
 
 
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8", "fp8_e4m3"])
+def test_pool_bytes_are_the_unpadded_rows(kv_dtype):
+    """The pools hold exactly what ``kv_bytes_per_token`` promises: the
+    row axis is ``heads * head_dim`` whole, no axis there to pad."""
+    c = PagedKVCache(3, 4, 16, page_size=8, num_pages=5,
+                     max_pages_per_seq=2, max_slots=2, kv_dtype=kv_dtype)
+    assert c.k_pages.shape == (3, 5, 8, 4 * 16)
+    assert sum(p.nbytes for p in c.pools) == \
+        c.kv_bytes_per_token * c.page_size * c.num_pages
+
+
+def test_aot_key_names_the_pool_geometry(tmp_path):
+    """Two caches built from the same arguments whose pools differ in
+    shape alone must not share compiled programs: the pools' shapes and
+    dtypes are part of the AOT key, whichever module decides them."""
+    net = get_decode_model("decode_tiny", vocab_size=VOCAB, max_length=32,
+                           units=32, num_heads=2)
+    net.initialize()
+
+    def key(reshape):
+        cache = PagedKVCache(2, 2, 16, page_size=8, num_pages=9,
+                             max_pages_per_seq=4, max_slots=2)
+        cache.set_pools(reshape(p) for p in cache.pools)
+        rt = DecodeRuntime(net, cache=cache, batch_buckets=(1, 2),
+                           seq_buckets=(8,), warm=False,
+                           aot_cache=str(tmp_path))
+        return rt.aot_cache.model_key
+
+    flat = key(lambda p: p)
+    assert flat == key(lambda p: p)
+    assert flat != key(lambda p: p.reshape(p.shape[:3] + (2, 16)))
+    assert flat != key(lambda p: p.astype("bfloat16"))
+
+
+def test_fp8_commit_stores_the_prompt_in_fp8():
+    """A prompt's K/V committed to fp8 pools reads back within e4m3's
+    error of what the fp32 pools hold: the commit quantizes as the step
+    does, by the pools' own sidecars."""
+    from mxnet_tpu.serving.decode import kv_dequantize_fp8
+    net = get_decode_model("decode_tiny", vocab_size=VOCAB, max_length=32,
+                           units=32, num_heads=2)
+    net.initialize()
+    tokens = np.array([_prompt(7, 8, 8)], "int32")
+    pools = {}
+    for kvd in ("float32", "fp8_e4m3"):
+        rt = DecodeRuntime(net, batch_buckets=(1,), seq_buckets=(8,),
+                           page_size=8, kv_dtype=kvd, warm=False)
+        rt.prefill(tokens, np.array([8], "int32"),
+                   np.array([[1, 0, 0, 0]], "int32"),
+                   np.zeros((1, 2), "uint32"), np.zeros((1,), "float32"))
+        pools[kvd] = [np.asarray(p)[:, 1] for p in rt.cache.pools]
+    for x, q, scale in zip(pools["float32"], pools["fp8_e4m3"][:2],
+                           pools["fp8_e4m3"][2:]):
+        assert np.abs(x).max() > 0
+        back = np.asarray(kv_dequantize_fp8(
+            q.reshape(q.shape[:-1] + (2, 16)), scale)).reshape(x.shape)
+        bound = np.abs(x) / 16.0 + scale[..., None] * 2e-3
+        assert (np.abs(back - x) <= bound + 1e-7).all()
+
+
 @pytest.fixture(scope="module")
 def fp8_session():
     net = get_decode_model("decode_tiny", vocab_size=VOCAB, max_length=32,
