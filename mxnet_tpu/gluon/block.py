@@ -478,6 +478,11 @@ class CachedOp:
         self._params = None
         self._aux_params = None
         self._jitted = {}
+        # cache_key -> [indices into the aux params of those a forward of
+        # that graph CHANGED]: only these come back as outputs and are
+        # rebound.  An inference graph changes none, so a served model's
+        # weights are not copied out of every call (and held twice).
+        self._aux_changed = {}
         self._out_fmt = [None]
         self._jax = jax
         self._seen_sigs = set()   # telemetry: (cache_key, shapes/dtypes)
@@ -492,10 +497,19 @@ class CachedOp:
             self._aux_params = [p for p in self._params if p.grad_req == "null"]
         return self._params, self._aux_params
 
-    def _make_fn(self, training, n_in, in_fmt):
+    def _fn_for(self, cache_key, n_in, in_fmt):
+        fn = self._jitted.get(cache_key)
+        if fn is None:
+            changed = self._aux_changed.setdefault(cache_key, [None])
+            fn = self._make_fn(cache_key[0], n_in, in_fmt, changed)
+            self._jitted[cache_key] = fn
+        return fn
+
+    def _make_fn(self, training, n_in, in_fmt, changed):
         params, aux = self._collect()
         block = self._block
         handles = [p.data() for p in params]
+        aux_at = [params.index(p) for p in aux]
         out_fmt = self._out_fmt
 
         def pure(*raw, __key__=None):
@@ -511,7 +525,12 @@ class CachedOp:
                     flat, fmt = _flatten(out, "output")
                     out_fmt[0] = fmt
                     out_raw = [o._data for o in flat]
-                    aux_raw = [p.data()._data for p in aux]
+                    # auxiliary state this forward wrote (BatchNorm's
+                    # moving statistics under training): the handle no
+                    # longer holds the argument it was given
+                    changed[0] = [j for j, k in enumerate(aux_at)
+                                  if handles[k]._data is not par_raw[k]]
+                    aux_raw = [handles[aux_at[j]]._data for j in changed[0]]
                 finally:
                     for h, o in zip(handles, old):
                         h._data = o
@@ -545,10 +564,7 @@ class CachedOp:
         sp = current_sequence_parallel()
         sp_key = None if sp is None else (id(sp[0]),) + tuple(sp[1:])
         cache_key = (training, len(flat_in), repr(in_fmt), sp_key)
-        fn = self._jitted.get(cache_key)
-        if fn is None:
-            fn = self._make_fn(training, len(flat_in), in_fmt)
-            self._jitted[cache_key] = fn
+        fn = self._fn_for(cache_key, len(flat_in), in_fmt)
         # a recompile is keyed by (cache_key, input shapes/dtypes): jax.jit
         # retraces SILENTLY on a new shape/dtype — the #1 hidden TPU perf
         # killer.  Signatures are tracked even with telemetry off so that
@@ -584,12 +600,15 @@ class CachedOp:
                                      attrs={"__key__": key})
         if not isinstance(outs, list):
             outs = [outs]
-        n_aux = len(aux)
+        changed = self._aux_changed[cache_key][0]
+        if changed is None:       # an executable stored before the list was
+            changed = range(len(aux))
+        n_aux = len(changed)
         if n_aux:
             aux_outs = outs[len(outs) - n_aux:]
             outs = outs[:len(outs) - n_aux]
-            for p, a in zip(aux, aux_outs):
-                p.data()._data = a._data
+            for j, a in zip(changed, aux_outs):
+                aux[j].data()._data = a._data
         ret, _ = _regroup(outs, self._out_fmt[0])
         return ret
 
@@ -613,10 +632,7 @@ class CachedOp:
         datas = [p.data() for p in params]
         sig = self._aot_sig(flat_inputs, in_fmt, training)
         cache_key = sig[0]
-        fn = self._jitted.get(cache_key)
-        if fn is None:
-            fn = self._make_fn(training, len(flat_inputs), in_fmt)
-            self._jitted[cache_key] = fn
+        fn = self._fn_for(cache_key, len(flat_inputs), in_fmt)
         raw = [x._materialize() for x in flat_inputs] + \
             [d._data for d in datas]
         # the PRNG key is a dynamic argument of the compiled function —
@@ -629,7 +645,7 @@ class CachedOp:
         return sig, compiled, self._out_fmt[0]
 
     def aot_install(self, flat_inputs, in_fmt, compiled, out_fmt,
-                    training=False):
+                    training=False, aux_changed=None):
         """Install a deserialized AOT executable for this signature.
         Registers the signature as seen (no recompile is counted, and
         :meth:`HybridBlock.compiled_signatures` includes it) and records
@@ -640,6 +656,9 @@ class CachedOp:
         self._seen_sigs.add(sig)
         if self._out_fmt[0] is None:
             self._out_fmt[0] = out_fmt
+        holder = self._aux_changed.setdefault(sig[0], [None])
+        if holder[0] is None and aux_changed is not None:
+            holder[0] = list(aux_changed)
         return sig
 
 
@@ -884,12 +903,17 @@ class HybridBlock(Block):
         hit = cache.load(cache_key)
         if hit is not None:
             fn, extra = hit
-            self._cached_op.aot_install(flat, in_fmt, fn,
-                                        extra.get("out_fmt"))
+            self._cached_op.aot_install(
+                flat, in_fmt, fn, extra.get("out_fmt"),
+                aux_changed=extra.get("aux_changed"))
         else:
-            _sig, compiled, out_fmt = \
+            sig, compiled, out_fmt = \
                 self._cached_op.aot_compile(flat, in_fmt)
-            cache.store(cache_key, compiled, extra={"out_fmt": out_fmt})
+            # an installed executable never traces: what tracing learned
+            # of the graph's outputs is stored beside it
+            cache.store(cache_key, compiled, extra={
+                "out_fmt": out_fmt,
+                "aux_changed": self._cached_op._aux_changed[sig[0]][0]})
         return (shapes, dtypes)
 
     def compile_grid(self, make_example, buckets, cache=None):

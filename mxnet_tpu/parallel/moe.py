@@ -1,4 +1,17 @@
-"""Expert parallelism — top-1 MoE dispatch over an ``ep`` mesh axis.
+"""Expert parallelism.
+
+Two layers live here.  :func:`moe_layer` / :func:`switch_moe_local` (below)
+are the top-1, one-expert-per-device Switch layout with its two
+``all_to_all`` exchanges.  :func:`routed_expert_share` is **one chip's share
+of an expert-parallel layer**: the chip is told which experts it holds, routes
+every token over ALL the experts at the published router width, and computes
+the part of the layer's result that its own experts give — grouped matrix
+products over the assignments sorted by expert, no capacity, no dropped
+token.  On one chip it runs without its exchange (no code stands in for the
+absent chips); summed over every chip's share it is the whole layer
+(``tests/test_latent_moe.py`` holds it to that).
+
+Top-1 MoE dispatch over an ``ep`` mesh axis:
 
 Absent in the reference (SURVEY.md §2.3: "no MoE ops"); TPU-first design:
 one expert per device along ``ep``, tokens routed by a learned gate,
@@ -19,7 +32,8 @@ from jax import lax
 from ..analysis import divergence as _div
 from ..analysis import sanitizer as _san
 
-__all__ = ["moe_layer", "switch_moe_local"]
+__all__ = ["moe_layer", "switch_moe_local", "group_limited_topk",
+           "routed_expert_share"]
 
 
 def switch_moe_local(expert_fn, params, x, axis_name, capacity):
@@ -92,3 +106,96 @@ def moe_layer(expert_fn, gate_w, expert_params, x, mesh, ep_axis="ep",
         in_specs=(param_specs, P(ep_axis)),
         out_specs=P(ep_axis),
     )(params, x)
+
+
+# --------------------------------------------------------------------------
+# one chip's share of a routed-expert layer (DeepSeek-V3 family routing)
+
+def group_limited_topk(scores, top_k, n_group, topk_group):
+    """The family's group-limited choice over router ``scores (T, E)``
+    (float32, every expert's): experts lie in ``n_group`` groups of
+    consecutive ids, a group scores the sum of its two largest, the
+    ``topk_group`` best groups stay and the ``top_k`` largest scores among
+    them are chosen.  Returns ``(ids (T, top_k) int32, scores (T, top_k))``
+    — the raw scores of the chosen, not yet normalised."""
+    T, E = scores.shape
+    if n_group > 1:
+        grouped = scores.reshape(T, n_group, E // n_group)
+        group_score = lax.top_k(grouped, 2)[0].sum(-1)
+        _, keep = lax.top_k(group_score, topk_group)
+        kept = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], keep].set(True)
+        scores = jnp.where(jnp.repeat(kept, E // n_group, axis=1),
+                           scores, -1.0)
+    chosen, ids = lax.top_k(scores, top_k)
+    return ids.astype(jnp.int32), chosen
+
+
+def _grouped(x, w, sizes):
+    """``x (M, K)`` rows sorted by group times ``w (G, K, N)``: one grouped
+    (ragged) product, each group's weights read at most once and no row
+    multiplied by another group's.  Products in the weights' dtype
+    (bfloat16 on the MXU, float32 accumulation); float32 weights take the
+    highest precision."""
+    return lax.ragged_dot(
+        x.astype(w.dtype), w, sizes, preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST if w.dtype == jnp.float32 else None)
+
+
+def routed_expert_share(x, router_w, w_gate, w_up, w_down, held, *,
+                        top_k, n_group=1, topk_group=1, scale=1.0,
+                        valid=None):
+    """This chip's part of ``sum_k w_k E_k(x)`` for ``x (T, D)`` float32.
+
+    ``router_w (D, E)`` float32 is the WHOLE router (``E`` = the published
+    expert count); ``held`` is the tuple of global expert ids whose weights
+    ``w_gate`` / ``w_up (G, D, F)`` and ``w_down (G, F, D)`` are, in that
+    order.  Scores are ``sigmoid(x router_w)`` in float32 at the highest
+    precision, the choice is :func:`group_limited_topk` over all ``E``, the
+    weights ``scale * s_k / sum_chosen s`` — all independent of ``held``.
+    Only the chosen experts that are held are computed: the ``T * top_k``
+    assignments are sorted by held expert (the others last), the first
+    ``T * min(top_k, G)`` rows — every held assignment fits, so no token is
+    ever dropped — go through three grouped products
+    ``(silu(x Wg) * (x Wu)) Wd`` and are summed back onto their tokens.
+    ``valid (T,) bool`` marks real rows; padding is routed nowhere.
+
+    Returns ``(y (T, D) float32, rows (G,) int32, assignments int32)``:
+    the partial result, the rows each held expert received, and the
+    assignments made over all ``E`` experts."""
+    import numpy as np
+    T, D = x.shape
+    E = router_w.shape[1]
+    G = len(held)
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x, router_w, precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32))
+        ids, chosen = group_limited_topk(scores, top_k, n_group, topk_group)
+        weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+        lookup = np.full((E,), G, "int32")
+        lookup[np.asarray(held, "int64")] = np.arange(G, dtype="int32")
+        local = jnp.asarray(lookup)[ids]              # G = held elsewhere
+        if valid is None:
+            n_assign = jnp.int32(T * top_k)
+        else:
+            local = jnp.where(valid[:, None], local, G)
+            n_assign = valid.sum().astype(jnp.int32) * top_k
+        flat = local.reshape(-1)
+        rows = (flat[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
+                ).sum(0).astype(jnp.int32)
+        M = T * min(top_k, G)
+        order = jnp.argsort(flat, stable=True)[:M]
+        token = order // top_k
+        live = jnp.arange(M) < rows.sum()
+    with jax.named_scope("moe.experts"):
+        xs = x[token]
+        hidden = jax.nn.silu(_grouped(xs, w_gate, rows)) \
+            * _grouped(xs, w_up, rows)
+        out = _grouped(hidden, w_down, rows)
+        # rows past the held assignments belong to no group: whatever the
+        # grouped product left there is not part of the result
+        out = jnp.where(live[:, None],
+                        out * weights.reshape(-1)[order][:, None], 0.0)
+        y = jnp.zeros((T, D), jnp.float32).at[token].add(out)
+    return y, rows, n_assign

@@ -11,6 +11,13 @@ discipline (PAPER.md design point #2) to that loop:
   prefill and per-token step are built from ONE set of pure layer
   functions, written row-stable so a request's tokens are bitwise
   independent of batch composition.
+- :class:`LatentMoELM` (``latent_moe.py``) — the DeepSeek-V3 family's
+  block behind the same runtime: RMSNorm, YaRN rotary, latent (MLA)
+  attention over a ONE-pool cache (expanded in prefill, absorbed in the
+  step), a dense FFN then routed + shared experts as one chip's share of
+  an expert-parallel deployment (``parallel.moe.routed_expert_share``).
+  bfloat16 products on the MXU; held to its plain reference within
+  tolerances, not to bitwise row stability.
 - :class:`PagedKVCache` (``kv_cache.py``) — device-resident page pools
   with a trash page for padding, generation-stamped slots (the ShmRing
   discipline: a post-free read raises ``StaleKVSlotError`` under
@@ -62,7 +69,9 @@ from .model import (  # noqa: F401
     kv_quantize_rows,
     kv_quantize_rows_fp8,
     rowdot,
+    sample_math,
 )
+from .latent_moe import LatentMoELM  # noqa: F401
 from .runtime import DecodeRuntime, seq_bucket_ladder  # noqa: F401
 from .scheduler import (  # noqa: F401
     DecodeScheduler,
@@ -77,7 +86,8 @@ from .speculate import (  # noqa: F401
     SpecState,
 )
 
-__all__ = ["CausalLM", "get_decode_model", "rowdot",
+__all__ = ["CausalLM", "LatentMoELM", "get_decode_model", "rowdot",
+           "sample_math",
            "kv_quantize_rows", "kv_dequantize",
            "kv_quantize_rows_fp8", "kv_dequantize_fp8",
            "PagedKVCache", "KVSlot", "KVCacheExhausted", "pages_needed",

@@ -2,8 +2,13 @@
 refcounted shared-prefix pages, and optional int8-quantized pools.
 
 The decode batch's attention state lives on device as page-pool arrays
-per cache — ``k_pages`` / ``v_pages`` of shape ``(layers, num_pages,
-page_size, heads * head_dim)``.  A sequence owns a *slot* (its identity in
+per cache, each ``(layers, num_pages, page_size, row width)``.  **The
+block says which pools** (``block.cache_layout()``: how many, each row's
+width, the dtype): :class:`CausalLM` keeps two, ``k_pages`` / ``v_pages``
+with a row of ``heads * head_dim`` values; a latent-attention block keeps
+ONE whose row is the token's ``(c_kv | k_r)`` shared by every head.  The
+allocator, the page tables, the prefix index and copy-on-write know
+nothing of what a row holds.  A sequence owns a *slot* (its identity in
 the allocator) and a fixed-length page table (``max_pages_per_seq``
 entries, padded with the reserved trash page 0) mapping logical token
 positions to physical pages.  Page 0 is never allocated: padded batch rows
@@ -178,8 +183,17 @@ class PagedKVCache:
 
     Parameters
     ----------
-    num_layers, num_heads, head_dim : int
-        K/V geometry (must match the model).
+    num_layers, num_heads, head_dim : int, optional
+        The two-pool K/V geometry of full multi-head attention: pools
+        ``k`` and ``v`` with a row of ``num_heads * head_dim`` values.
+        Left out when ``layout`` is given.
+    layout : dict, optional
+        The block's ``cache_layout()``: ``layers``, ``pools`` (``(name,
+        row_width, dtype)`` for each value pool — any number, each with
+        its own row width and storage dtype), ``quantizable`` (may
+        ``kv_dtype`` store them as int8 / fp8 with sidecars) and
+        ``shard_heads`` (the head count a ``mesh`` splits the row axis by,
+        or None where a row is not a concatenation of heads).
     page_size : int
         Tokens per page.
     num_pages : int
@@ -209,16 +223,29 @@ class PagedKVCache:
         axis, whose size must divide ``num_heads`` (a split by heads).
     """
 
-    def __init__(self, num_layers, num_heads, head_dim, page_size=16,
-                 num_pages=64, max_pages_per_seq=8, max_slots=16,
-                 dtype="float32", kv_dtype=None, prefix_sharing=True,
-                 prefix_entries=256, mesh=None, kv_axis="model"):
+    def __init__(self, num_layers=None, num_heads=None, head_dim=None,
+                 page_size=16, num_pages=64, max_pages_per_seq=8,
+                 max_slots=16, dtype="float32", kv_dtype=None,
+                 prefix_sharing=True, prefix_entries=256, mesh=None,
+                 kv_axis="model", layout=None):
         import jax.numpy as jnp
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is trash)")
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+        if layout is None:
+            if None in (num_layers, num_heads, head_dim):
+                raise ValueError("PagedKVCache needs num_layers, num_heads "
+                                 "and head_dim, or the block's layout")
+            row = int(num_heads) * int(head_dim)
+            layout = {"layers": num_layers, "quantizable": True,
+                      "shard_heads": int(num_heads),
+                      "pools": (("k", row, str(dtype)),
+                                ("v", row, str(dtype)))}
+        pools = tuple((str(n), int(w), str(d)) for n, w, d in layout["pools"])
+        self.pool_layout = pools
+        self.num_layers = int(layout["layers"])
+        self.num_heads = layout.get("shard_heads")
+        self.head_dim = pools[0][1] // self.num_heads if self.num_heads \
+            else None
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         self.max_pages_per_seq = int(max_pages_per_seq)
@@ -233,6 +260,17 @@ class PagedKVCache:
             raise ValueError(
                 f"kv_dtype must be 'float32', 'int8' or 'fp8_e4m3', "
                 f"got {kv_dtype!r}")
+        if kv_dtype != "float32" and not layout.get("quantizable"):
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r}: the block's pools "
+                f"{[n for n, _w, _d in pools]} are stored as the block "
+                f"states them; an int8/fp8 pool of these rows is not "
+                f"supported")
+        if mesh is not None and not self.num_heads:
+            raise ValueError(
+                f"a mesh splits a pool's row by heads, and a row of the "
+                f"block's pools {[n for n, _w, _d in pools]} is shared by "
+                f"all heads: a sharded pool of these rows is not supported")
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype in ("int8", "fp8_e4m3")
         # sidecar arity: int8 carries per-row (scale, mid) for K and V;
@@ -241,13 +279,10 @@ class PagedKVCache:
             kv_dtype]
         self.prefix_sharing = bool(prefix_sharing)
         self._prefix_entry_cap = int(prefix_entries)
-        shape = (self.num_layers, self.num_pages, self.page_size,
-                 self.num_heads * self.head_dim)
-        pool_dtype = {"float32": self.dtype, "int8": "int8",
-                      "fp8_e4m3": "float8_e4m3fn"}[kv_dtype]
-        k = jnp.zeros(shape, pool_dtype)
-        v = jnp.zeros(shape, pool_dtype)
-        qshape = shape[:3]
+        qshape = (self.num_layers, self.num_pages, self.page_size)
+        stored = {"int8": "int8", "fp8_e4m3": "float8_e4m3fn"}
+        values = tuple(jnp.zeros(qshape + (w,), stored.get(kv_dtype, d))
+                       for _n, w, d in pools)
         quant = tuple(jnp.zeros(qshape, "float32")
                       for _ in range(self.num_sidecars))
         if mesh is not None:
@@ -259,13 +294,11 @@ class PagedKVCache:
                     f"mesh's {kv_axis!r} axis ({mesh.shape[kv_axis]})")
             sharding = NamedSharding(
                 mesh, PartitionSpec(None, None, None, kv_axis))
-            k = jax.device_put(k, sharding)
-            v = jax.device_put(v, sharding)
+            values = tuple(jax.device_put(x, sharding) for x in values)
             rep = NamedSharding(mesh, PartitionSpec())
             quant = tuple(jax.device_put(q, rep) for q in quant)
         self.mesh = mesh          # the runtime replicates params over it
-        self.k_pages = k
-        self.v_pages = v
+        self._values = values
         # (k_scale, k_zero, v_scale, v_zero) — empty tuple in fp32 mode
         self._quant = quant
         self._copy_fn = None
@@ -297,13 +330,15 @@ class PagedKVCache:
     def kv_bytes_per_token(self):
         """Device bytes one token position costs across K+V pools (all
         layers), including the int8 scale/zero sidecars."""
-        row = self.num_heads * self.head_dim
-        if self.kv_dtype == "int8":
-            per_layer = 2 * (row + 2 * 4)    # int8 values + scale/mid f32
-        elif self.kv_dtype == "fp8_e4m3":
-            per_layer = 2 * (row + 4)        # fp8 values + scale f32
-        else:
-            per_layer = 2 * row * np.dtype(self.dtype).itemsize
+        per_layer = 0
+        for _n, row, dtype in self.pool_layout:
+            if self.kv_dtype == "int8":
+                per_layer += row + 2 * 4     # int8 values + scale/mid f32
+            elif self.kv_dtype == "fp8_e4m3":
+                per_layer += row + 4         # fp8 values + scale f32
+            else:
+                per_layer += row * (2 if dtype == "bfloat16"
+                                    else np.dtype(dtype).itemsize)
         return self.num_layers * per_layer
 
     @property
@@ -314,15 +349,25 @@ class PagedKVCache:
     @property
     def pools(self):
         """Every device pool array the commit/step programs thread
-        through (and donate): ``(k, v)`` in fp32, ``(k, v, k_scale,
-        k_zero, v_scale, v_zero)`` in int8, ``(k, v, k_scale, v_scale)``
-        in fp8_e4m3."""
-        return (self.k_pages, self.v_pages) + self._quant
+        through (and donate), the value pools in the layout's order and
+        then the sidecars: ``(k, v)`` in fp32, ``(k, v, k_scale, k_zero,
+        v_scale, v_zero)`` in int8, ``(k, v, k_scale, v_scale)`` in
+        fp8_e4m3; ``(latent,)`` for a latent-attention block."""
+        return self._values + self._quant
 
     def set_pools(self, arrays):
         arrays = tuple(arrays)
-        self.k_pages, self.v_pages = arrays[0], arrays[1]
-        self._quant = arrays[2:]
+        n = len(self.pool_layout)
+        self._values, self._quant = arrays[:n], arrays[n:]
+
+    @property
+    def k_pages(self):
+        """The two-pool geometry's key pool (the first value pool)."""
+        return self._values[0]
+
+    @property
+    def v_pages(self):
+        return self._values[1]
 
     # ------------------------------------------------------------ occupancy
     @property

@@ -1,0 +1,462 @@
+"""Latent-attention, routed-expert decode model (the DeepSeek-V3 family's
+block, as A.X-K1 publishes it) — a second block beside :class:`CausalLM`
+behind the same runtime, scheduler and paged cache.
+
+What differs from :class:`~mxnet_tpu.serving.decode.model.CausalLM`:
+
+- **RMSNorm, SwiGLU, an untied head, rotary positions** (YaRN-scaled, on a
+  ``qk_rope_head_dim``-wide slice of every query and on ONE key shared by
+  all heads).  There is no position table: ``max_length`` is the context
+  the block is built for, inside the rotary range.
+- **Latent attention (MLA).**  Per token and layer the cache holds one row
+  ``(c_kv | k_r)`` of ``kv_lora_rank + qk_rope_head_dim`` values shared by
+  every head — :meth:`cache_layout` says so and the paged cache builds ONE
+  pool of that row, padded to whole 128-lane tiles.  Prefill runs the *expanded* form (per-head keys
+  and values from ``c_kv W_kvb``); the decode step runs the *absorbed* form
+  (``W_kvb`` folded into the query and the output, scores and context taken
+  straight over the cached latent rows), so no per-head K/V is ever
+  materialised for a cached token.  Same mathematics, two contractions.
+- **A dense FFN in the first ``first_k_dense_replace`` layers, then routed +
+  shared experts.**  The routed part is
+  :func:`mxnet_tpu.parallel.moe.routed_expert_share`: this block holds the
+  experts ``held_experts`` (one chip's share of an expert-parallel
+  deployment), routes over all ``n_routed_experts``, and computes its own
+  experts' part of the sum.  The step program also returns, per expert
+  layer, the rows each held expert received (:meth:`record_step_extras`
+  turns them into the ``decode.moe.*`` counters).
+- **Precision and the contract.**  Weights and cache rows are ``dtype``
+  (bfloat16 as served); every weight product is a ``dot_general`` in that
+  dtype on the MXU with float32 accumulation; the residual stream, norms,
+  rotary angles, softmax, router scores and logits are float32.  Nothing
+  goes through ``rowdot``, so the contract is NOT bitwise row stability but
+  agreement with the plain reference (``perf/reference/axk1.py``) within
+  the tolerances ``tests/test_latent_moe.py`` writes down.  ``dtype=
+  "float32"`` (tests) runs the same programs at the highest precision.
+
+No drafter and no quantized latent pool: asking for either raises.
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+from ...gluon.block import HybridBlock
+from ...ndarray import NDArray, invoke_fn
+from ...telemetry import bus as _tel
+from .model import commit_destinations, sample_math
+
+__all__ = ["LatentMoELM", "yarn_inv_freq", "yarn_softmax_scale"]
+
+
+def yarn_inv_freq(dim, base, scaling=None):
+    """Rotary inverse frequencies ``(dim // 2,)`` float64 for a ``dim``-wide
+    slice.  With YaRN ``scaling`` (``factor``, ``original_max_position_
+    embeddings``, ``beta_fast``, ``beta_slow``) each frequency is a blend of
+    itself and itself over ``factor``: pairs that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    pairs that turn fewer than ``beta_slow`` times are slowed ``factor``
+    times, and a linear ramp over the pair index joins the two."""
+    j = np.arange(0, dim, 2, dtype="float64")
+    freq = base ** (-j / dim)
+    if not scaling:
+        return freq
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype="float64") - low)
+                   / (high - low), 0.0, 1.0)
+    return freq / factor * ramp + freq * (1.0 - ramp)
+
+
+def yarn_softmax_scale(qk_head_dim, scaling=None):
+    """``qk_head_dim ** -0.5``, times ``m ** 2`` with ``m = 0.1 *
+    mscale_all_dim * ln(factor) + 1`` where YaRN sets ``mscale_all_dim``."""
+    scale = qk_head_dim ** -0.5
+    if scaling and scaling.get("mscale_all_dim"):
+        m = 0.1 * scaling["mscale_all_dim"] * math.log(scaling["factor"]) + 1
+        scale *= m * m
+    return scale
+
+
+def _rms(x, g, eps):
+    import jax
+    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * g
+
+
+def _dot(a, w):
+    """``a (..., K) . w (K, N)`` in the weights' dtype, float32 out."""
+    import jax
+    import jax.numpy as jnp
+    return jnp.dot(a.astype(w.dtype), w, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST
+                   if w.dtype == jnp.float32 else None)
+
+
+def _einsum(spec, a, b, dtype):
+    import jax
+    import jax.numpy as jnp
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST
+                      if jnp.dtype(dtype) == jnp.float32 else None)
+
+
+def _swiglu(x, wg, wu, wd):
+    import jax
+    return _dot(jax.nn.silu(_dot(x, wg)) * _dot(x, wu), wd)
+
+
+class LatentMoELM(HybridBlock):
+    """Decoder-only transformer with latent attention and routed + shared
+    experts; see the module docstring.  ``forward(tokens (B, S), lengths
+    (B,))`` returns ``(last_logits (B, vocab) float32, rows (layers, B, S,
+    pool_width))`` — the latent cache rows ``(c_kv | k_r)`` of every
+    position, zero-padded to the pool's row, for the runtime's commit
+    program.
+
+    ``held_experts`` are the global ids of the routed experts whose weights
+    this block holds (default: all of them); the router is always
+    ``n_routed_experts`` wide.  ``vocab_size`` is the slice of the
+    vocabulary held here (embedding rows and head columns)."""
+
+    def __init__(self, vocab_size=512, hidden_size=64, num_layers=3,
+                 num_heads=4, q_lora_rank=32, kv_lora_rank=32,
+                 qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                 intermediate_size=128, moe_intermediate_size=32,
+                 n_routed_experts=16, held_experts=None,
+                 num_experts_per_tok=4, n_shared_experts=1, n_group=4,
+                 topk_group=2, routed_scaling_factor=2.5,
+                 first_k_dense_replace=1, rms_norm_eps=1e-6,
+                 rope_theta=10000.0, rope_scaling=None, max_length=128,
+                 dtype="bfloat16", **kwargs):
+        super().__init__(**kwargs)
+        self.vocab_size = int(vocab_size)
+        self.units = int(hidden_size)
+        self.num_layers = int(num_layers)
+        self.num_heads = int(num_heads)
+        self.q_lora_rank, self.kv_lora_rank = int(q_lora_rank), \
+            int(kv_lora_rank)
+        self.nope_dim, self.rope_dim = int(qk_nope_head_dim), \
+            int(qk_rope_head_dim)
+        self.v_dim = int(v_head_dim)
+        self.row_width = self.kv_lora_rank + self.rope_dim
+        # a pool's row fills whole 128-lane tiles (the chip pads a row to
+        # them anyway, and where it is left to choose it stores a ragged
+        # row pages-minor, a layout every program would first copy the
+        # whole pool out of — kv_cache's module docstring): the tail of a
+        # stored row is zeros, and the contractions run over them
+        self.pool_width = -(-self.row_width // 128) * 128
+        self.n_routed = int(n_routed_experts)
+        self.held = tuple(range(self.n_routed)) if held_experts is None \
+            else tuple(int(e) for e in held_experts)
+        if not self.held or len(set(self.held)) != len(self.held) or \
+                not all(0 <= e < self.n_routed for e in self.held):
+            raise ValueError(
+                f"held_experts={self.held} must be distinct ids in "
+                f"[0, {self.n_routed})")
+        self.top_k = int(num_experts_per_tok)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        if self.n_routed % self.n_group:
+            raise ValueError(f"n_routed_experts={self.n_routed} is not "
+                             f"divisible by n_group={self.n_group}")
+        self.routed_scale = float(routed_scaling_factor)
+        self.first_dense = int(first_k_dense_replace)
+        self.moe_layers = tuple(range(self.first_dense, self.num_layers))
+        self.eps = float(rms_norm_eps)
+        self.max_length = int(max_length)
+        self.dtype = str(dtype)
+        self._inv_freq = yarn_inv_freq(self.rope_dim, float(rope_theta),
+                                       rope_scaling).astype("float32")
+        self._scale = yarn_softmax_scale(self.nope_dim + self.rope_dim,
+                                         rope_scaling)
+        u, H, wd = self.units, self.num_heads, self.dtype
+        f_sh = int(moe_intermediate_size) * int(n_shared_experts)
+        G = len(self.held)
+
+        def reg(name, shape, init="normal", dtype=wd):
+            setattr(self, name, self.params.get(name, shape=shape, init=init,
+                                                dtype=dtype))
+
+        reg("embed", (self.vocab_size, u))
+        reg("head", (u, self.vocab_size))
+        reg("norm_f", (u,), "ones", "float32")
+        for i in range(self.num_layers):
+            p = f"l{i}_"
+            reg(p + "norm_attn", (u,), "ones", "float32")
+            reg(p + "wqa", (u, self.q_lora_rank))
+            reg(p + "norm_q", (self.q_lora_rank,), "ones", "float32")
+            reg(p + "wqb", (self.q_lora_rank,
+                            H * (self.nope_dim + self.rope_dim)))
+            reg(p + "wkva", (u, self.row_width))
+            reg(p + "norm_kv", (self.kv_lora_rank,), "ones", "float32")
+            reg(p + "wkvb", (self.kv_lora_rank,
+                             H * (self.nope_dim + self.v_dim)))
+            reg(p + "wo", (H * self.v_dim, u))
+            reg(p + "norm_ffn", (u,), "ones", "float32")
+            if i < self.first_dense:
+                f = int(intermediate_size)
+                reg(p + "wg", (u, f))
+                reg(p + "wu", (u, f))
+                reg(p + "wd", (f, u))
+            else:
+                f = int(moe_intermediate_size)
+                # router scores are float32 at the highest precision, so
+                # the choice of experts follows the reference's
+                reg(p + "router", (u, self.n_routed), dtype="float32")
+                reg(p + "exp_wg", (G, u, f))
+                reg(p + "exp_wu", (G, u, f))
+                reg(p + "exp_wd", (G, f, u))
+                reg(p + "sh_wg", (u, f_sh))
+                reg(p + "sh_wu", (u, f_sh))
+                reg(p + "sh_wd", (f_sh, u))
+        self._param_order = sorted(self._reg_params)
+
+    # ------------------------------------------------- what the runtime reads
+    #: one prompt a prefill call.  The expanded attention's float32 scores
+    #: ``(H, S, S)`` and per-head keys and values are per row (0.78 GB of
+    #: temporaries at 1 x 1536 and the published widths), and a prompt of a
+    #: hundred tokens already fills the MXU's rows, so a second prompt in
+    #: the call buys memory and no time.
+    max_prefill_batch = 1
+
+    def cache_layout(self):
+        """One pool: a token's row is ``(c_kv | k_r)``, shared by all heads,
+        stored in the block's dtype and zero-padded to whole lane tiles
+        (``pool_width``); not quantizable, not sharded by heads."""
+        return {"layers": self.num_layers,
+                "pools": (("latent", self.pool_width, self.dtype),),
+                "quantizable": False, "shard_heads": None,
+                "max_length": self.max_length}
+
+    def prefill_state(self, b, s):
+        """Shape and dtype of the cache rows :meth:`prefill_math` emits."""
+        return (self.num_layers, b, s, self.pool_width), self.dtype
+
+    def _params_dict(self, leaves):
+        return dict(zip(self._param_order, leaves))
+
+    def param_leaves(self):
+        return [self._reg_params[n].data()._data for n in self._param_order]
+
+    # ------------------------------------------------------------ pure math
+    def _rope(self, x, positions):
+        """Rotate the interleaved pairs ``(x[2j], x[2j+1])`` of the last
+        axis by ``positions * inv_freq[j]``; ``positions`` broadcasts over
+        ``x``'s leading axes.  The result lists the pairs' first members,
+        then their second (the family's order; a dot product of two
+        rotated vectors does not depend on it)."""
+        import jax.numpy as jnp
+        ang = positions[..., None].astype(jnp.float32) \
+            * jnp.asarray(self._inv_freq)
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1)
+
+    def _queries(self, p, i, a, positions):
+        """``(q_nope (..., H, nope), q_rope (..., H, rope))`` rotated at
+        ``positions (...)``."""
+        pre = f"l{i}_"
+        cq = _rms(_dot(a, p[pre + "wqa"]), p[pre + "norm_q"], self.eps)
+        q = _dot(cq, p[pre + "wqb"]).reshape(
+            a.shape[:-1] + (self.num_heads, self.nope_dim + self.rope_dim))
+        return (q[..., :self.nope_dim],
+                self._rope(q[..., self.nope_dim:], positions[..., None]))
+
+    def _latent_row(self, p, i, a, positions):
+        """The cache row ``(c_kv | k_r | 0...)`` of each token, float32,
+        ``pool_width`` wide."""
+        import jax.numpy as jnp
+        pre = f"l{i}_"
+        ckv_kr = _dot(a, p[pre + "wkva"])
+        ckv = _rms(ckv_kr[..., :self.kv_lora_rank], p[pre + "norm_kv"],
+                   self.eps)
+        kr = self._rope(ckv_kr[..., self.kv_lora_rank:], positions)
+        pad = jnp.zeros(kr.shape[:-1] + (self.pool_width - self.row_width,),
+                        kr.dtype)
+        return jnp.concatenate([ckv, kr, pad], axis=-1)
+
+    def _wkvb(self, p, i):
+        """``W_kvb`` as ``(c, H, nope + v)``."""
+        return p[f"l{i}_wkvb"].reshape(
+            self.kv_lora_rank, self.num_heads, self.nope_dim + self.v_dim)
+
+    def _ffn(self, p, i, h, valid, counts):
+        """``h + FFN(RMSNorm(h))`` over flat rows ``h (T, U)``."""
+        import jax
+        from ...parallel.moe import routed_expert_share
+        pre = f"l{i}_"
+        m = _rms(h, p[pre + "norm_ffn"], self.eps)
+        if i < self.first_dense:
+            with jax.named_scope("ffn.dense"):
+                return h + _swiglu(m, p[pre + "wg"], p[pre + "wu"],
+                                   p[pre + "wd"])
+        y, rows, n_assign = routed_expert_share(
+            m, p[pre + "router"], p[pre + "exp_wg"], p[pre + "exp_wu"],
+            p[pre + "exp_wd"], self.held, top_k=self.top_k,
+            n_group=self.n_group, topk_group=self.topk_group,
+            scale=self.routed_scale, valid=valid)
+        counts.append((rows, n_assign))
+        with jax.named_scope("moe.shared"):
+            shared = _swiglu(m, p[pre + "sh_wg"], p[pre + "sh_wu"],
+                             p[pre + "sh_wd"])
+        return h + y + shared
+
+    def attend_expanded(self, p, i, a, positions, causal):
+        """Expanded attention over a whole sequence ``a (B, S, U)``:
+        per-head keys and values from the (stored-precision) latent rows.
+        Returns ``(attention output (B, S, U) float32, rows (B, S,
+        pool_width) in the cache dtype)``."""
+        import jax
+        import jax.numpy as jnp
+        B, S, _ = a.shape
+        H, dt = self.num_heads, self.dtype
+        q_nope, q_rope = self._queries(p, i, a, positions)
+        rows = self._latent_row(p, i, a, positions).astype(dt)
+        ckv = rows[..., :self.kv_lora_rank]
+        kr = rows[..., self.kv_lora_rank:self.row_width]
+        kv = _einsum("bsc,chd->bshd", ckv, self._wkvb(p, i), dt)
+        k_nope, v = kv[..., :self.nope_dim], kv[..., self.nope_dim:]
+        s = (_einsum("bqhd,bkhd->bhqk", q_nope, k_nope, dt)
+             + _einsum("bqhr,bkr->bhqk", q_rope, kr, dt)) * self._scale
+        s = jnp.where(causal[None, None], s, -1e30)
+        pr = jax.nn.softmax(s, axis=-1)
+        o = _einsum("bhqk,bkhd->bqhd", pr, v, dt).reshape(B, S, -1)
+        return _dot(o, p[f"l{i}_wo"]), rows
+
+    def attend_absorbed(self, p, i, a, positions, rows, mask):
+        """Absorbed attention of one query per row ``a (B, U)`` over latent
+        ``rows (B, L, pool_width)`` (``mask (B, L)`` marks the live ones):
+        ``W_kvb``'s key half is folded into the query and its value half is
+        applied to the context.  Returns the attention output ``(B, U)``."""
+        import jax
+        import jax.numpy as jnp
+        dt, c = self.dtype, self.kv_lora_rank
+        q_nope, q_rope = self._queries(p, i, a, positions)
+        w = self._wkvb(p, i)
+        q_lat = _einsum("bhd,chd->bhc", q_nope, w[..., :self.nope_dim], dt)
+        pad = jnp.zeros(q_rope.shape[:-1]
+                        + (self.pool_width - self.row_width,), q_rope.dtype)
+        q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+        s = _einsum("bhk,blk->bhl", q, rows, dt) * self._scale
+        s = jnp.where(mask[:, None], s, -1e30)
+        pr = jax.nn.softmax(s, axis=-1)
+        ctx = _einsum("bhl,blk->bhk", pr, rows, dt)[..., :c]
+        o = _einsum("bhc,chd->bhd", ctx, w[..., self.nope_dim:], dt)
+        return _dot(o.reshape(o.shape[0], -1), p[f"l{i}_wo"])
+
+    def prefill_math(self, p, tokens, lengths):
+        """Pure prefill: ``(last_logits, rows)`` — see the class docstring.
+        Padded positions are routed to no expert."""
+        import jax
+        import jax.numpy as jnp
+        B, S = tokens.shape
+        h = p["embed"][tokens].astype(jnp.float32)
+        pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+        causal = jnp.tril(jnp.ones((S, S), bool))
+        valid = (pos < lengths[:, None]).reshape(-1)
+        out_rows, counts = [], []
+        for i in range(self.num_layers):
+            a = _rms(h, p[f"l{i}_norm_attn"], self.eps)
+            with jax.named_scope("mla.attend"):
+                o, rows = self.attend_expanded(p, i, a, pos, causal)
+            out_rows.append(rows)
+            h = self._ffn(p, i, (h + o).reshape(B * S, -1), valid,
+                          counts).reshape(B, S, -1)
+        last = _rms(h[jnp.arange(B), lengths - 1], p["norm_f"], self.eps)
+        with jax.named_scope("head"):
+            logits = _dot(last, p["head"])
+        return logits, jnp.stack(out_rows)
+
+    def step_program(self, p, tokens, positions, tables, pools, page_size):
+        """Pure fused decode step, one token a row: writes each row's latent
+        row into its page, gathers the row's paged context in place
+        (``pool[i, tables]``: only the pages the table names) and attends
+        absorbed.  Rows whose table starts with the trash page are padding
+        and are routed to no expert.  Returns ``(logits (B, vocab), (pool,),
+        (moe_rows (expert layers, held + 1) int32,))``: per expert layer
+        the rows each held expert received, then the assignments made over
+        all experts."""
+        import jax
+        import jax.numpy as jnp
+        (pool,) = pools
+        B = tokens.shape[0]
+        lctx = tables.shape[1] * page_size
+        h = p["embed"][tokens].astype(jnp.float32)
+        wp = jnp.take_along_axis(tables, (positions // page_size)[:, None],
+                                 axis=1)[:, 0]
+        woff = positions % page_size
+        mask = jnp.arange(lctx)[None, :] <= positions[:, None]
+        valid = tables[:, 0] != 0
+        counts = []
+        for i in range(self.num_layers):
+            a = _rms(h, p[f"l{i}_norm_attn"], self.eps)
+            with jax.named_scope("mla.attend"):
+                row = self._latent_row(p, i, a, positions)
+                pool = pool.at[i, wp, woff].set(row.astype(pool.dtype))
+                ctx_rows = pool[i, tables].reshape(B, lctx, self.pool_width)
+                o = self.attend_absorbed(p, i, a, positions, ctx_rows, mask)
+            h = self._ffn(p, i, h + o, valid, counts)
+        hf = _rms(h, p["norm_f"], self.eps)
+        with jax.named_scope("head"):
+            logits = _dot(hf, p["head"])
+        moe_rows = jnp.stack([jnp.concatenate([r, n[None]])
+                              for r, n in counts]) if counts \
+            else jnp.zeros((0, len(self.held) + 1), jnp.int32)
+        return logits, (pool,), (moe_rows,)
+
+    def commit_program(self, rows, lengths, tables, pools, page_size):
+        """Scatter the prefill's ``rows (layers, B, S, pool_width)`` into the
+        pool at the pages ``tables`` names, a layer at a time."""
+        (pool,) = pools
+        dest_page, dest_off = commit_destinations(
+            rows.shape[2], lengths, tables, page_size)
+        for i in range(self.num_layers):
+            pool = pool.at[i, dest_page, dest_off].set(
+                rows[i].astype(pool.dtype))
+        return (pool,)
+
+    sample_math = staticmethod(sample_math)
+
+    def record_step_extras(self, extras, model):
+        """Telemetry from one step's ``moe_rows`` (as fetched behind the
+        tokens, flat): the ``decode.moe.*`` counters ``docs/telemetry.md``
+        lists."""
+        rows = np.asarray(extras).reshape(-1, len(self.held) + 1)
+        if not rows.size:
+            return
+        held = rows[:, :-1]
+        _tel.count("decode.moe.assignments", int(rows[:, -1].sum()),
+                   model=model)
+        _tel.count("decode.moe.assignments_held", int(held.sum()),
+                   model=model)
+        _tel.count("decode.moe.experts_hit", int((held > 0).sum()),
+                   model=model)
+        _tel.count("decode.moe.layer_steps", int(held.shape[0]),
+                   model=model)
+        _tel.count("decode.moe.max_expert_rows", int(held.max()),
+                   model=model)
+
+    # ------------------------------------------------------- gluon frontend
+    def hybrid_forward(self, F, tokens, lengths, **params):
+        if not isinstance(tokens, NDArray) and not hasattr(tokens, "_data"):
+            raise NotImplementedError(
+                "LatentMoELM has no symbolic frontend (export is not "
+                "supported); the decode runtime compiles it through "
+                "compile_grid / the CachedOp path instead")
+        leaves = [params[n] for n in self._param_order]
+
+        def pure(tok, ln_, *leaf_vals):
+            return self.prefill_math(self._params_dict(leaf_vals), tok, ln_)
+
+        return tuple(invoke_fn(pure, [tokens, lengths] + leaves,
+                               op_name="latent_moe_prefill"))

@@ -34,7 +34,8 @@ import numpy as np
 from ...gluon.block import HybridBlock
 from ...ndarray import NDArray, invoke_fn
 
-__all__ = ["CausalLM", "get_decode_model", "rowdot", "kv_quantize_rows",
+__all__ = ["CausalLM", "get_decode_model", "rowdot", "sample_math",
+           "kv_quantize_rows",
            "kv_dequantize", "kv_quantize_rows_fp8", "kv_dequantize_fp8"]
 
 
@@ -159,6 +160,47 @@ def _kv_gather(state, i, tables, B, lctx, H, D):
                 kv_dequantize_fp8(g(state["v"]), side(1)))
     return (kv_dequantize(g(state["k"]), side(0), side(1)),
             kv_dequantize(g(state["v"]), side(2), side(3)))
+
+
+def commit_destinations(S, lengths, tables, page_size):
+    """``(page, offset) (B, S)`` at which a prefill's position ``j`` of row
+    ``b`` is stored: the page ``tables[b, j // page_size]``, or the trash
+    page where ``j`` is padding past ``lengths[b]``."""
+    import jax.numpy as jnp
+    B = lengths.shape[0]
+    j = jnp.arange(S)[None, :]
+    dest_page = jnp.where(
+        j < lengths[:, None],
+        jnp.take_along_axis(tables, j // page_size, axis=1), 0)
+    return dest_page, jnp.broadcast_to(j % page_size, (B, S))
+
+
+def sample_math(logits, keys, steps, temps):
+    """Per-row next-token choice on a deterministic per-request key
+    stream: greedy at ``temp == 0``, Gumbel-max temperature sampling
+    otherwise.  ``keys (B, 2) uint32`` are request base keys and
+    ``steps (B,) int32`` the per-request token index — folding inside
+    the program keeps the stream a pure function of (request seed,
+    token index), independent of batch composition or scheduling."""
+    import jax
+    import jax.numpy as jnp
+    greedy = jnp.argmax(logits, -1).astype("int32")
+
+    def with_gumbel(_):
+        folded = jax.vmap(jax.random.fold_in)(keys, steps)
+        u = jax.vmap(lambda kk: jax.random.uniform(
+            kk, (logits.shape[-1],), minval=1e-7, maxval=1.0))(folded)
+        g = -jnp.log(-jnp.log(u))
+        t = jnp.where(temps > 0, temps, 1.0)[:, None]
+        sampled = jnp.argmax(logits / t + g, -1).astype("int32")
+        return jnp.where(temps > 0, sampled, greedy)
+
+    # all-greedy batches skip the Gumbel streams entirely (threefry
+    # is the hot op at decode shapes); any sampled row takes the
+    # full branch, whose per-row folds are untouched — either way
+    # the returned tokens are bitwise the unconditional computation
+    return jax.lax.cond(jnp.any(temps > 0), with_gumbel,
+                        lambda _: greedy, None)
 
 
 class CausalLM(HybridBlock):
@@ -395,32 +437,46 @@ class CausalLM(HybridBlock):
         out = (logits, state["k"], state["v"])
         return out if state["q"] is None else out + tuple(state["q"])
 
-    def sample_math(self, logits, keys, steps, temps):
-        """Per-row next-token choice on a deterministic per-request key
-        stream: greedy at ``temp == 0``, Gumbel-max temperature sampling
-        otherwise.  ``keys (B, 2) uint32`` are request base keys and
-        ``steps (B,) int32`` the per-request token index — folding inside
-        the program keeps the stream a pure function of (request seed,
-        token index), independent of batch composition or scheduling."""
-        import jax
-        import jax.numpy as jnp
-        greedy = jnp.argmax(logits, -1).astype("int32")
+    sample_math = staticmethod(sample_math)
 
-        def with_gumbel(_):
-            folded = jax.vmap(jax.random.fold_in)(keys, steps)
-            u = jax.vmap(lambda kk: jax.random.uniform(
-                kk, (logits.shape[-1],), minval=1e-7, maxval=1.0))(folded)
-            g = -jnp.log(-jnp.log(u))
-            t = jnp.where(temps > 0, temps, 1.0)[:, None]
-            sampled = jnp.argmax(logits / t + g, -1).astype("int32")
-            return jnp.where(temps > 0, sampled, greedy)
+    # ------------------------------------- what the runtime and cache read
+    #: prompts one prefill call may hold (None: as many as a step)
+    max_prefill_batch = None
 
-        # all-greedy batches skip the Gumbel streams entirely (threefry
-        # is the hot op at decode shapes); any sampled row takes the
-        # full branch, whose per-row folds are untouched — either way
-        # the returned tokens are bitwise the unconditional computation
-        return jax.lax.cond(jnp.any(temps > 0), with_gumbel,
-                            lambda _: greedy, None)
+    def cache_layout(self):
+        """Two pools (keys, values) whose row is the whole token row
+        ``heads * head_dim`` in float32; quantizable (int8 / fp8 sidecars);
+        under a mesh the row axis is split by heads."""
+        row = self.num_heads * self.head_dim
+        return {"layers": self.num_layers,
+                "pools": (("k", row, "float32"), ("v", row, "float32")),
+                "quantizable": True, "shard_heads": self.num_heads,
+                "max_length": self.max_length}
+
+    def prefill_state(self, b, s):
+        """Shape and dtype of the K/V :meth:`prefill_math` emits."""
+        return (2, self.num_layers, b, s, self.num_heads,
+                self.head_dim), "float32"
+
+    def step_program(self, p, tokens, positions, tables, pools, page_size):
+        """:meth:`step_math` by the runtime's protocol: ``(logits, pools,
+        extras)`` with ``pools`` the values pools then the sidecars."""
+        out = self.step_math(p, tokens, positions, tables, pools[0],
+                             pools[1], page_size, quant=pools[2:] or None)
+        return out[0], tuple(out[1:]), ()
+
+    def commit_program(self, kv, lengths, tables, pools, page_size):
+        """Scatter the prefill's ``kv`` into the pools at the pages
+        ``tables`` names (positions past ``lengths`` go to the trash page):
+        one scatter a layer, as the step writes (a scatter over all layers
+        at once makes the compiler relayout both whole pools)."""
+        dest_page, dest_off = commit_destinations(
+            kv.shape[3], lengths, tables, page_size)
+        state = {"k": pools[0], "v": pools[1],
+                 "q": list(pools[2:]) if len(pools) > 2 else None}
+        for i in range(self.num_layers):
+            _kv_scatter(state, i, dest_page, dest_off, kv[0, i], kv[1, i])
+        return (state["k"], state["v"]) + tuple(state["q"] or ())
 
     # ------------------------------------------------------- gluon frontend
     def hybrid_forward(self, F, tokens, lengths, **params):

@@ -65,19 +65,38 @@ def seq_bucket_ladder(max_seqlen, min_bucket=8):
 
 
 class DecodeRuntime:
-    """A :class:`~mxnet_tpu.serving.decode.model.CausalLM` plus a
-    :class:`PagedKVCache`, compiled into the 2-D prefill grid and
-    per-batch-bucket step programs described in the module docstring.
+    """A decode block plus a :class:`PagedKVCache`, compiled into the 2-D
+    prefill grid and per-batch-bucket step programs described in the module
+    docstring.
+
+    **What a block is to the runtime** (:class:`~mxnet_tpu.serving.decode.
+    model.CausalLM` and :class:`~mxnet_tpu.serving.decode.latent_moe.
+    LatentMoELM` both are): a hybridizable block whose forward is the
+    prefill ``(tokens (B, S), lengths (B,)) -> (last_logits, state)``, with
+    ``vocab_size``, ``param_leaves()`` / ``_params_dict(leaves)``,
+    ``cache_layout()`` (the pools the cache builds, whether they may be
+    quantized or sharded, and ``max_length``, the context the block is
+    good for), ``max_prefill_batch`` (the most prompts one prefill call may
+    hold; None: as many as a step), ``prefill_state(b, s)`` (shape and
+    dtype of that ``state``),
+    ``commit_program(state, lengths, tables, pools, page_size) -> pools``,
+    ``step_program(params, tokens, positions, tables, pools, page_size)
+    -> (logits, pools, extras)`` and ``sample_math``.  ``extras`` are int32
+    arrays that ride the step's one fetch behind the tokens and are handed
+    to ``block.record_step_extras`` when telemetry is on.  A verify ladder
+    (``spec_buckets``) also needs ``verify_math``.
 
     Parameters
     ----------
-    block : CausalLM
+    block : CausalLM or LatentMoELM
         Initialized decode model (hybridized in place if needed).
     cache : PagedKVCache, optional
-        Built from the model geometry when omitted (``page_size`` /
-        ``num_pages`` / ``max_slots`` forwarded).
+        Built from the block's ``cache_layout()`` when omitted
+        (``page_size`` / ``num_pages`` / ``max_slots`` forwarded).
     batch_buckets : sequence of int
-        Decode-batch ladder; the cap is the max concurrent batch.
+        Decode-batch ladder; the cap is the max concurrent batch.  The
+        prefill grid takes the buckets up to the block's
+        ``max_prefill_batch`` (all of them where that is None).
     seq_buckets : sequence of int, optional
         Prompt-length ladder; defaults to :func:`seq_bucket_ladder` over
         the cache's context length.  Prompts longer than the cap are
@@ -104,6 +123,11 @@ class DecodeRuntime:
         if not getattr(block, "_active", False):
             block.hybridize()
         self._block = block
+        layout = block.cache_layout()
+        if spec_buckets and not hasattr(block, "verify_math"):
+            raise ValueError(
+                f"{type(block).__name__} has no verify program: a session "
+                f"of this block cannot speculate (drafter=None only)")
         self.name = name or getattr(block, "name", "decode")
         self.batch_buckets = tuple(sorted(set(
             int(b) for b in batch_buckets)))
@@ -111,18 +135,30 @@ class DecodeRuntime:
             raise ValueError(f"batch buckets {self.batch_buckets} must "
                              f"be >= 1")
         self.max_batch = self.batch_buckets[-1]
+        # the prefill grid's rows: the ladder as far as the block's own cap
+        cap = block.max_prefill_batch
+        self.prefill_batch_buckets = tuple(
+            b for b in self.batch_buckets if cap is None or b <= cap)
+        if not self.prefill_batch_buckets:
+            raise ValueError(
+                f"{type(block).__name__} prefills at most {cap} prompts a "
+                f"call: batch buckets {self.batch_buckets} need one that "
+                f"small")
+        self.max_prefill_batch = self.prefill_batch_buckets[-1]
+        # the context the block is good for: a position table's length, or
+        # the context a rotary block was built for
+        max_length = int(layout["max_length"])
         if cache is None:
             # floor, not ceil: the derived context (max_pages * page_size)
-            # must never exceed the model's position table
-            max_pages = block.max_length // int(page_size)
+            # must never exceed the model's max_length
+            max_pages = max_length // int(page_size)
             if max_pages < 1:
                 raise ValueError(
                     f"page_size={page_size} exceeds the model's "
-                    f"max_length={block.max_length} — no whole page fits "
+                    f"max_length={max_length} — no whole page fits "
                     f"the position table")
             cache = PagedKVCache(
-                block.num_layers, block.num_heads, block.head_dim,
-                page_size=page_size,
+                layout=layout, page_size=page_size,
                 num_pages=(num_pages if num_pages is not None
                            else max_pages * 2 * self.max_batch + 1),
                 max_pages_per_seq=max_pages,
@@ -130,10 +166,15 @@ class DecodeRuntime:
                            else 2 * self.max_batch),
                 kv_dtype=kv_dtype, prefix_sharing=prefix_sharing,
                 mesh=mesh)
-        if cache.context_length > block.max_length:
+        if cache.context_length > max_length:
             raise ValueError(
                 f"cache context {cache.context_length} exceeds the model's "
-                f"position table ({block.max_length})")
+                f"position table ({max_length})")
+        if [(w, d) for _n, w, d in cache.pool_layout] != \
+                [(w, d) for _n, w, d in layout["pools"]]:
+            raise ValueError(
+                f"cache pools {cache.pool_layout} are not the block's "
+                f"{tuple(layout['pools'])}")
         if cache.max_slots < self.max_batch:
             raise ValueError(
                 f"cache max_slots={cache.max_slots} < largest batch "
@@ -246,7 +287,8 @@ class DecodeRuntime:
         warming executes the real donated programs without touching a
         single allocated page.  (Building the ``jax.jit`` objects alone
         would defer XLA compilation to the first mid-traffic call.)"""
-        grid = [(b, s) for b in self.batch_buckets for s in self.seq_buckets]
+        grid = [(b, s) for b in self.prefill_batch_buckets
+                for s in self.seq_buckets]
         with _tel.span("decode.warmup", model=self.name,
                        grid=len(grid), steps=len(self.batch_buckets)):
             def make_example(b, s):
@@ -305,6 +347,7 @@ class DecodeRuntime:
         the byte-exact executable (zero trace, zero XLA compile); a miss
         AOT-compiles and commits it for the next process.  The warm()
         drive that follows then executes already-resolved programs."""
+        import jax
         pc = self.aot_cache
         block, cache = self._block, self.cache
         np_ = cache.max_pages_per_seq
@@ -335,9 +378,8 @@ class DecodeRuntime:
         for b, s in grid:
             if (b, s) in self._commit_fns:
                 continue
-            args = (self._params,
-                    np.zeros((2, block.num_layers, b, s,
-                              block.num_heads, block.head_dim), "float32"),
+            shape, dtype = block.prefill_state(b, s)
+            args = (self._params, jax.ShapeDtypeStruct(shape, dtype),
                     np.zeros((b, block.vocab_size), "float32"),
                     np.zeros((b,), "int32"), np.zeros((b, np_), "int32"),
                     np.zeros((b, 2), "uint32"), np.zeros((b,), "int32"),
@@ -346,7 +388,6 @@ class DecodeRuntime:
                 f"commit-b{b}-s{s}", self._build_commit(), args)
             self._commit_fns[(b, s)] = fn
         if self._sample_fn is None:
-            import jax
             args = (np.zeros((1, block.vocab_size), "float32"),
                     np.zeros((1, 2), "uint32"), np.zeros((1,), "int32"),
                     np.zeros((1,), "float32"))
@@ -382,17 +423,21 @@ class DecodeRuntime:
 
     def _build_step(self):
         import jax
+        import jax.numpy as jnp
         block, page_size = self._block, self.cache.page_size
-        quantized = self.cache.quantized
 
         def step(params, tokens, positions, tables, keys, steps, temps,
                  *pools):
             p = block._params_dict(params)
-            out = block.step_math(
-                p, tokens, positions, tables, pools[0], pools[1], page_size,
-                quant=pools[2:] if quantized else None)
-            nxt = block.sample_math(out[0], keys, steps, temps)
-            return (nxt,) + tuple(out[1:])
+            logits, pools, extras = block.step_program(
+                p, tokens, positions, tables, pools, page_size)
+            nxt = block.sample_math(logits, keys, steps, temps)
+            # what the host reads is ONE int32 vector: the tokens, then
+            # whatever the block counts (nothing for CausalLM)
+            if extras:
+                nxt = jnp.concatenate(
+                    [nxt] + [e.astype("int32").reshape(-1) for e in extras])
+            return (nxt,) + tuple(pools)
 
         n = len(self.cache.pools)
         return jax.jit(step, donate_argnums=tuple(range(7, 7 + n)))
@@ -451,29 +496,14 @@ class DecodeRuntime:
 
     def _build_commit(self):
         import jax
-        import jax.numpy as jnp
-        from .model import _kv_scatter
         block, page_size = self._block, self.cache.page_size
-        quantized = self.cache.quantized
 
         def commit(params, kv, logits, lengths, tables, keys, steps, temps,
                    *pools):
-            B, S = kv.shape[2], kv.shape[3]
-            j = jnp.arange(S)[None, :]
-            valid = j < lengths[:, None]
-            dest_page = jnp.where(
-                valid, jnp.take_along_axis(tables, j // page_size, axis=1),
-                0)
-            dest_off = jnp.broadcast_to(j % page_size, (B, S))
-            # one scatter a layer, as the step writes: a scatter over all
-            # layers at once makes the compiler relayout both whole pools
-            state = {"k": pools[0], "v": pools[1],
-                     "q": list(pools[2:]) if quantized else None}
-            for i in range(block.num_layers):
-                _kv_scatter(state, i, dest_page, dest_off,
-                            kv[0, i], kv[1, i])
+            pools = block.commit_program(kv, lengths, tables, pools,
+                                         page_size)
             first = block.sample_math(logits, keys, steps, temps)
-            return (first, state["k"], state["v"]) + tuple(state["q"] or ())
+            return (first,) + tuple(pools)
 
         n = len(self.cache.pools)
         return jax.jit(commit, donate_argnums=tuple(range(8, 8 + n)))
@@ -555,6 +585,11 @@ class DecodeRuntime:
                 cache.set_pools(out[1:])
             with _tel.span("decode.step.fetch"):
                 nxt = np.asarray(out[0])
+        if nxt.shape[0] > b:
+            # the block's counts rode the same fetch, behind the tokens
+            if _tel.enabled:
+                self._block.record_step_extras(nxt[b:], self.name)
+            nxt = nxt[:b]
         return nxt
 
     def verify(self, tokens, positions, n_draft, tables, keys, steps,

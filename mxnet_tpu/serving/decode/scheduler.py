@@ -709,8 +709,8 @@ class DecodeScheduler:
                 groups.setdefault(rt.seq_bucket_for(req.prompt.size),
                                   []).append(req)
         for s, reqs in sorted(groups.items()):
-            for i in range(0, len(reqs), rt.max_batch):
-                self._prefill_group(reqs[i:i + rt.max_batch], s)
+            for i in range(0, len(reqs), rt.max_prefill_batch):
+                self._prefill_group(reqs[i:i + rt.max_prefill_batch], s)
 
     def _admit_prefix_hit(self, req):
         """A full-prompt prefix hit: admission IS the time-to-first-token

@@ -171,10 +171,97 @@ def _pool_program(rt, kind, b, sds):
         return rt._build_verify(), \
             (params, sds((b, _SPEC_K + 1), i32), sds((b,), i32),
              sds((b,), i32)) + rows + pools
-    kv = sds((2, blk.num_layers, b, _SEQ, _HEADS, _UNITS // _HEADS), f32)
+    kv = sds(*blk.prefill_state(b, _SEQ))
     return rt._build_commit(), \
         (params, kv, sds((b, blk.vocab_size), f32), sds((b,), i32)) \
         + rows + pools
+
+
+# axk1_ep16's serving geometry (PERF.md section 4): a latent row of 512 + 64
+# values in a 640-wide pool row, pages of 16 tokens, 8193 pages, 128 pages a
+# row.  Every attention width is the published one; what the pool's geometry
+# does not depend on (the dense FFN's width, the experts held, the
+# vocabulary, the depth) is kept small so that the zeros fit a test.
+_L_PAGES, _L_ROW_PAGES, _L_SEQ = 8193, 128, 512
+
+
+@functools.lru_cache(maxsize=1)
+def _latent_runtime():
+    import mxnet_tpu as mx
+    from mxnet_tpu.serving.decode import (DecodeRuntime, LatentMoELM,
+                                          PagedKVCache)
+    net = LatentMoELM(
+        vocab_size=512, hidden_size=7168, num_layers=2, num_heads=64,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=2048,
+        moe_intermediate_size=2048, n_routed_experts=192,
+        held_experts=(0, 1), num_experts_per_tok=8, n_group=8, topk_group=4,
+        rope_scaling={"factor": 32, "original_max_position_embeddings": 4096,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                      "mscale_all_dim": 1},
+        max_length=_L_ROW_PAGES * _PAGE)
+    for p in net.collect_params().values():
+        p._load_init(mx.nd.zeros(p.shape, dtype=p.dtype), None)
+    cache = PagedKVCache(layout=net.cache_layout(), page_size=_PAGE,
+                         num_pages=2, max_pages_per_seq=_L_ROW_PAGES,
+                         max_slots=32)
+    return DecodeRuntime(net, cache=cache, batch_buckets=(1, 32),
+                         seq_buckets=(_L_SEQ,), warm=False)
+
+
+def _latent_pool_program(rt, kind, b, sds):
+    i32, u32, f32 = "int32", "uint32", "float32"
+    blk = rt.block
+    pools = tuple(sds(p.shape[:1] + (_L_PAGES,) + p.shape[2:], p.dtype)
+                  for p in rt.cache.pools)
+    if kind == "cow":
+        rt.cache.warm_programs()
+        return rt.cache._copy_fn, (sds((), i32), sds((), i32)) + pools
+    params = [sds(p.shape, p.dtype) for p in rt._params]
+    rows = (sds((b, _L_ROW_PAGES), i32), sds((b, 2), u32), sds((b,), i32),
+            sds((b,), f32))
+    if kind == "step":
+        return rt._build_step(), \
+            (params, sds((b,), i32), sds((b,), i32)) + rows + pools
+    shape, dtype = blk.prefill_state(b, _L_SEQ)
+    return rt._build_commit(), \
+        (params, sds(shape, dtype), sds((b, blk.vocab_size), f32),
+         sds((b,), i32)) + rows + pools
+
+
+@pytest.mark.parametrize("kind,b", [("step", 1), ("step", 32),
+                                    ("commit", 1), ("cow", 0)])
+def test_latent_pool_programs_touch_only_their_pages(one_chip, kind, b):
+    """The one latent pool (bfloat16, 640-wide rows) is held to what the
+    K/V pools are: no program copies it, none slices a whole layer out of
+    it, and it comes back in the buffer it was given."""
+    import numpy as np
+
+    rt = _latent_runtime()
+    fn, args = _latent_pool_program(
+        rt, kind, b, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    (pool,) = args[-1:]
+    assert pool.shape == (2, _L_PAGES, _PAGE, 640) and \
+        pool.dtype == jnp.bfloat16
+    compiled = fn.lower(*args).compile()
+    what = f"latent {kind}-b{b}"
+    layer = int(np.prod(pool.shape[1:]))
+    for op, dtype, dims in _materialised(compiled.as_text()):
+        if int(np.prod(dims)) < layer:
+            continue
+        assert (dtype, dims) == ("bf16", pool.shape), \
+            f"{what}: {op} writes {dtype}{list(dims)}, a layer of the " \
+            f"pool or more"
+        assert op != "copy", f"{what}: copies the whole pool"
+    stats = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    assert stats.alias_size_in_bytes >= pool_bytes, what
+    # a step's temporaries: the gathered context of its rows (b x 2048 x
+    # 640 values a layer), small beside the pool
+    assert stats.temp_size_in_bytes < pool_bytes / 2, \
+        f"{what}: {stats.temp_size_in_bytes / 1e9:.3f} GB of temporaries " \
+        f"beside {pool_bytes / 1e9:.3f} GB of pool"
 
 
 def _materialised(hlo_text):
