@@ -305,7 +305,8 @@ def phase_flash(size, platform, seed=0):
                 "tpu_custom_call": is_kernel, "max_abs_err": err,
                 "on": _require_on(platform, "flash output", [got])})
 
-    # --- one BERT forward with mask=None: the model's own kernel branch
+    # --- BERT forwards: the kernels without and with the key mask, then the
+    # same block sent down the dense tail
     cfg = size["bert"]
     net, (tokens, segments, _mask, positions), _ = _bert(cfg, seed)
     ctx = _ctx(platform)
@@ -321,21 +322,29 @@ def phase_flash(size, platform, seed=0):
             "dispatch.op_calls", {}).get('{op="_contrib_flash_attention"}', 0)
         before = calls()
         flash_seq = net(args[0], args[1], None, pos)[0]
+        masked_seq = net(args[0], args[1], ones, pos)[0]
         kernel_calls = calls() - before
     finally:
         if not was_on:
             telemetry.disable()
-    dense_seq = net(args[0], args[1], ones, pos)[0]
-    if kernel_calls < 1:
-        raise AssertionError("bert forward with mask=None never dispatched "
-                             "_contrib_flash_attention")
-    err = float(np.max(np.abs(flash_seq.asnumpy() - dense_seq.asnumpy())))
-    if not err <= BERT_PATH_TOL:
-        raise AssertionError(f"bert flash vs dense path: max error {err} > "
-                             f"{BERT_PATH_TOL}")
+    layers = len(net.encoder.layers._children)
+    if kernel_calls != 2 * layers:
+        raise AssertionError(
+            f"two bert forwards of {layers} layers dispatched "
+            f"_contrib_flash_attention {kernel_calls} times")
+    for cell in net.encoder.layers._children.values():
+        cell.attention._use_flash = False
+    net.hybridize()             # trace again, now with the dense tail
+    dense_seq = net(args[0], args[1], ones, pos)[0].asnumpy()
+    errs = {}
+    for name, seq in (("mask_none", flash_seq), ("mask_ones", masked_seq)):
+        errs[name] = float(np.max(np.abs(seq.asnumpy() - dense_seq)))
+        if not errs[name] <= BERT_PATH_TOL:
+            raise AssertionError(f"bert kernels ({name}) vs dense path: max "
+                                 f"error {errs[name]} > {BERT_PATH_TOL}")
     out["bert_forward"] = {
         "config": cfg, "flash_attention_dispatches": kernel_calls,
-        "shape": list(flash_seq.shape), "max_abs_err_vs_dense_path": err,
+        "shape": list(flash_seq.shape), "max_abs_err_vs_dense_path": errs,
         "on": _require_on(platform, "bert forward", [flash_seq._data])}
     return out
 

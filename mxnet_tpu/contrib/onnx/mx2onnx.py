@@ -915,3 +915,41 @@ def _crop(ctx, name, ins, attrs):
     ends = _int64_init(ctx, name + "_ends", [oy + h, ox + w])
     axes = _int64_init(ctx, name + "_axes", [2, 3])
     return ctx.add("Slice", name, [ins[0], starts, ends, axes])
+
+
+def _flash_attention_cv(ctx, name, ins, attrs):
+    """The attention kernels as the formula they compute, for a runtime
+    that has no Pallas: softmax(q k^T * scale + key bias) v over (B, H, T,
+    D).  Inference form: the dropout variant exports without its dropout,
+    as ``Dropout`` itself does."""
+    if _parse(attrs.get("causal"), False) in (True, 1, "True"):
+        raise NotImplementedError(f"{name}: causal attention has no ONNX "
+                                  f"export")
+    scale = _parse(attrs.get("scale"), None)
+    if scale is None:
+        raise NotImplementedError(f"{name}: export needs scale= given (the "
+                                  f"head width is not known here)")
+
+    def const(tag, value):
+        ctx.extra_initializers[f"{name}_{tag}"] = _np.asarray(
+            value, dtype=_np.float32)
+        return f"{name}_{tag}"
+
+    q, k, v = ins[:3]
+    kt = ctx.add("Transpose", name + "_kt", [k], {"perm": (0, 1, 3, 2)})
+    s = ctx.add("MatMul", name + "_qk", [q, kt])
+    s = ctx.add("Mul", name + "_scaled", [s, const("scale", float(scale))])
+    if len(ins) > 3:
+        # (B, T) 1 = valid -> (B, 1, 1, T) additive -1e30 on masked keys
+        away = ctx.add("Sub", name + "_away", [const("one", 1.0), ins[3]])
+        bias = ctx.add("Mul", name + "_bias", [away, const("neg", -1e30)])
+        for i in (1, 2):        # one axis a node: what the importer reads
+            axes = _int64_init(ctx, f"{name}_bias_axis{i}", [i])
+            bias = ctx.add("Unsqueeze", f"{name}_bias{i + 2}", [bias, axes])
+        s = ctx.add("Add", name + "_masked", [s, bias])
+    p = ctx.add("Softmax", name + "_p", [s], {"axis": -1})
+    return ctx.add("MatMul", name, [p, v])
+
+
+register("_contrib_flash_attention")(_flash_attention_cv)
+register("_contrib_flash_attention_dropout")(_flash_attention_cv)

@@ -15,6 +15,7 @@ from __future__ import annotations
 import math
 
 from ..gluon import Block, HybridBlock, nn
+from ..telemetry import bus as _tel
 
 __all__ = ["MultiHeadAttention", "PositionwiseFFN", "TransformerEncoderCell",
            "BERTEncoder", "BERTModel", "BERTClassifier", "get_bert_model"]
@@ -54,8 +55,9 @@ class MultiHeadAttention(HybridBlock):
         v = self._split_heads(F, v)
         from ..parallel.sp_context import current_sequence_parallel
         sp = current_sequence_parallel()
-        blockwise_ok = mask is None and not self.dropout._rate
-        if sp is not None and not blockwise_ok:
+        rate = self.dropout._rate
+        plain = mask is None and not rate
+        if sp is not None and not plain:
             import warnings
             warnings.warn(
                 "sequence-parallel scope active but attention falls back to "
@@ -63,8 +65,20 @@ class MultiHeadAttention(HybridBlock):
                 "ulysses) support neither a valid-length mask nor "
                 "attention-prob dropout yet. Long sequences will "
                 "materialize full score matrices.")
+        # the path is chosen by what is there: a sequence sharded over sp
+        # goes around the ring (or, with a mask or dropout, down the dense
+        # tail, where XLA partitions the T x T tensors); every other call
+        # takes the blockwise kernels, the mask and dropout's keep-mask
+        # being operands that are present or absent
+        kind = "dense"
+        if sp is not None:
+            kind = sp[3] if plain else "dense"
+        elif self._use_flash:
+            kind = "blockwise"
+        _tel.count("attention.path", kind=kind, masked=mask is not None,
+                   dropout=bool(rate))
         ctx = None
-        if blockwise_ok and sp is not None:
+        if kind in ("ring", "ulysses"):
             # sequence-parallel path: T stays sharded over the sp axis;
             # K/V ring around it (parallel/ring_attention.py) or heads are
             # all_to_all-sharded (parallel/ulysses.py), per the scope's impl
@@ -79,14 +93,20 @@ class MultiHeadAttention(HybridBlock):
                     qq, kk, vv, mesh, sp_axis=sp_axis, dp_axis=dp_axis,
                     scale=1.0),
                 [q, k, v])
-        elif blockwise_ok and self._use_flash:
-            # unmasked single-shard path: Pallas blockwise kernel
-            ctx = F.contrib.flash_attention(q, k, v, scale=1.0)
+        elif kind == "blockwise":
+            # the dropout op draws its key where self.dropout would (one a
+            # call, whatever the mode), so the step's key chain is the
+            # dense tail's
+            args = [q, k, v] if mask is None else [q, k, v, mask]
+            ctx = F.contrib.flash_attention_dropout(*args, p=rate,
+                                                    scale=1.0) \
+                if rate else F.contrib.flash_attention(*args, scale=1.0)
         if ctx is not None:
             ctx = F.transpose(ctx, axes=(0, 2, 1, 3))
             ctx = F.reshape(ctx, shape=(0, 0, -3))
             return self.proj(ctx)
-        # scores: (B, H, T, T) — one MXU batch_dot
+        # the dense tail: use_flash_attention=False, or a sequence-parallel
+        # scope with a mask or dropout.  scores: (B, H, T, T)
         scores = F.batch_dot(F.reshape(q, shape=(-3, 0, 0)),
                              F.reshape(k, shape=(-3, 0, 0)),
                              transpose_b=True)

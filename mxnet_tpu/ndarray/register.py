@@ -24,7 +24,8 @@ from .ndarray import NDArray, _as_nd, _wrap, invoke
 
 # Ops whose behavior depends on autograd train/test mode (reference: ops read
 # ``ctx.is_train`` from the OpContext, include/mxnet/op_attr_types.h).
-MODE_DEPENDENT = {"Dropout", "BatchNorm", "RNN", "_contrib_SyncBatchNorm"}
+MODE_DEPENDENT = {"Dropout", "BatchNorm", "RNN", "_contrib_SyncBatchNorm",
+                  "_contrib_flash_attention_dropout"}
 
 _MOMENTUM_DEFAULT = 0.9
 

@@ -3,179 +3,537 @@
 The reference's answer to "the framework op isn't fast enough" was
 hand-written CUDA (``src/operator/*.cu``) or NVRTC runtime compilation
 (``mx.rtc``, src/common/rtc.cc); the TPU-native answer is Pallas.  First
-resident kernel: **flash attention** — blockwise online-softmax attention
-that never materializes the T×T score matrix, streaming K/V blocks from
-VMEM while the running max/denominator stay in registers (the memory story
+resident kernel: **flash attention** — blockwise attention that never
+writes a T×T float32 tensor to HBM, in either direction (the memory story
 behind the sequence-parallel design, SURVEY.md §5.7).
 
-The public entry ``flash_attention`` is differentiable: forward runs the
-kernel, backward recomputes with the plain XLA formulation (standard
-flash-attention recompute trade — backward FLOPs for O(T²) memory).
+``flash_attention`` is differentiable and both passes are kernels, on the
+grid (group of heads, q blocks, k blocks), one (BQ, BK) score tile a head a
+step:
+
+- forward: online softmax in float32 with the running max, denominator
+  and accumulator in VMEM scratch; returns the output and the rows'
+  log-sum-exp, lane-dense as (batch*head, 1, T);
+- backward, one kernel: each step recomputes its tile of probabilities from
+  q, k and the saved log-sum-exp, takes ``delta = rowsum(dO * O)`` from the
+  saved output (exact with dropout, O being the dropped probabilities
+  times V), accumulates dQ in scratch and dK, dV into whole-sequence
+  float32 output blocks that stay in VMEM while a group's q blocks go by.
+  Those two blocks are the only thing whose size grows with T (2 KiB of
+  VMEM a token at 128 lanes): the backward asks the compiler for the VMEM
+  they take, and a v5e core's 128 MiB refuses it past some 57,000 tokens;
+  the forward has no such limit.  The key gradients of a head are
+  recentred over its keys afterwards (``_fa_backward`` says why).
+
+The kernels read (B, T, H*D) arrays, heads side by side as a layer's
+projections leave them, and a block is as many heads as fill the 128 lanes
+(``_layout``): the (B, H, T, D) of the public signature costs a layer no
+copy.
+
+Two optional operands, present or absent: ``kv_mask`` (B, T), 1 = valid
+key, which enters as an additive bias on the score tile; and ``keep``
+(B*H, T, T) int8, dropout's keep-mask on the probabilities, made by the
+caller (``ops/__init__.py`` draws it with ``jax.random.bernoulli``) and
+read a tile a step.  The row sum is taken from the undropped
+probabilities; the keep-mask and ``1 / (1 - rate)`` apply to what enters
+probabilities x values.
+
+Products are at XLA's default precision for the backend: on the TPU they
+take bfloat16 inputs and accumulate in float32, which is what XLA does to
+float32 operands there; under the interpreter on the CPU they are float32,
+as XLA's are.  ``jax.default_matmul_precision`` overrides both ways
+("highest" for an exactness check on the chip, "bfloat16" to see the
+chip's rounding on the CPU).  Softmax statistics, outputs and gradients
+are float32.
 """
 from __future__ import annotations
 
 import functools
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention"]
 
 _NEG = -1e30
 
-# Mosaic's default scoped-VMEM budget for one kernel on a v5e core; the
-# chip's compiler refuses a kernel whose blocks need more
-_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
-               seq_len, kv_len):
-    """One (batch*head, q-block) program: stream K/V blocks, online softmax.
+def _products(interpret):
+    """(operand dtype, precision) of the kernels' matrix products: XLA's
+    default for float32 operands on the platform the kernels run on (one
+    bfloat16 pass on the TPU, float32 under the interpreter on the CPU),
+    unless ``jax.default_matmul_precision`` names one."""
+    named = jax.config.jax_default_matmul_precision
+    if named == "bfloat16" or (named in (None, "default") and not interpret):
+        return jnp.bfloat16, None
+    return jnp.float32, jax.lax.Precision.HIGHEST
 
-    Block shapes: q (1, BQ, D), k/v (1, T, D), o (1, BQ, D).  ``seq_len``
-    is the padded T, ``kv_len`` the real one: keys at or past it are the
-    zero padding and are masked out (causal masking already hides them
-    from every real query row).
-    """
-    qi = pl.program_id(1)
-    bq = q_ref.shape[1]
-    d = q_ref.shape[2]
-    q = q_ref[0].astype(jnp.float32) * scale          # (BQ, D)
 
-    m0 = jnp.full((bq, 1), _NEG, dtype=jnp.float32)
-    l0 = jnp.zeros((bq, 1), dtype=jnp.float32)
-    acc0 = jnp.zeros((bq, d), dtype=jnp.float32)
-    num_k = seq_len // block_k
+def _eye(n):
+    return jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == \
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
 
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
 
-    def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (BQ, BK)
-        if causal or kv_len < seq_len:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            keep = q_pos >= k_pos if causal else k_pos < kv_len
-            s = jnp.where(keep, s, _NEG)
-        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - new_m)
-        corr = jnp.exp(m - new_m)
-        new_l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        new_acc = acc * corr + jnp.dot(p, v,
-                                       preferred_element_type=jnp.float32)
-        return new_m, new_l, new_acc
+def _col_to_row(col):
+    """(n, 1) -> (1, n) by a masked reduction: statistics live as columns
+    beside a score tile and as lane-dense rows in HBM (an (.., T, 1) array
+    is padded to 128 lanes there)."""
+    return jnp.sum(jnp.where(_eye(col.shape[0]), col, 0.0), axis=0,
+                   keepdims=True)
 
+
+def _row_to_col(row):
+    return jnp.sum(jnp.where(_eye(row.shape[1]), row, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _scores(q, k, bias_ref, qi, ki, causal, precision):
+    """The (BQ, BK) float32 score tile of step (qi, ki): q k^T, the key
+    bias, the causal mask."""
+    bq, bk = q.shape[0], k.shape[0]
+    s = jax.lax.dot_general(q, k, _NT, precision=precision,
+                            preferred_element_type=jnp.float32)
+    if bias_ref is not None:
+        s = s + bias_ref[0]
     if causal:
-        # skip fully-masked K blocks: block j is live iff j*BK <= last q pos
-        last_q = qi * bq + bq - 1
-        num_live = jnp.minimum((last_q // block_k) + 1, num_k)
+        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        s = jnp.where(q_pos >= k_pos, s, _NEG)
+    return s
+
+
+def _lanes(width, pack):
+    """One lane mask (1, width) a packed head, or ``[None]`` for one head a
+    block: head ``a`` of a block owns lanes ``a * d .. (a + 1) * d``."""
+    if pack == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return [lane // (width // pack) == a for a in range(pack)]
+
+
+def _own(sel, new, old):
+    """``new`` on a head's own lanes, ``old`` on the others."""
+    return new if sel is None else jnp.where(sel, new, old)
+
+
+def _when_live(qi, ki, bq, bk, causal, tile):
+    """Run ``tile`` unless every key of step (qi, ki) lies past the
+    diagonal of every query of the block."""
+    if causal:
+        pl.when(ki * bk <= qi * bq + bq - 1)(tile)
     else:
-        num_live = num_k
-    m, l, acc = jax.lax.fori_loop(0, num_live, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        tile()
 
 
-def _fa_forward(q, k, v, causal, scale, block_q, block_k, interpret):
-    b, h, t, d = q.shape
-    orig_t, orig_d = t, d
-    # pad D to the 128-lane tile and T to the block size; the kernel masks
-    # the padded key positions by length
-    pad_d = (-d) % 128
-    block = max(block_q, block_k)
-    pad_t = (-t) % block
-    if pad_d or pad_t:
-        cfg = [(0, 0), (0, 0), (0, pad_t), (0, pad_d)]
-        q = jnp.pad(q, cfg)
-        k = jnp.pad(k, cfg)
-        v = jnp.pad(v, cfg)
-        t, d = t + pad_t, d + pad_d
-    # K and V ride as whole-sequence blocks, double-buffered like the q/o
-    # blocks, beside the kernel's f32 scores, probabilities and accumulator.
-    # Past the budget the chip's compiler refuses the kernel ("scoped vmem
-    # limit"), so say so here with the numbers.  The estimate matches the
-    # v5e compiler's verdict at 128x128 blocks: bf16 passes to 16,000
-    # tokens, f32 to 7,936, at head_dim <= 128.
-    itemsize = jnp.dtype(q.dtype).itemsize
-    fixed = 4 * block_q * d * itemsize + \
-        4 * (2 * block_q * block_k + block_q * d)
-    if 4 * t * d * itemsize + fixed > _VMEM_LIMIT_BYTES:
-        max_t = (_VMEM_LIMIT_BYTES - fixed) // (4 * d * itemsize)
-        raise ValueError(
-            f"flash_attention: {orig_t} tokens (padded {t}) at head_dim "
-            f"{orig_d} (padded {d}) {q.dtype} need "
-            f"{4 * t * d * itemsize + fixed} bytes of VMEM for the "
-            f"whole-sequence K/V blocks, over the kernel's "
-            f"{_VMEM_LIMIT_BYTES}-byte budget; the limit at this width and "
-            f"dtype is {max_t // block * block} tokens")
-    bh = b * h
-    qf = q.reshape(bh, t, d)
-    kf = k.reshape(bh, t, d)
-    vf = v.reshape(bh, t, d)
+def _fa_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, keep_prob, biased,
+               dropped, pack, products):
+    """One (group of ``pack`` heads, q block, k block) step of the forward.
 
-    grid = (bh, t // block_q)
-    kernel = functools.partial(_fa_kernel, block_k=block_k, causal=causal,
-                               scale=scale, seq_len=t, kv_len=orig_t)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        interpret=interpret,
-    )(qf, kf, vf)
-    out = out.reshape(b, h, t, d)
-    return out[:, :, :orig_t, :orig_d]
+    Blocks: q, o (1, BQ, pack*D); k, v (1, BK, pack*D); bias (1, 1, BK)
+    float32 (0, -1e30 on a masked key, -inf on the padding); keep (pack,
+    BQ, BK) int8; lse (pack, 1, BQ).  Scratch: m, l (pack, BQ, 1) and acc
+    (BQ, pack*D), float32.
+    """
+    rest = list(rest)
+    bias_ref = rest.pop(0) if biased else None
+    keep_ref = rest.pop(0) if dropped else None
+    o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    mxu, precision = products
+    lanes = _lanes(q_ref.shape[2], pack)
+
+    @pl.when(ki == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def tile():
+        q = q_ref[0].astype(jnp.float32) * scale
+        k, v = k_ref[0].astype(mxu), v_ref[0].astype(mxu)
+        acc = acc_sc[...]
+        for a, sel in enumerate(lanes):
+            s = _scores(_own(sel, q, 0.0).astype(mxu), k, bias_ref, qi, ki,
+                        causal, precision)
+            m = m_sc[a]
+            new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - new_m)
+            corr = jnp.exp(m - new_m)
+            l_sc[a] = l_sc[a] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_sc[a] = new_m
+            if dropped:
+                p = p * keep_ref[a].astype(jnp.float32)
+            pv = jnp.dot(p.astype(mxu), v, precision=precision,
+                         preferred_element_type=jnp.float32)
+            acc = _own(sel, acc * corr + pv, acc)
+        acc_sc[...] = acc
+
+    _when_live(qi, ki, bq, bk, causal, tile)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _():
+        out = acc_sc[...]
+        for a, sel in enumerate(lanes):
+            l = l_sc[a]
+            out = _own(sel, acc_sc[...] / (l * keep_prob), out)
+            lse_ref[a] = _col_to_row(m_sc[a] + jnp.log(l))
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _reference(q, k, v, causal, scale):
+def _fa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
+                   causal, scale, keep_prob, biased, dropped, pack, products):
+    """One (group of ``pack`` heads, q block, k block) step of the backward.
+
+    Blocks as the forward's, with do (1, BQ, pack*D); outputs dq (1, BQ,
+    pack*D) and dk, dv (1, T, pack*D) float32, whole-sequence and resident
+    while the step's group of heads lasts.  Scratch: dq (BQ, pack*D), lse
+    and delta (pack, BQ, 1).
+    """
+    rest = list(rest)
+    bias_ref = rest.pop(0) if biased else None
+    keep_ref = rest.pop(0) if dropped else None
+    dq_ref, dk_ref, dv_ref, dq_sc, lse_sc, delta_sc = rest
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    mxu, precision = products
+    dot = functools.partial(jax.lax.dot_general, precision=precision,
+                            preferred_element_type=jnp.float32)
+    lanes = _lanes(q_ref.shape[2], pack)
+
+    @pl.when((qi == 0) & (ki == 0))
+    def _():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+        dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
+        odo = o_ref[0].astype(jnp.float32) * do_ref[0].astype(jnp.float32)
+        for a, sel in enumerate(lanes):
+            lse_sc[a] = _row_to_col(lse_ref[a])
+            delta_sc[a] = jnp.sum(_own(sel, odo, 0.0), axis=-1,
+                                  keepdims=True)
+
+    def tile():
+        q = q_ref[0].astype(jnp.float32) * scale
+        # dO / keep once on the (BQ, pack*D) block, not on the tiles
+        do = do_ref[0].astype(jnp.float32) * (1.0 / keep_prob)
+        k, v = k_ref[0].astype(mxu), v_ref[0].astype(mxu)
+        q_all, do_all = q.astype(mxu), do.astype(mxu)
+        dq = dk = dv = 0.0
+        for a, sel in enumerate(lanes):
+            s = _scores(_own(sel, q, 0.0).astype(mxu), k, bias_ref, qi, ki,
+                        causal, precision)
+            p = jnp.exp(s - lse_sc[a])
+            dp = dot(_own(sel, do, 0.0).astype(mxu), v, _NT)
+            pd = p
+            if dropped:
+                keep = keep_ref[a].astype(jnp.float32)
+                pd, dp = p * keep, dp * keep
+            ds = (p * (dp - delta_sc[a])).astype(mxu)
+            # each product is right on the head's own lanes only
+            dv = _own(sel, dot(pd.astype(mxu), do_all, _TN), dv)
+            dk = _own(sel, dot(ds, q_all, _TN), dk)
+            dq = _own(sel, dot(ds, k, (((1,), (0,)), ((), ()))), dq)
+        rows = pl.ds(pl.multiple_of(ki * bk, bk), bk)
+        dv_ref[0, rows, :] += dv
+        dk_ref[0, rows, :] += dk
+        dq_sc[...] += dq
+
+    _when_live(qi, ki, bq, bk, causal, tile)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
+
+
+def _blocks(t, block_q, block_k):
+    """(padded T, BQ, BK): T goes to the next multiple of 128 and the
+    blocks are the largest of 256 | 128 query rows and 512 | 256 | 128 keys
+    that divide it, unless the caller names them (T is then padded to
+    their larger one, which the smaller has to divide)."""
+    if block_q and block_k:
+        block = max(block_q, block_k)
+        if block % min(block_q, block_k):
+            raise ValueError(f"flash_attention: blocks {block_q} and "
+                             f"{block_k} do not divide one another")
+        return t + (-t) % block, block_q, block_k
+    tp = t + (-t) % 128
+    pick = lambda sizes: next(b for b in sizes if tp % b == 0)
+    return tp, block_q or pick((256, 128)), block_k or pick((512, 256, 128))
+
+
+def _layout(h, d):
+    """(pack, cols): heads a block, and blocks side by side in a row of
+    the arrays the kernels read (0: a head a row, see below).
+
+    A block holds as many heads as fill the 128 lanes, where the head
+    count divides (64-wide heads go in pairs): an (.., T, 64) float32 array
+    is padded to 128 lanes in HBM, so a head a block would move and hold
+    twice the bytes.  Where a block is whole lanes wide the arrays are (B,
+    T, H*D), heads side by side as the projections leave them, and a block
+    is ``pack`` heads of a row: a caller whose (B, H, T, D) is a transpose
+    of that (an attention layer's is) pays no copy, XLA folds the two
+    transposes.  Else they are (B*H, T, D), a head a block."""
+    pack = max(1, 128 // d)
+    if d * pack != 128 or h % pack:
+        pack = 1
+    return pack, h // pack if (pack * d) % 128 == 0 else 0
+
+
+def _pack(x, tp):
+    """(B, H, T, D) -> the kernels' (rows, Tp, cols * width), zero rows
+    past T."""
+    b, h, t, d = x.shape
+    if _layout(h, d)[1]:
+        x = x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+    else:
+        x = x.reshape(b * h, t, d)
+    return jnp.pad(x, [(0, 0), (0, tp - t), (0, 0)]) if tp > t else x
+
+
+def _unpack(x, like):
+    """The inverse, to ``like``'s (B, H, T, D) and dtype."""
+    b, h, t, d = like.shape
+    x = x[:, :t].astype(like.dtype)
+    if _layout(h, d)[1]:
+        return x.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+    return x.reshape(b, h, t, d)
+
+
+def _operands(q, kv_mask, keep, tp):
+    """The kernels' optional operands at the padded length: the key bias
+    (rows, 1, Tp) float32, needed when a mask is passed or keys are padding
+    (one row then serves every head), and the keep-mask, zero over the
+    padding."""
+    b, h, t, _ = q.shape
+    bias = None
+    if kv_mask is not None:
+        bias = (1.0 - kv_mask.astype(jnp.float32).reshape(b, 1, t)) * _NEG
+    elif tp > t:
+        bias = jnp.zeros((1, 1, t), jnp.float32)
+    if bias is not None and tp > t:
+        bias = jnp.pad(bias, [(0, 0), (0, 0), (0, tp - t)],
+                       constant_values=-jnp.inf)
+    if keep is not None:
+        if keep.shape != (b * h, t, t):
+            raise ValueError(f"flash_attention: keep-mask {keep.shape} for "
+                             f"{b} x {h} heads of {t} tokens")
+        keep = keep.astype(jnp.int8)
+        if tp > t:
+            keep = jnp.pad(keep, [(0, 0), (0, tp - t), (0, tp - t)])
+    return bias, keep
+
+
+class _Static(typing.NamedTuple):
+    """What a call fixes beside its arrays."""
+    causal: bool
+    scale: float
+    block_q: typing.Optional[int]
+    block_k: typing.Optional[int]
+    interpret: typing.Optional[bool]
+    rate: float
+
+
+def _call(kernel, name, static, shape, operands, outs, scratch, semantics,
+          bias, keep):
+    """``pallas_call`` of a forward or backward kernel on the grid (group
+    of heads, q block, k block) for attention of ``shape`` (B, H, T, D).
+    ``operands`` are (array as ``_pack`` lays it, kind) with kind "q" (a q
+    block a step), "k" (a k block a step) or "lse"; ``outs`` are (kind,
+    dtype) of the same kinds, and "whole" for a resident whole-sequence
+    block.  It is one jitted function of its arrays, so that the twelve
+    layers of a model trace and lower each kernel once, not twelve times."""
+    return _jitted_call(
+        *(x for x, _ in operands), *(x for x in (bias, keep)
+                                     if x is not None),
+        kernel=kernel, name=name, static=static, shape=tuple(shape),
+        kinds=tuple(kind for _, kind in operands),
+        outs=tuple((kind, jnp.dtype(dtype)) for kind, dtype in outs),
+        scratch=tuple(scratch), semantics=semantics,
+        biased=bias is not None, dropped=keep is not None)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "kernel", "name", "static", "shape", "kinds", "outs", "scratch",
+    "semantics", "biased", "dropped"))
+def _jitted_call(*arrays, kernel, name, static, shape, kinds, outs, scratch,
+                 semantics, biased, dropped):
+    arrays = list(arrays)
+    keep = arrays.pop() if dropped else None
+    bias = arrays.pop() if biased else None
+    operands = list(zip(arrays, kinds))
+    causal, scale, block_q, block_k, interpret, rate = static
+    b, h, t, d = shape
+    tp, bq, bk = _blocks(t, block_q, block_k)
+    pack, cols = _layout(h, d)
+    cols = cols or 1
+    width, groups = pack * d, b * h // pack
+    spec = {
+        "q": pl.BlockSpec((1, bq, width),
+                          lambda i, j, kk: (i // cols, j, i % cols)),
+        "k": pl.BlockSpec((1, bk, width),
+                          lambda i, j, kk: (i // cols, kk, i % cols)),
+        "lse": pl.BlockSpec((pack, 1, bq), lambda i, j, kk: (i, 0, j)),
+        "whole": pl.BlockSpec((1, tp, width),
+                              lambda i, j, kk: (i // cols, 0, i % cols))}
+    arrays = [x for x, _ in operands]
+    in_specs = [spec[kind] for _, kind in operands]
+    if bias is not None:
+        per_batch = bias.shape[0] > 1
+        arrays.append(bias)
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bk),
+            lambda i, j, kk: (i * pack // h if per_batch else 0, 0, kk)))
+    if keep is not None:
+        arrays.append(keep)
+        in_specs.append(pl.BlockSpec((pack, bq, bk),
+                                     lambda i, j, kk: (i, j, kk)))
+    rows = (groups // cols, tp, cols * width)
+    out_shape = {"q": rows, "whole": rows, "lse": (b * h, 1, tp)}
+    vmem = {"tile": (bq, width), "stat": (pack, bq, 1)}
+    # whole-sequence blocks (the backward's dK, dV; float32, two buffers
+    # each) outgrow Mosaic's default 16 MiB of scoped VMEM near 7,000
+    # tokens: ask for what they take, which a v5e core's 128 MiB bounds
+    resident = 8 * tp * width * [kind for kind, _ in outs].count("whole")
+    limit = resident + (8 << 20) if resident > (6 << 20) else None
+    def call(interpret):
+        return pl.pallas_call(
+            functools.partial(kernel, causal=causal, scale=scale,
+                              keep_prob=1.0 - rate, biased=bias is not None,
+                              dropped=keep is not None, pack=pack,
+                              products=_products(interpret)),
+            out_shape=tuple(jax.ShapeDtypeStruct(out_shape[kind], dtype)
+                            for kind, dtype in outs),
+            grid=(groups, tp // bq, tp // bk),
+            in_specs=in_specs,
+            out_specs=tuple(spec[kind] for kind, _ in outs),
+            scratch_shapes=[pltpu.VMEM(vmem[kind], jnp.float32)
+                            for kind in scratch],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=semantics, vmem_limit_bytes=limit),
+            interpret=interpret, name=name)
+
+    if interpret is not None:
+        return call(interpret)(*arrays)
+    # by where the arrays live, at lowering: a block initialised on the
+    # host of a process that holds a chip runs its first forward there
+    return jax.lax.platform_dependent(*arrays, cpu=call(True),
+                                      default=call(False))
+
+
+def _fa_forward(q, k, v, kv_mask, keep, static):
+    """Output (B, H, T, D), and what the backward reads: q, k, v and the
+    output as the kernels hold them (``_pack``), the rows' log-sum-exp
+    (B*H, 1, Tp), the key bias and the padded keep-mask."""
+    tp = _blocks(q.shape[2], static.block_q, static.block_k)[0]
+    bias, keep = _operands(q, kv_mask, keep, tp)
+    qf, kf, vf = (_pack(x, tp) for x in (q, k, v))
+    of, lse = _call(
+        _fa_kernel, "flash_attention_fwd", static, q.shape,
+        [(qf, "q"), (kf, "k"), (vf, "k")],
+        [("q", q.dtype), ("lse", jnp.float32)], ["stat", "stat", "tile"],
+        ("parallel", "parallel", "arbitrary"), bias, keep)
+    return _unpack(of, q), (qf, kf, vf, of, lse, bias, keep)
+
+
+def _fa_backward(like, res, do, static):
+    """(dq, dk, dv) as ``like`` = (q, k, v) shapes and dtypes."""
+    qf, kf, vf, of, lse, bias, keep = res
+    b, _, t, _ = like[0].shape
+    tp = _blocks(t, static.block_q, static.block_k)[0]
+    dq, dk, dv = _call(
+        _fa_bwd_kernel, "flash_attention_bwd", static, like[0].shape,
+        [(qf, "q"), (kf, "k"), (vf, "k"), (of, "q"),
+         (_pack(do, tp), "q"), (lse, "lse")],
+        [("q", qf.dtype), ("whole", jnp.float32), ("whole", jnp.float32)],
+        ["tile", "stat", "stat"], ("parallel", "arbitrary", "arbitrary"),
+        bias, keep)
+    # Scores do not change when every key of a head moves by one vector,
+    # so a head's key gradients sum to zero over its (unmasked) keys.  dS
+    # rounded to bfloat16 keeps that only to a bfloat16's rounding, and the
+    # remainder is all there is of the key bias's gradient: noise (0.015
+    # where float32 leaves 2e-6, my chip run, PR 27) that Adam's
+    # normalisation turns into full-size steps of that bias.  Take it out.
+    total = dk.sum(axis=1, keepdims=True)
+    if bias is None:
+        dk = dk - total / tp
+    else:
+        live = (bias == 0.0).astype(jnp.float32).reshape(-1, tp, 1)
+        share = live / jnp.maximum(live.sum(axis=1, keepdims=True), 1.0)
+        if share.shape[0] > 1:      # a row a batch: one for each of dk's
+            share = jnp.repeat(share, dk.shape[0] // b, axis=0)
+        dk = dk - share * total
+    return tuple(_unpack(g, x) for g, x in zip((dq, dk, dv), like))
+
+
+def _reference(q, k, v, causal, scale, kv_mask=None, keep=None, rate=0.0):
+    """The dense formula, (B, H, T, T) scores and all: what the tests and
+    ``chip_smoke.py`` hold the kernels to."""
+    b, h, t, _ = q.shape
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
+    if kv_mask is not None:
+        s = s + (1.0 - kv_mask.astype(jnp.float32))[:, None, None, :] * _NEG
     if causal:
-        tq, tk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((tq, tk), dtype=bool))
-        s = jnp.where(mask, s, _NEG)
+        s = jnp.where(jnp.tril(jnp.ones((t, t), dtype=bool)), s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
+    if keep is not None:
+        p = p * keep.reshape(b, h, t, t).astype(jnp.float32) / (1.0 - rate)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)) \
         .astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128, interpret=None):
+def _static(q, causal, scale, block_q, block_k, interpret, rate):
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    return _Static(causal, scale, block_q, block_k, interpret, rate)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 10))
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, interpret=None, kv_mask=None, keep=None,
+                    rate=0.0):
     """Blockwise attention, (B, H, T, D) → (B, H, T, D).
 
-    ``interpret=None`` auto-selects: the pallas interpreter on the CPU
-    backend (the tests), the compiled kernel everywhere else — a kernel the
-    chip's compiler refuses raises, it is never swapped for the dense
-    reference.  T is padded to the block size and D to 128 lanes
-    internally; sequences past the kernel's VMEM budget raise ``ValueError``.
+    ``kv_mask`` (B, T), 1 = valid key, and ``keep`` (B*H, T, T), dropout's
+    keep-mask on the probabilities at ``rate``, are operands that are there
+    or not.  ``interpret=None`` selects by the platform the call is lowered
+    for: the pallas interpreter on the CPU (the tests; a block's first
+    forward on the host), the compiled kernels everywhere else — a kernel
+    the chip's compiler refuses raises, it is never swapped for the dense
+    formula.  T is padded to the block size internally; 64-wide
+    heads ride two a block, so that every block fills the 128 lanes.
     """
-    scale_v = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return _fa_forward(q, k, v, causal, scale_v, block_q, block_k, interpret)
+    return _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                   kv_mask, keep, rate)[0]
 
 
-def _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out = flash_attention(q, k, v, causal, scale, block_q, block_k, interpret)
-    return out, (q, k, v)
+def _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret, kv_mask,
+            keep, rate):
+    out, res = _fa_forward(q, k, v, kv_mask, keep, _static(
+        q, causal, scale, block_q, block_k, interpret, rate))
+    # the gradients' (H, T, D) and dtypes, which the padded, packed
+    # residuals no longer tell, ride as empty arrays
+    like = tuple(jnp.zeros((0,) + x.shape[1:], x.dtype) for x in (q, k, v))
+    return out, (like, res)
 
 
-def _fa_bwd(causal, scale, block_q, block_k, interpret, res, g):
-    q, k, v = res
-    scale_v = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    _, vjp = jax.vjp(lambda q_, k_, v_: _reference(q_, k_, v_, causal,
-                                                   scale_v), q, k, v)
-    return vjp(g)
+def _fa_bwd(causal, scale, block_q, block_k, interpret, rate, saved, g):
+    like, res = saved
+    like = tuple(jax.ShapeDtypeStruct(g.shape, x.dtype) for x in like)
+    return _fa_backward(like, res, g, _static(
+        like[0], causal, scale, block_q, block_k, interpret, rate)) + \
+        (None, None)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

@@ -33,4 +33,5 @@ from .pipeline import (gpipe, gpipe_interleaved,  # noqa: F401
 from .moe import moe_layer, switch_moe_local  # noqa: F401
 from .sp_context import (  # noqa: F401
     sequence_parallel_scope, current_sequence_parallel,
+    traced_mesh_scope, traced_mesh,
 )

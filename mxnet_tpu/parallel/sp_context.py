@@ -12,9 +12,11 @@ from __future__ import annotations
 
 import contextlib
 
-__all__ = ["sequence_parallel_scope", "current_sequence_parallel"]
+__all__ = ["sequence_parallel_scope", "current_sequence_parallel",
+           "traced_mesh_scope", "traced_mesh"]
 
 _SCOPE = []
+_MESH = []
 
 
 @contextlib.contextmanager
@@ -38,3 +40,24 @@ def current_sequence_parallel():
     if mesh.shape.get(sp_axis, 1) <= 1:
         return None
     return mesh, sp_axis, dp_axis, impl
+
+
+@contextlib.contextmanager
+def traced_mesh_scope(mesh, dp_axis="dp", tp_axis="tp"):
+    """The mesh a step is being traced for.  XLA partitions its own
+    operations over it; a Mosaic kernel it cannot, so a layer that calls one
+    reads the mesh here and maps the kernel over the shards itself
+    (``ops/__init__.py`` does for the attention kernels)."""
+    _MESH.append((mesh, dp_axis, tp_axis))
+    try:
+        yield
+    finally:
+        _MESH.pop()
+
+
+def traced_mesh():
+    """(mesh, dp_axis, tp_axis) when inside a scope whose mesh has more
+    than one device."""
+    if not _MESH or _MESH[-1][0].size <= 1:
+        return None
+    return _MESH[-1]

@@ -11,6 +11,8 @@ of the reference's engine (weights/optimizer state update without extra HBM).
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -234,6 +236,7 @@ class SPMDTrainer:
                 f"sequence_parallel=True requires mesh axis {sp_axis!r} with "
                 f"size > 1; mesh has {dict(mesh.shape)}")
         self._dp_axis = dp_axis
+        self._tp_axis = kw.get("tp_axis", "tp")
         self._sp = (mesh, sp_axis, dp_axis, sp_impl) \
             if sequence_parallel else None
         with self._sp_scope():
@@ -246,12 +249,15 @@ class SPMDTrainer:
         self._trainable = [p for _, p in items if p.grad_req != "null"]
         self._aux = [p for _, p in items if p.grad_req == "null"]
 
+    @contextlib.contextmanager
     def _sp_scope(self):
-        import contextlib
-        if self._sp is None:
-            return contextlib.nullcontext()
-        from .sp_context import sequence_parallel_scope
-        return sequence_parallel_scope(*self._sp)
+        """What a layer may ask while the step is traced: the mesh, and
+        the sequence-parallel routing where that is on."""
+        from .sp_context import traced_mesh_scope, sequence_parallel_scope
+        with traced_mesh_scope(self._mesh, self._dp_axis, self._tp_axis), \
+                sequence_parallel_scope(*self._sp) if self._sp is not None \
+                else contextlib.nullcontext():
+            yield
 
     def install_preemption(self, handler, manager, extra=None):
         """Preemption-safe training without the ResilientTrainer wrapper:

@@ -30,6 +30,7 @@ AUX_INPUTS = {"BatchNorm": {3: "moving_mean", 4: "moving_var"},
 
 # Ops whose behavior depends on is_train (OpContext ctx.is_train in reference)
 MODE_DEPENDENT = {"Dropout", "BatchNorm", "RNN", "_contrib_SyncBatchNorm",
+                  "_contrib_flash_attention_dropout",
                   "_foreach", "_while_loop", "_cond"}
 
 _SIG_CACHE = {}

@@ -206,3 +206,155 @@ def test_bert_symbol_export_roundtrip(tmp_path):
     outs = [o.asnumpy() for o in ex.forward(is_train=False, **feeds)]
     for a, b in zip(ref, outs):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def _encoder_cell(dense):
+    """One post-LN layer with dropout 0.1 from a fixed seed; ``dense`` sends
+    its attention down the dense tail (``use_flash_attention=False``)."""
+    from mxnet_tpu.models import TransformerEncoderCell
+    mx.random.seed(5)
+    np.random.seed(5)
+    cell = TransformerEncoderCell(128, 256, 2, dropout=0.1, prefix="cell_")
+    cell.initialize()
+    cell.attention._use_flash = not dense
+    return cell
+
+
+def test_blockwise_attention_keeps_the_key_chain():
+    """A layer in training mode with a mask and dropout: the blockwise
+    kernels against the dense tail under the same ``mx.random`` state.  The
+    output and every parameter gradient agree to a float32 tolerance (so the
+    keep-mask on the probabilities is the one ``Dropout`` draws on the dense
+    (B * H, T, T) tensor, and the attention-output and feed-forward dropouts
+    after it draw what they drew), and the NEXT stochastic call after the
+    layer draws the same numbers on both."""
+    from mxnet_tpu import autograd
+    rng = np.random.RandomState(0)
+    x = mx.nd.array(rng.randn(2, 128, 128).astype("float32"))
+    mask = mx.nd.array((np.arange(128)[None, :]
+                        < np.array([[128], [77]])).astype("float32"))
+    w = mx.nd.array(rng.randn(2, 128, 128).astype("float32"))
+    runs = []
+    for dense in (False, True):
+        cell = _encoder_cell(dense)
+        mx.random.seed(123)
+        with autograd.record():
+            out = cell(x, mask)
+            loss = (out * w).sum()
+        loss.backward()
+        nxt = mx.nd.Dropout(mx.nd.ones((4, 64)), p=0.5,
+                            mode="always").asnumpy()
+        grads = {k: p.grad().asnumpy()
+                 for k, p in cell.collect_params().items()}
+        runs.append((out.asnumpy(), grads, nxt))
+    (out_b, grads_b, next_b), (out_d, grads_d, next_d) = runs
+    np.testing.assert_allclose(out_b, out_d, rtol=1e-4, atol=1e-5)
+    assert grads_b.keys() == grads_d.keys() and len(grads_b) == 12
+    for name in grads_d:
+        scale = np.abs(grads_d[name]).max()
+        np.testing.assert_allclose(grads_b[name], grads_d[name], rtol=1e-3,
+                                   atol=1e-4 * scale, err_msg=name)
+    np.testing.assert_array_equal(next_b, next_d)
+    # and dropout did drop: the layer differs from its inference output
+    assert np.abs(out_b - _encoder_cell(False)(x, mask).asnumpy()).max() > 0.1
+
+
+def _attention_paths(fn):
+    """``attention.path`` counts by kind made while ``fn`` runs."""
+    from mxnet_tpu import telemetry
+    was_on = telemetry.is_enabled()
+    telemetry.enable()
+    read = lambda: dict(telemetry.snapshot()["counters_by_label"].get(
+        "attention.path", {}))
+    try:
+        before = read()
+        fn()
+        after = read()
+    finally:
+        if not was_on:
+            telemetry.disable()
+    kinds = {}
+    for label, n in after.items():
+        kind = label.split('kind="')[1].split('"')[0]
+        kinds[kind] = kinds.get(kind, 0) + n - before.get(label, 0)
+    return {k: n for k, n in kinds.items() if n}
+
+
+def test_attention_path_counter_names_every_layers_path():
+    """Twelve layers with a mask and dropout, in training mode, take the
+    blockwise kernels twelve times and the dense tail never; what is left
+    to the dense tail is a layer built with ``use_flash_attention=False``
+    and a sequence-parallel scope with a mask or dropout (the ring takes
+    neither)."""
+    from mxnet_tpu import autograd
+    from mxnet_tpu.models import MultiHeadAttention
+    from mxnet_tpu.parallel import make_mesh, sequence_parallel_scope
+    net = get_bert_model("bert_tiny", vocab_size=50, max_length=16,
+                         num_layers=12, dropout=0.1)
+    net.initialize()
+    tokens, segments, mask, positions = _inputs(vocab=50)
+
+    def train_forward():
+        with autograd.train_mode():
+            net(tokens, segments, mask, positions)
+    assert _attention_paths(train_forward) == {"blockwise": 12}
+    assert _attention_paths(
+        lambda: net(tokens, segments, None, positions)) == {"blockwise": 12}
+
+    x = mx.nd.ones((4, 16, 128))
+    off = MultiHeadAttention(128, 2, use_flash_attention=False)
+    off.initialize()
+    assert _attention_paths(lambda: off(x)) == {"dense": 1}
+    attn = MultiHeadAttention(128, 2)
+    attn.initialize()
+    attn(x)
+    with sequence_parallel_scope(make_mesh(dp=2, sp=4)):
+        with pytest.warns(UserWarning, match="dense T×T path"):
+            assert _attention_paths(
+                lambda: attn(x, mx.nd.ones((4, 16)))) == {"dense": 1}
+
+
+def test_blockwise_attention_on_a_mesh_matches_one_device():
+    """Traced for a dp x tp mesh the kernels are mapped over the shards
+    (XLA partitions no Mosaic call): with a mask and dropout the step's
+    losses are those of the one-device step, the keep-mask being the same
+    bits wherever a shard lies."""
+    import jax
+    from mxnet_tpu.parallel import (SPMDTrainer, FunctionalOptimizer,
+                                    device_mesh)
+    vocab, T = 32, 16
+    rng = np.random.RandomState(0)
+    tokens = rng.randint(0, vocab, (8, T)).astype("int32")
+    valid = (np.arange(T)[None, :] < rng.randint(5, T + 1, (8, 1))) \
+        .astype("float32")
+    y = rng.randint(0, 2, (8,)).astype("float32")
+
+    class WithHead(mx.gluon.Block):
+        def __init__(self, bert):
+            super().__init__()
+            self.bert = bert
+            self.head = mx.gluon.nn.Dense(2)
+
+        def forward(self, tokens, valid):
+            _, pooled = self.bert(tokens, None, valid)
+            return self.head(pooled)
+
+    def losses(dp, tp):
+        mx.random.seed(7)
+        np.random.seed(7)
+        model = WithHead(get_bert_model(
+            "bert_tiny", vocab_size=vocab, max_length=T, dropout=0.1,
+            use_decoder=False, use_classifier=False))
+        model.initialize()
+        model(mx.nd.array(tokens, dtype="int32"), mx.nd.array(valid))
+        tr = SPMDTrainer(model, mx.gluon.loss.SoftmaxCrossEntropyLoss(),
+                         FunctionalOptimizer("sgd", 0.1),
+                         device_mesh({"pp": 1, "dp": dp, "sp": 1, "tp": tp},
+                                     devices=jax.devices()[:dp * tp]),
+                         n_in=2)
+        mx.random.seed(11)
+        return [float(tr.step((tokens, valid), y).asnumpy())
+                for _ in range(3)]
+
+    np.testing.assert_allclose(losses(dp=2, tp=2), losses(dp=1, tp=1),
+                               rtol=2e-4, atol=2e-5)

@@ -43,18 +43,29 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _flash_text(shape, dtype, causal, sharding):
-    x = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-    fn = jax.jit(lambda q, k, v: pallas_kernels._fa_forward(
-        q, k, v, causal, 0.125, 128, 128, False))
-    return fn.lower(x, x, x).compile().as_text()
+def _flash_text(shape, dtype, causal, sharding, grad=False, masked=False):
+    """HLO of the forward (or of dq, dk, dv) compiled for the described
+    chip; ``masked`` adds the key mask and dropout's keep-mask."""
+    b, h, t, _ = shape
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+    x = sds(shape, dtype)
+
+    def attend(q, k, v, mask, keep):
+        return pallas_kernels.flash_attention(
+            q, k, v, causal, 0.125, None, None, None,
+            mask if masked else None, keep if masked else None,
+            0.1 if masked else 0.0)
+    fn = attend if not grad else jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    return jax.jit(fn).lower(x, x, x, sds((b, t), jnp.float32),
+                             sds((b * h, t, t), jnp.int8)).compile().as_text()
 
 
 @pytest.mark.parametrize("shape,dtype", [
     ((32, 12, 128, 64), jnp.bfloat16),      # BERT-base b32 x s128
     ((32, 12, 128, 64), jnp.float32),
     ((1, 12, 8192, 64), jnp.bfloat16),      # long sequence, one row
-    ((1, 12, 200, 64), jnp.bfloat16),       # padded T: keys masked by length
+    ((1, 12, 200, 64), jnp.bfloat16),       # padded T: keys masked by bias
 ])
 def test_flash_kernel_compiles_for_v5e(one_chip, shape, dtype):
     for causal in (False, True):
@@ -62,35 +73,154 @@ def test_flash_kernel_compiles_for_v5e(one_chip, shape, dtype):
                                                 one_chip)
 
 
-@pytest.mark.parametrize("shape,dtype,limit", [
-    ((1, 12, 32768, 64), jnp.bfloat16, 16000),
-    ((1, 12, 16128, 64), jnp.bfloat16, 16000),
-    ((1, 12, 8064, 64), jnp.float32, 7936),
+@pytest.mark.parametrize("shape,dtype", [
+    ((1, 12, 32768, 64), jnp.bfloat16),
+    ((1, 12, 16128, 64), jnp.bfloat16),
+    ((1, 12, 8064, 64), jnp.float32),
 ])
-def test_flash_kernel_names_its_sequence_limit(shape, dtype, limit):
-    """Past the whole-sequence K/V blocks' VMEM budget the kernel raises
-    before the compiler does, naming the limit — it never hands back the
-    dense reference instead."""
-    x = jax.ShapeDtypeStruct(shape, dtype)
-    with pytest.raises(ValueError, match=f"limit at this width and dtype "
-                                         f"is {limit} tokens"):
-        jax.eval_shape(lambda q, k, v: pallas_kernels.flash_attention(
-            q, k, v), x, x, x)
+def test_flash_forward_has_no_sequence_limit(one_chip, shape, dtype):
+    """K and V ride a block a step, not whole: lengths the whole-sequence
+    kernel refused (it stopped at 16,000 tokens in bfloat16, 7,936 in
+    float32) compile."""
+    assert "tpu_custom_call" in _flash_text(shape, dtype, False, one_chip)
 
 
-def test_flash_limit_is_the_compilers(one_chip):
-    """The estimate agrees with the v5e compiler on both sides of the
-    limit: the largest accepted length compiles, and one block more is
-    refused by the compiler too when the check is lifted."""
-    assert "tpu_custom_call" in _flash_text((1, 12, 16000, 64), jnp.bfloat16,
-                                            False, one_chip)
-    budget = pallas_kernels._VMEM_LIMIT_BYTES
-    pallas_kernels._VMEM_LIMIT_BYTES = 1 << 40
-    try:
-        with pytest.raises(Exception, match="vmem"):
-            _flash_text((1, 12, 16128, 64), jnp.bfloat16, False, one_chip)
-    finally:
-        pallas_kernels._VMEM_LIMIT_BYTES = budget
+@pytest.mark.parametrize("shape,dtype,masked", [
+    ((32, 12, 512, 64), jnp.float32, True),     # the training cell's layer
+    ((32, 12, 128, 64), jnp.bfloat16, False),
+    ((2, 3, 200, 64), jnp.float32, True),       # odd heads: one a block
+    ((1, 12, 8192, 64), jnp.bfloat16, False),
+])
+def test_flash_backward_compiles_for_v5e(one_chip, shape, dtype,
+                                         masked):
+    """dq, dk and dv are kernels too: forward and backward, two Mosaic
+    calls, and with the mask and dropout no float32 (.., T, T) value."""
+    text = _flash_text(shape, dtype, False, one_chip, grad=True,
+                       masked=masked)
+    assert text.count("tpu_custom_call") >= 2
+    b, h, t, _ = shape
+    assert f"f32[{b * h},{t},{t}]" not in text
+    assert f"f32[{b},{h},{t},{t}]" not in text
+
+
+def test_flash_backward_limit_is_the_compilers(one_chip):
+    """The backward holds dK and dV as whole-sequence blocks and asks the
+    compiler for the VMEM they take, so 16,384 tokens, over Mosaic's default
+    budget, compile; past a core's own VMEM the chip's compiler refuses the
+    kernel and that is what the caller sees, never the dense formula."""
+    assert "tpu_custom_call" in _flash_text(
+        (1, 12, 16384, 64), jnp.bfloat16, False, one_chip, grad=True)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _flash_text((1, 12, 65536, 64), jnp.bfloat16, False, one_chip,
+                    grad=True)
+
+
+def test_bert_base_step_holds_no_float32_scores(one_chip):
+    """The training cell's program: the ``SPMDTrainer`` step of BERT-base at
+    (32, 512) with the mask passed and dropout 0.1.  Its attention is the
+    kernels (forward and backward a layer), the only (.., 512, 512) values
+    it writes to device memory are dropout's keep-masks, a byte an element,
+    and its temporaries are the activations' (10.56 GB with the dense
+    float32 scores and probabilities, sandbox compile, PR 23).  The day a
+    T x T float32 tensor comes back this names it."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import get_bert_model
+    from mxnet_tpu.parallel import (FunctionalOptimizer, SPMDTrainer,
+                                    device_mesh)
+    b, t, masked, vocab = 32, 512, 76, 30522
+    net = get_bert_model("bert_base", vocab_size=vocab, max_length=t,
+                         dropout=0.1)
+    net.initialize()
+    ce = mx.gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def loss_fn(out, label):
+        _seq, _pooled, mlm, nsp = out
+        return ce(mlm.reshape((-1, vocab)), mx.nd.slice_axis(
+            label, axis=1, begin=0, end=masked).reshape((-1,))).mean() + \
+            ce(nsp, mx.nd.slice_axis(label, axis=1, begin=masked,
+                                     end=masked + 1).reshape((-1,))).mean()
+
+    row = mx.nd.zeros((1, t), dtype="int32")
+    net(row, row, mx.nd.ones((1, t)), mx.nd.zeros((1, masked), dtype="int32"))
+    trainer = SPMDTrainer(
+        net, loss_fn, FunctionalOptimizer("adam", 1e-4),
+        device_mesh({"pp": 1, "dp": 1, "sp": 1, "tp": 1},
+                    devices=jax.devices()[:1]), n_in=4)
+    # the same step function, lowered for the described chip (the block's
+    # own first forward, above, was lowered for the CPU: interpreted)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    state = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                   trainer._state)
+    data = (sds((b, t), jnp.int32), sds((b, t), jnp.int32),
+            sds((b, t), jnp.float32), sds((b, masked), jnp.int32))
+    compiled = jax.jit(trainer._step_fn.__wrapped__, donate_argnums=(0,)) \
+        .lower(state, data, sds((b, masked + 1), jnp.int32),
+               sds((2,), jnp.uint32), sds((), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 24
+    for op, dtype, dims in _materialised(text):
+        if dims[-2:] == (t, t):
+            assert dtype in ("s8", "pred"), \
+                f"the step writes {dtype}{list(dims)} ({op}): a T x T " \
+                f"tensor wider than the keep-mask is back in device memory"
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < 9.0e9, f"{temps / 1e9:.3f} GB of temporaries"
+
+
+def test_dp_tp_step_maps_the_kernels_over_the_mesh(one_chip):
+    """XLA partitions no Mosaic call, so a step traced for a dp x tp mesh
+    maps the attention kernels over the shards itself (``shard_map`` in
+    ``ops/__init__.py``, told the mesh by ``SPMDTrainer``): compiled for the
+    four chips of the described host, two layers at BERT-base's widths with
+    the mask and dropout run their kernels on (batch / dp, heads / tp)."""
+    import numpy as np
+    import mxnet_tpu as mx
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.models import get_bert_model
+    from mxnet_tpu.parallel import (FunctionalOptimizer, SPMDTrainer,
+                                    device_mesh)
+    from mxnet_tpu.parallel.sp_context import traced_mesh_scope
+    b, t = 8, 128
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices).reshape(1, 2, 1, 2),
+                ("pp", "dp", "sp", "tp"))
+
+    class WithHead(mx.gluon.Block):
+        def __init__(self, bert):
+            super().__init__()
+            self.bert = bert
+            self.head = mx.gluon.nn.Dense(2)
+
+        def forward(self, tokens, valid):
+            return self.head(self.bert(tokens, None, valid)[1])
+
+    model = WithHead(get_bert_model(
+        "bert_base", vocab_size=1000, max_length=t, num_layers=2,
+        dropout=0.1, use_decoder=False, use_classifier=False))
+    model.initialize()
+    model(mx.nd.zeros((2, t), dtype="int32"), mx.nd.ones((2, t)))
+    trainer = SPMDTrainer(
+        model, mx.gluon.loss.SoftmaxCrossEntropyLoss(),
+        FunctionalOptimizer("adam", 1e-4),
+        device_mesh({"pp": 1, "dp": 2, "sp": 1, "tp": 2},
+                    devices=jax.devices()[:4]), n_in=2)
+    sds = lambda shape, dtype, spec: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, spec))
+    state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, a.sharding.spec), trainer._state)
+    with traced_mesh_scope(mesh, "dp", "tp"):
+        text = jax.jit(trainer._step_fn.__wrapped__).lower(
+            state, (sds((b, t), jnp.int32, P("dp")),
+                    sds((b, t), jnp.float32, P("dp"))),
+            sds((b,), jnp.float32, P("dp")), sds((2,), jnp.uint32, P()),
+            sds((), jnp.uint32, P())).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 4          # forward and backward of two layers
+    # each on its shard: 4 of 8 rows, 6 of 12 heads (384 of 768 lanes)
+    assert all("f32[4,128,384]" in ln for ln in calls)
 
 
 @pytest.mark.parametrize("kv_dtype", ["float32", "int8", "fp8_e4m3"])

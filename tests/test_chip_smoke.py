@@ -48,8 +48,11 @@ def test_flash_phase_tiny():
     out = chip_smoke.phase_flash(TINY, "cpu")
     assert len(out["kernel"]) == 4
     assert not any(k["tpu_custom_call"] for k in out["kernel"])
-    # bert_tiny has two layers: each dispatched the kernel op once
-    assert out["bert_forward"]["flash_attention_dispatches"] == 2
+    # bert_tiny has two layers: each dispatched the kernel op once in the
+    # forward without a mask and once in the one with it
+    assert out["bert_forward"]["flash_attention_dispatches"] == 4
+    assert set(out["bert_forward"]["max_abs_err_vs_dense_path"]) == {
+        "mask_none", "mask_ones"}
 
 
 def test_serve_and_fleet_phases_tiny(tmp_path):
