@@ -131,7 +131,7 @@ def main():
         params, mom, loss = compiled(params, mom, x, labels)
     print("warm loss:", float(np.asarray(loss)))
 
-    # honest timing: value-fetch barrier, RTT subtracted (see bench.py)
+    # honest timing: value-fetch barrier, RTT subtracted
     probes = [jax.jit(lambda v, i=i: v + i)(jnp.float32(1)) for i in range(6)]
     float(np.asarray(probes[0]))
     rtt = min(_t(lambda p=p: float(np.asarray(p))) for p in probes[1:])

@@ -36,7 +36,8 @@ consumes a pending value):
 Telemetry: ``dispatch.segment_compile_miss`` / ``segment_cache_hits`` /
 ``segments_flushed`` / ``ops_recorded`` / ``ops_fused`` counters and an
 ``engine.segment_flush`` span per flush — zero compile misses steady-state
-is the acceptance contract (``bench.py engine_bulk``, ci ``engine`` stage).
+is the acceptance contract (``tests/test_engine_bulk.py``, ci ``engine``
+stage).
 """
 from __future__ import annotations
 

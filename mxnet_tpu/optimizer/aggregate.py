@@ -28,8 +28,8 @@ Telemetry (when the bus is enabled): ``optimizer.update_group`` sub-spans
 inside ``trainer.update``, ``optimizer.update_groups`` / count the group
 dispatches, ``optimizer.state_bytes`` gauges the tracked slot memory, and
 ``optimizer.update_calls`` counts dispatches (group calls + per-param
-fallbacks) so dispatches/step is a measurable number (``bench.py``
-``optimizer`` config).
+fallbacks) so dispatches/step is a measurable number
+(``tests/test_optimizer_aggregate.py``).
 """
 from __future__ import annotations
 

@@ -85,7 +85,7 @@ def _env_fingerprint():
 def model_signature(block, salt=""):
     """A stable hex key naming *this model as a compile input*: parameter
     names/shapes/dtypes, the block's class, the source of its defining
-    module (an edited ``step_math`` must miss), and any caller ``salt``
+    module (an edited ``step_program`` must miss), and any caller ``salt``
     (serving geometry — bucket ladders, page/pool shapes — belongs
     there).  Parameter *values* are deliberately excluded: programs are
     functions of shapes, and a weight update must keep hitting."""

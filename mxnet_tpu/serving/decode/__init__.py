@@ -23,10 +23,12 @@ discipline (PAPER.md design point #2) to that loop:
   discipline: a post-free read raises ``StaleKVSlotError`` under
   ``MXNET_SANITIZE=slots``), refcounted **shared-prefix pages**
   (content-hashed at prefill commit, acquired by page-table update on a
-  hit, copy-on-write on divergence), optional **int8 pools**
-  (``kv_dtype="int8"``: per-row scale/mid sidecars, dequant fused into
-  the step program), and optional ``NamedSharding`` over the heads axis
-  so the cache scales with the mesh.
+  hit, copy-on-write on divergence) and optional ``NamedSharding`` over
+  the heads axis so the cache scales with the mesh.
+- :class:`PageFormat` (``kv_format.py``) — what the pools store (raw, or
+  int8 / fp8 e4m3 codes with per-row sidecars: ``kv_dtype``) and the only
+  two functions that index one; quantization is fused into whichever
+  program writes and reads.
 - :class:`DecodeRuntime` (``runtime.py``) — the 2-D *(batch x seqlen)*
   prefill grid warmed through ``HybridBlock.compile_grid`` plus ONE
   fused donated step program per batch bucket; ``decode.compile_miss``
@@ -36,7 +38,7 @@ discipline (PAPER.md design point #2) to that loop:
   boundaries, finished sequences free their KV slots immediately, and
   the serving backpressure/deadline/circuit-breaker machinery carries
   over with KV exhaustion as a new shed condition.
-- :class:`NgramDrafter` / :class:`ModelDrafter` (``speculate.py``) —
+- :class:`NgramDrafter` (``speculate.py``) —
   speculative decoding over the fused per-bucket **verify** program:
   a drafter proposes ``k`` tokens, one donated step scores them all,
   and deterministic-equality acceptance commits the matching prefix —
@@ -61,13 +63,16 @@ from .kv_cache import (  # noqa: F401
     PagedKVCache,
     pages_needed,
 )
-from .model import (  # noqa: F401
-    CausalLM,
-    get_decode_model,
+from .kv_format import (  # noqa: F401
+    PageFormat,
     kv_dequantize,
     kv_dequantize_fp8,
     kv_quantize_rows,
     kv_quantize_rows_fp8,
+)
+from .model import (  # noqa: F401
+    CausalLM,
+    get_decode_model,
     rowdot,
     sample_math,
 )
@@ -81,7 +86,6 @@ from .scheduler import (  # noqa: F401
 )
 from .speculate import (  # noqa: F401
     Drafter,
-    ModelDrafter,
     NgramDrafter,
     SpecState,
 )
@@ -90,8 +94,9 @@ __all__ = ["CausalLM", "LatentMoELM", "get_decode_model", "rowdot",
            "sample_math",
            "kv_quantize_rows", "kv_dequantize",
            "kv_quantize_rows_fp8", "kv_dequantize_fp8",
-           "PagedKVCache", "KVSlot", "KVCacheExhausted", "pages_needed",
+           "PagedKVCache", "PageFormat", "KVSlot", "KVCacheExhausted",
+           "pages_needed",
            "DecodeRuntime", "seq_bucket_ladder",
            "DecodeScheduler", "DecodeSession", "GenerationResult",
            "TokenStream",
-           "Drafter", "NgramDrafter", "ModelDrafter", "SpecState"]
+           "Drafter", "NgramDrafter", "SpecState"]

@@ -1,5 +1,5 @@
-"""Device-resident paged KV cache with generation-stamped slots,
-refcounted shared-prefix pages, and optional int8-quantized pools.
+"""Device-resident paged KV cache with generation-stamped slots and
+refcounted shared-prefix pages.
 
 The decode batch's attention state lives on device as page-pool arrays
 per cache, each ``(layers, num_pages, page_size, row width)``.  **The
@@ -8,26 +8,16 @@ width, the dtype): :class:`CausalLM` keeps two, ``k_pages`` / ``v_pages``
 with a row of ``heads * head_dim`` values; a latent-attention block keeps
 ONE whose row is the token's ``(c_kv | k_r)`` shared by every head.  The
 allocator, the page tables, the prefix index and copy-on-write know
-nothing of what a row holds.  A sequence owns a *slot* (its identity in
-the allocator) and a fixed-length page table (``max_pages_per_seq``
-entries, padded with the reserved trash page 0) mapping logical token
-positions to physical pages.  Page 0 is never allocated: padded batch rows
-and padded prompt positions scatter their K/V there, so one compiled
-program per batch bucket serves every batch composition.
-
-**The pool's minor axis is the whole token row** (``heads * head_dim``
-values), not ``head_dim``.  A TPU array lives in (8, 128) tiles of its two
-minor dimensions; with ``head_dim`` = 64 minor-most the client pads the
-resident pool and stores it pages-minor, a layout no scatter or gather
-computes in, so every program that took the donated pools began and ended
-with a copy of each whole pool.  A row that fills whole lane tiles is
-resident unpadded in the layout the programs compute in, and they update
-the donated buffers in place.  **A pool is indexed once**: ``pool[i,
-tables]`` / ``pool.at[i, pages, offsets]``, never ``pool[i][tables]`` —
-the chained form materialises all ``num_pages`` pages of layer ``i``
-before gathering a row's few.  ``tests/test_chip_compile.py`` holds every
-program (step, verify, commit, copy-on-write; fp32, int8, fp8) to this: no
-temporary the size of a pool or of one layer of one.
+nothing of what a row holds, nor of how it is stored: that is the page
+format's (``kv_format.PageFormat``, built here from the layout and
+``kv_dtype`` and handed to the block's programs as ``cache.pages``), which
+alone writes rows into the pools and reads them back.  A sequence owns a
+*slot* (its identity in the allocator) and a fixed-length page table
+(``max_pages_per_seq`` entries, padded with the reserved trash page 0)
+mapping logical token positions to physical pages.  Page 0 is never
+allocated: padded batch rows and padded prompt positions scatter their K/V
+there, so one compiled program per batch bucket serves every batch
+composition.
 
 **Slot-generation discipline** (the ShmRing pattern from the input
 pipeline, generalized): every slot carries a recycle generation, bumped on
@@ -58,16 +48,6 @@ recycled" (raises).  Published pages are pinned by the index and
 reclaimed LRU-first under allocation pressure, so a hot prefix survives
 across sessions without ever causing a spurious ``KVCacheExhausted``.
 
-**Quantized pools** (``kv_dtype="int8"``): K/V pages are stored int8
-with per-page-row affine scale/zero-point arrays (one ``(scale, zero)``
-pair per written token row per layer, shape ``(layers, num_pages,
-page_size)``), quantized at commit/step write and dequantized inside the
-fused per-bucket step program — KV HBM drops ~4x so the same pool bytes
-admit ~4x the pages.  Quantization is elementwise-deterministic, so the
-shared-vs-cold bitwise contract holds in int8 exactly as in fp32; what
-int8 relaxes is fidelity *versus the fp32 pools* (documented in
-``docs/serving.md``).
-
 Sharding: pass ``mesh`` (+ ``kv_axis``) and the page pools are created
 under a ``NamedSharding`` over the row axis (a contiguous split of
 ``heads * head_dim`` is a split by heads), so the cache scales with
@@ -89,6 +69,7 @@ import numpy as np
 from ...analysis import sanitizer as _san
 from ...resilience import faults as _faults
 from ...telemetry import bus as _tel
+from .kv_format import PageFormat
 
 __all__ = ["PagedKVCache", "KVSlot", "KVCacheExhausted", "pages_needed"]
 
@@ -206,11 +187,11 @@ class PagedKVCache:
     max_slots : int
         Concurrent-sequence bound (the scheduler's max batch bucket).
     dtype : str
-        Compute dtype of the K/V values (fp32 pools store this directly).
-    kv_dtype : str
-        ``"float32"``/``"fp32"`` (default) or ``"int8"`` — the *storage*
-        dtype of the pools.  int8 adds per-page-row scale/zero arrays and
-        the runtime fuses dequant into the step program.
+        Dtype of the K/V values in the two-pool geometry (a ``layout``
+        states its own).
+    kv_dtype : str, optional
+        The page format (``kv_format``): stored as the layout states by
+        default, or quantized with per-row sidecars.
     prefix_sharing : bool
         Refcount + content-hash prompt pages across sequences (default
         on).  Off, :meth:`alloc` ignores ``prompt`` and behaves exactly
@@ -228,7 +209,6 @@ class PagedKVCache:
                  max_slots=16, dtype="float32", kv_dtype=None,
                  prefix_sharing=True, prefix_entries=256, mesh=None,
                  kv_axis="model", layout=None):
-        import jax.numpy as jnp
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is trash)")
         if layout is None:
@@ -240,9 +220,11 @@ class PagedKVCache:
                       "shard_heads": int(num_heads),
                       "pools": (("k", row, str(dtype)),
                                 ("v", row, str(dtype)))}
-        pools = tuple((str(n), int(w), str(d)) for n, w, d in layout["pools"])
-        self.pool_layout = pools
-        self.num_layers = int(layout["layers"])
+        #: the page format: what the pools store, and their only reader
+        #: and writer
+        self.pages = PageFormat(layout, kv_dtype, page_size)
+        self.pool_layout = pools = self.pages.pool_layout
+        self.num_layers = self.pages.num_layers
         self.num_heads = layout.get("shard_heads")
         self.head_dim = pools[0][1] // self.num_heads if self.num_heads \
             else None
@@ -252,39 +234,14 @@ class PagedKVCache:
         self.max_slots = int(max_slots)
         self.context_length = self.max_pages_per_seq * self.page_size
         self.dtype = str(dtype)
-        kv_dtype = self.dtype if kv_dtype is None else str(kv_dtype)
-        kv_dtype = {"fp32": "float32", "float": "float32",
-                    "fp8": "fp8_e4m3", "float8_e4m3fn": "fp8_e4m3"}.get(
-            kv_dtype, kv_dtype)
-        if kv_dtype not in ("float32", "int8", "fp8_e4m3"):
-            raise ValueError(
-                f"kv_dtype must be 'float32', 'int8' or 'fp8_e4m3', "
-                f"got {kv_dtype!r}")
-        if kv_dtype != "float32" and not layout.get("quantizable"):
-            raise ValueError(
-                f"kv_dtype={kv_dtype!r}: the block's pools "
-                f"{[n for n, _w, _d in pools]} are stored as the block "
-                f"states them; an int8/fp8 pool of these rows is not "
-                f"supported")
         if mesh is not None and not self.num_heads:
             raise ValueError(
                 f"a mesh splits a pool's row by heads, and a row of the "
                 f"block's pools {[n for n, _w, _d in pools]} is shared by "
                 f"all heads: a sharded pool of these rows is not supported")
-        self.kv_dtype = kv_dtype
-        self.quantized = kv_dtype in ("int8", "fp8_e4m3")
-        # sidecar arity: int8 carries per-row (scale, mid) for K and V;
-        # fp8 e4m3 keeps sign+mantissa so a per-row scale alone suffices
-        self.num_sidecars = {"float32": 0, "int8": 4, "fp8_e4m3": 2}[
-            kv_dtype]
         self.prefix_sharing = bool(prefix_sharing)
         self._prefix_entry_cap = int(prefix_entries)
-        qshape = (self.num_layers, self.num_pages, self.page_size)
-        stored = {"int8": "int8", "fp8_e4m3": "float8_e4m3fn"}
-        values = tuple(jnp.zeros(qshape + (w,), stored.get(kv_dtype, d))
-                       for _n, w, d in pools)
-        quant = tuple(jnp.zeros(qshape, "float32")
-                      for _ in range(self.num_sidecars))
+        arrays = self.pages.new_pools(self.num_pages)
         if mesh is not None:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec
@@ -292,15 +249,16 @@ class PagedKVCache:
                 raise ValueError(
                     f"num_heads={self.num_heads} is not divisible by the "
                     f"mesh's {kv_axis!r} axis ({mesh.shape[kv_axis]})")
+            # the value pools split their row axis; the sidecars (one
+            # number a token row) are replicated
             sharding = NamedSharding(
                 mesh, PartitionSpec(None, None, None, kv_axis))
-            values = tuple(jax.device_put(x, sharding) for x in values)
             rep = NamedSharding(mesh, PartitionSpec())
-            quant = tuple(jax.device_put(q, rep) for q in quant)
+            arrays = tuple(
+                jax.device_put(x, sharding if j < len(pools) else rep)
+                for j, x in enumerate(arrays))
         self.mesh = mesh          # the runtime replicates params over it
-        self._values = values
-        # (k_scale, k_zero, v_scale, v_zero) — empty tuple in fp32 mode
-        self._quant = quant
+        self._pools = arrays
         self._copy_fn = None
         self._lock = threading.Lock()
         self._free_pages = list(range(1, self.num_pages))  # 0 = trash
@@ -326,48 +284,47 @@ class PagedKVCache:
     def usable_pages(self):
         return self.num_pages - 1
 
+    # ----------------------------------------- what the format answers
+    @property
+    def kv_dtype(self):
+        return self.pages.kv_dtype
+
+    @property
+    def quantized(self):
+        return self.pages.quantized
+
+    @property
+    def num_sidecars(self):
+        return self.pages.num_sidecars
+
     @property
     def kv_bytes_per_token(self):
-        """Device bytes one token position costs across K+V pools (all
-        layers), including the int8 scale/zero sidecars."""
-        per_layer = 0
-        for _n, row, dtype in self.pool_layout:
-            if self.kv_dtype == "int8":
-                per_layer += row + 2 * 4     # int8 values + scale/mid f32
-            elif self.kv_dtype == "fp8_e4m3":
-                per_layer += row + 4         # fp8 values + scale f32
-            else:
-                per_layer += row * (2 if dtype == "bfloat16"
-                                    else np.dtype(dtype).itemsize)
-        return self.num_layers * per_layer
+        """Device bytes one token position costs across every pool (all
+        layers), sidecars included."""
+        return self.pages.kv_bytes_per_token
 
     @property
     def page_bytes(self):
-        """Device bytes one page costs (K+V, all layers, sidecars)."""
+        """Device bytes one page costs (every pool, all layers)."""
         return self.kv_bytes_per_token * self.page_size
 
     @property
     def pools(self):
         """Every device pool array the commit/step programs thread
-        through (and donate), the value pools in the layout's order and
-        then the sidecars: ``(k, v)`` in fp32, ``(k, v, k_scale, k_zero,
-        v_scale, v_zero)`` in int8, ``(k, v, k_scale, v_scale)`` in
-        fp8_e4m3; ``(latent,)`` for a latent-attention block."""
-        return self._values + self._quant
+        through (and donate), in the format's order (``kv_format``)."""
+        return self._pools
 
     def set_pools(self, arrays):
-        arrays = tuple(arrays)
-        n = len(self.pool_layout)
-        self._values, self._quant = arrays[:n], arrays[n:]
+        self._pools = tuple(arrays)
 
     @property
     def k_pages(self):
         """The two-pool geometry's key pool (the first value pool)."""
-        return self._values[0]
+        return self._pools[0]
 
     @property
     def v_pages(self):
-        return self._values[1]
+        return self._pools[1]
 
     # ------------------------------------------------------------ occupancy
     @property
@@ -695,8 +652,8 @@ class PagedKVCache:
 
     def drop_prefix_cache(self):
         """Unpublish everything: every index-only page returns to the
-        pool (live slots keep theirs until freed).  The bench/ops
-        "drop caches" lever, and how tests separate a leak from a pin."""
+        pool (live slots keep theirs until freed).  The ops "drop
+        caches" lever, and how tests separate a leak from a pin."""
         with self._lock:
             for fh in list(self._full_index):
                 self._drop_full_locked(fh)
@@ -747,7 +704,7 @@ class PagedKVCache:
 
     def _copy_page(self, src, dst):
         """One jitted donated program copies page ``src`` onto ``dst``
-        across every pool (values + int8 sidecars) — physical page ids
+        across every pool (values and sidecars) — physical page ids
         are traced scalars, so every CoW event replays one executable."""
         import jax
         if self._copy_fn is None:
@@ -802,11 +759,6 @@ class PagedKVCache:
                        round(in_use / max(self.usable_pages, 1), 4))
             _tel.gauge("decode.kv_pages", in_use)
             _tel.gauge("decode.kv_bytes_per_token", self.kv_bytes_per_token)
-
-    def reset_peak(self):
-        """Restart the ``peak_pages`` high-water mark (bench phases)."""
-        with self._lock:
-            self.peak_pages = self.num_pages - 1 - len(self._free_pages)
 
     def stats(self):
         with self._lock:

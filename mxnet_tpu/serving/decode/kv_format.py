@@ -1,0 +1,217 @@
+"""The KV page format: what a page pool stores, and the only two functions
+that index one.
+
+A cache's attention state is a tuple of device arrays, the *pools*, each
+``(layers, num_pages, page_size, row width)``: the value pools the block's
+``cache_layout()`` states, in its order, then the format's float32
+*sidecars* ``(layers, num_pages, page_size)``, each value pool's side by
+side: ``(k, v)`` raw, ``(k, v, k_scale, k_mid, v_scale, v_mid)`` in int8,
+``(k, v, k_scale, v_scale)`` in fp8_e4m3, ``(latent,)`` for a
+latent-attention block.  Every program threads the whole tuple through
+and donates it; only :class:`PageFormat` knows which array is what.
+
+Three formats, chosen by ``kv_dtype``:
+
+- **raw** (``"float32"``, the default): each pool in the dtype the block
+  states (float32 K/V, a bfloat16 latent row); no sidecars.
+- **int8**: affine codes with a per-token-row ``(scale, mid)`` pair
+  (:func:`kv_quantize_rows`); about a quarter of the float32 bytes.
+- **fp8_e4m3**: e4m3 codes with a per-token-row absmax ``scale``
+  (:func:`kv_quantize_rows_fp8`; the sign bit and mantissa make a midpoint
+  unnecessary).
+
+Quantization happens at :meth:`PageFormat.write` and dequantization at
+:meth:`PageFormat.read`, inside whichever commit, step or verify program
+calls them, so a format costs no program of its own.  Both are elementwise
+per token row, so the row-stable contract (``model.py``) and the
+shared-vs-cold bitwise contract of prefix sharing hold in every format;
+what a quantized format relaxes is fidelity *versus the raw pools*
+(``docs/serving.md``).
+
+**The pool's minor axis is the whole token row** (``heads * head_dim``
+values), not ``head_dim``.  A TPU array lives in (8, 128) tiles of its two
+minor dimensions; with ``head_dim`` = 64 minor-most the client pads the
+resident pool and stores it pages-minor, a layout no scatter or gather
+computes in, so every program that took the donated pools began and ended
+with a copy of each whole pool.  A row that fills whole lane tiles is
+resident unpadded in the layout the programs compute in, and they update
+the donated buffers in place.  **A pool is indexed once**: ``pool[layer,
+tables]`` / ``pool.at[layer, pages, offsets]``, never
+``pool[layer][tables]`` — the chained form materialises all ``num_pages``
+pages of the layer before gathering a row's few.
+``tests/test_chip_compile.py`` holds every program (step, verify, commit,
+copy-on-write; each format) to this: no temporary the size of a pool or of
+one layer of one.
+"""
+from __future__ import annotations
+
+__all__ = ["PageFormat", "kv_quantize_rows", "kv_dequantize",
+           "kv_quantize_rows_fp8", "kv_dequantize_fp8"]
+
+
+def kv_quantize_rows(x):
+    """Affine int8 quantization of K/V token rows ``x (..., H, D)`` —
+    one ``(scale, mid)`` pair per leading index, reduced over the last
+    two axes only.  Returns ``(q int8, scale, mid)`` with
+    ``scale/mid`` of shape ``x.shape[:-2]``.
+
+    The reduction never crosses a leading axis, so quantization is
+    *row-stable* exactly like ``rowdot``: a token row's int8 codes are
+    a pure elementwise function of that row's fp32 values, independent of
+    batch composition, seq bucket, or physical page — which is why the
+    shared-vs-cold bitwise contract survives int8 pools.  An all-zero row
+    (the trash page, uninitialized pool entries) maps to
+    ``scale = mid = 0`` and dequantizes to exact ``0.0``."""
+    import jax.numpy as jnp
+    lo = x.min(axis=(-2, -1))
+    hi = x.max(axis=(-2, -1))
+    scale = (hi - lo) / 254.0
+    mid = (hi + lo) * 0.5
+    q = jnp.round((x - mid[..., None, None])
+                  / jnp.where(scale > 0, scale, 1.0)[..., None, None])
+    return jnp.clip(q, -127.0, 127.0).astype("int8"), scale, mid
+
+
+def kv_dequantize(q, scale, mid):
+    """Inverse of :func:`kv_quantize_rows` — elementwise, row-stable:
+    ``q * scale + mid`` broadcast over the trailing ``(H, D)`` axes."""
+    return (q.astype("float32") * scale[..., None, None]
+            + mid[..., None, None])
+
+
+def kv_quantize_rows_fp8(x):
+    """fp8 (e4m3) quantization of K/V token rows ``x (..., H, D)`` —
+    per-row *scale only* (e4m3 keeps a sign bit and enough mantissa that
+    a symmetric absmax scale suffices; no ``mid``), reduced over the last
+    two axes.  Returns ``(q float8_e4m3fn, scale)`` with ``scale`` of
+    shape ``x.shape[:-2]``.  Row-stable like :func:`kv_quantize_rows`;
+    an all-zero row maps to ``scale = 0`` and dequantizes to exact 0."""
+    import jax.numpy as jnp
+    amax = jnp.abs(x).max(axis=(-2, -1))
+    scale = amax / 448.0                 # e4m3fn finite max
+    q = x / jnp.where(scale > 0, scale, 1.0)[..., None, None]
+    return q.astype(jnp.float8_e4m3fn), scale
+
+
+def kv_dequantize_fp8(q, scale):
+    """Inverse of :func:`kv_quantize_rows_fp8` — ``q * scale`` broadcast
+    over the trailing ``(H, D)`` axes."""
+    return q.astype("float32") * scale[..., None, None]
+
+
+_ALIASES = {"fp32": "float32", "float": "float32",
+            "fp8": "fp8_e4m3", "float8_e4m3fn": "fp8_e4m3"}
+
+#: kv_dtype -> (stored dtype, rows -> (codes, *sidecars), its inverse,
+#: sidecars a value pool); None: raw
+_CODECS = {
+    "float32": None,
+    "int8": ("int8", kv_quantize_rows, kv_dequantize, 2),
+    "fp8_e4m3": ("float8_e4m3fn", kv_quantize_rows_fp8, kv_dequantize_fp8,
+                 1),
+}
+
+
+class PageFormat:
+    """How one cache's pages are stored; see the module docstring.
+
+    Built by :class:`~mxnet_tpu.serving.decode.kv_cache.PagedKVCache` from
+    the block's ``cache_layout()`` and ``kv_dtype``; the commit, step and
+    verify programs of a block receive it as ``pages`` and reach the pools
+    only through :meth:`write` and :meth:`read`."""
+
+    def __init__(self, layout, kv_dtype=None, page_size=16):
+        import jax.numpy as jnp
+        kv_dtype = _ALIASES.get(str(kv_dtype), str(kv_dtype)) \
+            if kv_dtype is not None else "float32"
+        if kv_dtype not in _CODECS:
+            raise ValueError(
+                f"kv_dtype must be 'float32', 'int8' or 'fp8_e4m3', "
+                f"got {kv_dtype!r}")
+        self.pool_layout = tuple((str(n), int(w), str(d))
+                                 for n, w, d in layout["pools"])
+        codec = self._codec = _CODECS[kv_dtype]
+        if codec and not layout.get("quantizable"):
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r}: the block's pools "
+                f"{[n for n, _w, _d in self.pool_layout]} are stored as the "
+                f"block states them; an int8/fp8 pool of these rows is not "
+                f"supported")
+        self.kv_dtype = kv_dtype
+        self.quantized = codec is not None
+        self.page_size = int(page_size)
+        self.num_layers = int(layout["layers"])
+        self._per = codec[3] if codec else 0
+        self.num_sidecars = self._per * len(self.pool_layout)
+        self._stored = tuple(jnp.dtype(codec[0] if codec else d)
+                             for _n, _w, d in self.pool_layout)
+        #: device bytes one token position costs across every pool (all
+        #: layers), sidecars included
+        self.kv_bytes_per_token = self.num_layers * sum(
+            w * dt.itemsize + 4 * self._per
+            for (_n, w, _d), dt in zip(self.pool_layout, self._stored))
+        # a row that is a concatenation of heads reads back as (heads,
+        # head_dim): the axes a quantized format reduces over
+        heads = layout.get("shard_heads")
+        self._row_shapes = tuple((heads, w // heads) if heads else (w,)
+                                 for _n, w, _d in self.pool_layout)
+
+    def new_pools(self, num_pages):
+        """Zeroed pools of ``num_pages`` pages: the value pools, then the
+        sidecars.  An all-zero row reads back as exact zeros in every
+        format (the trash page, pages never written)."""
+        import jax.numpy as jnp
+        shape = (self.num_layers, int(num_pages), self.page_size)
+        return tuple(jnp.zeros(shape + (w,), dt) for (_n, w, _d), dt
+                     in zip(self.pool_layout, self._stored)) + \
+            tuple(jnp.zeros(shape, "float32")
+                  for _ in range(self.num_sidecars))
+
+    def write(self, pools, layer, page, offset, rows):
+        """Store one layer's new token rows at ``(page, offset)`` and return
+        the updated pools.  ``rows`` holds one array a value pool, each with
+        the leading axes of ``page`` / ``offset`` (``(B,)`` for a step,
+        ``(B, K+1)`` for a verify, ``(B, S)`` for a prefill's commit) and
+        the row behind them, flat or as ``(heads, head_dim)`` — the axes a
+        quantized format reduces over before the row is flattened."""
+        pools = list(pools)
+        n = len(self.pool_layout)
+
+        def put(j, x):
+            pools[j] = pools[j].at[layer, page, offset].set(x)
+
+        def flat(x):
+            return x.reshape(page.shape + (-1,))
+
+        if self._codec is None:
+            for j, x in enumerate(rows):
+                put(j, flat(x).astype(pools[j].dtype))
+            return tuple(pools)
+        coded = [self._codec[1](x) for x in rows]
+        for j, c in enumerate(coded):
+            put(j, flat(c[0]))
+        for j, c in enumerate(coded):
+            for s, side in enumerate(c[1:]):
+                put(n + j * self._per + s, side)
+        return tuple(pools)
+
+    def read(self, pools, layer, tables):
+        """Gather one layer's whole paged context for every row of
+        ``tables (B, pages a row)``: one array a value pool, ``(B, pages a
+        row * page_size) + row`` (a row of heads as ``(heads, head_dim)``),
+        dequantized to float32 where the format is quantized.  Each pool is
+        indexed ONCE (the module docstring says why)."""
+        n = len(self.pool_layout)
+        lead = (tables.shape[0], tables.shape[1] * self.page_size)
+
+        def gather(j, row=()):
+            return pools[j][layer, tables].reshape(lead + row)
+
+        if self._codec is None:
+            return tuple(gather(j, row)
+                         for j, row in enumerate(self._row_shapes))
+        return tuple(
+            self._codec[2](gather(j, row),
+                           *(gather(n + j * self._per + s)
+                             for s in range(self._per)))
+            for j, row in enumerate(self._row_shapes))

@@ -377,24 +377,23 @@ class LatentMoELM(HybridBlock):
             logits = _dot(last, p["head"])
         return logits, jnp.stack(out_rows)
 
-    def step_program(self, p, tokens, positions, tables, pools, page_size):
+    def step_program(self, p, tokens, positions, tables, pools, pages):
         """Pure fused decode step, one token a row: writes each row's latent
-        row into its page, gathers the row's paged context in place
-        (``pool[i, tables]``: only the pages the table names) and attends
-        absorbed.  Rows whose table starts with the trash page are padding
-        and are routed to no expert.  Returns ``(logits (B, vocab), (pool,),
-        (moe_rows (expert layers, held + 1) int32,))``: per expert layer
-        the rows each held expert received, then the assignments made over
-        all experts."""
+        row into its page, gathers the row's paged context through
+        ``pages`` (the cache's ``PageFormat``: only the pages the table
+        names) and attends absorbed.  Rows whose table starts with the trash
+        page are padding and are routed to no expert.  Returns ``(logits (B,
+        vocab), pools, (moe_rows (expert layers, held + 1) int32,))``: per
+        expert layer the rows each held expert received, then the
+        assignments made over all experts."""
         import jax
         import jax.numpy as jnp
-        (pool,) = pools
-        B = tokens.shape[0]
-        lctx = tables.shape[1] * page_size
+        page_size = pages.page_size
         h = p["embed"][tokens].astype(jnp.float32)
         wp = jnp.take_along_axis(tables, (positions // page_size)[:, None],
                                  axis=1)[:, 0]
         woff = positions % page_size
+        lctx = tables.shape[1] * page_size
         mask = jnp.arange(lctx)[None, :] <= positions[:, None]
         valid = tables[:, 0] != 0
         counts = []
@@ -402,8 +401,8 @@ class LatentMoELM(HybridBlock):
             a = _rms(h, p[f"l{i}_norm_attn"], self.eps)
             with jax.named_scope("mla.attend"):
                 row = self._latent_row(p, i, a, positions)
-                pool = pool.at[i, wp, woff].set(row.astype(pool.dtype))
-                ctx_rows = pool[i, tables].reshape(B, lctx, self.pool_width)
+                pools = pages.write(pools, i, wp, woff, (row,))
+                (ctx_rows,) = pages.read(pools, i, tables)
                 o = self.attend_absorbed(p, i, a, positions, ctx_rows, mask)
             h = self._ffn(p, i, h + o, valid, counts)
         hf = _rms(h, p["norm_f"], self.eps)
@@ -412,18 +411,16 @@ class LatentMoELM(HybridBlock):
         moe_rows = jnp.stack([jnp.concatenate([r, n[None]])
                               for r, n in counts]) if counts \
             else jnp.zeros((0, len(self.held) + 1), jnp.int32)
-        return logits, (pool,), (moe_rows,)
+        return logits, pools, (moe_rows,)
 
-    def commit_program(self, rows, lengths, tables, pools, page_size):
-        """Scatter the prefill's ``rows (layers, B, S, pool_width)`` into the
+    def commit_program(self, rows, lengths, tables, pools, pages):
+        """Store the prefill's ``rows (layers, B, S, pool_width)`` in the
         pool at the pages ``tables`` names, a layer at a time."""
-        (pool,) = pools
         dest_page, dest_off = commit_destinations(
-            rows.shape[2], lengths, tables, page_size)
+            rows.shape[2], lengths, tables, pages.page_size)
         for i in range(self.num_layers):
-            pool = pool.at[i, dest_page, dest_off].set(
-                rows[i].astype(pool.dtype))
-        return (pool,)
+            pools = pages.write(pools, i, dest_page, dest_off, (rows[i],))
+        return pools
 
     sample_math = staticmethod(sample_math)
 

@@ -34,9 +34,7 @@ import numpy as np
 from ...gluon.block import HybridBlock
 from ...ndarray import NDArray, invoke_fn
 
-__all__ = ["CausalLM", "get_decode_model", "rowdot", "sample_math",
-           "kv_quantize_rows",
-           "kv_dequantize", "kv_quantize_rows_fp8", "kv_dequantize_fp8"]
+__all__ = ["CausalLM", "get_decode_model", "rowdot", "sample_math"]
 
 
 def rowdot(x, w):
@@ -59,107 +57,6 @@ def _gelu(x):
     import jax.numpy as jnp
     return 0.5 * x * (1.0 + jnp.tanh(
         0.7978845608028654 * (x + 0.044715 * x ** 3)))
-
-
-def kv_quantize_rows(x):
-    """Affine int8 quantization of K/V token rows ``x (..., H, D)`` —
-    one ``(scale, mid)`` pair per leading index, reduced over the last
-    two axes only.  Returns ``(q int8, scale, mid)`` with
-    ``scale/mid`` of shape ``x.shape[:-2]``.
-
-    The reduction never crosses a leading axis, so quantization is
-    *row-stable* exactly like :func:`rowdot`: a token row's int8 codes are
-    a pure elementwise function of that row's fp32 values, independent of
-    batch composition, seq bucket, or physical page — which is why the
-    shared-vs-cold bitwise contract survives int8 pools.  An all-zero row
-    (the trash page, uninitialized pool entries) maps to
-    ``scale = mid = 0`` and dequantizes to exact ``0.0``."""
-    import jax.numpy as jnp
-    lo = x.min(axis=(-2, -1))
-    hi = x.max(axis=(-2, -1))
-    scale = (hi - lo) / 254.0
-    mid = (hi + lo) * 0.5
-    q = jnp.round((x - mid[..., None, None])
-                  / jnp.where(scale > 0, scale, 1.0)[..., None, None])
-    return jnp.clip(q, -127.0, 127.0).astype("int8"), scale, mid
-
-
-def kv_dequantize(q, scale, mid):
-    """Inverse of :func:`kv_quantize_rows` — elementwise, row-stable:
-    ``q * scale + mid`` broadcast over the trailing ``(H, D)`` axes."""
-    return (q.astype("float32") * scale[..., None, None]
-            + mid[..., None, None])
-
-
-def kv_quantize_rows_fp8(x):
-    """fp8 (e4m3) quantization of K/V token rows ``x (..., H, D)`` —
-    per-row *scale only* (e4m3 keeps a sign bit and enough mantissa that
-    a symmetric absmax scale suffices; no ``mid``), reduced over the last
-    two axes.  Returns ``(q float8_e4m3fn, scale)`` with ``scale`` of
-    shape ``x.shape[:-2]``.  Row-stable like :func:`kv_quantize_rows`;
-    an all-zero row maps to ``scale = 0`` and dequantizes to exact 0."""
-    import jax.numpy as jnp
-    amax = jnp.abs(x).max(axis=(-2, -1))
-    scale = amax / 448.0                 # e4m3fn finite max
-    q = x / jnp.where(scale > 0, scale, 1.0)[..., None, None]
-    return q.astype(jnp.float8_e4m3fn), scale
-
-
-def kv_dequantize_fp8(q, scale):
-    """Inverse of :func:`kv_quantize_rows_fp8` — ``q * scale`` broadcast
-    over the trailing ``(H, D)`` axes."""
-    return q.astype("float32") * scale[..., None, None]
-
-
-def _kv_scatter(state, i, wp, woff, k, v):
-    """Write one layer's new K/V rows into the paged pools at
-    ``(page, offset)``, quantizing by sidecar arity: ``None`` = raw fp32,
-    2 sidecars = fp8 per-row scale, 4 = int8 per-row scale/mid.  ``wp`` /
-    ``woff`` may be ``(B,)`` (step), ``(B, K+1)`` (verify) or ``(B, S)``
-    (the prefill's commit); ``k`` / ``v`` carry matching leading axes plus
-    trailing ``(H, D)``, which the quantizers reduce over before it is
-    flattened to the pool's row."""
-    def put(pool, x):
-        return pool.at[i, wp, woff].set(x.reshape(x.shape[:-2] + (-1,)))
-
-    qs = state["q"]
-    if qs is None:
-        state["k"] = put(state["k"], k)
-        state["v"] = put(state["v"], v)
-        return
-    if len(qs) == 2:
-        kq, ksc = kv_quantize_rows_fp8(k)
-        vq, vsc = kv_quantize_rows_fp8(v)
-        rows = (ksc, vsc)
-    else:
-        kq, ksc, kmd = kv_quantize_rows(k)
-        vq, vsc, vmd = kv_quantize_rows(v)
-        rows = (ksc, kmd, vsc, vmd)
-    state["k"] = put(state["k"], kq)
-    state["v"] = put(state["v"], vq)
-    for j, row in enumerate(rows):
-        qs[j] = qs[j].at[i, wp, woff].set(row)
-
-
-def _kv_gather(state, i, tables, B, lctx, H, D):
-    """Gather one layer's full paged context ``(B, lctx, H, D)`` for every
-    row, dequantizing through whichever sidecars the pool carries.  Each
-    pool is indexed ONCE: ``pool[i][tables]`` would copy all of layer
-    ``i``'s pages out of the pool before gathering the rows' few."""
-    def g(pool):
-        return pool[i, tables].reshape(B, lctx, H, D)
-
-    def side(j):
-        return state["q"][j][i, tables].reshape(B, lctx)
-
-    qs = state["q"]
-    if qs is None:
-        return g(state["k"]), g(state["v"])
-    if len(qs) == 2:
-        return (kv_dequantize_fp8(g(state["k"]), side(0)),
-                kv_dequantize_fp8(g(state["v"]), side(1)))
-    return (kv_dequantize(g(state["k"]), side(0), side(1)),
-            kv_dequantize(g(state["v"]), side(2), side(3)))
 
 
 def commit_destinations(S, lengths, tables, page_size):
@@ -216,8 +113,8 @@ class CausalLM(HybridBlock):
     the commit program.
 
     The decode hot path never touches this class' forward directly — the
-    runtime compiles :meth:`prefill_fn` through the CachedOp ladder and
-    builds its fused step program from :meth:`step_math`.
+    runtime compiles the prefill through the CachedOp ladder and builds its
+    fused step program from :meth:`step_program`.
     """
 
     def __init__(self, vocab_size=512, units=128, num_layers=2, num_heads=4,
@@ -279,7 +176,7 @@ class CausalLM(HybridBlock):
         return [self._reg_params[n].data()._data for n in self._param_order]
 
     def _layer(self, p, i, h, attend):
-        """One pre-LN transformer layer.  ``attend(q, k, v)`` supplies the
+        """One pre-LN transformer layer.  ``attend(i, q, k, v)`` supplies the
         attention context — the ONLY piece that differs between prefill
         (dense causal) and decode step (paged-cache gather), so everything
         else is provably shared math."""
@@ -287,7 +184,7 @@ class CausalLM(HybridBlock):
         a = _ln(h, p[f"l{i}_ln1_g"], p[f"l{i}_ln1_b"])
         qkv = rowdot(a, p[f"l{i}_wqkv"]) + p[f"l{i}_bqkv"]
         q, k, v = jnp.split(qkv, 3, axis=-1)
-        ctx = attend(q * self._scale, k, v)
+        ctx = attend(i, q * self._scale, k, v)
         h = h + rowdot(ctx, p[f"l{i}_wo"]) + p[f"l{i}_bo"]
         m = _ln(h, p[f"l{i}_ln2_g"], p[f"l{i}_ln2_b"])
         return h + rowdot(_gelu(rowdot(m, p[f"l{i}_w1"]) + p[f"l{i}_b1"]),
@@ -303,7 +200,7 @@ class CausalLM(HybridBlock):
         causal = jnp.tril(jnp.ones((S, S), bool))
         ks, vs = [], []
 
-        def attend(q, k, v):
+        def attend(_i, q, k, v):
             q = q.reshape(B, S, H, D)
             k = k.reshape(B, S, H, D)
             v = v.reshape(B, S, H, D)
@@ -321,58 +218,52 @@ class CausalLM(HybridBlock):
         logits = rowdot(last, p["embed"].T)
         return logits, jnp.stack([jnp.stack(ks), jnp.stack(vs)])
 
-    def step_math(self, p, tokens, positions, tables, k_pages, v_pages,
-                  page_size, quant=None):
-        """Pure fused decode step for one token per row.
+    def step_program(self, p, tokens, positions, tables, pools, pages):
+        """Pure fused decode step for one token per row, by the runtime's
+        protocol: ``(logits, pools, extras)``.
 
         Writes each row's new K/V into its page (``tables`` routes padded
         rows to trash page 0), gathers the row's whole paged context
         (fixed length ``max_pages * page_size`` — constant shape is what
         keeps one compiled program per batch bucket AND makes the math
         identical regardless of physical page placement), and returns the
-        next-token logits.  Also returns the updated page arrays.
+        next-token logits with the updated pools.
 
-        With ``quant`` — the sidecar pools of a quantized cache:
-        ``(k_scale, k_mid, v_scale, v_mid)`` for int8, ``(k_scale,
-        v_scale)`` for fp8 — the new token row is quantized before the
-        scatter and the gathered context dequantized before the attention
-        einsums; both are row-stable, so per-row bitwise independence of
-        batch composition holds quantized exactly as in fp32.  The
-        updated sidecars are returned after the page arrays."""
+        ``pages`` is the cache's :class:`~mxnet_tpu.serving.decode.
+        kv_format.PageFormat`: it quantizes the new token row at the write
+        and dequantizes the gathered context before the attention einsums
+        where the pools are quantized; both are row-stable, so per-row
+        bitwise independence of batch composition holds in every format."""
         import jax
         import jax.numpy as jnp
         B = tokens.shape[0]
         H, D = self.num_heads, self.head_dim
+        page_size = pages.page_size
         lctx = tables.shape[1] * page_size
         h = p["embed"][tokens] + p["pos_embed"][positions]
         wp = jnp.take_along_axis(tables, (positions // page_size)[:, None],
                                  axis=1)[:, 0]
         woff = positions % page_size
         mask = jnp.arange(lctx)[None, :] <= positions[:, None]
-        state = {"k": k_pages, "v": v_pages, "i": 0,
-                 "q": list(quant) if quant is not None else None}
 
-        def attend(q, k, v):
-            i = state["i"]
+        def attend(i, q, k, v):
+            nonlocal pools
             q = q.reshape(B, H, D)
-            _kv_scatter(state, i, wp, woff,
-                        k.reshape(B, H, D), v.reshape(B, H, D))
-            kg, vg = _kv_gather(state, i, tables, B, lctx, H, D)
+            pools = pages.write(pools, i, wp, woff,
+                                (k.reshape(B, H, D), v.reshape(B, H, D)))
+            kg, vg = pages.read(pools, i, tables)
             s = jnp.einsum("bhd,blhd->bhl", q, kg)
             s = jnp.where(mask[:, None], s, -1e30)
             pr = jax.nn.softmax(s, axis=-1)
-            state["i"] = i + 1
             return jnp.einsum("bhl,blhd->bhd", pr, vg).reshape(B, -1)
 
         for i in range(self.num_layers):
             h = self._layer(p, i, h, attend)
         hf = _ln(h, p["lnf_g"], p["lnf_b"])
-        logits = rowdot(hf, p["embed"].T)
-        out = (logits, state["k"], state["v"])
-        return out if state["q"] is None else out + tuple(state["q"])
+        return rowdot(hf, p["embed"].T), pools, ()
 
-    def verify_math(self, p, tokens, positions, n_draft, tables, k_pages,
-                    v_pages, page_size, quant=None):
+    def verify_program(self, p, tokens, positions, n_draft, tables, pools,
+                       pages):
         """Pure fused speculative *verify*: ``K+1`` tokens per row in one
         program.  ``tokens (B, K+1)`` is ``[cur, d_1 .. d_K]`` — the row's
         current token followed by its drafted continuation, padded past
@@ -389,7 +280,7 @@ class CausalLM(HybridBlock):
         bitwise what the non-speculative step would produce after emitting
         ``d_1 .. d_j`` — the property the deterministic acceptance rule in
         the runtime's verify program builds on.  Returns
-        ``(logits (B, K+1, V), k_pages, v_pages[, sidecars...])``.
+        ``(logits (B, K+1, V), pools)``.
 
         Rejected candidates need no explicit rollback: their K/V sits at
         positions strictly greater than the row's post-verify position, so
@@ -399,6 +290,7 @@ class CausalLM(HybridBlock):
         import jax.numpy as jnp
         B, K1 = tokens.shape
         H, D = self.num_heads, self.head_dim
+        page_size = pages.page_size
         n_tab = tables.shape[1]
         lctx = n_tab * page_size
         offs = jnp.arange(K1, dtype="int32")[None, :]
@@ -415,27 +307,23 @@ class CausalLM(HybridBlock):
         wp = jnp.where(valid, owned, 0)
         woff = pos % page_size
         mask = jnp.arange(lctx)[None, None, :] <= pos[:, :, None]
-        state = {"k": k_pages, "v": v_pages, "i": 0,
-                 "q": list(quant) if quant is not None else None}
 
-        def attend(q, k, v):
-            i = state["i"]
+        def attend(i, q, k, v):
+            nonlocal pools
             q = q.reshape(B, K1, H, D)
-            _kv_scatter(state, i, wp, woff,
-                        k.reshape(B, K1, H, D), v.reshape(B, K1, H, D))
-            kg, vg = _kv_gather(state, i, tables, B, lctx, H, D)
+            pools = pages.write(
+                pools, i, wp, woff,
+                (k.reshape(B, K1, H, D), v.reshape(B, K1, H, D)))
+            kg, vg = pages.read(pools, i, tables)
             s = jnp.einsum("bqhd,blhd->bhql", q, kg)
             s = jnp.where(mask[:, None], s, -1e30)
             pr = jax.nn.softmax(s, axis=-1)
-            state["i"] = i + 1
             return jnp.einsum("bhql,blhd->bqhd", pr, vg).reshape(B, K1, -1)
 
         for i in range(self.num_layers):
             h = self._layer(p, i, h, attend)
         hf = _ln(h, p["lnf_g"], p["lnf_b"])
-        logits = rowdot(hf, p["embed"].T)
-        out = (logits, state["k"], state["v"])
-        return out if state["q"] is None else out + tuple(state["q"])
+        return rowdot(hf, p["embed"].T), pools
 
     sample_math = staticmethod(sample_math)
 
@@ -445,8 +333,8 @@ class CausalLM(HybridBlock):
 
     def cache_layout(self):
         """Two pools (keys, values) whose row is the whole token row
-        ``heads * head_dim`` in float32; quantizable (int8 / fp8 sidecars);
-        under a mesh the row axis is split by heads."""
+        ``heads * head_dim`` in float32; quantizable; under a mesh the row
+        axis is split by heads."""
         row = self.num_heads * self.head_dim
         return {"layers": self.num_layers,
                 "pools": (("k", row, "float32"), ("v", row, "float32")),
@@ -458,25 +346,17 @@ class CausalLM(HybridBlock):
         return (2, self.num_layers, b, s, self.num_heads,
                 self.head_dim), "float32"
 
-    def step_program(self, p, tokens, positions, tables, pools, page_size):
-        """:meth:`step_math` by the runtime's protocol: ``(logits, pools,
-        extras)`` with ``pools`` the values pools then the sidecars."""
-        out = self.step_math(p, tokens, positions, tables, pools[0],
-                             pools[1], page_size, quant=pools[2:] or None)
-        return out[0], tuple(out[1:]), ()
-
-    def commit_program(self, kv, lengths, tables, pools, page_size):
-        """Scatter the prefill's ``kv`` into the pools at the pages
-        ``tables`` names (positions past ``lengths`` go to the trash page):
-        one scatter a layer, as the step writes (a scatter over all layers
-        at once makes the compiler relayout both whole pools)."""
+    def commit_program(self, kv, lengths, tables, pools, pages):
+        """Store the prefill's ``kv`` in the pools at the pages ``tables``
+        names (positions past ``lengths`` go to the trash page): one write
+        a layer, as the step writes (a scatter over all layers at once
+        makes the compiler relayout both whole pools)."""
         dest_page, dest_off = commit_destinations(
-            kv.shape[3], lengths, tables, page_size)
-        state = {"k": pools[0], "v": pools[1],
-                 "q": list(pools[2:]) if len(pools) > 2 else None}
+            kv.shape[3], lengths, tables, pages.page_size)
         for i in range(self.num_layers):
-            _kv_scatter(state, i, dest_page, dest_off, kv[0, i], kv[1, i])
-        return (state["k"], state["v"]) + tuple(state["q"] or ())
+            pools = pages.write(pools, i, dest_page, dest_off,
+                                (kv[0, i], kv[1, i]))
+        return pools
 
     # ------------------------------------------------------- gluon frontend
     def hybrid_forward(self, F, tokens, lengths, **params):

@@ -21,18 +21,11 @@ pre-call arrays are poisoned at sites ``decode.prefill_commit`` /
 ``decode.step`` exactly like the aggregated-optimizer and engine-segment
 donation sites.
 
-With a quantized cache (``kv_dtype="int8"`` or ``"fp8_e4m3"``) the same
-two surfaces carry the quantization: the commit program scatter-*quantizes*
-the prefill's fp32 K/V into the pools (+ per-row sidecars) through the one
-writer the step and verify programs use (``model._kv_scatter``, a layer
-at a time) and the step program gather-*dequantizes* before attending —
-both fused into the already-compiled per-bucket executables, so the dtype
-costs zero extra programs and ``warm()`` covers it exactly like fp32.  The
-pool argument list simply grows from ``(k, v)`` to ``(k, v, k_scale,
-k_mid, v_scale, v_mid)`` for int8 or ``(k, v, k_scale, v_scale)`` for fp8
-(all donated, all poisoned).  Every program reads and writes only the
-pages its page table names, in the donated buffers (``kv_cache``'s module
-docstring says what that asks of the pools' shape and of their indexing).
+How the pools are stored is the cache's page format (``kv_format``): the
+runtime threads ``cache.pools`` through every program as one donated,
+poisoned tuple and hands the block ``cache.pages`` to write and read them
+with, so a quantized format costs no extra program and ``warm()`` covers
+it exactly like the raw one.
 """
 from __future__ import annotations
 
@@ -79,12 +72,15 @@ class DecodeRuntime:
     good for), ``max_prefill_batch`` (the most prompts one prefill call may
     hold; None: as many as a step), ``prefill_state(b, s)`` (shape and
     dtype of that ``state``),
-    ``commit_program(state, lengths, tables, pools, page_size) -> pools``,
-    ``step_program(params, tokens, positions, tables, pools, page_size)
-    -> (logits, pools, extras)`` and ``sample_math``.  ``extras`` are int32
-    arrays that ride the step's one fetch behind the tokens and are handed
-    to ``block.record_step_extras`` when telemetry is on.  A verify ladder
-    (``spec_buckets``) also needs ``verify_math``.
+    ``commit_program(state, lengths, tables, pools, pages) -> pools``,
+    ``step_program(params, tokens, positions, tables, pools, pages)
+    -> (logits, pools, extras)`` and ``sample_math``; ``pages`` is the
+    cache's ``PageFormat``, the block's only way into ``pools``.  ``extras``
+    are int32 arrays that ride the step's one fetch behind the tokens and
+    are handed to ``block.record_step_extras`` when telemetry is on.  A
+    verify ladder (``spec_buckets``) also needs ``verify_program(params,
+    tokens, positions, n_draft, tables, pools, pages) -> (logits (B, K+1,
+    vocab), pools)``.
 
     Parameters
     ----------
@@ -124,7 +120,7 @@ class DecodeRuntime:
             block.hybridize()
         self._block = block
         layout = block.cache_layout()
-        if spec_buckets and not hasattr(block, "verify_math"):
+        if spec_buckets and not hasattr(block, "verify_program"):
             raise ValueError(
                 f"{type(block).__name__} has no verify program: a session "
                 f"of this block cannot speculate (drafter=None only)")
@@ -424,13 +420,13 @@ class DecodeRuntime:
     def _build_step(self):
         import jax
         import jax.numpy as jnp
-        block, page_size = self._block, self.cache.page_size
+        block, pages = self._block, self.cache.pages
 
         def step(params, tokens, positions, tables, keys, steps, temps,
                  *pools):
             p = block._params_dict(params)
             logits, pools, extras = block.step_program(
-                p, tokens, positions, tables, pools, page_size)
+                p, tokens, positions, tables, pools, pages)
             nxt = block.sample_math(logits, keys, steps, temps)
             # what the host reads is ONE int32 vector: the tokens, then
             # whatever the block counts (nothing for CausalLM)
@@ -462,22 +458,20 @@ class DecodeRuntime:
         Acceptance is *deterministic equality*: offset ``j``'s target
         sample uses exactly the fold the non-speculative step ``j``
         would, over bitwise the same logits (see
-        :meth:`CausalLM.verify_math`), so the emitted stream —
+        :meth:`CausalLM.verify_program`), so the emitted stream —
         ``target[0 .. n_acc]`` — is always bitwise the non-speculative
         stream, for greedy AND sampled temperatures."""
         import jax
         import jax.numpy as jnp
-        block, page_size = self._block, self.cache.page_size
-        quantized = self.cache.quantized
+        block, pages = self._block, self.cache.pages
 
         def verify(params, tokens, positions, n_draft, tables, keys,
                    steps, temps, *pools):
             p = block._params_dict(params)
-            out = block.verify_math(
-                p, tokens, positions, n_draft, tables, pools[0], pools[1],
-                page_size, quant=pools[2:] if quantized else None)
+            logits, pools = block.verify_program(
+                p, tokens, positions, n_draft, tables, pools, pages)
             B, K1 = tokens.shape
-            flat = out[0].reshape(B * K1, -1)
+            flat = logits.reshape(B * K1, -1)
             # per-offset fold: row (b, j) samples with (key_b, step_b + j)
             # — bitwise the fold non-speculative step j would use
             target = block.sample_math(
@@ -489,19 +483,18 @@ class DecodeRuntime:
                   & (jnp.arange(1, K1, dtype="int32")[None, :]
                      <= n_draft[:, None]))
             n_acc = jnp.cumprod(ok.astype("int32"), axis=1).sum(axis=1)
-            return (target, n_acc) + tuple(out[1:])
+            return (target, n_acc) + tuple(pools)
 
         n = len(self.cache.pools)
         return jax.jit(verify, donate_argnums=tuple(range(8, 8 + n)))
 
     def _build_commit(self):
         import jax
-        block, page_size = self._block, self.cache.page_size
+        block, pages = self._block, self.cache.pages
 
         def commit(params, kv, logits, lengths, tables, keys, steps, temps,
                    *pools):
-            pools = block.commit_program(kv, lengths, tables, pools,
-                                         page_size)
+            pools = block.commit_program(kv, lengths, tables, pools, pages)
             first = block.sample_math(logits, keys, steps, temps)
             return (first,) + tuple(pools)
 

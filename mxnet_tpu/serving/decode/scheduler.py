@@ -6,7 +6,7 @@ as its slowest member finishes and new arrivals wait for the whole gang.
 step boundaries and evicts finished sequences immediately, freeing their
 KV pages for the next arrival — the device never idles while work is
 queued, which is where the tokens/sec win at mixed prompt lengths comes
-from (``bench.py decode`` measures both modes on the same machinery).
+from.
 
 The request plane carries over the PR 3 ``Batcher`` contract wholesale —
 bounded queue with backpressure, per-request deadlines with load shedding,
@@ -249,7 +249,7 @@ class DecodeScheduler:
     breaker_threshold / breaker_cooldown_ms
         Circuit breaker on consecutive prefill/step failures (None
         disables) — same semantics as ``serving.Batcher``.
-    drafter : Drafter | "ngram" | CausalLM | None
+    drafter : Drafter | "ngram" | None
         Enables speculative decoding: requests ride the fused verify
         program with this drafter's proposals (the runtime must have
         been built with ``spec_buckets``).  Output streams stay bitwise

@@ -53,8 +53,7 @@ Three observability layers ride on the bus (PR 15):
 
 Everything is off by default (flight recording excepted — it exists for
 the crash nobody armed telemetry for); when disabled each site costs one
-module attribute read (<2% on the eager microbench, see ``bench.py``
-config ``eager``).
+module attribute read.
 """
 from . import bus  # noqa: F401
 from . import exporters  # noqa: F401

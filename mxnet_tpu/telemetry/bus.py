@@ -11,9 +11,8 @@ traffic, and IO pipeline stalls.
 Design constraints (mirroring ``profiler.h``'s lock-free ring):
 
 - **Off by default.** Every instrumentation site guards on the module-global
-  ``enabled`` bool; a disabled check is one dict-free attribute read, so the
-  eager hot path stays within noise (<2% — measured by ``bench.py``'s
-  ``eager_dispatch`` config).
+  ``enabled`` bool; a disabled check is one dict-free attribute read on the
+  eager hot path.
 - **Bounded memory.** Events land in a ``deque(maxlen=capacity)``: old events
   fall off instead of growing the heap on long runs.  Appends are GIL-atomic;
   counters take a small lock only when enabled.
@@ -487,8 +486,8 @@ def span_aggregates():
 
 # ----------------------------------------------------------------- snapshot
 def snapshot():
-    """One dict with everything the bus knows — usable from tests,
-    bench.py, and monitor callbacks without touching exporters."""
+    """One dict with everything the bus knows — usable from tests, the
+    benchmark's drivers and monitor callbacks without touching exporters."""
     hist = {name: {"count": row["count"],
                    "sum": round(row["sum"], 3),
                    "min": round(row["min"], 3),

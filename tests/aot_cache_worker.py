@@ -1,5 +1,4 @@
-"""Subprocess worker for the AOT cold-start drill (ci gateway stage and
-``bench.py`` gateway config).
+"""Subprocess worker for the AOT cold-start drill (ci gateway stage).
 
 Each invocation is one "process restart": build + warm a DecodeSession
 against an on-disk AOT program cache (or none), generate a fixed prompt,

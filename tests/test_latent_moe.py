@@ -114,14 +114,15 @@ def decode_logits(net, tokens, n_prompt, pages, batch=1, row=0, seq_pad=16):
     padding) with the sequence in physical ``pages``: logits of positions
     ``n_prompt - 1 .. len(tokens) - 1``."""
     p = net._params_dict(net.param_leaves())
-    _cache, pools = new_pools(net)
+    cache, pools = new_pools(net)
     table = np.zeros((1, 8), "int32")
     table[0, :len(pages)] = pages
     prompt = np.zeros((1, seq_pad), "int32")
     prompt[0, :n_prompt] = tokens[:n_prompt]
     lengths = jnp.asarray([n_prompt], "int32")
     logits, rows = net.prefill_math(p, jnp.asarray(prompt), lengths)
-    pools = net.commit_program(rows, lengths, jnp.asarray(table), pools, PAGE)
+    pools = net.commit_program(rows, lengths, jnp.asarray(table), pools,
+                               cache.pages)
     out = [np.asarray(logits[0])]
     tables = np.zeros((batch, 8), "int32")
     tables[row] = table[0]
@@ -131,7 +132,7 @@ def decode_logits(net, tokens, n_prompt, pages, batch=1, row=0, seq_pad=16):
         tok[row], pos[row] = tokens[t], t
         logits, pools, extras = net.step_program(
             p, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables),
-            pools, PAGE)
+            pools, cache.pages)
         out.append(np.asarray(logits[row]))
     return np.stack(out), extras
 

@@ -258,19 +258,26 @@ def test_subclass_is_not_aggregated():
     assert telemetry.counter_value("optimizer.fallback_params") == 2
 
 
-def test_aggregation_size_chunks_groups():
+@pytest.mark.parametrize("shapes,cap,dispatches", [
+    ([(3,)] * 10, 4, 3),                      # ceil(10 / 4)
+    # a ResNet-like census, 66 (kernel, gamma, beta) trios and the
+    # classifier pair: 200 tensors of mixed shapes update in ONE dispatch
+    # (the per-parameter path takes one a tensor)
+    ([(8, 4, 3, 3), (8,), (8,)] * 66 + [(10, 8), (10,)], 256, 1),
+], ids=["same_shape_cap4", "resnet_like_200"])
+def test_aggregation_size_chunks_groups(shapes, cap, dispatches):
     """MXNET_OPTIMIZER_AGGREGATION_SIZE caps tensors per dispatch."""
     telemetry.enable()
     o = opt.SGD(learning_rate=0.1, momentum=0.9)
-    o.aggregate_num = 4
-    n = 10
-    ws = [nd.array(np.ones((3,), np.float32)) for _ in range(n)]
-    gs = [nd.array(np.ones((3,), np.float32)) for _ in range(n)]
+    o.aggregate_num = cap
+    n = len(shapes)
+    ws = [nd.array(np.ones(s, np.float32)) for s in shapes]
+    gs = [nd.array(np.ones(s, np.float32)) for s in shapes]
     c0 = telemetry.counter_value("optimizer.update_calls")
     u = opt.get_updater(o)
     u(list(range(n)), gs, ws)
-    # 10 same-shape tensors, cap 4 -> ceil(10/4) = 3 dispatches
-    assert telemetry.counter_value("optimizer.update_calls") - c0 == 3
+    assert telemetry.counter_value("optimizer.update_calls") - c0 \
+        == dispatches
 
 
 def test_sparse_grad_falls_back():
