@@ -149,6 +149,18 @@ def _leaky(ctx, name, ins, attrs):
                        {"alpha": float(_parse(attrs.get("slope"), 0.25))})
     if act == "prelu":
         return ctx.add("PRelu", name, ins)
+    if act == "gelu":
+        # 0.5 * x * (1 + erf(x / sqrt 2)), as ops/elemwise.py::gelu
+        def const(tag, value):
+            ctx.extra_initializers[f"{name}_{tag}"] = _np.asarray(
+                value, dtype=_np.float32)
+            return f"{name}_{tag}"
+        x = ins[0]
+        e = ctx.add("Erf", name + "_erf", [ctx.add(
+            "Div", name + "_scaled", [x, const("sqrt2", 2.0 ** 0.5)])])
+        half = ctx.add("Mul", name + "_half", [x, const("half", 0.5)])
+        return ctx.add("Mul", name, [half, ctx.add(
+            "Add", name + "_cdf2", [e, const("one", 1.0)])])
     raise NotImplementedError(f"LeakyReLU act_type={act}")
 
 

@@ -91,12 +91,13 @@ class Swish(HybridBlock):
 
 
 class GELU(HybridBlock):
-    """Gaussian Error Linear Unit — x * Φ(x).  Not in the 1.5 reference layer
-    set but required by the transformer/BERT model family (BASELINE config);
-    exact erf form so XLA fuses it."""
+    """Gaussian Error Linear Unit — x * Φ(x), the exact erf form.  Not in
+    the 1.5 reference layer set but required by the transformer/BERT model
+    family (BASELINE config).  One op (``LeakyReLU(act_type="gelu")``), so
+    that its differentiation rule is its own (``ops/elemwise.py::gelu``)."""
 
     def __init__(self, **kwargs):
         super().__init__(**kwargs)
 
     def hybrid_forward(self, F, x):
-        return 0.5 * x * (1.0 + F.erf(x / (2.0 ** 0.5)))
+        return F.LeakyReLU(x, act_type="gelu", name="fwd")

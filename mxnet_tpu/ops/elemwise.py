@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as _np
 
 from ..base import np_dtype, parse_bool, parse_float
+from ..telemetry import bus as _tel
 from .registry import register
 
 
@@ -128,6 +129,53 @@ def clip(x, a_min=None, a_max=None):
     return jnp.clip(x, parse_float(a_min), parse_float(a_max))
 
 
+def as_value(x, op):
+    """``x`` as a value that exists once in device memory, for the result
+    of an expensive elementwise recipe that matmuls consume (``op`` names
+    it for the ``matmul.operand`` counter).
+
+    XLA's fusion pass clones an elementwise producer into each of its
+    consumers; inside a convolution fusion the clone is run again for
+    every output tile that needs its operand tile, on the vector unit.
+    That is free for a cast or a LayerNorm apply and costs three to nine
+    passes for an ``erf`` or the threefry rounds of a dropout mask (BERT's
+    ``ffn2`` weight gradient: four times its twin ``ffn1``'s, PERF.md,
+    PR 29).  Behind the barrier the recipe is one fusion of its own, and
+    forward and backward consumers read what it wrote; it may still ride
+    as the epilogue of the matmul that PRODUCES its input.  Only a
+    differentiation rule calls this: an undifferentiated trace keeps the
+    recipe XLA may fuse as it likes, and an eager result is a value
+    already."""
+    _tel.count("matmul.operand", kind="value", op=op)
+    return jax.lax.optimization_barrier(x)
+
+
+def _gelu_erf(x):
+    return 0.5 * x * (1.0 + jax.lax.erf(x / (2.0 ** 0.5)))
+
+
+@jax.custom_vjp
+def gelu(x):
+    """Exact (erf) GELU, ``x * Phi(x)``.  Under differentiation its result
+    is a value (``as_value``): the matmul it feeds and that matmul's weight
+    gradient read it.  The derivative stays a recipe: it multiplies the
+    cotangent a matmul produced, once an element."""
+    _tel.count("matmul.operand", kind="recipe", op="gelu")
+    return _gelu_erf(x)
+
+
+def _gelu_fwd(x):
+    return as_value(_gelu_erf(x), "gelu"), x
+
+
+def _gelu_bwd(x, g):
+    _, pull = jax.vjp(_gelu_erf, x)
+    return pull(g)
+
+
+gelu.defvjp(_gelu_fwd, _gelu_bwd)
+
+
 @register("LeakyReLU")
 def leaky_relu(x, *args, act_type="leaky", slope=0.25, lower_bound=0.125,
                upper_bound=0.334):
@@ -142,7 +190,7 @@ def leaky_relu(x, *args, act_type="leaky", slope=0.25, lower_bound=0.125,
         alpha, scale = 1.6732632423543772, 1.0507009873554805
         return scale * jnp.where(x > 0, x, alpha * jnp.expm1(x))
     if act_type == "gelu":
-        return jax.nn.gelu(x, approximate=False)
+        return gelu(x)
     if act_type == "prelu":
         gamma = args[0]
         gamma = jnp.reshape(gamma, (1, -1) + (1,) * (x.ndim - 2)) if gamma.ndim == 1 else gamma

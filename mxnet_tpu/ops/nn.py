@@ -19,11 +19,15 @@ TPU-native redesign notes:
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..base import parse_bool, parse_float, parse_int, parse_tuple
+from ..telemetry import bus as _tel
+from .elemwise import as_value
 from .registry import register
 
 
@@ -679,6 +683,33 @@ from .random_ops import STOCHASTIC_OPS as _STOCH
 _STOCH.add("Dropout")
 
 
+def _masked(data, mask, keep):
+    return data * mask.astype(data.dtype) / keep
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _dropout(key, data, keep, shape):
+    """``data`` with the keep-mask ``bernoulli(key, keep, shape)`` applied.
+    Under differentiation the mask is a value (``as_value``), a byte an
+    element, drawn once: the forward and the backward read it, where XLA
+    would run threefry again inside every matmul the masked tensor or its
+    cotangent feeds."""
+    _tel.count("matmul.operand", kind="recipe", op="dropout")
+    return _masked(data, jax.random.bernoulli(key, keep, shape), keep)
+
+
+def _dropout_fwd(key, data, keep, shape):
+    mask = as_value(jax.random.bernoulli(key, keep, shape), "dropout")
+    return _masked(data, mask, keep), mask
+
+
+def _dropout_bwd(keep, shape, mask, g):
+    return None, g / keep * mask.astype(g.dtype)
+
+
+_dropout.defvjp(_dropout_fwd, _dropout_bwd)
+
+
 @register("Dropout")
 def dropout(key, data, p=0.5, mode="training", axes=None, cudnn_off=False,
             __training__=False):
@@ -692,9 +723,7 @@ def dropout(key, data, p=0.5, mode="training", axes=None, cudnn_off=False,
     if axes:
         for a in parse_tuple(axes):
             shape[a] = 1
-    keep = 1.0 - p_
-    mask = jax.random.bernoulli(key, keep, tuple(shape)).astype(data.dtype)
-    return data * mask / keep
+    return _dropout(key, data, 1.0 - p_, tuple(shape))
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,7 @@ does that on the chip.
 import functools
 import os
 import re
+import sys
 
 import pytest
 
@@ -115,21 +116,17 @@ def test_flash_backward_limit_is_the_compilers(one_chip):
                     grad=True)
 
 
-def test_bert_base_step_holds_no_float32_scores(one_chip):
-    """The training cell's program: the ``SPMDTrainer`` step of BERT-base at
-    (32, 512) with the mask passed and dropout 0.1.  Its attention is the
-    kernels (forward and backward a layer), the only (.., 512, 512) values
-    it writes to device memory are dropout's keep-masks, a byte an element,
-    and its temporaries are the activations' (10.56 GB with the dense
-    float32 scores and probabilities, sandbox compile, PR 23).  The day a
-    T x T float32 tensor comes back this names it."""
+def _bert_base_step(one_chip, num_layers=12):
+    """The training cell's program compiled for the described chip: the
+    ``SPMDTrainer`` step of BERT-base (``num_layers`` of its twelve) at
+    (32, 512) with the mask passed and dropout 0.1."""
     import mxnet_tpu as mx
     from mxnet_tpu.models import get_bert_model
     from mxnet_tpu.parallel import (FunctionalOptimizer, SPMDTrainer,
                                     device_mesh)
     b, t, masked, vocab = 32, 512, 76, 30522
     net = get_bert_model("bert_base", vocab_size=vocab, max_length=t,
-                         dropout=0.1)
+                         dropout=0.1, num_layers=num_layers)
     net.initialize()
     ce = mx.gluon.loss.SoftmaxCrossEntropyLoss()
 
@@ -154,9 +151,23 @@ def test_bert_base_step_holds_no_float32_scores(one_chip):
                                    trainer._state)
     data = (sds((b, t), jnp.int32), sds((b, t), jnp.int32),
             sds((b, t), jnp.float32), sds((b, masked), jnp.int32))
-    compiled = jax.jit(trainer._step_fn.__wrapped__, donate_argnums=(0,)) \
+    return jax.jit(trainer._step_fn.__wrapped__, donate_argnums=(0,)) \
         .lower(state, data, sds((b, masked + 1), jnp.int32),
                sds((2,), jnp.uint32), sds((), jnp.uint32)).compile()
+
+
+def test_bert_base_step_holds_no_float32_scores(one_chip):
+    """The training cell's program.  Its attention is the kernels (forward
+    and backward a layer), the only (.., 512, 512) values it writes to
+    device memory are dropout's keep-masks, a byte an element, and its
+    temporaries are the activations': 10.56 GB with the dense float32
+    scores and probabilities (sandbox compile, PR 23), 8.11 GB with the
+    kernels (PR 27), 9.34 GB since GELU's results (bfloat16, the width
+    their matmuls multiply in: 12 x 100.7 MB) and the hidden-state
+    dropouts' masks (25 x 12.6 MB) are values kept for the backward
+    (PR 29).  The day a T x T float32 tensor comes back this names it."""
+    t = 512
+    compiled = _bert_base_step(one_chip)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 24
     for op, dtype, dims in _materialised(text):
@@ -165,7 +176,44 @@ def test_bert_base_step_holds_no_float32_scores(one_chip):
                 f"the step writes {dtype}{list(dims)} ({op}): a T x T " \
                 f"tensor wider than the keep-mask is back in device memory"
     temps = compiled.memory_analysis().temp_size_in_bytes
-    assert temps < 9.0e9, f"{temps / 1e9:.3f} GB of temporaries"
+    assert temps < 10.0e9, f"{temps / 1e9:.3f} GB of temporaries"
+
+
+def test_bert_step_matmuls_read_their_operands(one_chip):
+    """No matmul over the 16,384 tokens computes its operand from an
+    expensive recipe: in two layers of the cell's step, no fusion that
+    holds a ``convolution`` has an ``erf`` or a threefry round
+    (``shift-right-logical``) among the instructions its operands are
+    computed FROM, where XLA would run the recipe again for every output
+    tile (``tools/fusion_audit.py``).  GELU's result and the dropouts'
+    keep-masks are values (``ops/elemwise.py::as_value``).  An ``erf`` on
+    a convolution's RESULT is not held against it: bias + GELU as
+    ``ffn1``'s epilogue, and GELU's derivative on dH, run once an element.
+
+    And the two feed-forward weight gradients, the same FLOPs and the same
+    bytes of (W, m, v), cost alike by the compiler's own
+    ``estimated_cycles``: 2,604,232 against 789,320 until PR 29, when
+    ``ffn2``'s held GELU and a keep-mask as recipes."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "tools"))
+    try:
+        import fusion_audit
+    finally:
+        sys.path.pop(0)
+    rows = fusion_audit.audit(_bert_base_step(one_chip, 2).as_text())
+    over_tokens = [r for r in rows if any(
+        "[32,512," in t for t in r["convolution_operands"])]
+    assert len(over_tokens) >= 2 * 12       # six matmuls a layer, twice
+    for r in over_tokens:
+        assert not r["producer_recipes"], \
+            f"{r['fusion']} ({r['result']}) computes a convolution " \
+            f"operand from {r['producer_recipes']}: " \
+            f"{r['estimated_cycles']:,} cycles"
+    update = lambda shape: [r["estimated_cycles"] for r in rows
+                            if r["result"].count(f"f32[{shape}]") == 3]
+    ffn2, ffn1 = update("768,3072"), update("3072,768")
+    assert len(ffn2) == len(ffn1) == 2
+    assert max(ffn2) < 1.5 * min(ffn1), (ffn2, ffn1)
 
 
 def test_dp_tp_step_maps_the_kernels_over_the_mesh(one_chip):

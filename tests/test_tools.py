@@ -87,3 +87,56 @@ def test_word_lm_example_learns():
     assert out.returncode == 0, (out.stdout + out.stderr)[-2000:]
     assert "final train perplexity" in out.stderr or \
         "final train perplexity" in out.stdout
+
+
+_HLO = '''HloModule jit_step
+
+%fused_computation.1 (param_0: f32[64,128], param_1: f32[128,256], param_2: u32[]) -> f32[64,256] {
+  %param_0 = f32[64,128]{1,0} parameter(0)
+  %param_2 = u32[] parameter(2)
+  %shift-right-logical.1 = u32[] shift-right-logical(%param_2, %param_2)
+  %broadcast.1 = u32[64,128]{1,0} broadcast(%shift-right-logical.1), dimensions={}
+  %convert.1 = f32[64,128]{1,0} convert(%broadcast.1)
+  %multiply.1 = f32[64,128]{1,0} multiply(%param_0, %convert.1)
+  %param_1 = f32[128,256]{1,0} parameter(1)
+  %convolution.1 = f32[64,256]{1,0} convolution(%multiply.1, %param_1), dim_labels=bf_io->bf
+  ROOT %erf.1 = f32[64,256]{1,0} erf(%convolution.1)
+}
+
+%fused_computation.2 (param_0.1: f32[64,256], param_1.1: f32[256,128]) -> f32[64,128] {
+  %param_0.1 = f32[64,256]{1,0} parameter(0)
+  %param_1.1 = f32[256,128]{1,0} parameter(1)
+  ROOT %convolution.2 = f32[64,128]{1,0} convolution(%param_0.1, %param_1.1), dim_labels=bf_io->bf
+}
+
+ENTRY %main (a: f32[64,128], w: f32[128,256], v: f32[256,128], k: u32[]) -> f32[64,128] {
+  %a = f32[64,128]{1,0} parameter(0)
+  %w = f32[128,256]{1,0} parameter(1)
+  %v = f32[256,128]{1,0} parameter(2)
+  %k = u32[] parameter(3)
+  %fusion.7 = f32[64,256]{1,0} fusion(%a, %w, %k), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step)/dot_general"}, backend_config={"window_config":{"estimated_cycles":"900","iteration_bounds":["2","3"]}}
+  ROOT %fusion.8 = f32[64,128]{1,0} fusion(%fusion.7, %v), kind=kOutput, calls=%fused_computation.2, backend_config={"window_config":{"estimated_cycles":"400","iteration_bounds":["1","1"]}}
+}
+'''
+
+
+def test_fusion_audit_tells_producers_from_epilogue():
+    """``tools/fusion_audit.py``: a recipe that FEEDS a convolution (run
+    again for every output tile) is told from one applied to its result."""
+    sys.path.insert(0, TOOLS)
+    try:
+        import fusion_audit
+    finally:
+        sys.path.pop(0)
+    big, small = fusion_audit.audit(_HLO)
+    assert (big["fusion"], big["estimated_cycles"]) == ("fusion.7", 900)
+    assert big["iteration_bounds"] == "2,3"
+    assert list(big["producer_recipes"]) == ["threefry"]
+    assert list(big["epilogue_recipes"]) == ["erf"]
+    assert big["producers"]["multiply"] == 1 and "erf" in big["epilogue"]
+    assert big["convolution_operands"][1].startswith("f32[128,256]")
+    assert (small["fusion"], small["estimated_cycles"]) == ("fusion.8", 400)
+    assert not small["producer_recipes"] and not small["epilogue_recipes"]
+    table = fusion_audit.format_rows([big, small])
+    assert "threefry FEEDS the convolution" in table
+    assert "erf in the epilogue" in table
