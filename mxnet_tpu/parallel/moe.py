@@ -142,6 +142,47 @@ def _grouped(x, w, sizes):
         precision=lax.Precision.HIGHEST if w.dtype == jnp.float32 else None)
 
 
+def route_to_held(x, router_w, held, *, top_k, n_group=1, topk_group=1,
+                  scale=1.0, valid=None):
+    """The routing of ``x (T, D)`` float32 as this chip sees it; call it
+    under the named scope ``moe.route``.  Scores are ``sigmoid(x router_w)``
+    over the WHOLE router ``(D, E)`` in float32 at the highest precision,
+    the choice is :func:`group_limited_topk`, the weights ``scale * s_k /
+    sum_chosen s`` — all independent of ``held``, the tuple of global ids of
+    the ``G`` experts held here.  ``valid (T,) bool`` marks real rows;
+    padding is routed nowhere.
+
+    Returns ``(local (T, top_k) int32, weights (T, top_k), assignments
+    int32)``: each choice's position in ``held`` (``G`` where it is held
+    elsewhere or the row is padding) with its weight, and the assignments
+    made over all ``E``."""
+    import numpy as np
+    T = x.shape[0]
+    E = router_w.shape[1]
+    G = len(held)
+    scores = jax.nn.sigmoid(jnp.dot(
+        x, router_w, precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32))
+    ids, chosen = group_limited_topk(scores, top_k, n_group, topk_group)
+    weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    lookup = np.full((E,), G, "int32")
+    lookup[np.asarray(held, "int64")] = np.arange(G, dtype="int32")
+    local = jnp.asarray(lookup)[ids]              # G = held elsewhere
+    if valid is None:
+        n_assign = jnp.int32(T * top_k)
+    else:
+        local = jnp.where(valid[:, None], local, G)
+        n_assign = valid.sum().astype(jnp.int32) * top_k
+    return local, weights, n_assign
+
+
+def rows_received(flat, G):
+    """``(G,) int32``: the rows each held expert received, from the flat
+    ``local`` of :func:`route_to_held`."""
+    return (flat[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
+            ).sum(0).astype(jnp.int32)
+
+
 def routed_expert_share(x, router_w, w_gate, w_up, w_down, held, *,
                         top_k, n_group=1, topk_group=1, scale=1.0,
                         valid=None):
@@ -150,40 +191,24 @@ def routed_expert_share(x, router_w, w_gate, w_up, w_down, held, *,
     ``router_w (D, E)`` float32 is the WHOLE router (``E`` = the published
     expert count); ``held`` is the tuple of global expert ids whose weights
     ``w_gate`` / ``w_up (G, D, F)`` and ``w_down (G, F, D)`` are, in that
-    order.  Scores are ``sigmoid(x router_w)`` in float32 at the highest
-    precision, the choice is :func:`group_limited_topk` over all ``E``, the
-    weights ``scale * s_k / sum_chosen s`` — all independent of ``held``.
+    order.  The routing is :func:`route_to_held`'s.
     Only the chosen experts that are held are computed: the ``T * top_k``
     assignments are sorted by held expert (the others last), the first
     ``T * min(top_k, G)`` rows — every held assignment fits, so no token is
     ever dropped — go through three grouped products
     ``(silu(x Wg) * (x Wu)) Wd`` and are summed back onto their tokens.
-    ``valid (T,) bool`` marks real rows; padding is routed nowhere.
 
     Returns ``(y (T, D) float32, rows (G,) int32, assignments int32)``:
     the partial result, the rows each held expert received, and the
     assignments made over all ``E`` experts."""
-    import numpy as np
     T, D = x.shape
-    E = router_w.shape[1]
     G = len(held)
     with jax.named_scope("moe.route"):
-        scores = jax.nn.sigmoid(jnp.dot(
-            x, router_w, precision=lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32))
-        ids, chosen = group_limited_topk(scores, top_k, n_group, topk_group)
-        weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
-        lookup = np.full((E,), G, "int32")
-        lookup[np.asarray(held, "int64")] = np.arange(G, dtype="int32")
-        local = jnp.asarray(lookup)[ids]              # G = held elsewhere
-        if valid is None:
-            n_assign = jnp.int32(T * top_k)
-        else:
-            local = jnp.where(valid[:, None], local, G)
-            n_assign = valid.sum().astype(jnp.int32) * top_k
+        local, weights, n_assign = route_to_held(
+            x, router_w, held, top_k=top_k, n_group=n_group,
+            topk_group=topk_group, scale=scale, valid=valid)
         flat = local.reshape(-1)
-        rows = (flat[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :]
-                ).sum(0).astype(jnp.int32)
+        rows = rows_received(flat, G)
         M = T * min(top_k, G)
         order = jnp.argsort(flat, stable=True)[:M]
         token = order // top_k

@@ -18,6 +18,13 @@ discipline (PAPER.md design point #2) to that loop:
   an expert-parallel deployment (``parallel.moe.routed_expert_share``).
   bfloat16 products on the MXU; held to its plain reference within
   tolerances, not to bitwise row stability.
+- :class:`HybridSSMMoELM` (``hybrid_moe.py``) — the ``nemotron_h``
+  family's block, built from a pattern string: Mamba-2 state-space mixers
+  (``mxnet_tpu.ops.ssm``: a chunked scan in prefill, one recurrence a row
+  in the step) whose recurrent state and convolution tail live a SLOT in
+  the cache's state pools, grouped-query attention layers that alone
+  page, and un-gated ``relu^2`` routed + shared experts as a chip's share.
+  No drafter, no quantized pools, no mesh; prefix sharing is skipped.
 - :class:`PagedKVCache` (``kv_cache.py``) — device-resident page pools
   with a trash page for padding, generation-stamped slots (the ShmRing
   discipline: a post-free read raises ``StaleKVSlotError`` under
@@ -28,7 +35,9 @@ discipline (PAPER.md design point #2) to that loop:
 - :class:`PageFormat` (``kv_format.py``) — what the pools store (raw, or
   int8 / fp8 e4m3 codes with per-row sidecars: ``kv_dtype``) and the only
   two functions that index one; quantization is fused into whichever
-  program writes and reads.
+  program writes and reads.  :class:`SlotState`, beside it, is the same
+  for state that is per sequence and not per token: pools of one row a
+  slot, the allocator's slots owning the rows.
 - :class:`DecodeRuntime` (``runtime.py``) — the 2-D *(batch x seqlen)*
   prefill grid warmed through ``HybridBlock.compile_grid`` plus ONE
   fused donated step program per batch bucket; ``decode.compile_miss``
@@ -65,6 +74,7 @@ from .kv_cache import (  # noqa: F401
 )
 from .kv_format import (  # noqa: F401
     PageFormat,
+    SlotState,
     kv_dequantize,
     kv_dequantize_fp8,
     kv_quantize_rows,
@@ -77,6 +87,7 @@ from .model import (  # noqa: F401
     sample_math,
 )
 from .latent_moe import LatentMoELM  # noqa: F401
+from .hybrid_moe import HybridSSMMoELM  # noqa: F401
 from .runtime import DecodeRuntime, seq_bucket_ladder  # noqa: F401
 from .scheduler import (  # noqa: F401
     DecodeScheduler,
@@ -90,11 +101,11 @@ from .speculate import (  # noqa: F401
     SpecState,
 )
 
-__all__ = ["CausalLM", "LatentMoELM", "get_decode_model", "rowdot",
+__all__ = ["CausalLM", "LatentMoELM", "HybridSSMMoELM", "get_decode_model", "rowdot",
            "sample_math",
            "kv_quantize_rows", "kv_dequantize",
            "kv_quantize_rows_fp8", "kv_dequantize_fp8",
-           "PagedKVCache", "PageFormat", "KVSlot", "KVCacheExhausted",
+           "PagedKVCache", "PageFormat", "SlotState", "KVSlot", "KVCacheExhausted",
            "pages_needed",
            "DecodeRuntime", "seq_bucket_ladder",
            "DecodeScheduler", "DecodeSession", "GenerationResult",

@@ -48,6 +48,19 @@ recycled" (raises).  Published pages are pinned by the index and
 reclaimed LRU-first under allocation pressure, so a hot prefix survives
 across sessions without ever causing a spurious ``KVCacheExhausted``.
 
+**State that is per sequence** (``cache_layout()``'s ``state`` section: a
+state-space layer's recurrent state and convolution tail) lives in *state
+pools* behind the page pools, one row a slot (``kv_format.SlotState``,
+``cache.pages.state``): the slot allocator owns the rows.  Slot ``i``'s
+state row is ``i + 1`` (row 0 is the trash slot) and rides as the last
+entry of the slot's table row, so the programs take it where they take the
+page tables.  Nothing zeroes a row between owners: the prefill's commit
+overwrites a slot's state whole before any step reads it.  The state at a
+prefix boundary is not in any page, so such a cache makes no prefix lookup
+(``decode.prefix.skipped`` counts the ones it was asked for) and publishes
+nothing; its ``layers`` counts the layers that page, which need not be all
+of the block's.
+
 Sharding: pass ``mesh`` (+ ``kv_axis``) and the page pools are created
 under a ``NamedSharding`` over the row axis (a contiguous split of
 ``heads * head_dim`` is a split by heads), so the cache scales with
@@ -120,12 +133,15 @@ class KVSlot:
                  "shared_pages", "page_gens", "prefix_logits")
 
     def __init__(self, slot_id, generation, pages, max_pages,
-                 shared_pages=0, page_gens=None):
+                 shared_pages=0, page_gens=None, state_row=None):
         self.slot_id = slot_id
         self.generation = generation
         self.pages = list(pages)
+        # where the cache keeps per-sequence state, the slot's state row
+        # ends the table row (kv_format.PageFormat.addresses)
         self.page_table = list(self.pages) + \
-            [TRASH_PAGE] * (max_pages - len(self.pages))
+            [TRASH_PAGE] * (max_pages - len(self.pages)) + \
+            ([int(state_row)] if state_row is not None else [])
         self.shared_pages = int(shared_pages)
         self.page_gens = list(page_gens) if page_gens is not None \
             else [0] * len(self.pages)
@@ -174,7 +190,10 @@ class PagedKVCache:
         its own row width and storage dtype), ``quantizable`` (may
         ``kv_dtype`` store them as int8 / fp8 with sidecars) and
         ``shard_heads`` (the head count a ``mesh`` splits the row axis by,
-        or None where a row is not a concatenation of heads).
+        or None where a row is not a concatenation of heads); optionally
+        ``state`` (``layers``, ``arrays``: per-sequence state kept a slot,
+        ``kv_format.SlotState``), with ``layers`` then counting only the
+        layers that page.
     page_size : int
         Tokens per page.
     num_pages : int
@@ -234,14 +253,27 @@ class PagedKVCache:
         self.max_slots = int(max_slots)
         self.context_length = self.max_pages_per_seq * self.page_size
         self.dtype = str(dtype)
+        #: the per-sequence state pools' format, or None
+        self.state = self.pages.state
+        #: entries of one row of a program's ``tables``
+        self.table_width = self.max_pages_per_seq + (self.state is not None)
+        if mesh is not None and self.state is not None:
+            raise ValueError(
+                "the block keeps per-sequence state in slot pools, which "
+                "are not sharded: a mesh is not supported for it")
         if mesh is not None and not self.num_heads:
             raise ValueError(
                 f"a mesh splits a pool's row by heads, and a row of the "
                 f"block's pools {[n for n, _w, _d in pools]} is shared by "
                 f"all heads: a sharded pool of these rows is not supported")
-        self.prefix_sharing = bool(prefix_sharing)
+        # recurrent state at a prefix boundary is in no page: a cache with
+        # state pools shares nothing, and counts what it was asked for
+        self._skips_prefix = bool(prefix_sharing) and self.state is not None
+        self.prefix_sharing = bool(prefix_sharing) and self.state is None
         self._prefix_entry_cap = int(prefix_entries)
         arrays = self.pages.new_pools(self.num_pages)
+        if self.state is not None:
+            arrays += self.state.new_pools(self.max_slots)
         if mesh is not None:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec
@@ -276,6 +308,7 @@ class PagedKVCache:
         self._full_index = OrderedDict()         # prompt hash -> _FullEntry
         self.prefix_hits = 0
         self.prefix_misses = 0
+        self.prefix_skipped = 0
         self.cow_copies = 0
         self.peak_pages = 0
 
@@ -466,12 +499,18 @@ class PagedKVCache:
             slot = KVSlot(slot_id, self._gen[slot_id], pages,
                           self.max_pages_per_seq,
                           shared_pages=len(shared),
-                          page_gens=[self._page_gen[p] for p in pages])
+                          page_gens=[self._page_gen[p] for p in pages],
+                          state_row=slot_id + 1 if self.state is not None
+                          else None)
             if entry is not None:
                 slot.prefix_logits = entry.logits
             self._live[slot_id] = slot
             if use_prefix:
                 self._count_lookup_locked(bool(shared))
+            elif self._skips_prefix and prompt is not None:
+                self.prefix_skipped += 1
+                if _tel.enabled:
+                    _tel.count("decode.prefix.skipped")
             in_use = self.num_pages - 1 - len(self._free_pages)
             self.peak_pages = max(self.peak_pages, in_use)
         if tail_copy is not None:
@@ -759,6 +798,16 @@ class PagedKVCache:
                        round(in_use / max(self.usable_pages, 1), 4))
             _tel.gauge("decode.kv_pages", in_use)
             _tel.gauge("decode.kv_bytes_per_token", self.kv_bytes_per_token)
+            if self.state is not None:
+                _tel.gauge("decode.state_slots_live", len(self._live))
+                _tel.gauge("decode.state_bytes", self.state_bytes)
+
+    @property
+    def state_bytes(self):
+        """Device bytes of the state pools (every slot and the trash row);
+        0 where the block keeps no per-sequence state."""
+        return 0 if self.state is None else \
+            self.state.bytes_per_slot * (self.max_slots + 1)
 
     def stats(self):
         with self._lock:
@@ -766,7 +815,12 @@ class PagedKVCache:
             pinned = sum(1 for p in range(1, self.num_pages)
                          if self._pin_refs[p] > 0)
             lookups = self.prefix_hits + self.prefix_misses
+            state = {} if self.state is None else {
+                "state_slots_live": len(self._live),
+                "state_bytes": self.state_bytes,
+                "prefix_skipped": self.prefix_skipped}
             return {
+                **state,
                 "pages_in_use": slot_pages,
                 "usable_pages": self.usable_pages,
                 "slots_in_use": self.max_slots - len(self._free_slots),

@@ -42,10 +42,21 @@ pages of the layer before gathering a row's few.
 ``tests/test_chip_compile.py`` holds every program (step, verify, commit,
 copy-on-write; each format) to this: no temporary the size of a pool or of
 one layer of one.
+
+**State that is per sequence, not per token** (a state-space layer's
+recurrent state, its convolution's tail) lives beside the pages in *state
+pools* ``(state layers, max_slots + 1) + shape``, one row a slot and row 0
+the trash slot, as page 0 is the trash page.  A block whose
+``cache_layout()`` has a ``state`` section gets them behind the page pools
+in the same donated tuple, and :class:`SlotState` (``pages.state``) is
+their only ``read`` and ``write``.  A sequence's row of ``tables`` then
+ends with its state row: :meth:`PageFormat.addresses` splits the two.
 """
 from __future__ import annotations
 
-__all__ = ["PageFormat", "kv_quantize_rows", "kv_dequantize",
+import math
+
+__all__ = ["PageFormat", "SlotState", "kv_quantize_rows", "kv_dequantize",
            "kv_quantize_rows_fp8", "kv_dequantize_fp8"]
 
 
@@ -112,6 +123,70 @@ _CODECS = {
 }
 
 
+class SlotState:
+    """The state pools of one cache: what a block's ``cache_layout()``
+    states under ``state`` (``layers``: how many layers keep such state;
+    ``arrays``: ``(name, shape, dtype)`` of each array a layer keeps for one
+    sequence), stored ``(layers, max_slots + 1) + shape`` behind the
+    ``first`` page pools of the cache's tuple.  Row ``slot_id + 1`` is a
+    slot's; row 0 takes the writes of padded batch rows.  A layer here is
+    the block's count among its state layers, not its depth."""
+
+    def __init__(self, spec, first):
+        import jax.numpy as jnp
+        self.num_layers = int(spec["layers"])
+        self.arrays = tuple((str(n), tuple(int(d) for d in shape),
+                             jnp.dtype(dt)) for n, shape, dt
+                            in spec["arrays"])
+        self.first = int(first)
+        #: device bytes one slot's state costs (every array, all layers)
+        self.bytes_per_slot = self.num_layers * sum(
+            math.prod(shape) * dt.itemsize for _n, shape, dt in self.arrays)
+
+    def new_pools(self, max_slots):
+        """Zeroed state pools for ``max_slots`` slots and the trash row."""
+        import jax.numpy as jnp
+        return tuple(jnp.zeros((self.num_layers, int(max_slots) + 1) + shape,
+                               dt) for _n, shape, dt in self.arrays)
+
+    def _which(self, names):
+        every = [n for n, _s, _d in self.arrays]
+        return range(len(every)) if names is None \
+            else [every.index(n) for n in names]
+
+    def read(self, pools, layer, rows, names=None):
+        """One state layer's arrays (all, or those of ``names``) for the
+        state rows ``rows (B,)``: a tuple, each ``(B,) + shape``.  Indexed
+        once, as a page pool is."""
+        return tuple(pools[self.first + j][layer, rows]
+                     for j in self._which(names))
+
+    def read_all(self, pools, layer, names=None):
+        """One state layer's arrays for EVERY state row, the trash row
+        first: a tuple, each ``(max_slots + 1,) + shape``."""
+        return tuple(pools[self.first + j][layer]
+                     for j in self._which(names))
+
+    def write_all(self, pools, layer, values, names=None):
+        """Store ``values`` (each ``(max_slots + 1,) + shape``) as one
+        layer's state of every row, where it lies; returns the pools."""
+        pools = list(pools)
+        for j, x in zip(self._which(names), values):
+            k = self.first + j
+            pools[k] = pools[k].at[layer].set(x.astype(pools[k].dtype))
+        return tuple(pools)
+
+    def write(self, pools, layer, rows, values, names=None):
+        """Store ``values`` (one array a state array, or a named one,
+        ``(B,) + shape``) as the whole of that state of ``rows`` in one
+        layer; returns the pools."""
+        pools = list(pools)
+        for j, x in zip(self._which(names), values):
+            k = self.first + j
+            pools[k] = pools[k].at[layer, rows].set(x.astype(pools[k].dtype))
+        return tuple(pools)
+
+
 class PageFormat:
     """How one cache's pages are stored; see the module docstring.
 
@@ -155,6 +230,18 @@ class PageFormat:
         heads = layout.get("shard_heads")
         self._row_shapes = tuple((heads, w // heads) if heads else (w,)
                                  for _n, w, _d in self.pool_layout)
+        #: the per-sequence state pools behind the page pools, or None
+        self.state = SlotState(
+            layout["state"], len(self.pool_layout) + self.num_sidecars) \
+            if layout.get("state") else None
+
+    def addresses(self, tables):
+        """``(page tables (B, pages a row), state rows (B,) or None)`` of a
+        program's ``tables``: with state pools a row's last entry is its
+        state row."""
+        if self.state is None:
+            return tables, None
+        return tables[:, :-1], tables[:, -1]
 
     def new_pools(self, num_pages):
         """Zeroed pools of ``num_pages`` pages: the value pools, then the

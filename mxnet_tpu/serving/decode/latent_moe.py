@@ -115,6 +115,20 @@ def _swiglu(x, wg, wu, wd):
     return _dot(jax.nn.silu(_dot(x, wg)) * _dot(x, wu), wd)
 
 
+def record_moe_rows(rows, model):
+    """The ``decode.moe.*`` counters from a step's ``rows (expert layers,
+    held + 1)``: per layer the rows each held expert received, then the
+    assignments made over all experts."""
+    if not rows.size:
+        return
+    held = rows[:, :-1]
+    _tel.count("decode.moe.assignments", int(rows[:, -1].sum()), model=model)
+    _tel.count("decode.moe.assignments_held", int(held.sum()), model=model)
+    _tel.count("decode.moe.experts_hit", int((held > 0).sum()), model=model)
+    _tel.count("decode.moe.layer_steps", int(held.shape[0]), model=model)
+    _tel.count("decode.moe.max_expert_rows", int(held.max()), model=model)
+
+
 class LatentMoELM(HybridBlock):
     """Decoder-only transformer with latent attention and routed + shared
     experts; see the module docstring.  ``forward(tokens (B, S), lengths
@@ -428,20 +442,8 @@ class LatentMoELM(HybridBlock):
         """Telemetry from one step's ``moe_rows`` (as fetched behind the
         tokens, flat): the ``decode.moe.*`` counters ``docs/telemetry.md``
         lists."""
-        rows = np.asarray(extras).reshape(-1, len(self.held) + 1)
-        if not rows.size:
-            return
-        held = rows[:, :-1]
-        _tel.count("decode.moe.assignments", int(rows[:, -1].sum()),
-                   model=model)
-        _tel.count("decode.moe.assignments_held", int(held.sum()),
-                   model=model)
-        _tel.count("decode.moe.experts_hit", int((held > 0).sum()),
-                   model=model)
-        _tel.count("decode.moe.layer_steps", int(held.shape[0]),
-                   model=model)
-        _tel.count("decode.moe.max_expert_rows", int(held.max()),
-                   model=model)
+        record_moe_rows(np.asarray(extras).reshape(-1, len(self.held) + 1),
+                        model)
 
     # ------------------------------------------------------- gluon frontend
     def hybrid_forward(self, F, tokens, lengths, **params):

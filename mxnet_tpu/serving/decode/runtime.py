@@ -25,7 +25,10 @@ How the pools are stored is the cache's page format (``kv_format``): the
 runtime threads ``cache.pools`` through every program as one donated,
 poisoned tuple and hands the block ``cache.pages`` to write and read them
 with, so a quantized format costs no extra program and ``warm()`` covers
-it exactly like the raw one.
+it exactly like the raw one.  Per-sequence state (a state-space layer's)
+rides the same way: its pools sit behind the page pools in the one donated
+tuple, and each row of ``tables`` ends with the row's state slot
+(``kv_format``), so no program takes an argument more for it.
 """
 from __future__ import annotations
 
@@ -57,25 +60,39 @@ def seq_bucket_ladder(max_seqlen, min_bucket=8):
     return tuple(sorted(set(ladder)))
 
 
+def _state_structs(spec):
+    """``block.prefill_state``'s answer as what a commit program is lowered
+    with: one ``(shape, dtype)`` or a tuple of them."""
+    import jax
+    if isinstance(spec[1], str):
+        return jax.ShapeDtypeStruct(*spec)
+    return tuple(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in spec)
+
+
 class DecodeRuntime:
     """A decode block plus a :class:`PagedKVCache`, compiled into the 2-D
     prefill grid and per-batch-bucket step programs described in the module
     docstring.
 
     **What a block is to the runtime** (:class:`~mxnet_tpu.serving.decode.
-    model.CausalLM` and :class:`~mxnet_tpu.serving.decode.latent_moe.
-    LatentMoELM` both are): a hybridizable block whose forward is the
-    prefill ``(tokens (B, S), lengths (B,)) -> (last_logits, state)``, with
+    model.CausalLM`, :class:`~mxnet_tpu.serving.decode.latent_moe.
+    LatentMoELM` and :class:`~mxnet_tpu.serving.decode.hybrid_moe.
+    HybridSSMMoELM` are): a hybridizable block whose forward is the
+    prefill ``(tokens (B, S), lengths (B,)) -> (last_logits, state)``
+    (``state`` one array, or several behind the logits), with
     ``vocab_size``, ``param_leaves()`` / ``_params_dict(leaves)``,
     ``cache_layout()`` (the pools the cache builds, whether they may be
     quantized or sharded, and ``max_length``, the context the block is
     good for), ``max_prefill_batch`` (the most prompts one prefill call may
     hold; None: as many as a step), ``prefill_state(b, s)`` (shape and
-    dtype of that ``state``),
+    dtype of that ``state``, or a tuple of such pairs),
     ``commit_program(state, lengths, tables, pools, pages) -> pools``,
     ``step_program(params, tokens, positions, tables, pools, pages)
     -> (logits, pools, extras)`` and ``sample_math``; ``pages`` is the
-    cache's ``PageFormat``, the block's only way into ``pools``.  ``extras``
+    cache's ``PageFormat``, the block's only way into ``pools``; ``tables
+    (B, cache.table_width)`` holds each row's page table and, for a block
+    with per-sequence state, its state row behind it (``pages.addresses``
+    splits them; ``pages.state`` reads and writes the rows).  ``extras``
     are int32 arrays that ride the step's one fetch behind the tokens and
     are handed to ``block.record_step_extras`` when telemetry is on.  A
     verify ladder (``spec_buckets``) also needs ``verify_program(params,
@@ -158,7 +175,10 @@ class DecodeRuntime:
                 num_pages=(num_pages if num_pages is not None
                            else max_pages * 2 * self.max_batch + 1),
                 max_pages_per_seq=max_pages,
+                # a slot of per-sequence state is megabytes whether or not
+                # a sequence holds it: such a block gets a slot a row
                 max_slots=(max_slots if max_slots is not None
+                           else self.max_batch if layout.get("state")
                            else 2 * self.max_batch),
                 kv_dtype=kv_dtype, prefix_sharing=prefix_sharing,
                 mesh=mesh)
@@ -297,7 +317,7 @@ class DecodeRuntime:
                         make_example, grid, cache=self.aot_cache).values())
             if self.aot_cache is not None:
                 self._warm_aot(grid)
-            np_ = self.cache.max_pages_per_seq
+            np_ = self.cache.table_width
             for b, s in grid:
                 self.prefill(np.zeros((b, s), "int32"),
                              np.ones((b,), "int32"),
@@ -346,7 +366,7 @@ class DecodeRuntime:
         import jax
         pc = self.aot_cache
         block, cache = self._block, self.cache
-        np_ = cache.max_pages_per_seq
+        np_ = cache.table_width
         pools = tuple(cache.pools)
         for b in self.batch_buckets:
             if b in self._step_fns:
@@ -374,8 +394,7 @@ class DecodeRuntime:
         for b, s in grid:
             if (b, s) in self._commit_fns:
                 continue
-            shape, dtype = block.prefill_state(b, s)
-            args = (self._params, jax.ShapeDtypeStruct(shape, dtype),
+            args = (self._params, _state_structs(block.prefill_state(b, s)),
                     np.zeros((b, block.vocab_size), "float32"),
                     np.zeros((b,), "int32"), np.zeros((b, np_), "int32"),
                     np.zeros((b, 2), "uint32"), np.zeros((b,), "int32"),
@@ -529,12 +548,16 @@ class DecodeRuntime:
                     elif self._warmed:
                         self._miss("prefill", (b, s))
                 with autograd.pause(train_mode=False):
-                    logits, kv = self._block(tok_nd, len_nd)
+                    logits, *state = self._block(tok_nd, len_nd)
                 self._prefill_sigs.add(sig)
                 commit = self._commit_fn(b, s)
                 cache = self.cache
                 pools = cache.pools
-                kv_raw, logits_raw = kv.data, logits.data
+                # one array as it is (the programs of the blocks that emit
+                # one do not change), several as a tuple
+                kv_raw = state[0].data if len(state) == 1 \
+                    else tuple(x.data for x in state)
+                logits_raw = logits.data
                 if self._replicate is not None:
                     kv_raw = self._replicate(kv_raw)
                     logits_raw = self._replicate(logits_raw)

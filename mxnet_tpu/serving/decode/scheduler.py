@@ -751,7 +751,7 @@ class DecodeScheduler:
             b = rt.batch_bucket_for(len(reqs))
             tokens = np.zeros((b, s), "int32")
             lengths = np.ones((b,), "int32")
-            tables = np.zeros((b, cache.max_pages_per_seq), "int32")
+            tables = np.zeros((b, cache.table_width), "int32")
             keys = np.zeros((b, 2), "uint32")
             temps = np.zeros((b,), "float32")
             for r, req in enumerate(reqs):
@@ -878,7 +878,7 @@ class DecodeScheduler:
                                       req.position // cache.page_size)
         tokens = np.zeros((b,), "int32")
         positions = np.zeros((b,), "int32")
-        tables = np.zeros((b, cache.max_pages_per_seq), "int32")
+        tables = np.zeros((b, cache.table_width), "int32")
         keys = np.zeros((b, 2), "uint32")
         steps = np.zeros((b,), "int32")
         temps = np.zeros((b,), "float32")
@@ -961,7 +961,7 @@ class DecodeScheduler:
         tokens = np.zeros((b, kb + 1), "int32")
         positions = np.zeros((b,), "int32")
         n_draft = np.zeros((b,), "int32")
-        tables = np.zeros((b, cache.max_pages_per_seq), "int32")
+        tables = np.zeros((b, cache.table_width), "int32")
         keys = np.zeros((b, 2), "uint32")
         steps = np.zeros((b,), "int32")
         temps = np.zeros((b,), "float32")

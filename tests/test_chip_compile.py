@@ -503,3 +503,113 @@ def test_pool_programs_touch_only_their_pages(one_chip, kind, b, kv_dtype,
     assert stats.temp_size_in_bytes < pool_bytes / 10, \
         f"{what}: {stats.temp_size_in_bytes / 1e9:.3f} GB of temporaries " \
         f"beside {pool_bytes / 1e9:.3f} GB of pools"
+
+
+# nemotron3_nano_ep8's serving geometry (PERF.md section 4): per Mamba layer
+# and slot a recurrent state of 64 x 64 x 128 float32 values (2 MB) and a
+# convolution tail of 3 x 6144 bfloat16, 32 slots and the trash row; K/V rows
+# of 2 x 128 in pages of 16, 96 pages a row.  Every width of the mixers and
+# of attention is the published one; what the pools' geometry does not depend
+# on (the experts held, the vocabulary, the depth) is kept small so that the
+# zeros fit a test.
+_H_PAGES, _H_ROW_PAGES, _H_SLOTS, _H_SEQ = 6145, 96, 32, 256
+
+
+@functools.lru_cache(maxsize=1)
+def _hybrid_runtime():
+    import mxnet_tpu as mx
+    from mxnet_tpu.serving.decode import (DecodeRuntime, HybridSSMMoELM,
+                                          PagedKVCache)
+    net = HybridSSMMoELM(
+        vocab_size=512, hidden_size=2688, pattern="M*EM*M",
+        mamba_num_heads=64, mamba_head_dim=64, ssm_state_size=128,
+        n_groups=8, conv_kernel=4, chunk_size=128, num_attention_heads=32,
+        num_key_value_heads=2, head_dim=128, moe_intermediate_size=1856,
+        moe_shared_expert_intermediate_size=3712, n_routed_experts=128,
+        held_experts=(0, 1), num_experts_per_tok=6,
+        max_length=_H_ROW_PAGES * _PAGE)
+    for p in net.collect_params().values():
+        p._load_init(mx.nd.zeros(p.shape, dtype=p.dtype), None)
+    cache = PagedKVCache(layout=net.cache_layout(), page_size=_PAGE,
+                         num_pages=2, max_pages_per_seq=_H_ROW_PAGES,
+                         max_slots=1)
+    return DecodeRuntime(net, cache=cache, batch_buckets=(1,),
+                         seq_buckets=(_H_SEQ,), warm=False)
+
+
+def _hybrid_program(rt, kind, b, sds):
+    """``(jitted program, its arguments with the pools last)``; the pools at
+    the cell's size: 6,145 pages, 33 state rows."""
+    i32, u32, f32 = "int32", "uint32", "float32"
+    blk, n_paged = rt.block, len(rt.cache.pool_layout)
+    pools = tuple(
+        sds(p.shape[:1] + ((_H_PAGES,) if j < n_paged else (_H_SLOTS + 1,))
+            + p.shape[2:], p.dtype) for j, p in enumerate(rt.cache.pools))
+    params = [sds(p.shape, p.dtype) for p in rt._params]
+    if kind == "prefill":
+        fn = jax.jit(lambda leaves, tok, ln: blk.prefill_math(
+            blk._params_dict(leaves), tok, ln))
+        return fn, (params, sds((b, _H_SEQ), i32), sds((b,), i32)), pools
+    rows = (sds((b, _H_ROW_PAGES + 1), i32), sds((b, 2), u32),
+            sds((b,), i32), sds((b,), f32))     # tables, keys, steps, temps
+    if kind == "step":
+        return rt._build_step(), \
+            (params, sds((b,), i32), sds((b,), i32)) + rows + pools, pools
+    state = tuple(sds(shape, dtype)
+                  for shape, dtype in blk.prefill_state(b, _H_SEQ))
+    return rt._build_commit(), \
+        (params, state, sds((b, blk.vocab_size), f32), sds((b,), i32)) \
+        + rows + pools, pools
+
+
+@pytest.mark.parametrize("kind,b", [("step", 1), ("step", 8), ("step", 32),
+                                    ("commit", 1), ("prefill", 1)])
+def test_hybrid_programs_touch_only_their_slots_and_pages(one_chip, kind, b):
+    """The state pools (23 x 33 x 2 MB at the cell's depth; three Mamba
+    layers here) are held to what the page pools are: no step, commit or
+    prefill program of the hybrid block holds a temporary the size of a
+    state pool or of one layer of it, none copies a pool, and the step and
+    commit give every pool back in the buffer it came in.  The 32-row step
+    runs over every slot where it lies (only the pool itself is that
+    large); the 1- and 8-row steps gather their rows."""
+    import numpy as np
+
+    rt = _hybrid_runtime()
+    fn, args, pools = _hybrid_program(
+        rt, kind, b, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    k_pool, _v, ssm_pool, conv_pool = pools
+    assert ssm_pool.shape == (3, 33, 64, 64, 128) and \
+        ssm_pool.dtype == jnp.float32
+    assert conv_pool.shape == (3, 33, 144, 128) and \
+        k_pool.shape == (2, _H_PAGES, _PAGE, 256)
+    compiled = fn.lower(*args).compile()
+    what = f"hybrid {kind}-b{b}"
+    own = {("f32", ssm_pool.shape), ("bf16", conv_pool.shape),
+           ("bf16", k_pool.shape)}
+    layer = int(np.prod(ssm_pool.shape[1:]))
+    # at 2688 wide a weight matrix is as large as a layer of state: the
+    # parameters, and the copies of one that the compiler streams ahead of
+    # its use, are not what is looked for
+    weights = {tuple(p.shape) for p in args[0]}
+    for op, dtype, dims in _materialised(compiled.as_text()):
+        if int(np.prod(dims)) < layer or dims in weights or \
+                op in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        assert (dtype, dims) in own, \
+            f"{what}: {op} writes {dtype}{list(dims)}, a layer of the " \
+            f"state pool or more"
+        assert op != "copy", f"{what}: copies a whole pool {dtype}{dims}"
+    stats = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    if kind == "prefill":
+        # one prompt of 256: its two chunks' states, decay matrices and
+        # scores, small beside one layer of 33 slots
+        assert stats.temp_size_in_bytes < layer * 4, what
+        return
+    assert stats.alias_size_in_bytes >= pool_bytes, what
+    # a gathering step's temporaries: its rows' states (b x 2 MB) and paged
+    # context; the every-slot step's: the rows' vectors at their slots
+    assert stats.temp_size_in_bytes < max(b, 4) * 3 * 2 ** 21, \
+        f"{what}: {stats.temp_size_in_bytes / 1e9:.3f} GB of temporaries " \
+        f"beside {pool_bytes / 1e9:.3f} GB of pools"
