@@ -45,6 +45,16 @@ as XLA's are.  ``jax.default_matmul_precision`` overrides both ways
 ("highest" for an exactness check on the chip, "bfloat16" to see the
 chip's rounding on the CPU).  Softmax statistics, outputs and gradients
 are float32.
+
+Second resident kernel: **the decode step's state-space recurrence on the
+rows' states where they lie** (``ssm_step_slots``): the whole state pool of
+a cache is an operand aliased to the result, the layer and each batch row's
+state row are prefetched scalars that pick the block a grid step moves, and
+each LIVE row's state crosses memory once each way: nothing for a padded
+row or an idle slot, where XLA's forms pass two to three times over every
+slot of the layer.  float32 on the vector unit, no product on the MXU.
+``by_platform`` is how its caller gets the kernel on the chip and the
+definition on the CPU, and a count of which was built.
 """
 from __future__ import annotations
 
@@ -58,7 +68,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "ssm_step_slots", "by_platform"]
 
 _NEG = -1e30
 
@@ -537,3 +547,163 @@ def _fa_bwd(causal, scale, block_q, block_k, interpret, rate, saved, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+# --------------------------------------------------------------------------
+# second resident kernel: one token of the state-space recurrence on the
+# rows' states where they lie in the cache's state pool
+
+
+def _ssm_slots_kernel(layer_ref, named_ref, rows_ref, decay_ref, s_ref,
+                      dtx_ref, b_ref, c_ref, o_ref, y_ref, *, heads,
+                      per_group):
+    """One batch row's whole ``(H, P, N)`` state of the layer a grid step:
+    ``S <- decay S + dt x (outer) B`` and ``y = S C``, a head at a time on
+    the vector unit, P on the sublanes and N on the lanes.  ``dt x`` and
+    ``y`` come and go with P on the sublanes too (``(P, H)``, a head a
+    lane), so that a head's column broadcasts along the lanes and a lane
+    reduction lands in it: nothing is laid out anew.  A padded row (state
+    row 0) does nothing: it names the block a live row beside it names
+    (``_ssm_slots_call``), so nothing is fetched or written back for it,
+    and its ``y`` is zeros (finite, whatever the buffer held)."""
+    del layer_ref
+    i = pl.program_id(0)
+
+    @pl.when(rows_ref[i] != 0)
+    def _():
+        for h in range(heads):
+            g = h // per_group
+            state = decay_ref[i * heads + h] * s_ref[0, 0, h] \
+                + dtx_ref[0, :, h:h + 1] * b_ref[0, g:g + 1, :]
+            o_ref[0, 0, h] = state
+            y_ref[0, :, h:h + 1] = jnp.sum(state * c_ref[0, g:g + 1, :],
+                                           axis=-1, keepdims=True)
+
+    @pl.when(rows_ref[i] == 0)
+    def _():
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    # no live row at all (a warm-up drive): every step names the trash row,
+    # and what is written back at the end is what was read
+    @pl.when(jnp.logical_and(named_ref[0] == 0, i == 0))
+    def _():
+        o_ref[...] = s_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssm_slots_call(pool, layer, rows, decay, dtx, B, C, *, interpret):
+    """``pallas_call`` on the grid (batch rows,) with the layer and the
+    rows' state rows as prefetched scalars and the WHOLE pool aliased to
+    its output.  One jitted function of its arrays, the layer among them:
+    every Mamba layer of every step program lowers this once and the chip
+    compiles one kernel body.
+
+    The block is a row's state of one layer (2 MB at Nemotron-3-Nano's 64
+    heads of 64 x 128): on the chip 2.59 ms for 23 layers at 14 live rows
+    of 32 where blocks of an eighth of it took 5.96, and a kernel that only
+    copies the blocks 2.26 (my chip runs, PR 31).  In, out and their second
+    buffers are four blocks of the core's memory; eight blocks' bytes and 8
+    MB are asked for.
+
+    The pipeline fetches a block when a step names another than the step
+    before it, and writes one back when the step after it names another.
+    So a padded row names what a live row beside it names, the nearest one
+    before it (the first live row, for padded rows in front of it): it
+    costs an empty grid step.  With no live row every step names the trash
+    row, which is copied through."""
+    b, p, heads = dtx.shape
+    groups, n = B.shape[1:]
+    live = rows != 0
+    before = jax.lax.cummax(jnp.where(live, jnp.arange(b), -1))
+    named = rows[jnp.where(before >= 0, before, jnp.argmax(live))]
+
+    state = pl.BlockSpec((1, 1, heads, p, n),
+                         lambda i, layer, named, rows:
+                         (layer[0], named[i], 0, 0, 0))
+    by_row = lambda i, *_: (i, 0, 0)
+    return pl.pallas_call(
+        functools.partial(_ssm_slots_kernel, heads=heads,
+                          per_group=heads // groups),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), state,
+                      pl.BlockSpec((1, p, heads), by_row),
+                      pl.BlockSpec((1, groups, n), by_row),
+                      pl.BlockSpec((1, groups, n), by_row)],
+            out_specs=(state, pl.BlockSpec((1, p, heads), by_row))),
+        out_shape=(jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct(dtx.shape, jnp.float32)),
+        # operand 4, counting the prefetched scalars: the pool
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=8 * heads * p * n * 4 + (8 << 20)),
+        interpret=interpret, name="ssm_step_slots",
+    )(layer, named, rows, decay, pool, dtx, B, C)
+
+
+def ssm_step_slots(pool, layer, rows, x, dt, A, B, C, D, *, interpret=False):
+    """One token of the Mamba-2 recurrence (``ops.ssm.ssm_step``, which is
+    its definition) for batch rows whose states lie in a state pool ``pool
+    (layers, state rows, H, P, N)`` float32, at ``pool[layer, rows[i]]``:
+    returns ``(pool, y (b, H, P))`` with those rows' states advanced where
+    they lie.  ``x (b, H, P)``, ``dt (b, H)`` (softplus-ed), ``A``, ``D
+    (H,)``, ``B``, ``C (b, G, N)``; ``layer`` a scalar (traced: one kernel
+    for every layer), ``rows (b,)`` int32, distinct but for 0, which says a
+    padded row.
+
+    Each live row's state crosses memory once each way; a padded row,
+    wherever it stands in the batch, moves nothing and its ``y`` is ``D x``
+    alone (what it reads of a state is zero: for the caller to ignore);
+    state row 0 (the trash row), every state row no batch row names and
+    every other layer keep their bits.  The decay, ``dt x`` and the sum
+    over the state are float32 on the vector unit, as ``ssm_step``'s: on
+    the chip the two agree to the last bit (my chip run, PR 31)."""
+    x, dt = x.astype(jnp.float32), dt.astype(jnp.float32)
+    pool, y = _ssm_slots_call(
+        pool, jnp.asarray(layer, jnp.int32).reshape(1),
+        rows.astype(jnp.int32), jnp.exp(dt * A).reshape(-1),
+        jnp.swapaxes(dt[..., None] * x, 1, 2), B.astype(jnp.float32),
+        C.astype(jnp.float32), interpret=interpret)
+    return pool, jnp.swapaxes(y, 1, 2) + D[:, None] * x
+
+
+# --------------------------------------------------------------------------
+# kernel on the chip, definition on the CPU, and a count of which was built
+
+
+_lowered_as_p = jax.extend.core.Primitive("lowered_as")
+_lowered_as_p.def_impl(lambda x, **_: x)
+_lowered_as_p.def_abstract_eval(lambda x, **_: x)
+
+
+def _lowered_as_lowering(ctx, x, *, counter, labels):
+    from ..telemetry import bus as _tel
+    _tel.count(counter, **dict(labels))
+    return [x]
+
+
+# not cacheable: every instance counts, not the first of its shape
+jax.interpreters.mlir.register_lowering(_lowered_as_p, _lowered_as_lowering,
+                                        cacheable=False)
+
+
+def by_platform(counter, *args, kernel, plain, **labels):
+    """``kernel(*args)`` where the call is lowered for the chip,
+    ``plain(*args)`` where it is lowered for the CPU (the tests; a program
+    built on the host), as ``flash_attention`` chooses its interpreter:
+    nothing a caller sets decides.  Both are traced and ONE is lowered; the
+    telemetry counter ``counter`` counts it then, with ``kind="kernel"`` or
+    ``"plain"`` and ``labels`` (an identity on the first array of ``args``,
+    whose lowering rule does the counting: a trace cannot know)."""
+    def counted(fn, kind):
+        items = tuple(sorted(dict(labels, kind=kind).items()))
+
+        def branch(*args):
+            (first, *rest), tree = jax.tree.flatten(args)
+            first = _lowered_as_p.bind(first, counter=counter, labels=items)
+            return fn(*jax.tree.unflatten(tree, [first] + rest))
+        return branch
+
+    return jax.lax.platform_dependent(*args, cpu=counted(plain, "plain"),
+                                      default=counted(kernel, "kernel"))

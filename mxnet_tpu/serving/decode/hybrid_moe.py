@@ -16,8 +16,10 @@ letter in ``pattern`` (the config's ``hybrid_override_pattern``):
   (:meth:`cache_layout`'s ``state`` section; ``kv_format.SlotState``).
   Prefill runs the **chunked** scan and hands over the state and the tail
   **as of each row's true length** (padding behind it has ``dt`` 0); the
-  step runs one recurrence a row on the slot's state, in place in the
-  donated pool.
+  step advances each live row's state where it lies in the donated pool,
+  in one kernel a layer (``ops.pallas_kernels.ssm_step_slots``; where the
+  program is lowered for the CPU, its definition ``ops.ssm.ssm_step``
+  between the slot's ``read`` and ``write``).
 - ``*`` — **grouped-query attention**: ``num_attention_heads`` queries over
   ``num_key_value_heads`` keys and values (query head ``j`` reads KV head
   ``j // (heads / kv heads)``), no biases and no rotary (the state-space
@@ -347,18 +349,20 @@ class HybridSSMMoELM(HybridBlock):
         cache's ``SlotState``) keeps at state rows ``rows (B,)`` of this
         layer: returns ``(output (B, U), pools)``.
 
-        Where the batch holds most of the cache's slots (the 32-row program
-        of a 32-slot cache) the recurrence runs over **every slot's state
-        where it lies**: each row's ``x``, ``dt``, ``B``, ``C`` is put at
-        its slot, a slot with no row gets ``dt`` = 0 (decay 1, no input:
-        its state is rewritten as it was), and no state is gathered out of
-        the pool or scattered back.  On the chip that is 7.6 ms for 23
-        layers of 33 slots against 13.6 ms for gather, update and scatter
-        of 32 rows, which each make a pass of their own over the 2 MB a row
-        (``PERF.md``, PR 30).  A small batch gathers its rows."""
+        The recurrence has ONE form for every batch: on the chip the kernel
+        ``ops.pallas_kernels.ssm_step_slots``, which gets the whole state
+        pool through ``slots.in_place`` and moves each LIVE row's state
+        from the pool once and back once, found by its state row (a padded
+        row and a slot no row names move nothing: 2.4 ms for 23 layers at
+        14 live rows where a pass over every slot took 7.6, ``PERF.md``,
+        PR 31); where the program is lowered for the CPU its definition,
+        ``slots.read`` -> ``ops.ssm.ssm_step`` -> ``slots.write``
+        (``by_platform``: nothing a caller sets chooses, and
+        ``ssm.step.path`` counts which was built)."""
         import jax
         import jax.numpy as jnp
         from ...ops import ssm
+        from ...ops.pallas_kernels import by_platform, ssm_step_slots
         pre = f"l{i}_"
         n = self._nth[i]
         with jax.named_scope("ssm.mix"):
@@ -375,19 +379,21 @@ class HybridSSMMoELM(HybridBlock):
         with jax.named_scope("ssm.mix"):
             x, Bm, Cm = self._mamba_split(xc)
             dt = jax.nn.softplus(dt + p[pre + "dt_bias"])
-            A, D = -jnp.exp(p[pre + "A_log"]), p[pre + "D"]
-            (every,) = slots.read_all(pools, n, ("ssm",))
-            if 2 * a.shape[0] > every.shape[0]:
-                at_slot = lambda v: jnp.zeros(
-                    every.shape[:1] + v.shape[1:], v.dtype).at[rows].set(v)
-                every, y = ssm.ssm_step(every, at_slot(x), at_slot(dt), A,
-                                        at_slot(Bm), at_slot(Cm), D)
-                pools = slots.write_all(pools, n, (every,), ("ssm",))
-                y = y[rows]
-            else:
+            step = (x, dt, -jnp.exp(p[pre + "A_log"]), Bm, Cm, p[pre + "D"])
+
+            def kernel(pools, *step):
+                return slots.in_place(
+                    pools, n, rows, "ssm", lambda pool, layer, rows:
+                    ssm_step_slots(pool, layer, rows, *step))
+
+            def plain(pools, *step):
                 (state,) = slots.read(pools, n, rows, ("ssm",))
-                state, y = ssm.ssm_step(state, x, dt, A, Bm, Cm, D)
-                pools = slots.write(pools, n, rows, (state,), ("ssm",))
+                state, y = ssm.ssm_step(state, *step)
+                return slots.write(pools, n, rows, (state,), ("ssm",)), y
+
+            pools, y = by_platform("ssm.step.path", tuple(pools), *step,
+                                   kernel=kernel, plain=plain,
+                                   rows=a.shape[0])
             out = self._mamba_out(p, i, y, z)
         return out, pools
 

@@ -49,7 +49,9 @@ pools* ``(state layers, max_slots + 1) + shape``, one row a slot and row 0
 the trash slot, as page 0 is the trash page.  A block whose
 ``cache_layout()`` has a ``state`` section gets them behind the page pools
 in the same donated tuple, and :class:`SlotState` (``pages.state``) is
-their only ``read`` and ``write``.  A sequence's row of ``tables`` then
+their only ``read`` and ``write``, and the one door (``in_place``) through
+which a kernel gets a whole state pool with the layer and the rows to find
+its blocks by.  A sequence's row of ``tables`` then
 ends with its state row: :meth:`PageFormat.addresses` splits the two.
 """
 from __future__ import annotations
@@ -130,7 +132,13 @@ class SlotState:
     sequence), stored ``(layers, max_slots + 1) + shape`` behind the
     ``first`` page pools of the cache's tuple.  Row ``slot_id + 1`` is a
     slot's; row 0 takes the writes of padded batch rows.  A layer here is
-    the block's count among its state layers, not its depth."""
+    the block's count among its state layers, not its depth.
+
+    ``read`` and ``write`` move rows' state out of a pool and into it (a
+    prefill's commit, the convolution's tail, the CPU's form of the step);
+    ``in_place`` hands a kernel the pool itself, and in a step program
+    built for the chip that kernel is the one reader and writer of the
+    recurrent state."""
 
     def __init__(self, spec, first):
         import jax.numpy as jnp
@@ -163,18 +171,24 @@ class SlotState:
 
     def read_all(self, pools, layer, names=None):
         """One state layer's arrays for EVERY state row, the trash row
-        first: a tuple, each ``(max_slots + 1,) + shape``."""
+        first: a tuple, each ``(max_slots + 1,) + shape``.  No program reads
+        a layer whole since the step's recurrence became a kernel; kept
+        because the benchmark's ``tests/perf/test_nemotron3_cell.py``
+        patches it by name (``PERF.md`` section 7)."""
         return tuple(pools[self.first + j][layer]
                      for j in self._which(names))
 
-    def write_all(self, pools, layer, values, names=None):
-        """Store ``values`` (each ``(max_slots + 1,) + shape``) as one
-        layer's state of every row, where it lies; returns the pools."""
+    def in_place(self, pools, layer, rows, name, fn):
+        """Hand the WHOLE pool of the state array ``name`` to ``fn(pool,
+        layer, rows) -> (pool, out)``, a kernel that finds ``rows``' state
+        of ``layer`` where it lies and gives the pool back in the buffer it
+        came in (``ops.pallas_kernels.ssm_step_slots``); returns ``(pools,
+        out)``.  Never a slice: ``pool[layer]`` handed to a custom call is a
+        copy of every slot's state in, and another out."""
+        (j,) = self._which((name,))
         pools = list(pools)
-        for j, x in zip(self._which(names), values):
-            k = self.first + j
-            pools[k] = pools[k].at[layer].set(x.astype(pools[k].dtype))
-        return tuple(pools)
+        pools[self.first + j], out = fn(pools[self.first + j], layer, rows)
+        return tuple(pools), out
 
     def write(self, pools, layer, rows, values, names=None):
         """Store ``values`` (one array a state array, or a named one,

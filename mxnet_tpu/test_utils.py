@@ -323,3 +323,24 @@ def fd_grad_check(sym, location, aux=None, rtol=5e-2, atol=1e-2, **kw):
     """check_numeric_gradient with the contract tranches' tolerances."""
     check_numeric_gradient(sym, location, aux_states=aux, rtol=rtol,
                            atol=atol, **kw)
+
+
+def counted(name, fn):
+    """``{labels: n}`` of what the telemetry counter ``name`` gained while
+    ``fn`` ran, a key a label set as the snapshot writes it
+    (``'{kind="plain",rows="4"}'``); telemetry is switched on for the call
+    and left as it was."""
+    from . import telemetry
+    was_on = telemetry.is_enabled()
+    telemetry.enable()
+    read = lambda: dict(
+        telemetry.snapshot()["counters_by_label"].get(name, {}))
+    try:
+        before = read()
+        fn()
+        after = read()
+    finally:
+        if not was_on:
+            telemetry.disable()
+    return {k: n - before.get(k, 0) for k, n in after.items()
+            if n != before.get(k, 0)}

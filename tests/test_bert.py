@@ -261,22 +261,11 @@ def test_blockwise_attention_keeps_the_key_chain():
 
 def _attention_paths(fn):
     """``attention.path`` counts by kind made while ``fn`` runs."""
-    from mxnet_tpu import telemetry
-    was_on = telemetry.is_enabled()
-    telemetry.enable()
-    read = lambda: dict(telemetry.snapshot()["counters_by_label"].get(
-        "attention.path", {}))
-    try:
-        before = read()
-        fn()
-        after = read()
-    finally:
-        if not was_on:
-            telemetry.disable()
+    from mxnet_tpu.test_utils import counted
     kinds = {}
-    for label, n in after.items():
+    for label, n in counted("attention.path", fn).items():
         kind = label.split('kind="')[1].split('"')[0]
-        kinds[kind] = kinds.get(kind, 0) + n - before.get(label, 0)
+        kinds[kind] = kinds.get(kind, 0) + n
     return {k: n for k, n in kinds.items() if n}
 
 

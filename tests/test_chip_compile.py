@@ -562,6 +562,19 @@ def _hybrid_program(rt, kind, b, sds):
         + rows + pools, pools
 
 
+_SSM_CALL = re.compile(
+    r"%([\w.\-]+) = \(f32\[3,33,64,64,128\]\S*, f32\[\d+,64,64\]\S*\) "
+    r"custom-call\(((?:%[\w.\-]+(?:, )?|/\*index=\d+\*/)+)\), "
+    r"custom_call_target=\"tpu_custom_call\"(.*)$", re.M)
+
+
+def _ssm_calls(hlo_text):
+    """``(name, operands, the rest of its line)`` of every recurrence kernel
+    (``ssm_step_slots``) in a compiled step, in program order."""
+    return [(m.group(1), re.findall(r"%([\w.\-]+)", m.group(2)), m.group(3))
+            for m in _SSM_CALL.finditer(hlo_text)]
+
+
 @pytest.mark.parametrize("kind,b", [("step", 1), ("step", 8), ("step", 32),
                                     ("commit", 1), ("prefill", 1)])
 def test_hybrid_programs_touch_only_their_slots_and_pages(one_chip, kind, b):
@@ -569,9 +582,11 @@ def test_hybrid_programs_touch_only_their_slots_and_pages(one_chip, kind, b):
     layers here) are held to what the page pools are: no step, commit or
     prefill program of the hybrid block holds a temporary the size of a
     state pool or of one layer of it, none copies a pool, and the step and
-    commit give every pool back in the buffer it came in.  The 32-row step
-    runs over every slot where it lies (only the pool itself is that
-    large); the 1- and 8-row steps gather their rows."""
+    commit give every pool back in the buffer it came in.  Every step
+    program, whatever its batch, advances the recurrence in ONE kernel a
+    Mamba layer (``ssm_step_slots``, under ``ssm.mix``), which moves its
+    rows' states between the pool and the core's own memory: the program's
+    temporaries hold no row's state, gathered or otherwise."""
     import numpy as np
 
     rt = _hybrid_runtime()
@@ -592,7 +607,8 @@ def test_hybrid_programs_touch_only_their_slots_and_pages(one_chip, kind, b):
     # parameters, and the copies of one that the compiler streams ahead of
     # its use, are not what is looked for
     weights = {tuple(p.shape) for p in args[0]}
-    for op, dtype, dims in _materialised(compiled.as_text()):
+    text = compiled.as_text()
+    for op, dtype, dims in _materialised(text):
         if int(np.prod(dims)) < layer or dims in weights or \
                 op in ("parameter", "get-tuple-element", "bitcast"):
             continue
@@ -602,14 +618,78 @@ def test_hybrid_programs_touch_only_their_slots_and_pages(one_chip, kind, b):
         assert op != "copy", f"{what}: copies a whole pool {dtype}{dims}"
     stats = compiled.memory_analysis()
     pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    calls = _ssm_calls(text)
     if kind == "prefill":
         # one prompt of 256: its two chunks' states, decay matrices and
         # scores, small beside one layer of 33 slots
         assert stats.temp_size_in_bytes < layer * 4, what
+        assert not calls, what
         return
     assert stats.alias_size_in_bytes >= pool_bytes, what
-    # a gathering step's temporaries: its rows' states (b x 2 MB) and paged
-    # context; the every-slot step's: the rows' vectors at their slots
-    assert stats.temp_size_in_bytes < max(b, 4) * 3 * 2 ** 21, \
-        f"{what}: {stats.temp_size_in_bytes / 1e9:.3f} GB of temporaries " \
+    if kind == "commit":
+        assert not calls, what
+        return
+    assert len(calls) == 3, f"{what}: {len(calls)} recurrence kernels for " \
+        f"3 Mamba layers"
+    for _name, _operands, rest in calls:
+        assert "/ssm.mix/" in rest and "ssm_step_slots" in rest, \
+            f"{what}: the kernel left its scope: {rest[:300]}"
+    # the paged context of b rows (2 x 1536 x 256 bf16 a row a layer), the
+    # rows' vectors and weights streamed ahead of their use; ONE row's state
+    # of one layer is 2 MB and does not fit beside them (6.2 MB at b = 1, 7.0
+    # at b = 8 and at b = 32, where the gathering form held 64 MB and more;
+    # sandbox compiles, PR 31)
+    assert stats.temp_size_in_bytes < (7 << 20) + b * (3 << 17), \
+        f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
         f"beside {pool_bytes / 1e9:.3f} GB of pools"
+
+
+def test_hybrid_step_kernels_work_in_the_donated_state_pool(one_chip):
+    """The recurrent-state pool goes from the program's donated parameter
+    through the three layers' kernels to the program's result as ONE
+    buffer: the first kernel's pool operand is the parameter itself, each
+    later one's is the pool the kernel before it returned, each call
+    aliases that operand to its first result, the program's result is the
+    last kernel's, and the module aliases that result to the parameter."""
+    rt = _hybrid_runtime()
+    fn, args, pools = _hybrid_program(
+        rt, "step", 32, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    text = fn.lower(*args).compile().as_text()
+    calls = _ssm_calls(text)
+    assert len(calls) == 3
+    param = re.search(r"%([\w.\-]+) = f32\[3,33,64,64,128\]\S* "
+                      r"parameter\((\d+)\)", text)
+    pool, number = param.group(1), int(param.group(2))
+    for name, operands, rest in calls:
+        # four scalars come first: the layer, the state row each grid step
+        # names, the rows' own state rows; the decays
+        assert operands[4] == pool, \
+            f"{name} reads its pool from %{operands[4]}, not %{pool}"
+        assert "output_to_operand_aliasing={{0}: (4, {})}" in rest, name
+        got = re.search(r"%([\w.\-]+) = f32\[3,33,64,64,128\]\S* "
+                        r"get-tuple-element\(%" + re.escape(name)
+                        + r"\), index=0", text)
+        pool = got.group(1)
+    root = re.search(r"ROOT %[\w.\-]+ = \(.*\) tuple\((.*?)\)",
+                     text[text.index("ENTRY"):])
+    out = [o.strip().lstrip("%") for o in
+           re.sub(r"/\*index=\d+\*/", "", root.group(1)).split(",")]
+    n_paged = len(rt.cache.pool_layout)
+    assert out[1 + n_paged] == pool
+    assert f"{{{1 + n_paged}}}: ({number}, {{}}" in text.splitlines()[0]
+
+
+def test_hybrid_step_for_the_chip_counts_the_kernel_once_a_mamba_layer(
+        one_chip):
+    """``ssm.step.path``: lowering the 32-row step for the described chip
+    counts ``kind="kernel"`` once a Mamba layer and ``plain`` never (for
+    the CPU it is the other way round: ``tests/test_ssm_step_kernel.py``).
+    Both forms are traced; the count is made when one is lowered."""
+    from mxnet_tpu.test_utils import counted
+    rt = _hybrid_runtime()
+    fn, args, _pools = _hybrid_program(
+        rt, "step", 32, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    assert counted("ssm.step.path", lambda: fn.lower(*args)) == \
+        {'{kind="kernel",rows="32"}': 3}

@@ -195,10 +195,11 @@ def test_prefill_hands_over_state_as_of_the_true_length():
 @pytest.mark.parametrize("batch,row,slot_row", [(4, 2, 3), (2, 0, 4)])
 def test_batch_composition_and_slot_placement_do_not_change_a_row(
         dtype, batch, row, slot_row):
-    """Solo in a one-row program (which gathers its slot) against a row of
-    a padded batch (4 of 4 slots: the program that runs over every slot
-    where it lies; 2 of 4: the gather again) in other pages and another
-    slot."""
+    """Solo in a one-row program against a row of a padded batch (of 4 and
+    of 2 rows, the live row not the first) in other pages and another slot:
+    the step finds a row's state by its state row, wherever the row stands
+    (here the CPU's form, read / ``ssm_step`` / write; the chip's kernel is
+    held to the same in ``tests/test_ssm_step_kernel.py``)."""
     net, _w = build(tiny_cfg(dtype))
     tokens = np.random.default_rng(9).integers(0, 97, 20)
     solo, _x, _p = decode_logits(net, tokens, 5, pages=[1, 2, 3], slot_row=1)
@@ -208,8 +209,9 @@ def test_batch_composition_and_slot_placement_do_not_change_a_row(
 
 
 def test_a_step_leaves_the_other_slots_as_they_were():
-    """The every-slot form rewrites a slot with no row in the batch as it
-    was, bit for bit; padded rows write the trash row only."""
+    """A step leaves a slot with no row in the batch as it was, bit for
+    bit; padded rows write the trash row only (the chip's kernel writes not
+    even that: ``tests/test_ssm_step_kernel.py``)."""
     net, _w = build(tiny_cfg("float32"))
     cache = new_cache(net)
     first = cache.pages.state.first

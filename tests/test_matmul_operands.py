@@ -10,7 +10,6 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, gluon
 from mxnet_tpu import random as mx_random
-from mxnet_tpu import telemetry
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.ops import registry
 
@@ -172,21 +171,10 @@ def test_keep_mask_is_the_keys_bernoulli_bit_for_bit(call, axes):
 
 def _operand_counts(fn):
     """``matmul.operand`` counts by (op, kind) made while ``fn`` runs."""
-    was_on = telemetry.is_enabled()
-    telemetry.enable()
-    read = lambda: dict(telemetry.snapshot()["counters_by_label"].get(
-        "matmul.operand", {}))
-    try:
-        before = read()
-        fn()
-        after = read()
-    finally:
-        if not was_on:
-            telemetry.disable()
+    from mxnet_tpu.test_utils import counted
     part = lambda label, name: label.split(name + '="')[1].split('"')[0]
-    counts = {(part(label, "op"), part(label, "kind")):
-              n - before.get(label, 0) for label, n in after.items()}
-    return {k: n for k, n in counts.items() if n}
+    return {(part(label, "op"), part(label, "kind")): n
+            for label, n in counted("matmul.operand", fn).items()}
 
 
 def test_operand_counter_says_value_only_under_differentiation():
