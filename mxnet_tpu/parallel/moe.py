@@ -143,14 +143,17 @@ def _grouped(x, w, sizes):
 
 
 def route_to_held(x, router_w, held, *, top_k, n_group=1, topk_group=1,
-                  scale=1.0, valid=None):
+                  scale=1.0, valid=None, select_bias=None):
     """The routing of ``x (T, D)`` float32 as this chip sees it; call it
     under the named scope ``moe.route``.  Scores are ``sigmoid(x router_w)``
     over the WHOLE router ``(D, E)`` in float32 at the highest precision,
     the choice is :func:`group_limited_topk`, the weights ``scale * s_k /
     sum_chosen s`` — all independent of ``held``, the tuple of global ids of
     the ``G`` experts held here.  ``valid (T,) bool`` marks real rows;
-    padding is routed nowhere.
+    padding is routed nowhere.  ``select_bias (E,)`` float32 (the family's
+    ``noaux_tc`` score correction) joins the scores for the CHOICE only:
+    the experts are the ``top_k`` of ``s + select_bias``, their weights are
+    still made of ``s``.
 
     Returns ``(local (T, top_k) int32, weights (T, top_k), assignments
     int32)``: each choice's position in ``held`` (``G`` where it is held
@@ -163,7 +166,12 @@ def route_to_held(x, router_w, held, *, top_k, n_group=1, topk_group=1,
     scores = jax.nn.sigmoid(jnp.dot(
         x, router_w, precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32))
-    ids, chosen = group_limited_topk(scores, top_k, n_group, topk_group)
+    if select_bias is None:
+        ids, chosen = group_limited_topk(scores, top_k, n_group, topk_group)
+    else:
+        ids, _ = group_limited_topk(scores + select_bias, top_k, n_group,
+                                    topk_group)
+        chosen = jnp.take_along_axis(scores, ids, axis=-1)
     weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
     lookup = np.full((E,), G, "int32")
     lookup[np.asarray(held, "int64")] = np.arange(G, dtype="int32")
@@ -185,13 +193,14 @@ def rows_received(flat, G):
 
 def routed_expert_share(x, router_w, w_gate, w_up, w_down, held, *,
                         top_k, n_group=1, topk_group=1, scale=1.0,
-                        valid=None):
+                        valid=None, select_bias=None):
     """This chip's part of ``sum_k w_k E_k(x)`` for ``x (T, D)`` float32.
 
     ``router_w (D, E)`` float32 is the WHOLE router (``E`` = the published
     expert count); ``held`` is the tuple of global expert ids whose weights
     ``w_gate`` / ``w_up (G, D, F)`` and ``w_down (G, F, D)`` are, in that
-    order.  The routing is :func:`route_to_held`'s.
+    order.  The routing is :func:`route_to_held`'s (``select_bias`` is its
+    argument).
     Only the chosen experts that are held are computed: the ``T * top_k``
     assignments are sorted by held expert (the others last), the first
     ``T * min(top_k, G)`` rows — every held assignment fits, so no token is
@@ -206,7 +215,8 @@ def routed_expert_share(x, router_w, w_gate, w_up, w_down, held, *,
     with jax.named_scope("moe.route"):
         local, weights, n_assign = route_to_held(
             x, router_w, held, top_k=top_k, n_group=n_group,
-            topk_group=topk_group, scale=scale, valid=valid)
+            topk_group=topk_group, scale=scale, valid=valid,
+            select_bias=select_bias)
         flat = local.reshape(-1)
         rows = rows_received(flat, G)
         M = T * min(top_k, G)

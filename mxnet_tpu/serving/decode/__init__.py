@@ -25,6 +25,16 @@ discipline (PAPER.md design point #2) to that loop:
   the cache's state pools, grouped-query attention layers that alone
   page, and un-gated ``relu^2`` routed + shared experts as a chip's share.
   No drafter, no quantized pools, no mesh; prefix sharing is skipped.
+- :class:`WindowMoELM` (``window_moe.py``) — the ``mimo_v2`` family's
+  block, built from ``hybrid_layer_pattern`` and ``moe_layer_freq``:
+  sliding-window layers (a learned sink bias in the softmax, their own
+  count of K/V heads) whose last ``window`` tokens live in a RING a slot of
+  the cache's state pools, bounded whatever the context, beside global
+  grouped-query layers that page; keys wider than values, partial rotary
+  with a base a kind; a dense SwiGLU layer, then routed experts chosen
+  through a selection bias as a chip's share.  Prefill goes by query blocks
+  and holds no ``(heads, S, S)`` array.  No drafter, no quantized pools, no
+  mesh; prefix sharing is skipped.
 - :class:`PagedKVCache` (``kv_cache.py``) — device-resident page pools
   with a trash page for padding, generation-stamped slots (the ShmRing
   discipline: a post-free read raises ``StaleKVSlotError`` under
@@ -37,7 +47,8 @@ discipline (PAPER.md design point #2) to that loop:
   two functions that index one; quantization is fused into whichever
   program writes and reads.  :class:`SlotState`, beside it, is the same
   for state that is per sequence and not per token: pools of one row a
-  slot, the allocator's slots owning the rows.
+  slot, the allocator's slots owning the rows (a state-space layer's
+  recurrent state; a window layer's ring).
 - :class:`DecodeRuntime` (``runtime.py``) — the 2-D *(batch x seqlen)*
   prefill grid warmed through ``HybridBlock.compile_grid`` plus ONE
   fused donated step program per batch bucket; ``decode.compile_miss``
@@ -88,6 +99,7 @@ from .model import (  # noqa: F401
 )
 from .latent_moe import LatentMoELM  # noqa: F401
 from .hybrid_moe import HybridSSMMoELM  # noqa: F401
+from .window_moe import WindowMoELM  # noqa: F401
 from .runtime import DecodeRuntime, seq_bucket_ladder  # noqa: F401
 from .scheduler import (  # noqa: F401
     DecodeScheduler,
@@ -101,7 +113,8 @@ from .speculate import (  # noqa: F401
     SpecState,
 )
 
-__all__ = ["CausalLM", "LatentMoELM", "HybridSSMMoELM", "get_decode_model", "rowdot",
+__all__ = ["CausalLM", "LatentMoELM", "HybridSSMMoELM", "WindowMoELM",
+           "get_decode_model", "rowdot",
            "sample_math",
            "kv_quantize_rows", "kv_dequantize",
            "kv_quantize_rows_fp8", "kv_dequantize_fp8",

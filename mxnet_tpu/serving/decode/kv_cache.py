@@ -49,7 +49,10 @@ reclaimed LRU-first under allocation pressure, so a hot prefix survives
 across sessions without ever causing a spurious ``KVCacheExhausted``.
 
 **State that is per sequence** (``cache_layout()``'s ``state`` section: a
-state-space layer's recurrent state and convolution tail) lives in *state
+state-space layer's recurrent state and convolution tail; a sliding-window
+layer's ring of its last ``window`` tokens' K/V, so that one manager holds
+two kinds of attention state: pages that grow with the context for the
+global layers, a bounded ring a slot for the window layers) lives in *state
 pools* behind the page pools, one row a slot (``kv_format.SlotState``,
 ``cache.pages.state``): the slot allocator owns the rows.  Slot ``i``'s
 state row is ``i + 1`` (row 0 is the trash slot) and rides as the last
@@ -803,6 +806,12 @@ class PagedKVCache:
                 _tel.gauge("decode.state_bytes", self.state_bytes)
 
     @property
+    def page_pool_bytes(self):
+        """Device bytes of the page pools (every page, the trash page too):
+        the kind of attention state that grows with the context."""
+        return self.page_bytes * self.num_pages
+
+    @property
     def state_bytes(self):
         """Device bytes of the state pools (every slot and the trash row);
         0 where the block keeps no per-sequence state."""
@@ -823,6 +832,7 @@ class PagedKVCache:
                 **state,
                 "pages_in_use": slot_pages,
                 "usable_pages": self.usable_pages,
+                "page_pool_bytes": self.page_pool_bytes,
                 "slots_in_use": self.max_slots - len(self._free_slots),
                 "max_slots": self.max_slots,
                 "peak_pages": self.peak_pages,

@@ -44,7 +44,9 @@ copy-on-write; each format) to this: no temporary the size of a pool or of
 one layer of one.
 
 **State that is per sequence, not per token** (a state-space layer's
-recurrent state, its convolution's tail) lives beside the pages in *state
+recurrent state, its convolution's tail; a sliding-window layer's ring of
+its last ``window`` tokens' keys and values, which is bounded whatever the
+context) lives beside the pages in *state
 pools* ``(state layers, max_slots + 1) + shape``, one row a slot and row 0
 the trash slot, as page 0 is the trash page.  A block whose
 ``cache_layout()`` has a ``state`` section gets them behind the page pools
@@ -136,7 +138,8 @@ class SlotState:
 
     ``read`` and ``write`` move rows' state out of a pool and into it (a
     prefill's commit, the convolution's tail, the CPU's form of the step);
-    ``in_place`` hands a kernel the pool itself, and in a step program
+    ``write_at`` updates one entry of a row's array (the newest token of a
+    window layer's ring); ``in_place`` hands a kernel the pool itself, and in a step program
     built for the chip that kernel is the one reader and writer of the
     recurrent state."""
 
@@ -198,6 +201,18 @@ class SlotState:
         for j, x in zip(self._which(names), values):
             k = self.first + j
             pools[k] = pools[k].at[layer, rows].set(x.astype(pools[k].dtype))
+        return tuple(pools)
+
+    def write_at(self, pools, layer, rows, index, values, names=None):
+        """Store ``values`` (``(B,) + shape[1:]``) as entry ``index (B,)``
+        along the first axis of that state of ``rows`` in one layer, where
+        it lies: ONE token's keys and values in a window layer's ring, the
+        rest of the ring untouched.  Indexed once, as :meth:`write` is."""
+        pools = list(pools)
+        for j, x in zip(self._which(names), values):
+            k = self.first + j
+            pools[k] = pools[k].at[layer, rows, index].set(
+                x.astype(pools[k].dtype))
         return tuple(pools)
 
 

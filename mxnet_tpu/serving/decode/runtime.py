@@ -76,8 +76,9 @@ class DecodeRuntime:
 
     **What a block is to the runtime** (:class:`~mxnet_tpu.serving.decode.
     model.CausalLM`, :class:`~mxnet_tpu.serving.decode.latent_moe.
-    LatentMoELM` and :class:`~mxnet_tpu.serving.decode.hybrid_moe.
-    HybridSSMMoELM` are): a hybridizable block whose forward is the
+    LatentMoELM`, :class:`~mxnet_tpu.serving.decode.hybrid_moe.
+    HybridSSMMoELM` and :class:`~mxnet_tpu.serving.decode.window_moe.
+    WindowMoELM` are): a hybridizable block whose forward is the
     prefill ``(tokens (B, S), lengths (B,)) -> (last_logits, state)``
     (``state`` one array, or several behind the logits), with
     ``vocab_size``, ``param_leaves()`` / ``_params_dict(leaves)``,

@@ -693,3 +693,149 @@ def test_hybrid_step_for_the_chip_counts_the_kernel_once_a_mamba_layer(
             tuple(shape), dtype, sharding=one_chip))
     assert counted("ssm.step.path", lambda: fn.lower(*args)) == \
         {'{kind="kernel",rows="32"}': 3}
+
+
+# ------------------------------------------- the window / global block's
+# the cell's geometry (mimo_v2_5_ep16.mixed_lengths): 18,433 pages, 288 a
+# row (4,608 tokens), 33 rings a window layer, the longest prefill bucket
+_W_PAGES, _W_ROW_PAGES, _W_SLOTS, _W_SEQ = 18433, 288, 32, 4096
+
+
+@functools.lru_cache(maxsize=1)
+def _window_runtime():
+    """MiMo-V2.5's attention at every published width (64 query heads, 4
+    global and 8 window K/V heads, keys 192 wide over values 128, a window
+    of 128) in a global + dense layer and two window + expert layers; two
+    held experts and a small vocabulary, which are not what is asked
+    about."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.serving.decode import (DecodeRuntime, PagedKVCache,
+                                          WindowMoELM)
+    net = WindowMoELM(
+        vocab_size=512, hidden_size=4096, layer_pattern=(0, 1, 1),
+        moe_layer_freq=(0, 1, 1), num_attention_heads=64,
+        num_key_value_heads=4, swa_num_key_value_heads=8, head_dim=192,
+        v_head_dim=128, sliding_window=128, intermediate_size=2048,
+        moe_intermediate_size=2048, n_routed_experts=256,
+        held_experts=(0, 1), num_experts_per_tok=8,
+        max_length=_W_ROW_PAGES * _PAGE)
+    for p in net.collect_params().values():
+        p._load_init(mx.nd.zeros(p.shape, dtype=p.dtype), None)
+    cache = PagedKVCache(layout=net.cache_layout(), page_size=_PAGE,
+                         num_pages=2, max_pages_per_seq=_W_ROW_PAGES,
+                         max_slots=1)
+    return DecodeRuntime(net, cache=cache, batch_buckets=(1,),
+                         seq_buckets=(_W_SEQ,), warm=False)
+
+
+def _window_program(rt, kind, b, sds):
+    """``(jitted program, its arguments with the pools last, the pools)``;
+    the pools at the cell's size."""
+    i32, u32, f32 = "int32", "uint32", "float32"
+    blk, n_paged = rt.block, len(rt.cache.pool_layout)
+    pools = tuple(
+        sds(p.shape[:1] + ((_W_PAGES,) if j < n_paged else (_W_SLOTS + 1,))
+            + p.shape[2:], p.dtype) for j, p in enumerate(rt.cache.pools))
+    params = [sds(p.shape, p.dtype) for p in rt._params]
+    if kind == "prefill":
+        fn = jax.jit(lambda leaves, tok, ln: blk.prefill_math(
+            blk._params_dict(leaves), tok, ln))
+        return fn, (params, sds((b, _W_SEQ), i32), sds((b,), i32)), pools
+    rows = (sds((b, _W_ROW_PAGES + 1), i32), sds((b, 2), u32),
+            sds((b,), i32), sds((b,), f32))     # tables, keys, steps, temps
+    if kind == "step":
+        return rt._build_step(), \
+            (params, sds((b,), i32), sds((b,), i32)) + rows + pools, pools
+    state = tuple(sds(shape, dtype)
+                  for shape, dtype in blk.prefill_state(b, _W_SEQ))
+    return rt._build_commit(), \
+        (params, state, sds((b, blk.vocab_size), f32), sds((b,), i32)) \
+        + rows + pools, pools
+
+
+def test_window_prefill_holds_no_heads_by_s_by_s_array(one_chip):
+    """A prompt of 4,096: the float32 scores of ONE layer as a ``(heads, S,
+    S)`` array would be 64 x 4,096^2 x 4 B = 4.3 GB.  The program goes by
+    query blocks of the window: its largest array is a global layer's
+    scores of one block over all keys (64 x 128 x 4,096 float32, 134 MB) or
+    a window layer's band (64 x 4,096 x 256, 268 MB), and all its
+    temporaries together are a quarter of that one array."""
+    import numpy as np
+    rt = _window_runtime()
+    fn, args, _pools = _window_program(
+        rt, "prefill", 1, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    compiled = fn.lower(*args).compile()
+    heads, S = 64, _W_SEQ
+    for op, dtype, dims in _materialised(compiled.as_text()):
+        assert int(np.prod(dims)) < heads * S * S // 8, \
+            f"window prefill: {op} writes {dtype}{list(dims)}"
+        # (S, hidden) is 4,096 x 4,096 here too: an S x S array of scores
+        # has a heads axis beside them
+        assert len(dims) < 3 or sum(d == S for d in dims) < 2, \
+            f"window prefill: {op} writes {dtype}{list(dims)}: S x S"
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        heads * S * S * 4 // 4
+
+
+@pytest.mark.parametrize("kind,b", [("step", 1), ("step", 32),
+                                    ("commit", 1)])
+def test_window_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
+    """Two kinds of attention state in one donated tuple.  No step or
+    commit program copies a pool or holds a temporary the size of one layer
+    of the page pools, and each gives every pool back in the buffer it came
+    in.  Of a window layer's K/V a step holds the rings of its ``b`` rows
+    (``b x 128`` tokens, gathered by state row) and nothing beyond: no
+    array with a window layer's row (8 heads: 1,536 keys, 1,024 values) has
+    more tokens than that, whatever the 4,608 the context allows."""
+    import numpy as np
+    rt = _window_runtime()
+    fn, args, pools = _window_program(
+        rt, kind, b, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    k_pool, v_pool, ring_k, ring_v = pools
+    assert k_pool.shape == (1, _W_PAGES, _PAGE, 768) and \
+        v_pool.shape == (1, _W_PAGES, _PAGE, 512)
+    assert ring_k.shape == (2, 33, 128, 1536) and \
+        ring_v.shape == (2, 33, 128, 1024)
+    compiled = fn.lower(*args).compile()
+    what = f"window {kind}-b{b}"
+    # a pool updated where it lies, under whatever shape the compiler
+    # gives the one page layer here ((18433, 16, 768), (294928, 768))
+    own = {int(np.prod(p.shape)) for p in pools}
+    weights = {tuple(p.shape) for p in args[0]}
+    page_layer = int(np.prod(v_pool.shape[1:]))
+    window = 128
+    for op, dtype, dims in _materialised(compiled.as_text()):
+        if op in ("parameter", "get-tuple-element", "bitcast") or \
+                dims in weights:
+            continue
+        n = int(np.prod(dims))
+        if dtype == "bf16" and n in own:
+            assert op != "copy", f"{what}: copies a whole pool {dtype}{dims}"
+            continue
+        assert n < page_layer, \
+            f"{what}: {op} writes {dtype}{list(dims)}, a layer of a page " \
+            f"pool or more"
+        # (two axes: a row's vector, or a slice of the projection's weight
+        # streamed ahead of its use)
+        if kind == "step" and len(dims) > 2 and dtype == "bf16" and \
+                dims[-1] in (1536, 1024):
+            # (at b = 1 the compiler stages ONE layer's 33 rings, 8.6 MB,
+            # ahead of the row's gather: never more than a layer's rings)
+            assert n <= max(b, _W_SLOTS + 1) * window * dims[-1], \
+                f"{what}: {op} writes {dtype}{list(dims)}: more of a " \
+                f"window layer's K/V than {b} rings"
+    stats = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    assert stats.alias_size_in_bytes >= pool_bytes, what
+    if kind == "commit":
+        assert stats.temp_size_in_bytes < (8 << 20), what
+        return
+    # the paged context of b rows of the ONE global layer here (4,608 x
+    # (768 + 512) bf16 a row, gathered whole: ROADMAP R4 / S2), the b rings
+    # of a window layer, weights streamed ahead of their use
+    # (534 MB at b = 32, 377 of them the gathered context; sandbox compile)
+    assert stats.temp_size_in_bytes < (48 << 20) + b * (17 << 20), \
+        f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
+        f"beside {pool_bytes / 1e9:.3f} GB of pools"

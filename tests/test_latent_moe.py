@@ -24,6 +24,7 @@ width, and the benchmark counts the served tokens it moves.  A lower
 precision than bfloat16 fails the comparison that decides ``correct``
 (``tests/perf/test_axk1_cell.py``).
 """
+import gc
 import os
 import sys
 
@@ -423,7 +424,10 @@ def test_served_weights_are_held_once(which):
 
     def live():
         """Live arrays of a parameter matrix's shape (other tests' blocks
-        of the same size are among them: the count, not the list)."""
+        of the same size are among them: the count, not the list; what
+        earlier tests left for the collector is collected first, or it may
+        go between the two counts)."""
+        gc.collect()
         return sum((a.shape, str(a.dtype)) in sizes
                    for a in jax.live_arrays())
 
