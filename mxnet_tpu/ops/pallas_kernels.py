@@ -55,6 +55,17 @@ row or an idle slot, where XLA's forms pass two to three times over every
 slot of the layer.  float32 on the vector unit, no product on the MXU.
 ``by_platform`` is how its caller gets the kernel on the chip and the
 definition on the CPU, and a count of which was built.
+
+Third resident kernel: **the decode step's grouped-query attention over the
+rows' pages where they lie** (``paged_attention``): the cache's K and V page
+pools stay whole in device memory, the layer, the page tables and the rows'
+positions are prefetched scalars, and each LIVE row's LIVE pages cross
+memory once, a page a DMA, in double-buffered blocks under an online
+softmax: nothing for a padded row or for a reserved page that holds no
+token yet, where the gathering form copies every reserved page of every row
+of the program, and the compiler copies them once more.  Products in the
+pools' dtype on the MXU, float32 accumulation and softmax.
+``serving.decode.kv_format.PageFormat.attend`` is its one caller.
 """
 from __future__ import annotations
 
@@ -68,7 +79,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "ssm_step_slots", "by_platform"]
+__all__ = ["flash_attention", "ssm_step_slots", "paged_attention",
+           "by_platform"]
 
 _NEG = -1e30
 
@@ -666,6 +678,180 @@ def ssm_step_slots(pool, layer, rows, x, dt, A, B, C, D, *, interpret=False):
         jnp.swapaxes(dt[..., None] * x, 1, 2), B.astype(jnp.float32),
         C.astype(jnp.float32), interpret=interpret)
     return pool, jnp.swapaxes(y, 1, 2) + D[:, None] * x
+
+
+# --------------------------------------------------------------------------
+# third resident kernel: one query token a row over the row's LIVE pages of
+# the cache's K and V page pools, where they lie
+
+
+def _paged_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, *, pages_a_row, block_pages, groups,
+                  scale):
+    """One batch row a grid step: the row's tokens ``0 .. position`` go by
+    in blocks of ``block_pages`` pages, each page brought by its own DMA
+    from ``pool[layer, tables[row, j]]`` into one of two blocks of VMEM (a
+    block's pages are on their way while the block before it is computed),
+    under a running max, denominator and accumulator in float32.  Only the
+    pages that hold a token of the row are asked for; a padded row (its
+    first page the trash page) asks for none and gives zeros.
+
+    ``q`` is block-diagonal over the K/V heads (``paged_attention``), so a
+    block's scores of every head are one product with the whole key rows
+    and its values one product with the whole value rows; each K/V head's
+    query heads then keep their own head's columns."""
+    b = pl.program_id(0)
+    layer, first = layer_ref[0], b * pages_a_row
+    block = k_buf.shape[1]
+    page = block // block_pages
+    tokens = pos_ref[b] + 1
+    heads, dv = q_ref.shape[1] // groups, v_buf.shape[2] // groups
+    # float32 pools (a block built in float32) multiply at full precision,
+    # as the blocks' own products do
+    precision = jax.lax.Precision.HIGHEST if k_buf.dtype == jnp.float32 \
+        else None
+
+    def pages_of(blk):
+        # the pages of block ``blk`` that hold a token of the row
+        return jnp.clip(pl.cdiv(tokens - blk * block, page), 0, block_pages)
+
+    def copies(blk, slot, j):
+        at = tables_ref[first + blk * block_pages + j]
+        rows = pl.ds(pl.multiple_of(j * page, page), page)
+        return (pltpu.make_async_copy(k_hbm.at[layer, at],
+                                      k_buf.at[slot, rows], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, at],
+                                      v_buf.at[slot, rows], sems.at[1, slot]))
+
+    def each_page(blk, slot, what):
+        def one(j, _):
+            for c in copies(blk, slot, j):
+                what(c)
+        jax.lax.fori_loop(0, pages_of(blk), one, None)
+
+    def attend(blk, carry):
+        m, l, acc = carry
+        slot = blk % 2
+
+        @pl.when(blk + 1 < pl.cdiv(tokens, block))
+        def _():
+            each_page(blk + 1, 1 - slot, lambda c: c.start())
+
+        each_page(blk, slot, lambda c: c.wait())
+
+        # a page of the block that was not asked for holds whatever the
+        # buffer held before: its scores are masked, and its values are
+        # made zeros, which a probability of zero leaves zeros
+        def forget(j, _):
+            v_buf[slot, pl.ds(pl.multiple_of(j * page, page), page)] = \
+                jnp.zeros((page, v_buf.shape[2]), v_buf.dtype)
+        jax.lax.fori_loop(pages_of(blk), block_pages, forget, None)
+        v = v_buf[slot]
+        s = jax.lax.dot_general(q_ref[0], k_buf[slot], _NT,
+                                precision=precision,
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(blk * block + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block), 1) < tokens, s, _NEG)
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - new_m)
+        corr = jnp.exp(m - new_m)
+        pv = jnp.dot(p.astype(v.dtype), v, precision=precision,
+                     preferred_element_type=jnp.float32)
+        return (new_m, l * corr + jnp.sum(p, axis=-1, keepdims=True),
+                acc * corr + pv)
+
+    @pl.when(tables_ref[first] != 0)
+    def _():
+        each_page(0, 0, lambda c: c.start())
+        rows = q_ref.shape[1]
+        _m, l, acc = jax.lax.fori_loop(
+            0, pl.cdiv(tokens, block), attend,
+            (jnp.full((rows, 1), _NEG, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, v_buf.shape[2]), jnp.float32)))
+        out = acc / l
+        for g in range(groups):
+            o_ref[0, g * heads:(g + 1) * heads] = \
+                out[g * heads:(g + 1) * heads, g * dv:(g + 1) * dv]
+
+    @pl.when(tables_ref[first] == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("groups", "scale", "block_pages",
+                                             "interpret"))
+def _paged_call(layer, tables, positions, q, k_pool, v_pool, *, groups, scale,
+                block_pages, interpret):
+    """``pallas_call`` on the grid (batch rows,) with the layer, the page
+    tables (flat: a 2-D array in scalar memory is padded to 128 columns)
+    and the positions as prefetched scalars, and the WHOLE pools left where
+    they are (``pl.ANY``): the kernel fetches what it needs.  One jitted
+    function of its arrays, the layer among them: every layer of every step
+    program lowers this once and the chip compiles one body a shape."""
+    b, rows, _ = q.shape
+    page, kw = k_pool.shape[2:]
+    vw = v_pool.shape[3]
+    by_row = lambda i, *_: (i, 0, 0)
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, pages_a_row=tables.shape[1],
+                          block_pages=block_pages, groups=groups,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec((1, rows, kw), by_row),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, rows, vw // groups), by_row),
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages * page, kw), k_pool.dtype),
+                pltpu.VMEM((2, block_pages * page, vw), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, vw // groups), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="paged_attention",
+    )(layer, tables.reshape(-1), positions, q, k_pool, v_pool)
+
+
+def paged_attention(q, k_pool, v_pool, layer, tables, positions, *,
+                    block_pages=None, interpret=False):
+    """Grouped-query attention of ONE query token a row, ``q (b, g, r, dk)``
+    float32 at ``positions (b,)``, over the row's keys and values ``0 ..
+    position`` where they lie in the page pools ``k_pool (layers, pages,
+    page size, g * dk)`` and ``v_pool (..., g * dv)`` (the token that the
+    step just wrote among them): page ``j`` of row ``i`` is ``pool[layer,
+    tables[i, j]]``.  ``layer`` a scalar (traced: one kernel for every
+    layer).  Returns ``(b, g, r, dv)`` float32.
+
+    Only the pages that hold a token of a live row cross memory, once; a
+    page past a row's position, a page no table names, and everything of a
+    padded row (``tables[i, 0] == 0``, the trash page; its output is zeros)
+    are not read.  The pools are read where they are and keep their bits.
+
+    Products take operands in the pools' dtype and accumulate in float32
+    (the probabilities are cast before the value product); the softmax is
+    float32 with the scale ``dk ** -0.5``, online over blocks of
+    ``block_pages`` pages.
+
+    A head's slice of a key row need not start at a lane tile's edge (4
+    heads of 192), so the kernel slices none: ``q`` goes in block-diagonal
+    over the K/V heads, ``(g * r, g * dk)`` a row with zeros off a head's
+    own columns, and the scores of all heads are one product with the whole
+    key rows, the values one product with the whole value rows, of which
+    each K/V head's query heads keep their own 128-aligned columns: ``g``
+    times the arithmetic of a step whose arithmetic is small."""
+    b, g, r, dk = q.shape
+    page = k_pool.shape[2]
+    if block_pages is None:
+        block_pages = max(1, min(tables.shape[1], 512 // page))
+    q = (q[:, :, :, None, :] * jnp.eye(g, dtype=q.dtype)[:, None, :, None]
+         ).reshape(b, g * r, g * dk).astype(k_pool.dtype)
+    out = _paged_call(jnp.asarray(layer, jnp.int32).reshape(1),
+                      tables.astype(jnp.int32), positions.astype(jnp.int32),
+                      q, k_pool, v_pool, groups=g, scale=dk ** -0.5,
+                      block_pages=block_pages, interpret=interpret)
+    return out.reshape(b, g, r, -1)
 
 
 # --------------------------------------------------------------------------

@@ -412,6 +412,11 @@ class HybridSSMMoELM(HybridBlock):
         """Grouped-query attention of ``q (B, Q, g, r, D)`` over ``k``, ``v
         (B, L, kv_width)`` (stored precision) where ``mask (B, Q, L)``
         allows.  Returns the attention output ``(B, Q, U)``."""
+        return _dot(self.attend_heads(q, k, v, mask), p[f"l{i}_wo"])
+
+    def attend_heads(self, q, k, v, mask):
+        """:meth:`attend` before the output projection: the heads' outputs
+        side by side, ``(B, Q, heads * D)`` float32."""
         import jax
         import jax.numpy as jnp
         B, L, _ = k.shape
@@ -422,7 +427,7 @@ class HybridSSMMoELM(HybridBlock):
         s = jnp.where(mask[:, None, None], s, -1e30)
         pr = jax.nn.softmax(s, axis=-1)
         o = _einsum("bgrql,blgd->bqgrd", pr, v, dt)
-        return _dot(o.reshape(o.shape[:2] + (-1,)), p[f"l{i}_wo"])
+        return o.reshape(o.shape[:2] + (-1,))
 
     def _experts(self, p, i, m, valid, counts):
         """Routed share + shared expert of flat rows ``m (T, U)``."""
@@ -476,9 +481,10 @@ class HybridSSMMoELM(HybridBlock):
         """Pure fused decode step, one token a row.  ``tables`` ends with
         each row's state slot (``pages.addresses``): a Mamba layer advances
         the slot's state through ``pages.state`` (:meth:`mamba_step`); an
-        attention layer writes the row's K/V into its
-        page and attends over the row's paged context.  Padded rows (page
-        table all trash) use the trash slot and are routed to no expert.
+        attention layer writes the row's K/V into its page and attends
+        over the row's paged context through ``pages.attend``.  Padded rows
+        (page table all trash) use the trash slot and are routed to no
+        expert.
         Returns ``(logits (B, vocab), pools, (moe_rows (expert layers, held
         + 1) int32, live rows (1,) int32))``."""
         import jax
@@ -489,8 +495,6 @@ class HybridSSMMoELM(HybridBlock):
         wp = jnp.take_along_axis(ptab, (positions // page_size)[:, None],
                                  axis=1)[:, 0]
         woff = positions % page_size
-        lctx = ptab.shape[1] * page_size
-        mask = (jnp.arange(lctx)[None, :] <= positions[:, None])[:, None]
         valid = ptab[:, 0] != 0
         counts = []
         for i, kind in enumerate(self.pattern):
@@ -502,8 +506,10 @@ class HybridSSMMoELM(HybridBlock):
                 with jax.named_scope("attn.gqa"):
                     q, k, v = self._qkv(p, i, a)
                     pools = pages.write(pools, n, wp, woff, (k, v))
-                    ck, cv = pages.read(pools, n, ptab)
-                    o = self.attend(p, i, q[:, None], ck, cv, mask)[:, 0]
+                    o = _dot(pages.attend(
+                        pools, n, ptab, positions, q,
+                        lambda ck, cv, mask: self.attend_heads(
+                            q[:, None], ck, cv, mask)[:, 0]), p[f"l{i}_wo"])
             else:
                 o = self._experts(p, i, a, valid, counts)
             h = h + o

@@ -1,5 +1,5 @@
-"""The KV page format: what a page pool stores, and the only two functions
-that index one.
+"""The KV page format: what a page pool stores, and the only three functions
+that reach into one.
 
 A cache's attention state is a tuple of device arrays, the *pools*, each
 ``(layers, num_pages, page_size, row width)``: the value pools the block's
@@ -9,6 +9,23 @@ side: ``(k, v)`` raw, ``(k, v, k_scale, k_mid, v_scale, v_mid)`` in int8,
 ``(k, v, k_scale, v_scale)`` in fp8_e4m3, ``(latent,)`` for a
 latent-attention block.  Every program threads the whole tuple through
 and donates it; only :class:`PageFormat` knows which array is what.
+
+**Three doors.**  :meth:`PageFormat.write` stores token rows at ``(page,
+offset)``; :meth:`PageFormat.read` gathers a layer's whole paged context
+through page tables, every RESERVED page of every row of the program,
+dequantized where the format is quantized; :meth:`PageFormat.attend` (raw K
+and V pools only) is one decode step's grouped-query attention over the
+pools where they lie: lowered for the chip, one Pallas kernel
+(``ops.pallas_kernels.paged_attention``) that brings each live row's LIVE
+pages from the pool, once, and nothing for a padded row or a reserved page
+that holds no token yet; lowered for the CPU, ``read`` and the block's own
+attention over the gathered context.  ``WindowMoELM``'s global layers and
+``HybridSSMMoELM``'s attention layers step through ``attend``; their
+prefills' commits through ``write``.  Two blocks stay on ``read``, and share
+nothing with the kernel: ``CausalLM`` (float32 K/V through ``rowdot`` under
+the row-stable and shared-vs-cold bitwise contracts, and int8 / fp8 pools,
+whose dequantization is ``read``'s) and ``LatentMoELM`` (one latent pool
+and the absorbed form's arithmetic: another kernel's).
 
 Three formats, chosen by ``kv_dtype``:
 
@@ -222,7 +239,7 @@ class PageFormat:
     Built by :class:`~mxnet_tpu.serving.decode.kv_cache.PagedKVCache` from
     the block's ``cache_layout()`` and ``kv_dtype``; the commit, step and
     verify programs of a block receive it as ``pages`` and reach the pools
-    only through :meth:`write` and :meth:`read`."""
+    only through :meth:`write`, :meth:`read` and :meth:`attend`."""
 
     def __init__(self, layout, kv_dtype=None, page_size=16):
         import jax.numpy as jnp
@@ -331,3 +348,41 @@ class PageFormat:
                            *(gather(n + j * self._per + s)
                              for s in range(self._per)))
             for j, row in enumerate(self._row_shapes))
+
+    def attend(self, pools, layer, tables, positions, q, plain):
+        """Grouped-query attention of ONE query token a row, ``q (B, g, r,
+        dk)`` float32 at ``positions (B,)``, over the row's keys and values
+        ``0 .. position`` of one layer (the token this step wrote among
+        them: hand over the pools :meth:`write` returned).  Returns the
+        heads' outputs side by side, ``(B, g * r * dv)`` float32.  Raw K and
+        V pools only.
+
+        Where the program is lowered for the chip this is ONE kernel
+        (``ops.pallas_kernels.paged_attention``) that reads the live rows'
+        LIVE pages out of the whole pools where they lie and nothing else:
+        no page past a row's position, nothing of a padded row.  Where it
+        is lowered for the CPU it is :meth:`read` and the block's own
+        attention over the gathered context, ``plain(k, v, mask (B, 1,
+        reserved context)) -> (B, g * r * dv)``: what every test on the CPU
+        and both plain references read.  The counter
+        ``decode.attn.paged.lowered`` (``kind="kernel" | "plain"``) says
+        which was lowered; nothing a caller sets chooses."""
+        import jax.numpy as jnp
+        from ...ops.pallas_kernels import by_platform, paged_attention
+        if self._codec is not None or len(self.pool_layout) != 2:
+            raise ValueError(
+                f"attend reads raw K and V pools, not {self.kv_dtype} pools "
+                f"of {[n for n, _w, _d in self.pool_layout]}")
+
+        def kernel(k_pool, v_pool):
+            return paged_attention(q, k_pool, v_pool, layer, tables,
+                                   positions).reshape(q.shape[0], -1)
+
+        def gathered(k_pool, v_pool):
+            k, v = self.read((k_pool, v_pool), layer, tables)
+            reserved = jnp.arange(tables.shape[1] * self.page_size)
+            return plain(k, v,
+                         (reserved[None, :] <= positions[:, None])[:, None])
+
+        return by_platform("decode.attn.paged.lowered", pools[0], pools[1],
+                           kernel=kernel, plain=gathered, rows=q.shape[0])

@@ -13,7 +13,9 @@ layer's entry in ``layer_pattern`` (the config's ``hybrid_layer_pattern``):
 - ``0`` — **global**: ``num_key_value_heads`` K/V heads, causal softmax
   over the whole context, rotary base ``rope_theta``.  These layers page:
   the cache's K and V pools (rows of different widths) have one layer for
-  each, and a step gathers the row's paged context.
+  each, and a step attends over the row's live pages where they lie
+  (``PageFormat.attend``: one kernel on the chip, gather + :meth:`attend`
+  on the CPU).
 - ``1`` — **window**: ``swa_num_key_value_heads`` K/V heads, token ``i``
   reads ``i - window + 1 .. i``, rotary base ``swa_rope_theta``, and a
   learned **sink bias** a query head that joins the softmax's denominator
@@ -439,10 +441,10 @@ class WindowMoELM(HybridBlock):
         ring and attends over the ring (an entry holds a position of the
         last ``window``, or one that this sequence never wrote: masked); a
         global layer writes into the row's page and attends over the row's
-        paged context.  Padded rows (page table all trash) use the trash
-        slot and are routed to no expert.  Returns ``(logits (B, vocab),
-        pools, (moe_rows (expert layers, held + 1) int32, live rows (1,)
-        int32))``."""
+        paged context through ``pages.attend``.  Padded rows (page table
+        all trash) use the trash slot and are routed to no expert.  Returns
+        ``(logits (B, vocab), pools, (moe_rows (expert layers, held + 1)
+        int32, live rows (1,) int32))``."""
         import jax
         import jax.numpy as jnp
         ptab, srow = pages.addresses(tables)
@@ -451,8 +453,6 @@ class WindowMoELM(HybridBlock):
         wp = jnp.take_along_axis(ptab, (positions // page_size)[:, None],
                                  axis=1)[:, 0]
         woff = positions % page_size
-        lctx = ptab.shape[1] * page_size
-        paged = (jnp.arange(lctx)[None, :] <= positions[:, None])[:, None]
         # ring entry e holds position p - (p - e) mod W: one of the last W
         # where that is not negative
         went = positions % W
@@ -469,12 +469,14 @@ class WindowMoELM(HybridBlock):
                 if kind == WINDOW:
                     pools = pages.state.write_at(pools, n, srow, went, (k, v))
                     ck, cv = pages.state.read(pools, n, srow)
-                    o = self.attend(p, i, q[:, None], ck, cv, ringed)
+                    o = self.attend(p, i, q[:, None], ck, cv, ringed)[:, 0]
                 else:
                     pools = pages.write(pools, n, wp, woff, (k, v))
-                    ck, cv = pages.read(pools, n, ptab)
-                    o = self.attend(p, i, q[:, None], ck, cv, paged)
-                o = _dot(o[:, 0], p[f"l{i}_wo"])
+                    o = pages.attend(
+                        pools, n, ptab, positions, q,
+                        lambda ck, cv, paged: self.attend(
+                            p, i, q[:, None], ck, cv, paged)[:, 0])
+                o = _dot(o, p[f"l{i}_wo"])
             h = self._mlp(p, i, h + o, valid, counts)
         hf = _rms(h, p["norm_f"], self.eps)
         with jax.named_scope("head"):

@@ -116,6 +116,34 @@ def test_flash_backward_limit_is_the_compilers(one_chip):
                     grad=True)
 
 
+@pytest.mark.parametrize("b", [1, 32])
+@pytest.mark.parametrize("g,r,dk,dv,pages,layers,num_pages", [
+    (4, 16, 192, 128, 288, 2, 18433),       # MiMo-V2.5's global layers
+    (2, 16, 128, 128, 96, 6, 6145)])        # Nemotron-3-Nano's attention
+def test_paged_attention_kernel_compiles_for_v5e(one_chip, b, g, r, dk, dv,
+                                                 pages, layers, num_pages):
+    """The decode step's paged-attention kernel at the two cells' shapes,
+    pools as served (bfloat16, pages of 16 tokens, the whole row minor): one
+    ``tpu_custom_call`` whose K and V operands are the pools themselves, no
+    copy, slice or relayout of one before it."""
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    k_pool = sds((layers, num_pages, 16, g * dk), jnp.bfloat16)
+    v_pool = sds((layers, num_pages, 16, g * dv), jnp.bfloat16)
+    compiled = jax.jit(pallas_kernels.paged_attention).lower(
+        sds((b, g, r, dk), jnp.float32), k_pool, v_pool, sds((), jnp.int32),
+        sds((b, pages), jnp.int32), sds((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = re.search(r"custom-call\((.*?)\), custom_call_target", text)
+    operands = re.findall(r"%([\w.\-]+)", call.group(1))
+    params = dict(re.findall(r"%([\w.\-]+) = bf16\[[\d,]+\]\S* "
+                             r"parameter\((\d)\)", text))
+    assert [params.get(o) for o in operands[-2:]] == ["1", "2"], \
+        f"the kernel reads {operands[-2:]}, not the pools {params}"
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20) \
+        + b * g * r * g * dk * 2
+
+
 def _bert_base_step(one_chip, num_layers=12):
     """The training cell's program compiled for the described chip: the
     ``SPMDTrainer`` step of BERT-base (``num_layers`` of its twelve) at
@@ -568,6 +596,17 @@ _SSM_CALL = re.compile(
     r"custom_call_target=\"tpu_custom_call\"(.*)$", re.M)
 
 
+_PAGED_CALL = re.compile(
+    r"%([\w.\-]+) = f32\[\d+,\d+,128\]\S* custom-call\(.*"
+    r"custom_call_target=\"tpu_custom_call\"(.*paged_attention.*)$", re.M)
+
+
+def _paged_calls(hlo_text):
+    """``(name, the rest of its line)`` of every paged-attention kernel
+    (``paged_attention``) in a compiled step."""
+    return [(m.group(1), m.group(2)) for m in _PAGED_CALL.finditer(hlo_text)]
+
+
 def _ssm_calls(hlo_text):
     """``(name, operands, the rest of its line)`` of every recurrence kernel
     (``ssm_step_slots``) in a compiled step, in program order."""
@@ -634,12 +673,24 @@ def test_hybrid_programs_touch_only_their_slots_and_pages(one_chip, kind, b):
     for _name, _operands, rest in calls:
         assert "/ssm.mix/" in rest and "ssm_step_slots" in rest, \
             f"{what}: the kernel left its scope: {rest[:300]}"
-    # the paged context of b rows (2 x 1536 x 256 bf16 a row a layer), the
-    # rows' vectors and weights streamed ahead of their use; ONE row's state
-    # of one layer is 2 MB and does not fit beside them (6.2 MB at b = 1, 7.0
-    # at b = 8 and at b = 32, where the gathering form held 64 MB and more;
-    # sandbox compiles, PR 31)
-    assert stats.temp_size_in_bytes < (7 << 20) + b * (3 << 17), \
+    # the two grouped-query layers attend in ONE kernel each over the pages
+    # where they lie (PR 34): no row's reserved context (1,536 tokens of
+    # 256 keys or values) is gathered, whole or by pages
+    paged = _paged_calls(text)
+    assert len(paged) == 2 and all("/attn.gqa/" in rest
+                                   for _name, rest in paged), \
+        f"{what}: {len(paged)} paged-attention kernels under attn.gqa for " \
+        f"2 grouped-query layers"
+    for op, dtype, dims in _materialised(text):
+        assert _H_ROW_PAGES * _PAGE not in dims and \
+            dims[-3:-1] != (_H_ROW_PAGES, _PAGE), \
+            f"{what}: {op} writes {dtype}{list(dims)}: a reserved context"
+    # the rows' vectors and weights streamed ahead of their use; ONE row's
+    # state of one layer is 2 MB and does not fit beside them, nor does one
+    # row's paged context of one layer, 1.5 MB (6.1 MB at b = 1, 7.7 at b =
+    # 32; sandbox compiles, PR 34.  With the context gathered it was 6.2 and
+    # 7.0 + 0.4 a row, PR 31; with the states gathered too, 64 MB and more)
+    assert stats.temp_size_in_bytes < (7 << 20) + b * (1 << 15), \
         f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
         f"beside {pool_bytes / 1e9:.3f} GB of pools"
 
@@ -693,6 +744,26 @@ def test_hybrid_step_for_the_chip_counts_the_kernel_once_a_mamba_layer(
             tuple(shape), dtype, sharding=one_chip))
     assert counted("ssm.step.path", lambda: fn.lower(*args)) == \
         {'{kind="kernel",rows="32"}': 3}
+
+
+@pytest.mark.parametrize("block", ["hybrid", "window"])
+def test_step_counts_the_paged_kernel_once_a_paged_layer(one_chip, block):
+    """``decode.attn.paged.lowered``: lowering the 32-row step for the
+    described chip counts ``kind="kernel"`` once a layer that pages (two
+    grouped-query layers here, one global layer there) and ``plain`` never;
+    lowering the same program for the CPU, the other way round.  Nothing but
+    the platform differs between the two."""
+    from mxnet_tpu.test_utils import counted
+    rt, program, layers = {
+        "hybrid": (_hybrid_runtime, _hybrid_program, 2),
+        "window": (_window_runtime, _window_program, 1)}[block]
+    for sharding, kind in ((one_chip, "kernel"), (None, "plain")):
+        fn, args, _pools = program(
+            rt(), "step", 32, lambda shape, dtype: jax.ShapeDtypeStruct(
+                tuple(shape), dtype, sharding=sharding))
+        assert counted("decode.attn.paged.lowered",
+                       lambda: fn.lower(*args)) == \
+            {f'{{kind="{kind}",rows="32"}}': layers}
 
 
 # ------------------------------------------- the window / global block's
@@ -787,7 +858,11 @@ def test_window_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
     in.  Of a window layer's K/V a step holds the rings of its ``b`` rows
     (``b x 128`` tokens, gathered by state row) and nothing beyond: no
     array with a window layer's row (8 heads: 1,536 keys, 1,024 values) has
-    more tokens than that, whatever the 4,608 the context allows."""
+    more tokens than that, whatever the 4,608 the context allows.  Of the
+    global layer's K/V a step holds NOTHING (PR 34): one paged-attention
+    kernel under ``attn.global`` reads the pages where they lie, and no
+    array of ``(b, reserved context, 768 | 512)``, whole or by pages, is
+    written."""
     import numpy as np
     rt = _window_runtime()
     fn, args, pools = _window_program(
@@ -806,11 +881,17 @@ def test_window_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
     weights = {tuple(p.shape) for p in args[0]}
     page_layer = int(np.prod(v_pool.shape[1:]))
     window = 128
-    for op, dtype, dims in _materialised(compiled.as_text()):
+    text = compiled.as_text()
+    for op, dtype, dims in _materialised(text):
         if op in ("parameter", "get-tuple-element", "bitcast") or \
                 dims in weights:
             continue
         n = int(np.prod(dims))
+        if kind == "step":
+            assert _W_ROW_PAGES * _PAGE not in dims and \
+                dims[-3:-1] != (_W_ROW_PAGES, _PAGE), \
+                f"{what}: {op} writes {dtype}{list(dims)}: a reserved " \
+                f"context"
         if dtype == "bf16" and n in own:
             assert op != "copy", f"{what}: copies a whole pool {dtype}{dims}"
             continue
@@ -832,10 +913,15 @@ def test_window_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
     if kind == "commit":
         assert stats.temp_size_in_bytes < (8 << 20), what
         return
-    # the paged context of b rows of the ONE global layer here (4,608 x
-    # (768 + 512) bf16 a row, gathered whole: ROADMAP R4 / S2), the b rings
-    # of a window layer, weights streamed ahead of their use
-    # (534 MB at b = 32, 377 of them the gathered context; sandbox compile)
-    assert stats.temp_size_in_bytes < (48 << 20) + b * (17 << 20), \
+    paged = _paged_calls(text)
+    assert len(paged) == 1 and "/attn.global/" in paged[0][1], \
+        f"{what}: {len(paged)} paged-attention kernels under attn.global " \
+        f"for 1 global layer"
+    # the b rings of a window layer (0.66 MB a row), the rows' vectors,
+    # weights streamed ahead of their use: 5.0 MB at b = 1, 17.8 at b = 32
+    # (sandbox compiles, PR 34).  Until PR 34 the paged context of the b rows
+    # was gathered whole (ROADMAP S2, delivered): 534 MB at b = 32, 377 of
+    # them the context (4,608 x (768 + 512) bf16 a row)
+    assert stats.temp_size_in_bytes < (6 << 20) + b * (1 << 19), \
         f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
         f"beside {pool_bytes / 1e9:.3f} GB of pools"
