@@ -587,8 +587,11 @@ class DecodeRuntime:
         next token per row (host int32 array)."""
         b = tokens.shape[0]
         fn = self._step_fn(b)
+        # cpu_ms: in a .dispatch nothing waits for the device, so wall minus
+        # CPU is the time this thread stood off the CPU while launching (the
+        # runtime's blocking, the interpreter's lock)
         with _tel.span("decode.step", model=self.name, batch=b):
-            with _tel.span("decode.step.dispatch"):
+            with _tel.span("decode.step.dispatch", cpu=True):
                 cache = self.cache
                 pools = cache.pools
                 out = fn(

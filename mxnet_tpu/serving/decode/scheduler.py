@@ -556,7 +556,11 @@ class DecodeScheduler:
         joins and step the batch outside it.  The ONE body both the live
         worker and ``close()``'s inline settle run, so the two paths can
         never diverge."""
-        with _tel.span("decode.boundary",
+        # the turn reads this thread's and the process's CPU clocks, once:
+        # cpu_ms over the turn's seconds is the loop's own share of a core,
+        # proc_cpu_ms - cpu_ms what every OTHER thread (the door's writers,
+        # clients in the process, the runtime's own) burned meanwhile
+        with _tel.span("decode.boundary", cpu="process",
                        active=len(self._active)) as boundary:
             # admission, the lock's wait included
             with _tel.span("decode.admit",

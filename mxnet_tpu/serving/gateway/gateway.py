@@ -330,6 +330,20 @@ class Gateway:
 
     # ---------------------------------------------------- POST /v1/generate
     def _route_generate(self, h):
+        if not _tel.enabled:
+            self._handle_generate(h)
+            return
+        # what one request costs the interpreter on its handler thread,
+        # from the wire to the last flush: this thread's CPU time, counted
+        # beside gateway.responses (two clock reads a request)
+        cpu0 = time.thread_time()
+        try:
+            self._handle_generate(h)
+        finally:
+            _tel.count("gateway.handler_cpu_ms",
+                       (time.thread_time() - cpu0) * 1e3, route="generate")
+
+    def _handle_generate(self, h):
         t_wire = time.perf_counter()
         body = self._parse(h)
         if body is None:

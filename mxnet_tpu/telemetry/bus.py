@@ -36,6 +36,11 @@ Design constraints (mirroring ``profiler.h``'s lock-free ring):
   span sits in the ``/host:CPU`` plane of the ``.xplane.pb`` under its own
   name, on the thread that ran it, on the device trace's time axis.  Spans
   recorded after the fact (``record_span``) stay on the bus clock only.
+- **CPU time where a span asks for it.** ``span(name, cpu=True)`` lands the
+  running thread's CPU time in ``cpu_ms``, ``cpu="process"`` the whole
+  process's in ``proc_cpu_ms`` beside it: wall minus CPU is the time the
+  thread stood off the CPU (the decode loop's turn and its step's launch
+  ask; nothing else does, and a span that does not ask reads no CPU clock).
 
 Enable via ``MXNET_TELEMETRY=1`` in the environment (checked at import) or
 ``mxnet_tpu.telemetry.enable()``.
@@ -366,16 +371,21 @@ class Span:
 
     The span is also a profiler annotation (:func:`annotation`) with the
     attrs it was opened with; ones :meth:`set` later reach the bus event
-    only."""
+    only.
 
-    __slots__ = ("name", "attrs", "_t0", "_trace", "_ann")
+    ``cpu`` (see :func:`span`) makes the span read the CPU clocks just
+    outside its two ends and land ``cpu_ms`` (and ``proc_cpu_ms``) in its
+    attrs at exit."""
 
-    def __init__(self, name, attrs):
+    __slots__ = ("name", "attrs", "_t0", "_trace", "_ann", "_cpu")
+
+    def __init__(self, name, attrs, cpu=False):
         self.name = name
         self.attrs = attrs
         self._t0 = None
         self._trace = None
         self._ann = None
+        self._cpu = cpu
 
     def set(self, **attrs):
         """Attach attributes mid-span (shows in the trace event args)."""
@@ -389,6 +399,13 @@ class Span:
             sid = new_id()
             stack.append((parent_trace, sid))
             self._trace = (parent_trace, sid, parent_span)
+        if self._cpu:
+            # a CPU clock is a system call: read before the span opens and
+            # after it has closed, so that neither its duration nor its
+            # annotation holds a read
+            self._cpu = (time.thread_time(),
+                         time.process_time() if self._cpu == "process"
+                         else None)
         self._t0 = time.perf_counter()
         _open_spans[id(self)] = (self.name, self._t0,
                                  threading.get_ident())
@@ -399,6 +416,14 @@ class Span:
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
+        t1 = None
+        if self._cpu:
+            t1 = time.perf_counter()
+            t_cpu, p_cpu = self._cpu
+            self.attrs["cpu_ms"] = (time.thread_time() - t_cpu) * 1e3
+            if p_cpu is not None:
+                self.attrs["proc_cpu_ms"] = \
+                    (time.process_time() - p_cpu) * 1e3
         # the stack pop must happen even if the bus was disabled mid-span,
         # or the thread's context stack would corrupt for every later span
         if self._trace is not None:
@@ -412,17 +437,31 @@ class Span:
             return False
         # attrs as a dict, NOT **kwargs: an attribute named t1/name/t0
         # must stay an attribute, not collide with record_span's params
-        _emit_span(self.name, self._t0, None, self.attrs or None,
+        _emit_span(self.name, self._t0, t1, self.attrs or None,
                    trace=self._trace)
         return False
 
 
-def span(name, **attrs):
+def span(name, cpu=False, **attrs):
     """Start a timed scope: ``with telemetry.span("trainer.step"): ...``.
-    Returns a shared no-op when the bus is disabled."""
+    Returns a shared no-op when the bus is disabled.
+
+    ``cpu`` is a reserved parameter, not an attr.  ``cpu=True`` lands
+    ``cpu_ms`` in the span's attrs at exit: the CPU time of the thread
+    that ran it (``time.thread_time()``, read just before the span opens
+    and just after it has closed: its own ``dur`` holds neither read, and
+    ``cpu_ms`` may pass ``dur`` by a read's tail), so ``dur - cpu_ms`` is
+    the time that thread was off the CPU inside the span: blocked in the
+    runtime, or runnable and waiting for the interpreter's lock.
+    ``cpu="process"`` also lands ``proc_cpu_ms`` (``time.process_time()``:
+    every thread of the process), so ``proc_cpu_ms - cpu_ms`` is what the
+    OTHER threads burned meanwhile.  A read is a system call (6 us on the
+    chip's host, whose CPU clocks tick in 10 ms: one span reads 0 or a
+    tick, sums over many are unbiased), so ask where a metric reads the
+    answer.  Off by default: a span that does not ask reads no CPU clock."""
     if not enabled:
         return _NOOP
-    return Span(name, attrs)
+    return Span(name, attrs, cpu)
 
 
 def open_spans():

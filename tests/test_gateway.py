@@ -372,6 +372,56 @@ def test_healthz_metrics_and_routes_share_the_port(decode_sess):
     assert "gateway:gateway" not in json.loads(raw)["components"]
 
 
+@pytest.mark.parametrize("stream", [False, True])
+def test_handler_cpu_is_counted_beside_the_responses(decode_sess, stream,
+                                                     monkeypatch):
+    """What a request costs the interpreter on its handler thread, from the
+    wire to the last flush: two reads of the thread's CPU clock a request,
+    counted by route beside ``gateway.responses``, buffered or streamed.
+    The clock is a scripted one that moves a millisecond a read, so the
+    count is exact whatever the host's clock can resolve; the count lands
+    behind the last flush, so the client waits for it."""
+    reads = iter(range(10**6))
+    monkeypatch.setattr(time, "thread_time", lambda: next(reads) * 1e-3)
+
+    def counted(more_than):
+        deadline = time.perf_counter() + 10
+        while time.perf_counter() < deadline:
+            snap = telemetry.snapshot()
+            got = snap["counters"].get("gateway.handler_cpu_ms", 0.0)
+            if got > more_than:
+                return got, snap
+            time.sleep(0.005)
+        raise AssertionError("gateway.handler_cpu_ms did not grow")
+
+    telemetry.enable()
+    with Gateway() as gw:
+        gw.add_decode("tiny", decode_sess)
+        seen = 0.0
+        for k in range(2):
+            st, _, _ = _post(gw.port, "/v1/generate",
+                             {"model": "tiny", "prompt": [5, 9, 2 + k],
+                              "max_new_tokens": 6, "stream": stream})
+            assert st == 200
+            got, snap = counted(seen)
+            # the handler's two reads, and whatever the scheduler's spans
+            # read in between
+            assert got - seen >= 1.0
+            assert snap["counters"]["gateway.responses"] == k + 1
+            assert snap["counters_by_label"]["gateway.handler_cpu_ms"] == {
+                '{route="generate"}': got}
+            seen = got
+    telemetry.disable()
+    with Gateway() as gw:           # off: nothing is counted, no clock read
+        gw.add_decode("tiny", decode_sess)
+        at = next(reads)
+        st, _, _ = _post(gw.port, "/v1/generate",
+                         {"model": "tiny", "prompt": [1],
+                          "max_new_tokens": 2})
+        assert st == 200 and next(reads) == at + 1
+    assert telemetry.snapshot()["counters"]["gateway.handler_cpu_ms"] == seen
+
+
 def test_unhealthy_gateway_flips_healthz(decode_sess):
     gw = Gateway()
     try:

@@ -1,12 +1,14 @@
 """Telemetry subsystem: event bus, exporters, instrumentation, profiler
 integration (ISSUE 1 tentpole + satellites)."""
 import json
+import time
 
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
+from mxnet_tpu.telemetry import bus
 
 
 @pytest.fixture(autouse=True)
@@ -579,3 +581,99 @@ def test_span_is_a_profiler_annotation_on_its_own_thread(tmp_path):
     # the bus event keeps everything, on the bus clock
     ev = [e for e in telemetry.trace_events() if e["name"] == "toy.outer"][0]
     assert ev["args"]["late"] == 5 and ev["args"]["rows"] == 3
+
+
+# ------------------------------------------------------- CPU time on a span
+def _burn(seconds):
+    t = time.thread_time()
+    while time.thread_time() - t < seconds:
+        sum(range(1000))
+
+
+@pytest.mark.parametrize("cpu,carries", [
+    (False, set()), (True, {"cpu_ms"}),
+    ("process", {"cpu_ms", "proc_cpu_ms"})])
+def test_span_carries_cpu_time_only_where_asked(cpu, carries):
+    telemetry.enable()
+    with telemetry.span("t.cpu", cpu=cpu, rows=3):
+        _burn(0.02)
+        time.sleep(0.03)
+    (ev,) = [e for e in bus.events() if e[1] == "t.cpu"]
+    attrs = ev[6]
+    assert attrs["rows"] == 3 and "cpu" not in attrs
+    assert {k for k in attrs if k.endswith("cpu_ms")} == carries
+    if carries:
+        # burned 20 ms, slept 30: CPU time is under the wall time
+        assert 15.0 <= attrs["cpu_ms"] <= ev[4] / 1e3 - 20.0
+    if "proc_cpu_ms" in carries:
+        assert attrs["proc_cpu_ms"] >= attrs["cpu_ms"] - 1.0
+
+
+def test_cpu_clock_reads_lie_outside_the_spans_own_duration(monkeypatch):
+    """A CPU clock is a system call (6 us on the chip's host): it is read
+    before the span opens and after it has closed, so a span that asks is
+    no longer than one that does not, and the metrics that read its
+    duration see no read.  Here a read takes 20 ms."""
+    real = time.thread_time
+
+    def slow():
+        time.sleep(0.02)
+        return real()
+
+    monkeypatch.setattr(time, "thread_time", slow)
+    monkeypatch.setattr(time, "process_time", slow)
+    telemetry.enable()
+    with telemetry.span("t.outer"):
+        with telemetry.span("t.cpu", cpu="process"):
+            pass
+    monkeypatch.undo()
+    dur = {e[1]: e[4] / 1e3 for e in bus.events() if e[1].startswith("t.")}
+    assert dur["t.cpu"] < 10.0 and dur["t.outer"] >= 80.0
+
+
+def test_bus_off_reads_no_cpu_clock(monkeypatch):
+    """With the bus off a span that asks for CPU time is the shared no-op
+    before any clock is read, and neither the decode step nor a request
+    through the door reads one: both clocks are patched to raise."""
+    import urllib.request
+    from mxnet_tpu.serving.decode import DecodeRuntime, DecodeSession, \
+        get_decode_model
+    from mxnet_tpu.serving.gateway import Gateway
+    from mxnet_tpu.telemetry import http as thttp
+
+    net = get_decode_model("decode_tiny", vocab_size=61, max_length=32,
+                           units=32, num_heads=2)
+    net.initialize()
+    rt = DecodeRuntime(net, batch_buckets=(1,), seq_buckets=(8,),
+                       page_size=8)
+    width = rt.cache.table_width
+    args = (np.zeros((1,), "int32"), np.zeros((1,), "int32"),
+            np.zeros((1, width), "int32"), np.zeros((1, 2), "uint32"),
+            np.zeros((1,), "int32"), np.zeros((1,), "float32"))
+    rt.step(*args)                                  # compiles, clocks live
+
+    def refuse(*_a):
+        raise AssertionError("a CPU clock was read with the bus off")
+
+    assert not bus.enabled
+    sess = DecodeSession(net, page_size=8, batch_buckets=(1,),
+                         seq_buckets=(8,))
+    gw = Gateway(port=0)
+    gw.add_decode("m", sess)
+    try:
+        monkeypatch.setattr(time, "thread_time", refuse)
+        monkeypatch.setattr(time, "process_time", refuse)
+        assert telemetry.span("t.cpu", cpu="process") is bus._NOOP
+        assert rt.step(*args).shape == (1,)
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{gw.port}/v1/generate",
+            data=json.dumps({"model": "m", "prompt": [5, 9, 2],
+                              "max_new_tokens": 3}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=120) as r:
+            assert len(json.loads(r.read())["token_ids"]) == 3
+    finally:
+        monkeypatch.undo()
+        gw.close()
+        sess.close()
+        thttp.stop_server()

@@ -476,6 +476,33 @@ class TestSchedulerPhases:
             steps = hists["decode.step_ms"]
             assert steps["sum"] >= sum(e[4] for e in calls) / 1e3 - 1e-3
 
+    TURN = {"decode.admit", "decode.prefill.prepare",
+            "decode.prefill.fanout", "decode.step.prepare",
+            "decode.step.fanout", "decode.prefill.dispatch",
+            "decode.prefill.fetch", "decode.step.dispatch",
+            "decode.step.fetch", "decode.boundary"}
+    # a CPU clock is a system call: only the two spans that a metric reads
+    # it from ask
+    CPU = {"decode.boundary", "decode.step.dispatch"}
+
+    @pytest.mark.parametrize("name", sorted(TURN))
+    def test_the_loop_accounts_for_its_cpu(self, served, name):
+        """The turn carries the thread's CPU time and the process's, which
+        holds the thread's; the step's launch carries the thread's (never
+        more than its wall time, beyond the clocks' grain); no other phase
+        of a turn reads a CPU clock."""
+        found = [e for e in served["spans"] if e[1] == name]
+        assert found
+        for e in found:
+            a = _attrs(e)
+            assert ("cpu_ms" in a) == (name in self.CPU)
+            assert ("proc_cpu_ms" in a) == (name == "decode.boundary")
+            if name in self.CPU:
+                assert 0.0 <= a["cpu_ms"] <= e[4] / 1e3 + 1.0, (a, e[4])
+            if name == "decode.boundary":
+                assert a["proc_cpu_ms"] >= a["cpu_ms"] - 1.0
+            assert "leaves" not in a and "host_args" not in a
+
     def test_metrics_exposition_has_one_type_per_family(self, served):
         text = served["metrics"]
         typed = [ln.split()[2] for ln in text.splitlines()
