@@ -542,7 +542,7 @@ class HybridSSMMoELM(HybridBlock):
     sample_math = staticmethod(sample_math)
 
     def record_step_extras(self, extras, model):
-        """Telemetry from one step's counts (as fetched behind the tokens,
+        """Telemetry from one step's counts (the program's vector of them,
         flat): the ``decode.moe.*`` counters :class:`LatentMoELM` emits,
         and ``decode.ssm.layer_steps`` / ``decode.ssm.state_rows`` (Mamba
         layers run, and live rows' states they read and wrote)."""

@@ -439,8 +439,8 @@ class LatentMoELM(HybridBlock):
     sample_math = staticmethod(sample_math)
 
     def record_step_extras(self, extras, model):
-        """Telemetry from one step's ``moe_rows`` (as fetched behind the
-        tokens, flat): the ``decode.moe.*`` counters ``docs/telemetry.md``
+        """Telemetry from one step's ``moe_rows`` (the program's vector of
+        counts, flat): the ``decode.moe.*`` counters ``docs/telemetry.md``
         lists."""
         record_moe_rows(np.asarray(extras).reshape(-1, len(self.held) + 1),
                         model)

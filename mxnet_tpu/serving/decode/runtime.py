@@ -43,7 +43,7 @@ from ..aot import as_program_cache
 from ..runtime import default_buckets, place_block
 from .kv_cache import PagedKVCache
 
-__all__ = ["DecodeRuntime", "seq_bucket_ladder"]
+__all__ = ["DecodeRuntime", "StepFlight", "seq_bucket_ladder"]
 
 
 def seq_bucket_ladder(max_seqlen, min_bucket=8):
@@ -67,6 +67,18 @@ def _state_structs(spec):
     if isinstance(spec[1], str):
         return jax.ShapeDtypeStruct(*spec)
     return tuple(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in spec)
+
+
+class StepFlight:
+    """A launched decode step: ``tokens``, its sampled next token per row,
+    int32 ``(b,)`` and still on the device, and ``counts``, empty or the one
+    vector of the block's counts."""
+
+    __slots__ = ("tokens", "counts")
+
+    def __init__(self, tokens, counts):
+        self.tokens = tokens
+        self.counts = counts
 
 
 class DecodeRuntime:
@@ -94,8 +106,9 @@ class DecodeRuntime:
     (B, cache.table_width)`` holds each row's page table and, for a block
     with per-sequence state, its state row behind it (``pages.addresses``
     splits them; ``pages.state`` reads and writes the rows).  ``extras``
-    are int32 arrays that ride the step's one fetch behind the tokens and
-    are handed to ``block.record_step_extras`` when telemetry is on.  A
+    are int32 arrays that leave the step program as one vector behind the
+    pools; it is fetched, and handed to ``block.record_step_extras``, only
+    when telemetry is on.  A
     verify ladder (``spec_buckets``) also needs ``verify_program(params,
     tokens, positions, n_draft, tables, pools, pages) -> (logits (B, K+1,
     vocab), pools)``.
@@ -233,12 +246,14 @@ class DecodeRuntime:
             self._params = [jax.device_put(p, rep)
                             for p in block.param_leaves()]
             self._replicate = lambda x: jax.device_put(x, rep)
+            self._place = self._replicate
         else:
             # one device for parameters, page pools and every program:
             # committing the pools beside the placed block leaves jit no
             # choice of where to run
             self.device = place_block(block)
             self._params = block.param_leaves()
+            self._place = lambda x: jax.device_put(x, self.device)
             cache.set_pools(jax.device_put(p, self.device)
                             for p in cache.pools)
         self._step_fns = {}       # batch_bucket -> donated jit
@@ -257,7 +272,7 @@ class DecodeRuntime:
                  f":pg{cache.page_size}:np{cache.num_pages}"
                  f":mp{cache.max_pages_per_seq}:sl{cache.max_slots}"
                  f":kv{cache.kv_dtype}:pfx{cache.prefix_sharing}"
-                 f":spec{self.spec_buckets}"
+                 f":spec{self.spec_buckets}:counts-behind-pools"
                  f":pools{[(p.shape, str(p.dtype)) for p in cache.pools]}")
         self._warmed = False
         if warm:
@@ -448,12 +463,14 @@ class DecodeRuntime:
             logits, pools, extras = block.step_program(
                 p, tokens, positions, tables, pools, pages)
             nxt = block.sample_math(logits, keys, steps, temps)
-            # what the host reads is ONE int32 vector: the tokens, then
-            # whatever the block counts (nothing for CausalLM)
-            if extras:
-                nxt = jnp.concatenate(
-                    [nxt] + [e.astype("int32").reshape(-1) for e in extras])
-            return (nxt,) + tuple(pools)
+            # the tokens are an output of their own, int32 (b,): the next
+            # step's ``tokens`` as they are, without a visit to the host.
+            # Whatever the block counts (nothing for CausalLM) is one more
+            # vector behind the pools, fetched only where it is recorded
+            counts = (jnp.concatenate(
+                [e.astype("int32").reshape(-1) for e in extras]),) \
+                if extras else ()
+            return (nxt.astype("int32"),) + tuple(pools) + counts
 
         n = len(self.cache.pools)
         return jax.jit(step, donate_argnums=tuple(range(7, 7 + n)))
@@ -581,36 +598,58 @@ class DecodeRuntime:
                 first = np.asarray(out[0])
         return first, logits_host
 
-    def step(self, tokens, positions, tables, keys, steps, temps):
-        """One decode step for a batch padded to a batch bucket (padded
-        rows: token 0, position 0, all-trash table).  Returns the sampled
-        next token per row (host int32 array)."""
-        b = tokens.shape[0]
-        fn = self._step_fn(b)
+    def launch(self, tokens, positions, tables, keys, steps, temps):
+        """Hand one decode step to the device and return without waiting
+        for it: the first half of :meth:`step`.  The batch is padded to a
+        batch bucket (padded rows: position 0, all-trash table, any token).
+        ``tokens`` is a host array, or the ``tokens`` of the
+        :class:`StepFlight` an earlier launch returned: still a future on
+        the device, it becomes this step's input unread, so a caller may
+        launch step n+1 before it collects step n.  The page pools are
+        donated and replaced by this step's (futures too: whatever is
+        launched next is ordered behind it by the device)."""
+        fn = self._step_fn(tokens.shape[0])
         # cpu_ms: in a .dispatch nothing waits for the device, so wall minus
         # CPU is the time this thread stood off the CPU while launching (the
         # runtime's blocking, the interpreter's lock)
-        with _tel.span("decode.step", model=self.name, batch=b):
-            with _tel.span("decode.step.dispatch", cpu=True):
-                cache = self.cache
-                pools = cache.pools
-                out = fn(
-                    self._params, tokens.astype("int32"),
-                    positions.astype("int32"), tables.astype("int32"),
-                    keys.astype("uint32"), steps.astype("int32"),
-                    temps.astype("float32"), *pools)
-                if _san.donation:
-                    # the step donated the page pools (see prefill above)
-                    _san.poison(list(pools), "decode.step")
-                cache.set_pools(out[1:])
-            with _tel.span("decode.step.fetch"):
-                nxt = np.asarray(out[0])
-        if nxt.shape[0] > b:
-            # the block's counts rode the same fetch, behind the tokens
-            if _tel.enabled:
-                self._block.record_step_extras(nxt[b:], self.name)
-            nxt = nxt[:b]
+        with _tel.span("decode.step.dispatch", cpu=True):
+            if isinstance(tokens, np.ndarray):
+                # placed like a step's own result, so that a program has
+                # ONE signature whichever side its tokens come from
+                tokens = self._place(tokens.astype("int32"))
+            cache = self.cache
+            pools = cache.pools
+            n = len(pools)
+            out = fn(
+                self._params, tokens,
+                positions.astype("int32"), tables.astype("int32"),
+                keys.astype("uint32"), steps.astype("int32"),
+                temps.astype("float32"), *pools)
+            if _san.donation:
+                # the step donated the page pools (see prefill above)
+                _san.poison(list(pools), "decode.step")
+            cache.set_pools(out[1:1 + n])
+        return StepFlight(out[0], out[1 + n:])
+
+    def collect(self, flight):
+        """Wait for a launched step and return its sampled next token per
+        row (host int32 array): the second half of :meth:`step`.  A
+        program's failure surfaces here."""
+        with _tel.span("decode.step.fetch"):
+            nxt = np.asarray(flight.tokens)
+        if flight.counts and _tel.enabled:
+            # the block's counts: the program that made the tokens made them
+            self._block.record_step_extras(np.asarray(flight.counts[0]),
+                                           self.name)
         return nxt
+
+    def step(self, tokens, positions, tables, keys, steps, temps):
+        """One decode step, launched and collected: returns the sampled
+        next token per row (host int32 array)."""
+        with _tel.span("decode.step", model=self.name,
+                       batch=tokens.shape[0]):
+            return self.collect(self.launch(tokens, positions, tables, keys,
+                                            steps, temps))
 
     def verify(self, tokens, positions, n_draft, tables, keys, steps,
                temps):

@@ -25,9 +25,10 @@ batch composition — so the same request returns bitwise-identical tokens
 solo or inside any continuous batch (tested, and the property that makes
 "replay this request" a debugging tool).
 
-Fault sites: ``decode.step`` fires inside the per-step try (an injected
-fault fails that step's active requests and frees their slots — the
-mid-decode crash drill), ``decode.kv_alloc`` inside the cache allocator.
+Fault sites: ``decode.step`` fires inside the per-step try, before a step's
+launch (an injected fault fails the active requests, the rows of the step
+still in flight among them, and frees their slots — the mid-decode crash
+drill), ``decode.kv_alloc`` inside the cache allocator.
 """
 from __future__ import annotations
 
@@ -233,6 +234,18 @@ class _Request:
         self.spec_state = None
 
 
+class _Flying:
+    """The plain step in flight: the runtime's ``step`` (a ``StepFlight``)
+    and its ``rows``, the request in each place of the batch (None: a place
+    nobody rides)."""
+
+    __slots__ = ("step", "rows")
+
+    def __init__(self, step, rows):
+        self.step = step
+        self.rows = rows
+
+
 class DecodeScheduler:
     """Worker thread running the continuous decode loop for one
     :class:`DecodeRuntime` (see module docstring for the contract).
@@ -295,6 +308,7 @@ class DecodeScheduler:
         self._started = False
         self._worker = None
         self._active = []                 # worker-thread-owned
+        self._flying = None               # the plain step in flight, or None
         self.steps_failed = 0
         self.worker_restarts = 0
         if breaker_threshold is not None and int(breaker_threshold) < 1:
@@ -534,9 +548,9 @@ class DecodeScheduler:
         everything under it) carry ``parent_id`` and self times add up."""
         with _trace.use(_trace.start() if _tel.enabled else None):
             with self._lock:
-                if not self._queue and not self._active:
+                if not self._queue and not self._running():
                     with _tel.span("decode.idle"):
-                        while not self._queue and not self._active:
+                        while not self._queue and not self._running():
                             if self._closed:
                                 return False
                             self._not_empty.wait()
@@ -545,11 +559,16 @@ class DecodeScheduler:
                     return False
             self._boundary()
             with self._lock:
-                if self._closed and not self._active and \
+                if self._closed and not self._running() and \
                         (not self._drain or not self._queue):
                     self._shed_queue_locked("shutdown")
                     return False
         return True
+
+    def _running(self):
+        """Whether the batch still asks for a turn: a request holds a slot,
+        or a step is in flight (its last rows may have left already)."""
+        return bool(self._active) or self._flying is not None
 
     def _boundary(self):
         """One step boundary — admit under the lock, then prefill the
@@ -576,8 +595,11 @@ class DecodeScheduler:
             boundary.set(joining=len(joining))
             try:
                 if joining:
+                    # behind the step in flight, which is collected after
+                    # it: a joining prompt's launch hides behind that step
+                    # as a decode step's does
                     self._prefill(joining)
-                if self._active:
+                if self._running():
                     self._step()
             except BaseException as e:
                 self._fail_active(e, joining)
@@ -614,6 +636,7 @@ class DecodeScheduler:
             if req.sink is not None:
                 req.sink._fail(exc)
         self._active = []
+        self._flying = None
 
     def _shed_queue_locked(self, reason):
         while self._queue:
@@ -813,63 +836,151 @@ class DecodeScheduler:
         self._consecutive_failures = 0
 
     def _step(self):
-        """One decode step over the active batch, padded to a batch
-        bucket.  Injectable mid-decode crash: ``decode.step``.
+        """The active batch's turn at the device.  Injectable mid-decode
+        crash: ``decode.step``.
+
+        **A pipeline of depth one over consecutive plain steps.**  While
+        the rows of the next step are the rows of the step in flight
+        (:meth:`_rows_ahead`), step n+1 is launched BEFORE step n's tokens
+        are collected: everything but the tokens is known to the host
+        (positions, sampling indices, page tables: every page was reserved
+        at admission), and the tokens go from one program to the next on
+        the device.  The launch and this thread's wake then run behind the
+        chip's work and not between its steps.  A row that reaches its
+        ``max_new`` is known ahead and is simply not in step n+1 (its place
+        rides as a padded row).  A row that ends by ``eos_id`` is known one
+        step late: it rides step n+1 on its own reserved page, that token
+        is dropped, and its slot is freed behind that launch (the device
+        orders every later writer behind it).  Where the rows change in a
+        way the device's tokens cannot follow (a join, whose first token is
+        on the host; a smaller program that would do; a row that
+        speculates), the step in flight is collected and fanned out first
+        (:meth:`_land`) and this step is built from the host's tokens.
 
         With a drafter bound, boundaries where at least one active row
         produced a draft ride the fused verify program instead
-        (:meth:`_spec_step`) — non-speculating rows ride along with
-        ``n_draft = 0``, which is bitwise the plain step for them."""
+        (:meth:`_spec_step`), never ahead of anything: a row's accepted
+        count decides its next input.  Non-speculating rows ride along
+        with ``n_draft = 0``, which is bitwise the plain step for them."""
         rt, cache = self._runtime, self._cache
-        n = len(self._active)
-        b = rt.batch_bucket_for(n)
+        rows = self._rows_ahead()
+        if rows is None:
+            self._land()
+            rows = list(self._active)
+            if not rows:
+                return
+        flying = self._flying
+        live = [req for req in rows if req is not None]
+        n = len(live)
+        b = rt.batch_bucket_for(len(rows))
         with _tel.span("decode.step.prepare", rows=n, batch_bucket=b):
             if _faults.active:
                 _faults.check("decode.step")
             if _san.slots:
-                for req in self._active:
+                for req in live:
                     cache.check_slot(req.slot)
-            drafts = self._collect_drafts()
+            drafts = self._collect_drafts() if flying is None else None
             if drafts is None:
-                args = self._step_args(b)
+                args = self._step_args(
+                    rows, b, None if flying is None else flying.step.tokens)
         if drafts is not None:
             self._spec_step(drafts)
             return
         _flight.record("decode.step", detail=rt.name, value=n)
         t0 = time.perf_counter()
-        nxt = rt.step(*args)
-        t1 = time.perf_counter()
-        with _tel.span("decode.step.fanout", rows=n, batch_bucket=b):
+        # the runtime's call of one turn, under the one span its readers
+        # know: the launch of this step, the collect of the one before it
+        with _tel.span("decode.step", model=rt.name, batch=b):
+            self._flying = _Flying(rt.launch(*args), rows)
+            if flying is not None and _tel.enabled:
+                _tel.count("decode.steps_ahead", model=rt.name)
+            # a launched step is taken: whatever is built next starts from it
+            for req in live:
+                req.position += 1
+                req.step_idx += 1
+            nxt = None if flying is None else rt.collect(flying.step)
+        if flying is not None:
+            self._fanout(flying, nxt, t0, time.perf_counter())
+
+    def _rows_ahead(self):
+        """The rows of a step that may be launched behind the one in
+        flight, in that step's places (None: a place whose row has all its
+        steps launched, or left), or None where there is no such step:
+        nothing is in flight, none of its rows goes on, a request that needs
+        a step is not among them (a join: its token is on the host), one of
+        them speculates, or the rows left fit a smaller program."""
+        flying = self._flying
+        if flying is None:
+            return None
+        rows = [req if req is not None and req.slot is not None
+                and req.step_idx < req.max_new else None
+                for req in flying.rows]
+        n = len(rows) - rows.count(None)
+        if not n or any(req is not None and req.spec for req in rows):
+            return None
+        if n != sum(req.step_idx < req.max_new for req in self._active):
+            return None
+        if self._runtime.batch_bucket_for(n) != flying.step.tokens.shape[0]:
+            return None
+        return rows
+
+    def _land(self):
+        """Collect the step in flight, if there is one, and fan its tokens
+        out: what makes the turn that follows a synchronous one."""
+        flying, rt = self._flying, self._runtime
+        if flying is None:
+            return
+        t0 = time.perf_counter()
+        with _tel.span("decode.step", model=rt.name,
+                       batch=flying.step.tokens.shape[0]):
+            nxt = rt.collect(flying.step)
+        self._flying = None
+        self._fanout(flying, nxt, t0, time.perf_counter())
+
+    def _fanout(self, flying, nxt, t0, t1):
+        """One collected step's tokens to their requests.  A row that left
+        while the step was in flight (it had ended by ``eos_id`` the step
+        before, or was aborted) has no slot any more: its token is
+        dropped."""
+        rt = self._runtime
+        rows = [(r, req) for r, req in enumerate(flying.rows)
+                if req is not None and req.slot is not None]
+        n = len(rows)
+        with _tel.span("decode.step.fanout", rows=n,
+                       batch_bucket=flying.step.tokens.shape[0]):
             if _tel.enabled:
                 _tel.count("decode.steps", model=rt.name)
                 _tel.count("decode.tokens", n, model=rt.name)
                 _tel.observe("decode.step_ms", (t1 - t0) * 1e3)
-                for req in self._active:
+                for _r, req in rows:
                     if req.ctx is not None:
                         # every step the request rode, on its own lane —
                         # "which steps served me" is visible per request
                         _tel.record_span("decode.ride_step", t0, t1,
                                          tid=req.lane, trace=req.ctx,
                                          model=rt.name, batch=n)
-            still = []
-            for r, req in enumerate(self._active):
+            finished = False
+            for r, req in rows:
                 req.cur = int(nxt[r])
                 req.tokens.append(req.cur)
                 if req.sink is not None:
                     req.sink._put(req.cur)
-                req.position += 1
-                req.step_idx += 1
                 if self._is_finished(req):
                     self._finish(req)
-                else:
-                    still.append(req)
-            self._active = still
+                    finished = True
+            if finished:
+                self._active = [req for req in self._active
+                                if req.slot is not None]
         self._consecutive_failures = 0
 
-    def _step_args(self, b):
-        """The plain step's host arrays for the active batch, padded to
-        batch bucket ``b``, behind the copy-on-write fence."""
+    def _step_args(self, rows, b, tokens=None):
+        """The plain step's host arrays for ``rows`` (None: a place nobody
+        rides, padded like the places behind the batch), padded to batch
+        bucket ``b``, behind the copy-on-write fence.  ``tokens``: the
+        device's tokens of the step in flight, handed on unread; without
+        them each row's last token, from the host."""
         cache = self._cache
+        live = [(r, req) for r, req in enumerate(rows) if req is not None]
         if cache.prefix_sharing:
             # copy-on-write fence: the page each row is about to write
             # must be exclusively owned.  Admission already privatized
@@ -877,17 +988,19 @@ class DecodeScheduler:
             # prompt), so this is two refcount reads per row — but it is
             # the guard that makes "a shared page is never scribbled on"
             # an invariant instead of an accident.
-            for req in self._active:
+            for _r, req in live:
                 cache.ensure_writable(req.slot,
                                       req.position // cache.page_size)
-        tokens = np.zeros((b,), "int32")
+        if tokens is None:
+            tokens = np.zeros((b,), "int32")
+            for r, req in live:
+                tokens[r] = req.cur
         positions = np.zeros((b,), "int32")
         tables = np.zeros((b, cache.table_width), "int32")
         keys = np.zeros((b, 2), "uint32")
         steps = np.zeros((b,), "int32")
         temps = np.zeros((b,), "float32")
-        for r, req in enumerate(self._active):
-            tokens[r] = req.cur
+        for r, req in live:
             positions[r] = req.position
             tables[r] = req.slot.page_table
             keys[r] = req.key
@@ -1089,9 +1202,12 @@ class DecodeScheduler:
     def _fail_active(self, exc, joining=()):
         """A prefill/step crash fails the requests that were in flight —
         their slots are freed, the worker survives, the breaker advances
-        (consecutive failures open it).  ``joining`` covers requests
-        admitted this boundary whose prefill never completed (they are
-        not in the active list yet)."""
+        (consecutive failures open it).  A step's own failure surfaces
+        where it is collected, a turn after its launch, with the next step
+        already behind it: the rows of both are the active batch, failed
+        here once, and what is in flight is dropped with them.
+        ``joining`` covers requests admitted this boundary whose prefill
+        never completed (they are not in the active list yet)."""
         self.steps_failed += 1
         _flight.record("decode.step_failure",
                        detail=f"{self._runtime.name}: {exc!r}")
@@ -1113,6 +1229,7 @@ class DecodeScheduler:
             if req.sink is not None:
                 req.sink._fail(exc)
         self._active = []
+        self._flying = None
         if self._breaker_threshold is None:
             return
         self._consecutive_failures += 1
@@ -1147,7 +1264,7 @@ class DecodeScheduler:
         if drain:
             while True:
                 with self._lock:
-                    if not self._queue and not self._active:
+                    if not self._queue and not self._running():
                         break
                 self._boundary()
         else:

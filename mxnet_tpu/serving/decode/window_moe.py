@@ -513,7 +513,7 @@ class WindowMoELM(HybridBlock):
             * sum(self._widths(WINDOW)) * jnp.dtype(self.dtype).itemsize
 
     def record_step_extras(self, extras, model):
-        """Telemetry from one step's counts (as fetched behind the tokens,
+        """Telemetry from one step's counts (the program's vector of them,
         flat): the ``decode.moe.*`` counters of the shared expert layer,
         ``decode.window.layer_steps`` / ``decode.window.ring_rows`` (window
         layers run, and live rows' rings they wrote and read), and the

@@ -533,6 +533,65 @@ def test_pool_programs_touch_only_their_pages(one_chip, kind, b, kv_dtype,
         f"beside {pool_bytes / 1e9:.3f} GB of pools"
 
 
+def test_a_scripted_run_compiles_nothing_after_warm():
+    """The run-time half of "one step program a batch bucket" (ISSUE 36): a
+    step takes its tokens from the host in a synchronous turn and from the
+    step before it, still on the device, in a turn launched ahead.  After
+    ``warm()`` a scripted run with joins and finishes across two buckets
+    counts no ``decode.compile_miss`` and no compile of the backend, and
+    each bucket's program has ONE executable for both sides.  (Runs on the
+    CPU: what is guarded is the program's signature, not a kernel.)"""
+    import numpy as np
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.serving.decode import (DecodeRuntime, DecodeScheduler,
+                                          get_decode_model)
+    net = get_decode_model("decode_tiny", vocab_size=61, max_length=32,
+                           units=32, num_heads=2)
+    net.initialize()
+    rt = DecodeRuntime(net, batch_buckets=(2, 4), seq_buckets=(8,),
+                       page_size=8, prefix_sharing=False)
+    compiles, sides = [], set()
+    launch = rt.launch
+
+    def on(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    def seen(tokens, *rest):
+        sides.add((tokens.shape[0], isinstance(tokens, np.ndarray)))
+        return launch(tokens, *rest)
+
+    rt.launch = seen
+    telemetry.enable()
+    telemetry.reset()
+    jax.monitoring.register_event_duration_secs_listener(on)
+    s = DecodeScheduler(rt, start=False)
+    try:
+        futs = [s.submit([3 + i, 7, 11], max_new_tokens=9, seed=i)
+                for i in range(2)]
+        for _ in range(3):
+            s._boundary()
+        futs += [s.submit([5 + i, 2], max_new_tokens=3, seed=9 + i)
+                 for i in range(2)]                 # two join: four rows
+        for _ in range(40):
+            if not s._running():
+                break
+            s._boundary()                           # two finish: two rows
+        assert [len(f.result(0).token_ids) for f in futs] == [9, 9, 3, 3]
+        counters = telemetry.snapshot()["counters"]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+        telemetry.disable()
+        telemetry.reset()
+        s.close(drain=False, timeout=10.0)
+    assert sides == {(b, host) for b in (2, 4) for host in (True, False)}
+    assert not counters.get("decode.compile_miss") and not compiles
+    assert counters["decode.steps_ahead"] >= 4
+    assert sorted(rt._step_fns) == [2, 4]
+    assert [fn._cache_size() for fn in rt._step_fns.values()] == [1, 1]
+
+
+
 # nemotron3_nano_ep8's serving geometry (PERF.md section 4): per Mamba layer
 # and slot a recurrent state of 64 x 64 x 128 float32 values (2 MB) and a
 # convolution tail of 3 x 6144 bfloat16, 32 slots and the trash row; K/V rows
