@@ -54,7 +54,9 @@ each LIVE row's state crosses memory once each way: nothing for a padded
 row or an idle slot, where XLA's forms pass two to three times over every
 slot of the layer.  float32 on the vector unit, no product on the MXU.
 ``by_platform`` is how its caller gets the kernel on the chip and the
-definition on the CPU, and a count of which was built.
+definition on the CPU, and a count of which was built.  ``kda_step_slots``
+is the same kernel for the gated delta rule's matrix state (``ops.
+delta_rule``: a decay a key channel, a rank-one correction, 64 KB a head).
 
 Third resident kernel: **the decode step's grouped-query attention over the
 rows' pages where they lie** (``paged_attention``): the cache's K and V page
@@ -79,8 +81,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "ssm_step_slots", "paged_attention",
-           "by_platform"]
+__all__ = ["flash_attention", "ssm_step_slots", "kda_step_slots",
+           "paged_attention", "by_platform"]
 
 _NEG = -1e30
 
@@ -602,6 +604,19 @@ def _ssm_slots_kernel(layer_ref, named_ref, rows_ref, decay_ref, s_ref,
         o_ref[...] = s_ref[...]
 
 
+def _rows_named(rows):
+    """The state row each grid step of a slots kernel NAMES, from the batch
+    rows' state rows ``rows (b,)`` (0: a padded row).  The pipeline fetches
+    a block when a step names another than the step before it, and writes
+    one back when the step after it names another; so a padded row names
+    what the nearest live row before it names (the first live row, for
+    padded rows in front of it) and costs an empty grid step.  With no live
+    row every step names row 0, the trash row."""
+    live = rows != 0
+    before = jax.lax.cummax(jnp.where(live, jnp.arange(rows.shape[0]), -1))
+    return rows[jnp.where(before >= 0, before, jnp.argmax(live))]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _ssm_slots_call(pool, layer, rows, decay, dtx, B, C, *, interpret):
     """``pallas_call`` on the grid (batch rows,) with the layer and the
@@ -625,9 +640,7 @@ def _ssm_slots_call(pool, layer, rows, decay, dtx, B, C, *, interpret):
     row, which is copied through."""
     b, p, heads = dtx.shape
     groups, n = B.shape[1:]
-    live = rows != 0
-    before = jax.lax.cummax(jnp.where(live, jnp.arange(b), -1))
-    named = rows[jnp.where(before >= 0, before, jnp.argmax(live))]
+    named = _rows_named(rows)
 
     state = pl.BlockSpec((1, 1, heads, p, n),
                          lambda i, layer, named, rows:
@@ -678,6 +691,106 @@ def ssm_step_slots(pool, layer, rows, x, dt, A, B, C, D, *, interpret=False):
         jnp.swapaxes(dt[..., None] * x, 1, 2), B.astype(jnp.float32),
         C.astype(jnp.float32), interpret=interpret)
     return pool, jnp.swapaxes(y, 1, 2) + D[:, None] * x
+
+
+# --------------------------------------------------------------------------
+# the same for the gated delta rule: one token of the recurrence on the
+# rows' matrix states where they lie in the cache's state pool
+
+
+def _kda_slots_kernel(layer_ref, named_ref, rows_ref, beta_ref, s_ref,
+                      a_ref, k_ref, q_ref, v_ref, o_ref, y_ref, *, heads):
+    """One batch row's whole ``(H, dk, dv)`` state of the layer a grid
+    step, a head at a time on the vector unit with ``dk`` on the sublanes
+    and ``dv`` on the lanes: ``S <- a S``, ``u = beta (v - S^T k)``, ``S <-
+    S + k u^T``, ``y = S^T q``.  The decay ``a``, ``k`` and ``q`` come with
+    ``dk`` on the sublanes (``(dk, H)``, a head a lane) so that a head's
+    column broadcasts along the lanes; ``v`` and ``y`` are rows of lanes
+    (``(H, dv)``) and a reduction over the sublanes lands in one.  A padded
+    row (state row 0) does nothing, as in ``_ssm_slots_kernel``."""
+    del layer_ref
+    i = pl.program_id(0)
+
+    @pl.when(rows_ref[i] != 0)
+    def _():
+        for h in range(heads):
+            k = k_ref[0, :, h:h + 1]
+            state = a_ref[0, :, h:h + 1] * s_ref[0, 0, h]
+            u = beta_ref[i * heads + h] * (
+                v_ref[0, h:h + 1, :]
+                - jnp.sum(state * k, axis=0, keepdims=True))
+            state = state + k * u
+            o_ref[0, 0, h] = state
+            y_ref[0, h:h + 1, :] = jnp.sum(state * q_ref[0, :, h:h + 1],
+                                           axis=0, keepdims=True)
+
+    @pl.when(rows_ref[i] == 0)
+    def _():
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    # no live row at all (a warm-up drive): every step names the trash row,
+    # and what is written back at the end is what was read
+    @pl.when(jnp.logical_and(named_ref[0] == 0, i == 0))
+    def _():
+        o_ref[...] = s_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kda_slots_call(pool, layer, rows, beta, decay, k, q, v, *, interpret):
+    """``pallas_call`` on the grid (batch rows,), as ``_ssm_slots_call``:
+    the layer and the rows' state rows are prefetched scalars, the WHOLE
+    pool is aliased to its output, and the block is a row's state of one
+    layer in whole heads (64 KB a head, 4 MB at Solar-Open2's 64 heads of
+    128 x 128; in, out and their second buffers are four blocks).  One
+    jitted function of its arrays, the layer among them: every KDA layer of
+    every step program lowers this once."""
+    b, dk, heads = k.shape
+    dv = v.shape[-1]
+    state = pl.BlockSpec((1, 1, heads, dk, dv),
+                         lambda i, layer, named, rows:
+                         (layer[0], named[i], 0, 0, 0))
+    by_row = lambda i, *_: (i, 0, 0)
+    column, lanes = pl.BlockSpec((1, dk, heads), by_row), \
+        pl.BlockSpec((1, heads, dv), by_row)
+    return pl.pallas_call(
+        functools.partial(_kda_slots_kernel, heads=heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), state, column,
+                      column, column, lanes],
+            out_specs=(state, lanes)),
+        out_shape=(jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct(v.shape, jnp.float32)),
+        # operand 4, counting the prefetched scalars: the pool
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=8 * heads * dk * dv * 4 + (8 << 20)),
+        interpret=interpret, name="kda_step_slots",
+    )(layer, _rows_named(rows), rows, beta, pool, decay, k, q, v)
+
+
+def kda_step_slots(pool, layer, rows, q, k, v, g, beta, *, interpret=False):
+    """One token of the gated delta rule (``ops.delta_rule.
+    delta_rule_step``, which is its definition) for batch rows whose states
+    lie in a state pool ``pool (layers, state rows, H, dk, dv)`` float32,
+    at ``pool[layer, rows[i]]``: returns ``(pool, o (b, H, dv))`` with those
+    rows' states advanced where they lie.  ``q``, ``k``, ``g (b, H, dk)``
+    (``g <= 0`` the log-decay a key channel), ``v (b, H, dv)``, ``beta (b,
+    H)``; ``layer`` a scalar (traced: one kernel for every layer), ``rows
+    (b,)`` int32, distinct but for 0, which says a padded row.
+
+    Each live row's state crosses memory once each way; a padded row,
+    wherever it stands in the batch, moves nothing and its ``o`` is zeros;
+    state row 0 (the trash row), every state row no batch row names and
+    every other layer keep their bits.  All float32 on the vector unit."""
+    f32 = lambda x: x.astype(jnp.float32)
+    column = lambda x: jnp.swapaxes(f32(x), 1, 2)
+    return _kda_slots_call(
+        pool, jnp.asarray(layer, jnp.int32).reshape(1),
+        rows.astype(jnp.int32), f32(beta).reshape(-1),
+        column(jnp.exp(f32(g))), column(k), column(q), f32(v),
+        interpret=interpret)
 
 
 # --------------------------------------------------------------------------

@@ -33,7 +33,8 @@ from ..analysis import divergence as _div
 from ..analysis import sanitizer as _san
 
 __all__ = ["moe_layer", "switch_moe_local", "group_limited_topk",
-           "routed_expert_share"]
+           "routed_expert_share",
+           "routed_expert_share_by_expert"]
 
 
 def switch_moe_local(expert_fn, params, x, axis_name, capacity):
@@ -234,3 +235,47 @@ def routed_expert_share(x, router_w, w_gate, w_up, w_down, held, *,
                         out * weights.reshape(-1)[order][:, None], 0.0)
         y = jnp.zeros((T, D), jnp.float32).at[token].add(out)
     return y, rows, n_assign
+
+
+def routed_expert_share_by_expert(x, router_w, w_gate, w_up, w_down, held, *,
+                                  top_k, n_group=1, topk_group=1, scale=1.0,
+                                  valid=None, select_bias=None):
+    """:func:`routed_expert_share`'s contract and answer for FEW rows (a
+    decode step's batch): the same routing, and the products as ONE DENSE
+    CHAIN AN EXPERT over all ``T`` rows, ``(silu(x Wg) * (x Wu)) Wd`` times
+    the expert's weight for each row (0 where the row did not choose it),
+    under a conditional that skips an expert no row chose, so that its
+    weights are not read.  At a step's few rows the chip's grouped product
+    does not stream (it read a third of the bandwidth at 11 rows and 4
+    experts hit a layer, ``PERF.md``, PR 37) where a plain product by
+    expert does; at a prefill's hundreds of rows every expert is hit and
+    the grouped products, which multiply no row by an expert it did not
+    choose, are the form to use."""
+    G = len(held)
+    with jax.named_scope("moe.route"):
+        local, weights, n_assign = route_to_held(
+            x, router_w, held, top_k=top_k, n_group=n_group,
+            topk_group=topk_group, scale=scale, valid=valid,
+            select_bias=select_bias)
+        rows = rows_received(local.reshape(-1), G)
+        # (T, G): each held expert's weight for each row
+        coef = ((local[:, :, None] == jnp.arange(G, dtype=jnp.int32))
+                * weights[:, :, None]).sum(1)
+
+    def dot(a, w):
+        return jnp.dot(a.astype(w.dtype), w,
+                       preferred_element_type=jnp.float32,
+                       precision=lax.Precision.HIGHEST
+                       if w.dtype == jnp.float32 else None)
+
+    def one(g):
+        hidden = jax.nn.silu(dot(x, w_gate[g])) * dot(x, w_up[g])
+        return dot(hidden, w_down[g]) * coef[:, g:g + 1]
+
+    with jax.named_scope("moe.experts"):
+        y = jnp.zeros((x.shape[0], w_down.shape[-1]), jnp.float32)
+        for g in range(G):
+            y = y + lax.cond(rows[g] > 0, lambda g=g: one(g),
+                             lambda: jnp.zeros_like(y))
+    return y, rows, n_assign
+

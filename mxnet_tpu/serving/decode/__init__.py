@@ -35,6 +35,15 @@ discipline (PAPER.md design point #2) to that loop:
   through a selection bias as a chip's share.  Prefill goes by query blocks
   and holds no ``(heads, S, S)`` array.  No drafter, no quantized pools, no
   mesh; prefix sharing is skipped.
+- :class:`LinearMoELM` (``linear_moe.py``) — the ``solar_open2``
+  family's block: gated delta-rule ("KDA") linear-attention layers
+  (``mxnet_tpu.ops.delta_rule``: a chunked form with a triangular solve in
+  prefill, one rank-one update a row in the step) whose ``(heads, dk, dv)``
+  matrix state and convolution tails live a SLOT in the cache's state
+  pools, three to each NoPE grouped-query layer with an output gate, which
+  alone pages; routed + shared SwiGLU experts in every layer as a chip's
+  share.  No drafter, no quantized pools, no mesh; prefix sharing is
+  skipped.
 - :class:`PagedKVCache` (``kv_cache.py``) — device-resident page pools
   with a trash page for padding, generation-stamped slots (the ShmRing
   discipline: a post-free read raises ``StaleKVSlotError`` under
@@ -100,6 +109,7 @@ from .model import (  # noqa: F401
 from .latent_moe import LatentMoELM  # noqa: F401
 from .hybrid_moe import HybridSSMMoELM  # noqa: F401
 from .window_moe import WindowMoELM  # noqa: F401
+from .linear_moe import LinearMoELM  # noqa: F401
 from .runtime import DecodeRuntime, seq_bucket_ladder  # noqa: F401
 from .scheduler import (  # noqa: F401
     DecodeScheduler,
@@ -114,6 +124,7 @@ from .speculate import (  # noqa: F401
 )
 
 __all__ = ["CausalLM", "LatentMoELM", "HybridSSMMoELM", "WindowMoELM",
+           "LinearMoELM",
            "get_decode_model", "rowdot",
            "sample_math",
            "kv_quantize_rows", "kv_dequantize",

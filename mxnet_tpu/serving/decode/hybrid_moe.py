@@ -54,7 +54,8 @@ import numpy as np
 from ...gluon.block import HybridBlock
 from ...ndarray import NDArray, invoke_fn
 from ...telemetry import bus as _tel
-from .latent_moe import _dot, _einsum, _rms, record_moe_rows
+from .latent_moe import (_dot, _einsum, _rms, moe_rows_of,
+                         record_moe_rows)
 from .model import commit_destinations, sample_math
 
 __all__ = ["HybridSSMMoELM"]
@@ -103,6 +104,23 @@ def routed_relu2_share(x, router_w, w_up, w_down, held, *, top_k, n_group=1,
             y = y + lax.cond(rows[g] > 0, lambda g=g: one(g),
                              lambda: jnp.zeros_like(y))
     return y, rows, n_assign
+
+
+def gqa_heads(q, k, v, mask, kv_heads, head_dim, dtype):
+    """Grouped-query attention of ``q (B, Q, g, r, D)`` float32 over ``k``,
+    ``v (B, L, g * D)`` (stored precision) where ``mask (B, Q, L)`` allows,
+    at scale ``D^-0.5``, products in ``dtype`` and the softmax float32: the
+    heads' outputs side by side, ``(B, Q, g * r * D)`` float32."""
+    import jax
+    import jax.numpy as jnp
+    B, L, _ = k.shape
+    k = k.reshape(B, L, kv_heads, head_dim)
+    v = v.reshape(B, L, kv_heads, head_dim)
+    s = _einsum("bqgrd,blgd->bgrql", q, k, dtype) * head_dim ** -0.5
+    s = jnp.where(mask[:, None, None], s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1)
+    o = _einsum("bgrql,blgd->bqgrd", pr, v, dtype)
+    return o.reshape(o.shape[:2] + (-1,))
 
 
 class HybridSSMMoELM(HybridBlock):
@@ -417,17 +435,8 @@ class HybridSSMMoELM(HybridBlock):
     def attend_heads(self, q, k, v, mask):
         """:meth:`attend` before the output projection: the heads' outputs
         side by side, ``(B, Q, heads * D)`` float32."""
-        import jax
-        import jax.numpy as jnp
-        B, L, _ = k.shape
-        dt = self.dtype
-        k = k.reshape(B, L, self.kv_heads, self.head_dim)
-        v = v.reshape(B, L, self.kv_heads, self.head_dim)
-        s = _einsum("bqgrd,blgd->bgrql", q, k, dt) * self.head_dim ** -0.5
-        s = jnp.where(mask[:, None, None], s, -1e30)
-        pr = jax.nn.softmax(s, axis=-1)
-        o = _einsum("bgrql,blgd->bqgrd", pr, v, dt)
-        return o.reshape(o.shape[:2] + (-1,))
+        return gqa_heads(q, k, v, mask, self.kv_heads, self.head_dim,
+                         self.dtype)
 
     def _experts(self, p, i, m, valid, counts):
         """Routed share + shared expert of flat rows ``m (T, U)``."""
@@ -516,10 +525,8 @@ class HybridSSMMoELM(HybridBlock):
         hf = _rms(h, p["norm_f"], self.eps)
         with jax.named_scope("head"):
             logits = _dot(hf, p["head"])
-        moe_rows = jnp.stack([jnp.concatenate([r, n[None]])
-                              for r, n in counts]) if counts \
-            else jnp.zeros((0, len(self.held) + 1), jnp.int32)
-        return logits, pools, (moe_rows, valid.sum().astype(jnp.int32)[None])
+        return logits, pools, (moe_rows_of(counts, len(self.held)),
+                               valid.sum().astype(jnp.int32)[None])
 
     def commit_program(self, state, lengths, tables, pools, pages):
         """Store a prefill's ``(k_rows, v_rows, ssm, conv)``: the K/V rows

@@ -19,8 +19,9 @@ pools where they lie: lowered for the chip, one Pallas kernel
 (``ops.pallas_kernels.paged_attention``) that brings each live row's LIVE
 pages from the pool, once, and nothing for a padded row or a reserved page
 that holds no token yet; lowered for the CPU, ``read`` and the block's own
-attention over the gathered context.  ``WindowMoELM``'s global layers and
-``HybridSSMMoELM``'s attention layers step through ``attend``; their
+attention over the gathered context.  ``WindowMoELM``'s global layers,
+``HybridSSMMoELM``'s attention layers and ``LinearMoELM``'s gated
+grouped-query layers step through ``attend``; their
 prefills' commits through ``write``.  Two blocks stay on ``read``, and share
 nothing with the kernel: ``CausalLM`` (float32 K/V through ``rowdot`` under
 the row-stable and shared-vs-cold bitwise contracts, and int8 / fp8 pools,
@@ -202,8 +203,8 @@ class SlotState:
         """Hand the WHOLE pool of the state array ``name`` to ``fn(pool,
         layer, rows) -> (pool, out)``, a kernel that finds ``rows``' state
         of ``layer`` where it lies and gives the pool back in the buffer it
-        came in (``ops.pallas_kernels.ssm_step_slots``); returns ``(pools,
-        out)``.  Never a slice: ``pool[layer]`` handed to a custom call is a
+        came in (``ops.pallas_kernels.ssm_step_slots``, ``kda_step_slots``);
+        returns ``(pools, out)``.  Never a slice: ``pool[layer]`` handed to a custom call is a
         copy of every slot's state in, and another out."""
         (j,) = self._which((name,))
         pools = list(pools)

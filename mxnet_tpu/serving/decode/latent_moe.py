@@ -129,6 +129,16 @@ def record_moe_rows(rows, model):
     _tel.count("decode.moe.max_expert_rows", int(held.max()), model=model)
 
 
+def moe_rows_of(counts, G):
+    """A step program's ``moe_rows (expert layers, G + 1) int32`` from its
+    ``counts``, one ``(rows each of the G held experts received,
+    assignments made over all experts)`` an expert layer."""
+    import jax.numpy as jnp
+    if not counts:
+        return jnp.zeros((0, G + 1), jnp.int32)
+    return jnp.stack([jnp.concatenate([r, n[None]]) for r, n in counts])
+
+
 class LatentMoELM(HybridBlock):
     """Decoder-only transformer with latent attention and routed + shared
     experts; see the module docstring.  ``forward(tokens (B, S), lengths
@@ -422,10 +432,7 @@ class LatentMoELM(HybridBlock):
         hf = _rms(h, p["norm_f"], self.eps)
         with jax.named_scope("head"):
             logits = _dot(hf, p["head"])
-        moe_rows = jnp.stack([jnp.concatenate([r, n[None]])
-                              for r, n in counts]) if counts \
-            else jnp.zeros((0, len(self.held) + 1), jnp.int32)
-        return logits, pools, (moe_rows,)
+        return logits, pools, (moe_rows_of(counts, len(self.held)),)
 
     def commit_program(self, rows, lengths, tables, pools, pages):
         """Store the prefill's ``rows (layers, B, S, pool_width)`` in the

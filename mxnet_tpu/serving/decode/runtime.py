@@ -89,8 +89,9 @@ class DecodeRuntime:
     **What a block is to the runtime** (:class:`~mxnet_tpu.serving.decode.
     model.CausalLM`, :class:`~mxnet_tpu.serving.decode.latent_moe.
     LatentMoELM`, :class:`~mxnet_tpu.serving.decode.hybrid_moe.
-    HybridSSMMoELM` and :class:`~mxnet_tpu.serving.decode.window_moe.
-    WindowMoELM` are): a hybridizable block whose forward is the
+    HybridSSMMoELM`, :class:`~mxnet_tpu.serving.decode.window_moe.
+    WindowMoELM` and :class:`~mxnet_tpu.serving.decode.linear_moe.
+    LinearMoELM` are): a hybridizable block whose forward is the
     prefill ``(tokens (B, S), lengths (B,)) -> (last_logits, state)``
     (``state`` one array, or several behind the logits), with
     ``vocab_size``, ``param_leaves()`` / ``_params_dict(leaves)``,

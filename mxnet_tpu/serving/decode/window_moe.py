@@ -63,7 +63,8 @@ import numpy as np
 from ...gluon.block import HybridBlock
 from ...ndarray import NDArray, invoke_fn
 from ...telemetry import bus as _tel
-from .latent_moe import _dot, _einsum, _rms, _swiglu, record_moe_rows
+from .latent_moe import (_dot, _einsum, _rms, _swiglu, moe_rows_of,
+                         record_moe_rows)
 from .model import commit_destinations, sample_math
 
 __all__ = ["WindowMoELM"]
@@ -481,10 +482,8 @@ class WindowMoELM(HybridBlock):
         hf = _rms(h, p["norm_f"], self.eps)
         with jax.named_scope("head"):
             logits = _dot(hf, p["head"])
-        moe_rows = jnp.stack([jnp.concatenate([r, n[None]])
-                              for r, n in counts]) if counts \
-            else jnp.zeros((0, len(self.held) + 1), jnp.int32)
-        return logits, pools, (moe_rows, valid.sum().astype(jnp.int32)[None])
+        return logits, pools, (moe_rows_of(counts, len(self.held)),
+                               valid.sum().astype(jnp.int32)[None])
 
     def commit_program(self, state, lengths, tables, pools, pages):
         """Store a prefill's ``(k_rows, v_rows, ring_k, ring_v)``: the

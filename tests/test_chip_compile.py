@@ -984,3 +984,191 @@ def test_window_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
     assert stats.temp_size_in_bytes < (6 << 20) + b * (1 << 19), \
         f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
         f"beside {pool_bytes / 1e9:.3f} GB of pools"
+
+
+# ------------------------------------- the linear-attention / gated GQA block's
+# the cell's geometry (solar_open2_ep16.reason_long): 10,241 pages, 320 a row
+# (5,120 tokens), 33 state rows a KDA layer, the longest prefill bucket
+_K_PAGES, _K_ROW_PAGES, _K_SLOTS, _K_SEQ = 10241, 320, 32, 2048
+
+
+@pytest.mark.parametrize("b", [1, 32])
+def test_kda_step_kernel_compiles_for_v5e(one_chip, b):
+    """``kda_step_slots`` at Solar-Open2's widths (64 heads of 128 x 128, 4
+    MB a row's state of one layer, four such blocks in the core's memory)
+    over the cell's state pool of six layers and 33 state rows: one Mosaic
+    call that gives the pool back in the buffer it came in."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    pool = sds((6, _K_SLOTS + 1, 64, 128, 128), "float32")
+    row = sds((b, 64, 128), "float32")
+    fn = jax.jit(lambda pool, rows, q, k, v, g, beta:
+                 pallas_kernels.kda_step_slots(pool, 3, rows, q, k, v, g,
+                                               beta), donate_argnums=0)
+    compiled = fn.lower(pool, sds((b,), "int32"), row, row, row, row,
+                        sds((b, 64), "float32")).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "kda_step_slots" in text
+    assert "output_to_operand_aliasing={{0}: (4, {})}" in text
+    # nothing the size of a row's state beside the pool: the kernel moves
+    # states between the pool and the core's own memory
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@functools.lru_cache(maxsize=1)
+def _linear_runtime():
+    """Solar-Open2's mixers at every published width (KDA: 64 heads of 128,
+    4 taps, gates of rank 128; grouped-query: 64 queries over 8 K/V heads
+    of 128 with the output gate) in one grouped-query layer and two KDA
+    layers, each with its expert sublayer at width 1280; two held experts
+    and a small vocabulary, which are not what is asked about."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.serving.decode import (DecodeRuntime, LinearMoELM,
+                                          PagedKVCache)
+    net = LinearMoELM(
+        vocab_size=512, hidden_size=4096, num_layers=3, gqa_layers=(0,),
+        num_attention_heads=64, num_key_value_heads=8, head_dim=128,
+        kda_num_heads=64, kda_head_dim=128, conv_kernel=4, gate_rank=128,
+        chunk_size=64, moe_intermediate_size=1280, n_routed_experts=320,
+        held_experts=(0, 1), num_experts_per_tok=8,
+        max_length=_K_ROW_PAGES * _PAGE)
+    for p in net.collect_params().values():
+        p._load_init(mx.nd.zeros(p.shape, dtype=p.dtype), None)
+    cache = PagedKVCache(layout=net.cache_layout(), page_size=_PAGE,
+                         num_pages=2, max_pages_per_seq=_K_ROW_PAGES,
+                         max_slots=1)
+    return DecodeRuntime(net, cache=cache, batch_buckets=(1,),
+                         seq_buckets=(_K_SEQ,), warm=False)
+
+
+def _linear_program(rt, kind, b, sds):
+    """``(jitted program, its arguments with the pools last, the pools)``;
+    the pools at the cell's size."""
+    i32, u32, f32 = "int32", "uint32", "float32"
+    blk, n_paged = rt.block, len(rt.cache.pool_layout)
+    pools = tuple(
+        sds(p.shape[:1] + ((_K_PAGES,) if j < n_paged else (_K_SLOTS + 1,))
+            + p.shape[2:], p.dtype) for j, p in enumerate(rt.cache.pools))
+    params = [sds(p.shape, p.dtype) for p in rt._params]
+    if kind == "prefill":
+        fn = jax.jit(lambda leaves, tok, ln: blk.prefill_math(
+            blk._params_dict(leaves), tok, ln))
+        return fn, (params, sds((b, _K_SEQ), i32), sds((b,), i32)), pools
+    rows = (sds((b, _K_ROW_PAGES + 1), i32), sds((b, 2), u32),
+            sds((b,), i32), sds((b,), f32))     # tables, keys, steps, temps
+    if kind == "step":
+        return rt._build_step(), \
+            (params, sds((b,), i32), sds((b,), i32)) + rows + pools, pools
+    state = tuple(sds(shape, dtype)
+                  for shape, dtype in blk.prefill_state(b, _K_SEQ))
+    return rt._build_commit(), \
+        (params, state, sds((b, blk.vocab_size), f32), sds((b,), i32)) \
+        + rows + pools, pools
+
+
+_KDA_CALL = re.compile(
+    r"%([\w.\-]+) = \(f32\[2,33,64,128,128\]\S*, f32\[\d+,64,128\]\S*\) "
+    r"custom-call\(.*custom_call_target=\"tpu_custom_call\"(.*)$", re.M)
+
+
+def test_linear_prefill_holds_no_heads_by_s_by_s_array(one_chip):
+    """A prompt of 2,048: the float32 scores of the grouped-query layer as a
+    ``(heads, S, S)`` array would be 64 x 2,048^2 x 4 B = 1.07 GB, and the
+    in-chunk decay of ONE KDA layer as ``(heads, chunks, C, C, dk)`` 2.1 GB.
+    The program goes by query blocks of 256 (scores of 64 x 256 x 2,048,
+    134 MB) and forms the in-chunk matrices by sub-chunks: its largest array
+    is the three convolutions' input side by side (2,048 x 24,576 float32,
+    201 MB), no array is a quarter of either, and the recurrence is XLA's
+    (no kernel in prefill)."""
+    import numpy as np
+    rt = _linear_runtime()
+    fn, args, _pools = _linear_program(
+        rt, "prefill", 1, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    compiled = fn.lower(*args).compile()
+    heads, S = 64, _K_SEQ
+    text = compiled.as_text()
+    for op, dtype, dims in _materialised(text):
+        assert int(np.prod(dims)) < heads * S * S // 4, \
+            f"linear prefill: {op} writes {dtype}{list(dims)}"
+        assert len(dims) < 3 or sum(d == S for d in dims) < 2, \
+            f"linear prefill: {op} writes {dtype}{list(dims)}: S x S"
+    assert not _KDA_CALL.search(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        heads * S * S * 4
+
+
+@pytest.mark.parametrize("kind,b", [("step", 1), ("step", 32),
+                                    ("commit", 1)])
+def test_linear_programs_touch_only_their_slots_and_pages(one_chip, kind, b):
+    """The matrix-state pool (6 x 33 x 4.19 MB at the cell's depth; two KDA
+    layers here) is held to what the page pools are: no step or commit
+    program copies a pool or holds a temporary the size of one layer of the
+    state pool, and each gives every pool back in the buffer it came in.
+    Every step program, whatever its batch, advances the recurrence in ONE
+    kernel a KDA layer (``kda_step_slots``, under ``kda.recur``) and
+    attends in ONE paged-attention kernel under ``attn.gqa``: the program's
+    temporaries hold no row's state and no row's context."""
+    import numpy as np
+    from mxnet_tpu.test_utils import counted
+    rt = _linear_runtime()
+    fn, args, pools = _linear_program(
+        rt, kind, b, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    k_pool, _v, kda_pool, conv_pool = pools
+    assert kda_pool.shape == (2, 33, 64, 128, 128) and \
+        kda_pool.dtype == jnp.float32
+    assert conv_pool.shape == (2, 33, 576, 128) and \
+        k_pool.shape == (1, _K_PAGES, _PAGE, 1024)
+    compiled = None
+
+    def lower():
+        nonlocal compiled
+        compiled = fn.lower(*args).compile()
+
+    lowered = counted("kda.step.path", lower)
+    what = f"linear {kind}-b{b}"
+    own = {int(np.prod(p.shape)) for p in pools}
+    weights = {tuple(p.shape) for p in args[0]}
+    layer = int(np.prod(kda_pool.shape[1:]))
+    text = compiled.as_text()
+    for op, dtype, dims in _materialised(text):
+        if op in ("parameter", "get-tuple-element", "bitcast") or \
+                dims in weights:
+            continue
+        n = int(np.prod(dims))
+        if n in own:
+            assert op != "copy", f"{what}: copies a whole pool {dtype}{dims}"
+            continue
+        assert n < layer, \
+            f"{what}: {op} writes {dtype}{list(dims)}, a layer of the " \
+            f"state pool or more"
+        if kind == "step":
+            assert _K_ROW_PAGES * _PAGE not in dims and \
+                dims[-3:-1] != (_K_ROW_PAGES, _PAGE), \
+                f"{what}: {op} writes {dtype}{list(dims)}: a reserved " \
+                f"context"
+    stats = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    assert stats.alias_size_in_bytes >= pool_bytes, what
+    calls = [m.group(2) for m in _KDA_CALL.finditer(text)]
+    if kind == "commit":
+        assert not calls and not lowered, what
+        return
+    assert lowered == {f'{{kind="kernel",rows="{b}"}}': 2}, what
+    assert len(calls) == 2, f"{what}: {len(calls)} recurrence kernels for " \
+        f"2 KDA layers"
+    for rest in calls:
+        assert "/kda.recur/" in rest and "kda_step_slots" in rest, \
+            f"{what}: the kernel left its scope: {rest[:300]}"
+        assert "output_to_operand_aliasing={{0}: (4, {})}" in rest, what
+    paged = _paged_calls(text)
+    assert len(paged) == 1 and "/attn.gqa/" in paged[0][1], \
+        f"{what}: {len(paged)} paged-attention kernels under attn.gqa for " \
+        f"1 grouped-query layer"
+    # the rows' vectors (three of 8192 a KDA layer, the tails' 147 KB a
+    # row), weights streamed ahead of their use; ONE row's state of one
+    # layer is 4.19 MB and a row's reserved context 10.5 MB
+    assert stats.temp_size_in_bytes < (24 << 20) + b * (1 << 20), \
+        f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
+        f"beside {pool_bytes / 1e9:.3f} GB of pools"
