@@ -68,6 +68,13 @@ token yet, where the gathering form copies every reserved page of every row
 of the program, and the compiler copies them once more.  Products in the
 pools' dtype on the MXU, float32 accumulation and softmax.
 ``serving.decode.kv_format.PageFormat.attend`` is its one caller.
+
+Fourth resident kernel: **the same walk over a latent-attention block's ONE
+pool** (``paged_latent_attention``), whose row is keys and values at once
+and shared by every head: a live page crosses memory once and the block in
+VMEM is both operands of the absorbed form (scores ``q . block^T``, context
+``p . block``), under a softmax scale the caller gives.  A body and a jitted
+call of its own behind the same door; ``paged_attention``'s are untouched.
 """
 from __future__ import annotations
 
@@ -82,7 +89,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "ssm_step_slots", "kda_step_slots",
-           "paged_attention", "by_platform"]
+           "paged_attention", "paged_latent_attention", "by_platform"]
 
 _NEG = -1e30
 
@@ -965,6 +972,141 @@ def paged_attention(q, k_pool, v_pool, layer, tables, positions, *,
                       q, k_pool, v_pool, groups=g, scale=dk ** -0.5,
                       block_pages=block_pages, interpret=interpret)
     return out.reshape(b, g, r, -1)
+
+
+# --------------------------------------------------------------------------
+# fourth resident kernel: the same walk over ONE pool whose rows are keys
+# and values at once (a latent-attention block's)
+
+
+def _paged_latent_kernel(layer_ref, tables_ref, pos_ref, q_ref, x_hbm, o_ref,
+                         x_buf, sems, *, pages_a_row, block_pages, scale):
+    """``_paged_kernel``'s walk (one batch row a grid step, the row's live
+    pages in blocks of ``block_pages``, a page a DMA into one of two blocks
+    of VMEM, a padded row asks for none and gives zeros) over one pool: a
+    page is fetched ONCE and the block in VMEM is both operands, the scores
+    ``q . block^T`` of every head and the context ``p . block``."""
+    b = pl.program_id(0)
+    layer, first = layer_ref[0], b * pages_a_row
+    block = x_buf.shape[1]
+    page = block // block_pages
+    tokens = pos_ref[b] + 1
+    precision = jax.lax.Precision.HIGHEST if x_buf.dtype == jnp.float32 \
+        else None
+
+    def pages_of(blk):
+        # the pages of block ``blk`` that hold a token of the row
+        return jnp.clip(pl.cdiv(tokens - blk * block, page), 0, block_pages)
+
+    def rows_of(j):
+        return pl.ds(pl.multiple_of(j * page, page), page)
+
+    def each_page(blk, slot, what):
+        def one(j, _):
+            at = tables_ref[first + blk * block_pages + j]
+            what(pltpu.make_async_copy(x_hbm.at[layer, at],
+                                       x_buf.at[slot, rows_of(j)],
+                                       sems.at[slot]))
+        jax.lax.fori_loop(0, pages_of(blk), one, None)
+
+    def attend(blk, carry):
+        m, l, acc = carry
+        slot = blk % 2
+
+        @pl.when(blk + 1 < pl.cdiv(tokens, block))
+        def _():
+            each_page(blk + 1, 1 - slot, lambda c: c.start())
+
+        each_page(blk, slot, lambda c: c.wait())
+
+        # a page of the block that was not asked for holds whatever the
+        # buffer held before: its scores are masked, and as values it is
+        # made zeros, which a probability of zero leaves zeros
+        def forget(j, _):
+            x_buf[slot, rows_of(j)] = jnp.zeros((page, x_buf.shape[2]),
+                                                x_buf.dtype)
+        jax.lax.fori_loop(pages_of(blk), block_pages, forget, None)
+        x = x_buf[slot]
+        s = jax.lax.dot_general(q_ref[0], x, _NT, precision=precision,
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(blk * block + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block), 1) < tokens, s, _NEG)
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - new_m)
+        corr = jnp.exp(m - new_m)
+        px = jnp.dot(p.astype(x.dtype), x, precision=precision,
+                     preferred_element_type=jnp.float32)
+        return (new_m, l * corr + jnp.sum(p, axis=-1, keepdims=True),
+                acc * corr + px)
+
+    @pl.when(tables_ref[first] != 0)
+    def _():
+        each_page(0, 0, lambda c: c.start())
+        heads = q_ref.shape[1]
+        _m, l, acc = jax.lax.fori_loop(
+            0, pl.cdiv(tokens, block), attend,
+            (jnp.full((heads, 1), _NEG, jnp.float32),
+             jnp.zeros((heads, 1), jnp.float32),
+             jnp.zeros((heads, x_buf.shape[2]), jnp.float32)))
+        o_ref[0] = acc / l
+
+    @pl.when(tables_ref[first] == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_pages",
+                                             "interpret"))
+def _paged_latent_call(layer, tables, positions, q, pool, *, scale,
+                       block_pages, interpret):
+    """``_paged_call`` for one pool: the same grid, prefetched scalars and
+    whole pool left where it is, one jitted function of its arrays."""
+    b, heads, width = q.shape
+    page = pool.shape[2]
+    by_row = lambda i, *_: (i, 0, 0)
+    return pl.pallas_call(
+        functools.partial(_paged_latent_kernel, pages_a_row=tables.shape[1],
+                          block_pages=block_pages, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec((1, heads, width), by_row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, heads, width), by_row),
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages * page, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((b, heads, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="paged_latent_attention",
+    )(layer, tables.reshape(-1), positions, q, pool)
+
+
+def paged_latent_attention(q, pool, layer, tables, positions, *, scale,
+                           block_pages=None, interpret=False):
+    """Attention of ONE query token a row, ``q (b, heads, width)`` float32
+    at ``positions (b,)``, over the row's tokens ``0 .. position`` where
+    they lie in ONE page pool ``(layers, pages, page size, width)`` whose
+    rows are keys AND values, shared by every head (a latent-attention
+    block's ``(c_kv | k_r | 0...)``, the query folded into that space):
+    ``softmax(scale * q . rows^T) . rows``, ``(b, heads, width)`` float32,
+    of which the caller keeps the columns that are values.  ``scale`` is the
+    caller's (YaRN's, not a head width's); ``layer``, ``tables`` and the
+    padded row as in :func:`paged_attention`.
+
+    Only the pages that hold a token of a live row cross memory, ONCE for
+    both products; the pool is read where it is and keeps its bits.
+    Products take operands in the pool's dtype and accumulate in float32
+    (the probabilities are cast before the context product); the softmax is
+    float32, online over blocks of ``block_pages`` pages (32 pages of 16
+    tokens of 640 bfloat16 values are 655 KB of VMEM a block, of two)."""
+    page = pool.shape[2]
+    if block_pages is None:
+        block_pages = max(1, min(tables.shape[1], 512 // page))
+    return _paged_latent_call(
+        jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
+        positions.astype(jnp.int32), q.astype(pool.dtype), pool,
+        scale=float(scale), block_pages=block_pages, interpret=interpret)
 
 
 # --------------------------------------------------------------------------

@@ -13,20 +13,22 @@ and donates it; only :class:`PageFormat` knows which array is what.
 **Three doors.**  :meth:`PageFormat.write` stores token rows at ``(page,
 offset)``; :meth:`PageFormat.read` gathers a layer's whole paged context
 through page tables, every RESERVED page of every row of the program,
-dequantized where the format is quantized; :meth:`PageFormat.attend` (raw K
-and V pools only) is one decode step's grouped-query attention over the
-pools where they lie: lowered for the chip, one Pallas kernel
-(``ops.pallas_kernels.paged_attention``) that brings each live row's LIVE
-pages from the pool, once, and nothing for a padded row or a reserved page
-that holds no token yet; lowered for the CPU, ``read`` and the block's own
-attention over the gathered context.  ``WindowMoELM``'s global layers,
-``HybridSSMMoELM``'s attention layers and ``LinearMoELM``'s gated
-grouped-query layers step through ``attend``; their
-prefills' commits through ``write``.  Two blocks stay on ``read``, and share
-nothing with the kernel: ``CausalLM`` (float32 K/V through ``rowdot`` under
-the row-stable and shared-vs-cold bitwise contracts, and int8 / fp8 pools,
-whose dequantization is ``read``'s) and ``LatentMoELM`` (one latent pool
-and the absorbed form's arithmetic: another kernel's).
+dequantized where the format is quantized; :meth:`PageFormat.attend` (raw
+pools only) is one decode step's attention of one query token a row over the
+pools where they lie: lowered for the chip, one Pallas kernel that brings
+each live row's LIVE pages from the pool, once, and nothing for a padded row
+or a reserved page that holds no token yet; lowered for the CPU, ``read``
+and the block's own attention over the gathered context.  Which kernel is
+what the format observes of its pools: K and V pools are grouped-query
+attention (``ops.pallas_kernels.paged_attention``), ONE pool is a latent
+row that is keys and values at once (``paged_latent_attention``).
+``WindowMoELM``'s global layers, ``HybridSSMMoELM``'s attention layers,
+``LinearMoELM``'s gated grouped-query layers and every layer of
+``LatentMoELM`` step through ``attend``; their prefills' commits through
+``write``.  One block stays on ``read``, and shares nothing with the
+kernels: ``CausalLM`` (float32 K/V through ``rowdot`` under the row-stable
+and shared-vs-cold bitwise contracts, and int8 / fp8 pools, whose
+dequantization is ``read``'s).
 
 Three formats, chosen by ``kv_dtype``:
 
@@ -350,40 +352,58 @@ class PageFormat:
                              for s in range(self._per)))
             for j, row in enumerate(self._row_shapes))
 
-    def attend(self, pools, layer, tables, positions, q, plain):
-        """Grouped-query attention of ONE query token a row, ``q (B, g, r,
-        dk)`` float32 at ``positions (B,)``, over the row's keys and values
-        ``0 .. position`` of one layer (the token this step wrote among
-        them: hand over the pools :meth:`write` returned).  Returns the
-        heads' outputs side by side, ``(B, g * r * dv)`` float32.  Raw K and
-        V pools only.
+    def attend(self, pools, layer, tables, positions, q, plain, scale=None):
+        """Attention of ONE query token a row at ``positions (B,)`` over the
+        row's tokens ``0 .. position`` of one layer (the token this step
+        wrote among them: hand over the pools :meth:`write` returned).  Raw
+        pools only, and what they are decides the form:
+
+        - **K and V pools**: grouped-query attention, ``q (B, g, r, dk)``
+          float32, the scale ``dk ** -0.5``; returns the heads' outputs side
+          by side, ``(B, g * r * dv)`` float32.  ``plain(k, v, mask)``.
+        - **one pool** (a latent row, keys and values at once and shared by
+          every head): ``q (B, heads, row width)`` float32, folded into the
+          row's space by the block, the softmax ``scale`` the block's to
+          give; returns the context ``(B, heads, row width)`` float32, of
+          which the block keeps the columns that are values.  ``plain(rows,
+          mask)``.
 
         Where the program is lowered for the chip this is ONE kernel
-        (``ops.pallas_kernels.paged_attention``) that reads the live rows'
-        LIVE pages out of the whole pools where they lie and nothing else:
-        no page past a row's position, nothing of a padded row.  Where it
-        is lowered for the CPU it is :meth:`read` and the block's own
-        attention over the gathered context, ``plain(k, v, mask (B, 1,
-        reserved context)) -> (B, g * r * dv)``: what every test on the CPU
-        and both plain references read.  The counter
+        (``ops.pallas_kernels.paged_attention`` / ``paged_latent_attention``)
+        that reads the live rows' LIVE pages out of the whole pools where
+        they lie and nothing else: no page past a row's position, nothing
+        of a padded row.  Where it is lowered for the CPU it is :meth:`read`
+        and the block's own attention over the gathered context,
+        ``plain(*gathered, mask (B, 1, reserved context))``: what every test
+        on the CPU and the plain references read.  The counter
         ``decode.attn.paged.lowered`` (``kind="kernel" | "plain"``) says
         which was lowered; nothing a caller sets chooses."""
         import jax.numpy as jnp
-        from ...ops.pallas_kernels import by_platform, paged_attention
-        if self._codec is not None or len(self.pool_layout) != 2:
+        from ...ops.pallas_kernels import (by_platform, paged_attention,
+                                           paged_latent_attention)
+        n = len(self.pool_layout)
+        if self._codec is not None or n not in (1, 2):
             raise ValueError(
-                f"attend reads raw K and V pools, not {self.kv_dtype} pools "
-                f"of {[n for n, _w, _d in self.pool_layout]}")
+                f"attend reads raw K and V pools or one raw pool that is "
+                f"both, not {self.kv_dtype} pools of "
+                f"{[name for name, _w, _d in self.pool_layout]}")
+        if (scale is None) != (n == 2):
+            raise ValueError(
+                "the softmax scale is the key width's over K and V pools "
+                "and the block's to give over one pool")
 
-        def kernel(k_pool, v_pool):
-            return paged_attention(q, k_pool, v_pool, layer, tables,
-                                   positions).reshape(q.shape[0], -1)
+        def kernel(*value_pools):
+            if n == 2:
+                return paged_attention(q, *value_pools, layer, tables,
+                                       positions).reshape(q.shape[0], -1)
+            return paged_latent_attention(q, *value_pools, layer, tables,
+                                          positions, scale=scale)
 
-        def gathered(k_pool, v_pool):
-            k, v = self.read((k_pool, v_pool), layer, tables)
+        def gathered(*value_pools):
+            context = self.read(value_pools, layer, tables)
             reserved = jnp.arange(tables.shape[1] * self.page_size)
-            return plain(k, v,
+            return plain(*context,
                          (reserved[None, :] <= positions[:, None])[:, None])
 
-        return by_platform("decode.attn.paged.lowered", pools[0], pools[1],
+        return by_platform("decode.attn.paged.lowered", *pools[:n],
                            kernel=kernel, plain=gathered, rows=q.shape[0])

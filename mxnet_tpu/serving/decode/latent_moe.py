@@ -37,6 +37,7 @@ No drafter and no quantized latent pool: asking for either raises.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
@@ -357,26 +358,46 @@ class LatentMoELM(HybridBlock):
         o = _einsum("bhqk,bkhd->bqhd", pr, v, dt).reshape(B, S, -1)
         return _dot(o, p[f"l{i}_wo"]), rows
 
+    def _fold(self, p, i, a, positions):
+        """One query per row ``a (B, U)`` in the latent rows' own space,
+        ``(B, H, pool_width)`` float32: ``W_kvb``'s key half folded into the
+        unrotated part, the rotated part beside it, zeros over the row's
+        padding."""
+        import jax.numpy as jnp
+        q_nope, q_rope = self._queries(p, i, a, positions)
+        q_lat = _einsum("bhd,chd->bhc", q_nope,
+                        self._wkvb(p, i)[..., :self.nope_dim], self.dtype)
+        pad = jnp.zeros(q_rope.shape[:-1]
+                        + (self.pool_width - self.row_width,), q_rope.dtype)
+        return jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+
+    def context_absorbed(self, q, rows, mask):
+        """The folded queries' attention over latent ``rows (B, L,
+        pool_width)``, keys and values at once (``mask (B, 1, L)`` marks the
+        live ones): ``(B, H, pool_width)`` float32.  What
+        ``PageFormat.attend`` computes over the pool's pages."""
+        import jax
+        import jax.numpy as jnp
+        s = _einsum("bhk,blk->bhl", q, rows, self.dtype) * self._scale
+        pr = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+        return _einsum("bhl,blk->bhk", pr, rows, self.dtype)
+
+    def _unfold(self, p, i, ctx):
+        """The attention output ``(B, U)`` of a context ``(B, H,
+        pool_width)``: ``W_kvb``'s value half applied to its ``c_kv``
+        columns, then ``W_o``."""
+        o = _einsum("bhc,chd->bhd", ctx[..., :self.kv_lora_rank],
+                    self._wkvb(p, i)[..., self.nope_dim:], self.dtype)
+        return _dot(o.reshape(o.shape[0], -1), p[f"l{i}_wo"])
+
     def attend_absorbed(self, p, i, a, positions, rows, mask):
         """Absorbed attention of one query per row ``a (B, U)`` over latent
         ``rows (B, L, pool_width)`` (``mask (B, L)`` marks the live ones):
         ``W_kvb``'s key half is folded into the query and its value half is
         applied to the context.  Returns the attention output ``(B, U)``."""
-        import jax
-        import jax.numpy as jnp
-        dt, c = self.dtype, self.kv_lora_rank
-        q_nope, q_rope = self._queries(p, i, a, positions)
-        w = self._wkvb(p, i)
-        q_lat = _einsum("bhd,chd->bhc", q_nope, w[..., :self.nope_dim], dt)
-        pad = jnp.zeros(q_rope.shape[:-1]
-                        + (self.pool_width - self.row_width,), q_rope.dtype)
-        q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)
-        s = _einsum("bhk,blk->bhl", q, rows, dt) * self._scale
-        s = jnp.where(mask[:, None], s, -1e30)
-        pr = jax.nn.softmax(s, axis=-1)
-        ctx = _einsum("bhl,blk->bhk", pr, rows, dt)[..., :c]
-        o = _einsum("bhc,chd->bhd", ctx, w[..., self.nope_dim:], dt)
-        return _dot(o.reshape(o.shape[0], -1), p[f"l{i}_wo"])
+        q = self._fold(p, i, a, positions)
+        return self._unfold(p, i, self.context_absorbed(q, rows,
+                                                        mask[:, None]))
 
     def prefill_math(self, p, tokens, lengths):
         """Pure prefill: ``(last_logits, rows)`` — see the class docstring.
@@ -403,13 +424,13 @@ class LatentMoELM(HybridBlock):
 
     def step_program(self, p, tokens, positions, tables, pools, pages):
         """Pure fused decode step, one token a row: writes each row's latent
-        row into its page, gathers the row's paged context through
-        ``pages`` (the cache's ``PageFormat``: only the pages the table
-        names) and attends absorbed.  Rows whose table starts with the trash
-        page are padding and are routed to no expert.  Returns ``(logits (B,
-        vocab), pools, (moe_rows (expert layers, held + 1) int32,))``: per
-        expert layer the rows each held expert received, then the
-        assignments made over all experts."""
+        row into its page and attends absorbed over the row's paged context
+        through ``pages.attend`` (the cache's ``PageFormat``: the live pages
+        where they lie on the chip, the gathered context on the CPU).  Rows
+        whose table starts with the trash page are padding and are routed
+        to no expert.  Returns ``(logits (B, vocab), pools, (moe_rows
+        (expert layers, held + 1) int32,))``: per expert layer the rows each
+        held expert received, then the assignments made over all experts."""
         import jax
         import jax.numpy as jnp
         page_size = pages.page_size
@@ -417,8 +438,6 @@ class LatentMoELM(HybridBlock):
         wp = jnp.take_along_axis(tables, (positions // page_size)[:, None],
                                  axis=1)[:, 0]
         woff = positions % page_size
-        lctx = tables.shape[1] * page_size
-        mask = jnp.arange(lctx)[None, :] <= positions[:, None]
         valid = tables[:, 0] != 0
         counts = []
         for i in range(self.num_layers):
@@ -426,8 +445,12 @@ class LatentMoELM(HybridBlock):
             with jax.named_scope("mla.attend"):
                 row = self._latent_row(p, i, a, positions)
                 pools = pages.write(pools, i, wp, woff, (row,))
-                (ctx_rows,) = pages.read(pools, i, tables)
-                o = self.attend_absorbed(p, i, a, positions, ctx_rows, mask)
+                q = self._fold(p, i, a, positions)
+                ctx = pages.attend(
+                    pools, i, tables, positions, q,
+                    functools.partial(self.context_absorbed, q),
+                    scale=self._scale)
+                o = self._unfold(p, i, ctx)
             h = self._ffn(p, i, h + o, valid, counts)
         hf = _rms(h, p["norm_f"], self.eps)
         with jax.named_scope("head"):

@@ -132,16 +132,39 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip, b, g, r, dk, dv,
     compiled = jax.jit(pallas_kernels.paged_attention).lower(
         sds((b, g, r, dk), jnp.float32), k_pool, v_pool, sds((), jnp.int32),
         sds((b, pages), jnp.int32), sds((b,), jnp.int32)).compile()
-    text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
-    call = re.search(r"custom-call\((.*?)\), custom_call_target", text)
-    operands = re.findall(r"%([\w.\-]+)", call.group(1))
-    params = dict(re.findall(r"%([\w.\-]+) = bf16\[[\d,]+\]\S* "
-                             r"parameter\((\d)\)", text))
-    assert [params.get(o) for o in operands[-2:]] == ["1", "2"], \
-        f"the kernel reads {operands[-2:]}, not the pools {params}"
+    assert _one_kernel_reads(compiled.as_text(), 2) == ["1", "2"]
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20) \
         + b * g * r * g * dk * 2
+
+
+def _one_kernel_reads(hlo_text, n):
+    """The parameter numbers behind the last ``n`` operands (the pools) of
+    the program's ONE ``tpu_custom_call``; ``None`` for an operand that is
+    not a bfloat16 parameter itself (a copy, a slice, a relayout of one)."""
+    assert hlo_text.count('custom_call_target="tpu_custom_call"') == 1
+    call = re.search(r"custom-call\((.*?)\), custom_call_target", hlo_text)
+    operands = re.findall(r"%([\w.\-]+)", call.group(1))
+    params = dict(re.findall(r"%([\w.\-]+) = bf16\[[\d,]+\]\S* "
+                             r"parameter\((\d)\)", hlo_text))
+    return [params.get(o) for o in operands[-n:]]
+
+
+@pytest.mark.parametrize("b", [1, 32])
+def test_paged_latent_kernel_compiles_for_v5e(one_chip, b):
+    """The decode step's latent paged-attention kernel at A.X-K1's shape as
+    its cell serves it (64 heads over one 640-wide bfloat16 row, pages of 16
+    tokens, 128 a row): one ``tpu_custom_call`` whose pool operand is the
+    pool itself, no copy, slice or relayout of it before, and nothing held
+    beside the queries and the context."""
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    fn = functools.partial(pallas_kernels.paged_latent_attention, scale=0.1)
+    compiled = jax.jit(fn).lower(
+        sds((b, 64, 640), jnp.float32),
+        sds((5, _L_PAGES, 16, 640), jnp.bfloat16), sds((), jnp.int32),
+        sds((b, _L_ROW_PAGES), jnp.int32), sds((b,), jnp.int32)).compile()
+    assert _one_kernel_reads(compiled.as_text(), 1) == ["1"]
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20) \
+        + b * 64 * 640 * 2
 
 
 def _bert_base_step(one_chip, num_layers=12):
@@ -440,7 +463,13 @@ def _latent_pool_program(rt, kind, b, sds):
 def test_latent_pool_programs_touch_only_their_pages(one_chip, kind, b):
     """The one latent pool (bfloat16, 640-wide rows) is held to what the
     K/V pools are: no program copies it, none slices a whole layer out of
-    it, and it comes back in the buffer it was given."""
+    it, and it comes back in the buffer it was given.  Of the cached latent
+    rows a step holds NOTHING (PR 38): one latent paged-attention kernel a
+    layer under ``mla.attend`` reads the pages where they lie, so no array
+    of ``(b, reserved context, 640)``, whole or by pages, is written, nor
+    the ``(b, 64, reserved context)`` float32 scores over one: of that
+    width there are the rows' folded queries and contexts ``(b, 64, 640)``
+    and nothing larger."""
     import numpy as np
 
     rt = _latent_runtime()
@@ -453,8 +482,18 @@ def test_latent_pool_programs_touch_only_their_pages(one_chip, kind, b):
     compiled = fn.lower(*args).compile()
     what = f"latent {kind}-b{b}"
     layer = int(np.prod(pool.shape[1:]))
-    for op, dtype, dims in _materialised(compiled.as_text()):
-        if int(np.prod(dims)) < layer:
+    reserved = _L_ROW_PAGES * _PAGE
+    text = compiled.as_text()
+    for op, dtype, dims in _materialised(text):
+        n = int(np.prod(dims))
+        if kind == "step" and (dtype, dims) != ("bf16", pool.shape):
+            assert dims[-1:] != (640,) or n <= b * 64 * 640, \
+                f"{what}: {op} writes {dtype}{list(dims)}: cached latent " \
+                f"rows, gathered"
+            assert dims[-2:] != (64, reserved) or n != b * 64 * reserved, \
+                f"{what}: {op} writes {dtype}{list(dims)}: scores over a " \
+                f"reserved context"
+        if n < layer:
             continue
         assert (dtype, dims) == ("bf16", pool.shape), \
             f"{what}: {op} writes {dtype}{list(dims)}, a layer of the " \
@@ -463,10 +502,19 @@ def test_latent_pool_programs_touch_only_their_pages(one_chip, kind, b):
     stats = compiled.memory_analysis()
     pool_bytes = int(np.prod(pool.shape)) * 2
     assert stats.alias_size_in_bytes >= pool_bytes, what
-    # a step's temporaries: the gathered context of its rows (b x 2048 x
-    # 640 values a layer), small beside the pool
-    assert stats.temp_size_in_bytes < pool_bytes / 2, \
-        f"{what}: {stats.temp_size_in_bytes / 1e9:.3f} GB of temporaries " \
+    if kind != "step":
+        assert stats.temp_size_in_bytes < pool_bytes / 2, what
+        return
+    paged = re.findall(r"custom_call_target=\"tpu_custom_call\""
+                       r"(.*paged_latent_attention.*)$", text, re.M)
+    assert len(paged) == 2 and all("/mla.attend/" in p for p in paged), \
+        f"{what}: {len(paged)} latent paged-attention kernels under " \
+        f"mla.attend for 2 layers"
+    # the rows' vectors, queries and contexts (0.33 MB a row), weights
+    # streamed ahead of their use: 4.1 MB at b = 1, 28.0 at b = 32 (sandbox
+    # compiles, PR 38); a gathered context would add 2.6 MB a row a layer
+    assert stats.temp_size_in_bytes < (6 << 20) + b * (1 << 20), \
+        f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
         f"beside {pool_bytes / 1e9:.3f} GB of pool"
 
 
@@ -805,19 +853,21 @@ def test_hybrid_step_for_the_chip_counts_the_kernel_once_a_mamba_layer(
         {'{kind="kernel",rows="32"}': 3}
 
 
-@pytest.mark.parametrize("block", ["hybrid", "window"])
+@pytest.mark.parametrize("block", ["hybrid", "window", "latent"])
 def test_step_counts_the_paged_kernel_once_a_paged_layer(one_chip, block):
     """``decode.attn.paged.lowered``: lowering the 32-row step for the
     described chip counts ``kind="kernel"`` once a layer that pages (two
-    grouped-query layers here, one global layer there) and ``plain`` never;
-    lowering the same program for the CPU, the other way round.  Nothing but
-    the platform differs between the two."""
+    grouped-query layers here, one global layer there, both layers of the
+    latent block) and ``plain`` never; lowering the same program for the
+    CPU, the other way round.  Nothing but the platform differs between the
+    two."""
     from mxnet_tpu.test_utils import counted
     rt, program, layers = {
         "hybrid": (_hybrid_runtime, _hybrid_program, 2),
-        "window": (_window_runtime, _window_program, 1)}[block]
+        "window": (_window_runtime, _window_program, 1),
+        "latent": (_latent_runtime, _latent_pool_program, 2)}[block]
     for sharding, kind in ((one_chip, "kernel"), (None, "plain")):
-        fn, args, _pools = program(
+        fn, args, *_pools = program(
             rt(), "step", 32, lambda shape, dtype: jax.ShapeDtypeStruct(
                 tuple(shape), dtype, sharding=sharding))
         assert counted("decode.attn.paged.lowered",
