@@ -33,6 +33,18 @@ What differs from :class:`~mxnet_tpu.serving.decode.model.CausalLM`:
   the tolerances ``tests/test_latent_moe.py`` writes down.  ``dtype=
   "float32"`` (tests) runs the same programs at the highest precision.
 
+- **The residual path** is ``h + f(norm(h))`` with ``hc_mult == 1`` and
+  manifold-constrained hyper-connections with ``hc_mult == n > 1``
+  (:mod:`mxnet_tpu.ops.hyper_connection`, Xing4.0's): the three programs
+  then carry ``X (rows, n, hidden)`` float32 between sublayers, every
+  sublayer reads ``Hpre X``, and its output is written back as ``Hres X +
+  Hpost^T y`` with ``Hres`` made doubly stochastic by ``hc_sinkhorn_iters``
+  Sinkhorn rounds.  The streams start as ``n`` copies of the embedding and
+  end summed; they are not state between steps, so the cache, the scheduler
+  and the runtime see the same block.  :meth:`_sublayer` is the one place
+  that adds a sublayer's output or writes it back (``_ffn`` goes through
+  it).
+
 No drafter and no quantized latent pool: asking for either raises.
 """
 from __future__ import annotations
@@ -44,6 +56,7 @@ import numpy as np
 
 from ...gluon.block import HybridBlock
 from ...ndarray import NDArray, invoke_fn
+from ...ops import hyper_connection as _hc
 from ...telemetry import bus as _tel
 from .model import commit_destinations, sample_math
 
@@ -151,7 +164,12 @@ class LatentMoELM(HybridBlock):
     ``held_experts`` are the global ids of the routed experts whose weights
     this block holds (default: all of them); the router is always
     ``n_routed_experts`` wide.  ``vocab_size`` is the slice of the
-    vocabulary held here (embedding rows and head columns)."""
+    vocabulary held here (embedding rows and head columns).
+    ``select_bias=True`` gives every expert layer the family's ``noaux_tc``
+    score-correction bias ``(n_routed_experts,)`` float32: it joins the
+    scores for the choice of experts only.  ``hc_mult > 1`` makes the
+    residual path ``hc_mult`` streams mixed by hyper-connections (module
+    docstring), with ``hc_res_clamp = (lo, hi)`` on the residual logits."""
 
     def __init__(self, vocab_size=512, hidden_size=64, num_layers=3,
                  num_heads=4, q_lora_rank=32, kv_lora_rank=32,
@@ -162,7 +180,9 @@ class LatentMoELM(HybridBlock):
                  topk_group=2, routed_scaling_factor=2.5,
                  first_k_dense_replace=1, rms_norm_eps=1e-6,
                  rope_theta=10000.0, rope_scaling=None, max_length=128,
-                 dtype="bfloat16", **kwargs):
+                 dtype="bfloat16", hc_mult=1, hc_sinkhorn_iters=20,
+                 hc_eps=1e-6, hc_res_clamp=(-30.0, 30.0), select_bias=False,
+                 **kwargs):
         super().__init__(**kwargs)
         self.vocab_size = int(vocab_size)
         self.units = int(hidden_size)
@@ -199,6 +219,12 @@ class LatentMoELM(HybridBlock):
         self.eps = float(rms_norm_eps)
         self.max_length = int(max_length)
         self.dtype = str(dtype)
+        self.hc_mult = int(hc_mult)
+        if self.hc_mult < 1:
+            raise ValueError(f"hc_mult={hc_mult} must be at least 1")
+        self.hc_iters, self.hc_eps = int(hc_sinkhorn_iters), float(hc_eps)
+        self.hc_clamp = (float(hc_res_clamp[0]), float(hc_res_clamp[1]))
+        self.select_bias = bool(select_bias)
         self._inv_freq = yarn_inv_freq(self.rope_dim, float(rope_theta),
                                        rope_scaling).astype("float32")
         self._scale = yarn_softmax_scale(self.nope_dim + self.rope_dim,
@@ -227,6 +253,14 @@ class LatentMoELM(HybridBlock):
                              H * (self.nope_dim + self.v_dim)))
             reg(p + "wo", (H * self.v_dim, u))
             reg(p + "norm_ffn", (u,), "ones", "float32")
+            if self.hc_mult > 1:
+                n = self.hc_mult
+                for sub in ("attn", "ffn"):
+                    reg(p + f"hc_{sub}_phi", (n * u, n * (n + 2)),
+                        dtype="float32")
+                    reg(p + f"hc_{sub}_a", (3,), "ones", "float32")
+                    reg(p + f"hc_{sub}_b", (n * (n + 2),), "zeros",
+                        "float32")
             if i < self.first_dense:
                 f = int(intermediate_size)
                 reg(p + "wg", (u, f))
@@ -237,6 +271,9 @@ class LatentMoELM(HybridBlock):
                 # router scores are float32 at the highest precision, so
                 # the choice of experts follows the reference's
                 reg(p + "router", (u, self.n_routed), dtype="float32")
+                if self.select_bias:
+                    reg(p + "select_bias", (self.n_routed,), "zeros",
+                        "float32")
                 reg(p + "exp_wg", (G, u, f))
                 reg(p + "exp_wu", (G, u, f))
                 reg(p + "exp_wd", (G, f, u))
@@ -315,26 +352,69 @@ class LatentMoELM(HybridBlock):
         return p[f"l{i}_wkvb"].reshape(
             self.kv_lora_rank, self.num_heads, self.nope_dim + self.v_dim)
 
-    def _ffn(self, p, i, h, valid, counts):
-        """``h + FFN(RMSNorm(h))`` over flat rows ``h (T, U)``."""
+    def _sublayer(self, p, i, sub, h, f, resid=None):
+        """One sublayer (``sub`` is ``"attn"`` or ``"ffn"``) on the residual
+        path: ``f`` maps the normed input ``(..., U)`` to the parts of the
+        sublayer's output, and this is the one place that adds them to ``h
+        (..., U)`` (``hc_mult == 1``) or writes their sum back to the streams
+        ``h (..., n, U)``.  ``resid``, a list, receives the sublayer's
+        ``sinkhorn_residual`` a row."""
+        gain = p[f"l{i}_norm_{sub}"]
+        if self.hc_mult == 1:
+            for part in f(_rms(h, gain, self.eps)):
+                h = h + part
+            return h
+        hc = {k: p[f"l{i}_hc_{sub}_{k}"] for k in ("phi", "a", "b")}
+        h_pre, h_post, h_res = _hc.hc_coefficients(
+            h, hc, self.hc_iters, self.hc_eps, self.hc_clamp)
+        if resid is not None:
+            resid.append(_hc.sinkhorn_residual(h_res))
+        parts = f(_rms(_hc.hc_read(h, h_pre), gain, self.eps))
+        return _hc.hc_write(h, h_res, h_post, sum(parts))
+
+    def _ffn(self, p, i, h, valid, counts, resid=None):
+        """``h + FFN(RMSNorm(h))`` over flat rows ``h (T, U)`` (streams ``(T,
+        n, U)`` with ``hc_mult > 1``): the feed-forward sublayer whole.
+        ``perf/tools/route_flips.py`` follows the program through it."""
+        return self._sublayer(
+            p, i, "ffn", h,
+            lambda m: self._ffn_parts(p, i, m, valid, counts), resid)
+
+    def _ffn_parts(self, p, i, m, valid, counts):
+        """The parts of ``FFN(m)`` over flat normed rows ``m (T, U)``: the
+        dense SwiGLU, or the held experts' share and the shared expert."""
         import jax
         from ...parallel.moe import routed_expert_share
         pre = f"l{i}_"
-        m = _rms(h, p[pre + "norm_ffn"], self.eps)
         if i < self.first_dense:
             with jax.named_scope("ffn.dense"):
-                return h + _swiglu(m, p[pre + "wg"], p[pre + "wu"],
-                                   p[pre + "wd"])
+                return (_swiglu(m, p[pre + "wg"], p[pre + "wu"],
+                                p[pre + "wd"]),)
         y, rows, n_assign = routed_expert_share(
             m, p[pre + "router"], p[pre + "exp_wg"], p[pre + "exp_wu"],
             p[pre + "exp_wd"], self.held, top_k=self.top_k,
             n_group=self.n_group, topk_group=self.topk_group,
-            scale=self.routed_scale, valid=valid)
+            scale=self.routed_scale, valid=valid,
+            select_bias=p[pre + "select_bias"] if self.select_bias
+            else None)
         counts.append((rows, n_assign))
         with jax.named_scope("moe.shared"):
             shared = _swiglu(m, p[pre + "sh_wg"], p[pre + "sh_wu"],
                              p[pre + "sh_wd"])
-        return h + y + shared
+        return y, shared
+
+    def _streams(self, h):
+        """The residual path's start: the embedding ``h (..., U)`` itself,
+        or ``hc_mult`` copies of it ``(..., n, U)``."""
+        import jax.numpy as jnp
+        if self.hc_mult == 1:
+            return h
+        return jnp.broadcast_to(h[..., None, :],
+                                h.shape[:-1] + (self.hc_mult, h.shape[-1]))
+
+    def _merged(self, h):
+        """The residual path's end: the streams summed."""
+        return h if self.hc_mult == 1 else h.sum(-2)
 
     def attend_expanded(self, p, i, a, positions, causal):
         """Expanded attention over a whole sequence ``a (B, S, U)``:
@@ -405,18 +485,22 @@ class LatentMoELM(HybridBlock):
         import jax
         import jax.numpy as jnp
         B, S = tokens.shape
-        h = p["embed"][tokens].astype(jnp.float32)
+        h = self._streams(p["embed"][tokens].astype(jnp.float32))
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
         causal = jnp.tril(jnp.ones((S, S), bool))
         valid = (pos < lengths[:, None]).reshape(-1)
         out_rows, counts = [], []
         for i in range(self.num_layers):
-            a = _rms(h, p[f"l{i}_norm_attn"], self.eps)
-            with jax.named_scope("mla.attend"):
-                o, rows = self.attend_expanded(p, i, a, pos, causal)
-            out_rows.append(rows)
-            h = self._ffn(p, i, (h + o).reshape(B * S, -1), valid,
-                          counts).reshape(B, S, -1)
+            def attend(a, i=i):
+                with jax.named_scope("mla.attend"):
+                    o, rows = self.attend_expanded(p, i, a, pos, causal)
+                out_rows.append(rows)
+                return (o,)
+
+            h = self._sublayer(p, i, "attn", h, attend)
+            h = self._ffn(p, i, h.reshape((B * S,) + h.shape[2:]), valid,
+                          counts).reshape(h.shape)
+        h = self._merged(h)
         last = _rms(h[jnp.arange(B), lengths - 1], p["norm_f"], self.eps)
         with jax.named_scope("head"):
             logits = _dot(last, p["head"])
@@ -430,32 +514,44 @@ class LatentMoELM(HybridBlock):
         whose table starts with the trash page are padding and are routed
         to no expert.  Returns ``(logits (B, vocab), pools, (moe_rows
         (expert layers, held + 1) int32,))``: per expert layer the rows each
-        held expert received, then the assignments made over all experts."""
+        held expert received, then the assignments made over all experts.
+        With ``hc_mult > 1`` the extras end with one more number, the bits
+        of a float32: how far the step's worst ``Hres`` of a live row lies
+        from doubly stochastic (``hyper_connection.sinkhorn_residual``)."""
         import jax
         import jax.numpy as jnp
         page_size = pages.page_size
-        h = p["embed"][tokens].astype(jnp.float32)
+        h = self._streams(p["embed"][tokens].astype(jnp.float32))
         wp = jnp.take_along_axis(tables, (positions // page_size)[:, None],
                                  axis=1)[:, 0]
         woff = positions % page_size
         valid = tables[:, 0] != 0
-        counts = []
+        counts, resid = [], []
         for i in range(self.num_layers):
-            a = _rms(h, p[f"l{i}_norm_attn"], self.eps)
-            with jax.named_scope("mla.attend"):
-                row = self._latent_row(p, i, a, positions)
-                pools = pages.write(pools, i, wp, woff, (row,))
-                q = self._fold(p, i, a, positions)
-                ctx = pages.attend(
-                    pools, i, tables, positions, q,
-                    functools.partial(self.context_absorbed, q),
-                    scale=self._scale)
-                o = self._unfold(p, i, ctx)
-            h = self._ffn(p, i, h + o, valid, counts)
-        hf = _rms(h, p["norm_f"], self.eps)
+            def attend(a, i=i):
+                nonlocal pools
+                with jax.named_scope("mla.attend"):
+                    row = self._latent_row(p, i, a, positions)
+                    pools = pages.write(pools, i, wp, woff, (row,))
+                    q = self._fold(p, i, a, positions)
+                    ctx = pages.attend(
+                        pools, i, tables, positions, q,
+                        functools.partial(self.context_absorbed, q),
+                        scale=self._scale)
+                    return (self._unfold(p, i, ctx),)
+
+            h = self._sublayer(p, i, "attn", h, attend, resid)
+            h = self._ffn(p, i, h, valid, counts, resid)
+        hf = _rms(self._merged(h), p["norm_f"], self.eps)
         with jax.named_scope("head"):
             logits = _dot(hf, p["head"])
-        return logits, pools, (moe_rows_of(counts, len(self.held)),)
+        extras = (moe_rows_of(counts, len(self.held)),)
+        if resid:
+            # the largest over the live rows and the sublayers, as its bits:
+            # the runtime's vector of counts is int32
+            worst = jnp.where(valid[None], jnp.stack(resid), 0.0).max()
+            extras += (jax.lax.bitcast_convert_type(worst, jnp.int32),)
+        return logits, pools, extras
 
     def commit_program(self, rows, lengths, tables, pools, pages):
         """Store the prefill's ``rows (layers, B, S, pool_width)`` in the
@@ -469,11 +565,17 @@ class LatentMoELM(HybridBlock):
     sample_math = staticmethod(sample_math)
 
     def record_step_extras(self, extras, model):
-        """Telemetry from one step's ``moe_rows`` (the program's vector of
-        counts, flat): the ``decode.moe.*`` counters ``docs/telemetry.md``
-        lists."""
-        record_moe_rows(np.asarray(extras).reshape(-1, len(self.held) + 1),
-                        model)
+        """Telemetry from one step's extras (the program's vector of counts,
+        flat): the ``decode.moe.*`` counters ``docs/telemetry.md`` lists
+        and, with ``hc_mult > 1``, the gauges ``decode.hc.streams`` and
+        ``decode.hc.sinkhorn_residual``."""
+        extras = np.asarray(extras)
+        if self.hc_mult > 1:
+            _tel.gauge("decode.hc.streams", self.hc_mult)
+            _tel.gauge("decode.hc.sinkhorn_residual",
+                       float(extras[-1:].view(np.float32)[0]))
+            extras = extras[:-1]
+        record_moe_rows(extras.reshape(-1, len(self.held) + 1), model)
 
     # ------------------------------------------------------- gluon frontend
     def hybrid_forward(self, F, tokens, lengths, **params):

@@ -875,6 +875,34 @@ def test_step_counts_the_paged_kernel_once_a_paged_layer(one_chip, block):
             {f'{{kind="{kind}",rows="32"}}': layers}
 
 
+@pytest.mark.parametrize("rows", [32, 2048])
+def test_hyper_connections_compile_as_a_loop_for_v5e(one_chip, rows):
+    """One sublayer's mixing at Xing4.0's widths (4 streams of 3,584), for a
+    step's rows and a prefill's tokens: the 20 Sinkhorn rounds stay ONE loop
+    on the device (unrolled they are 2.6 s of compiling a sublayer, twelve
+    minutes a 2,048-token prefill program of 80), the streams keep a layout
+    without padding (the 4-wide axis is not tiled to 8), and nothing is a
+    reduction of the whole stream in float64 or a copy of it."""
+    from mxnet_tpu.ops import hyper_connection as hc
+    n, C = 4, 3584
+
+    def sublayer(X, phi, a, b, y):
+        h_pre, h_post, h_res = hc.hc_coefficients(
+            X, {"phi": phi, "a": a, "b": b}, 20, 1e-6, (-30.0, 30.0))
+        return hc.hc_write(X, h_res, h_post, hc.hc_read(X, h_pre) * y)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(sublayer).lower(
+        sds(rows, n, C), sds(n * C, n * (n + 2)), sds(3), sds(n * (n + 2)),
+        sds(rows, C)).compile().as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" fusion\(", text)) < 40
+    padded = re.findall(rf"f32\[{rows},4,3584\]\{{2,1,0:T\(8,128\)", text)
+    assert not padded, padded[:2]
+
+
 # ------------------------------------------- the window / global block's
 # the cell's geometry (mimo_v2_5_ep16.mixed_lengths): 18,433 pages, 288 a
 # row (4,608 tokens), 33 rings a window layer, the longest prefill bucket

@@ -47,6 +47,8 @@ from mxnet_tpu.serving.decode import (CausalLM, DecodeRuntime,  # noqa: E402
 from mxnet_tpu.serving.decode import latent_moe  # noqa: E402
 from perf.harness.weights import seed_key  # noqa: E402
 from perf.reference import axk1 as ref  # noqa: E402
+from perf.reference import xing4 as hc_ref  # noqa: E402
+from perf.systems import hyper_latent_moe_gateway  # noqa: E402
 from perf.systems.latent_moe_gateway import program_name  # noqa: E402
 
 TOL = {"float32": 2e-5, "bfloat16": 4e-2}
@@ -443,3 +445,150 @@ def test_served_weights_are_held_once(which):
         # and the compiled prefill has the two outputs of the forward
         op = net._cached_op
         assert all(h[0] == [] for h in op._aux_changed.values())
+
+
+# ----------------------------- (g) the hyper-connected residual path (mHC)
+def hc_cfg(dtype="float32", first_dense=1, bias_std=0.05,
+           held=(0, 1, 2, 3, 8, 9), n_layer=2):
+    """Xing4.0's keys at the tiny size: one group, the selection bias, four
+    streams; ``phi`` drawn wide enough (0.15 x sqrt(256) = 2.4, the
+    published widths' 0.02 x sqrt(14336)) that the coefficients differ from
+    token to token."""
+    cfg = tiny_cfg(dtype, held, n_layer)
+    cfg.update({
+        "n_group": 1, "topk_group": 1, "topk_method": "noaux_tc",
+        "first_k_dense_replace": first_dense, "routed_scaling_factor": 2.0,
+        "selection_bias_std": bias_std, "hc_mult": 4,
+        "hc_sinkhorn_iters": 20, "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
+        "mhc_h_res_clamp_max": 30,
+        "hc_init": {"phi_std": 0.15, "alpha": [0.3, 0.3, 0.3],
+                    "res_diagonal": 1.5}})
+    return cfg
+
+
+def hc_build(cfg, seed=3):
+    """``(block, reference weights)`` through the benchmark's system file."""
+    w = hc_ref.weights(cfg, seed_key(seed, stream=1))
+    return hyper_latent_moe_gateway.block(cfg, 64, w, jax.devices()[0]), w
+
+
+HC_TOKENS = np.random.default_rng(0).integers(0, 97, 12)
+
+
+def hc_reference(w, cfg, precision="float32"):
+    return np.asarray(hc_ref.forward(w, cfg, jnp.asarray(HC_TOKENS),
+                                     precision))[8:]
+
+
+@pytest.mark.parametrize("dtype,variant", [
+    ("float32", {}), ("bfloat16", {}),
+    ("float32", {"first_dense": 2, "n_layer": 3}),
+    ("float32", {"bias_std": 1.0})], ids=["float32", "bfloat16", "dense2",
+                                          "bias"])
+def test_hyper_connected_prefill_then_decode_matches_reference(dtype,
+                                                               variant):
+    """Prefill of 9 tokens, then 3 steps through the paged cache, on four
+    streams: float32 to 1e-4 of the largest logit (measured 1.2e-6: the
+    mathematics), bfloat16 to 4e-2 (measured 1.6e-2), which an e4m3 rounding
+    of the weights fails (it moves the REFERENCE by 0.16).  Two leading dense
+    layers and a wide selection bias move the answer, and the program
+    follows the reference there too."""
+    cfg = hc_cfg(dtype, **variant)
+    net, w = hc_build(cfg)
+    got, extras = decode_logits(net, HC_TOKENS, 9, pages=[3, 5])
+    want = hc_reference(w, cfg)
+    scale = np.abs(want).max()
+    assert scale > 0.5
+    tol = {"float32": 1e-4, "bfloat16": TOL["bfloat16"]}[dtype]
+    assert np.abs(got - want).max() <= tol * scale
+    # the step's extras: the experts' rows, then the bits of the worst
+    # distance of a live row's Hres from doubly stochastic
+    moe_rows, resid = (np.asarray(e) for e in extras)
+    assert moe_rows.shape == (1, 7)
+    assert 0 <= float(resid.view(np.float32)) < 1e-4
+    if dtype == "bfloat16":
+        fp8 = hc_reference(w, cfg, "weights_fp8")
+        assert np.abs(fp8 - want).max() > 2 * tol * scale
+    if "bias_std" in variant:
+        # the wide bias is not a rounding: on the same weights with the bias
+        # zeroed the reference reads another answer
+        unbiased = {k: jnp.zeros_like(v)
+                    if k.endswith("e_score_correction_bias") else v
+                    for k, v in w.items()}
+        assert np.abs(hc_reference(unbiased, cfg) - want).max() \
+            > 0.01 * scale
+
+
+@pytest.mark.parametrize("control", ["sinkhorn_off", "hc_static"])
+def test_a_broken_mixing_is_another_model(control):
+    """The two controls of the benchmark's check move the reference's
+    logits by tens of percent: no Sinkhorn round, or coefficients that no
+    longer depend on the token, are not within any tolerance above."""
+    cfg = hc_cfg(n_layer=3)
+    w = hc_ref.weights(cfg, seed_key(3, stream=1))
+    want = hc_reference(w, cfg)
+    assert np.abs(hc_reference(w, cfg, control) - want).max() \
+        > 0.1 * np.abs(want).max()
+
+
+def test_plain_residual_path_registers_and_computes_nothing_new():
+    """``hc_mult == 1``: no hyper-connection or selection-bias parameter,
+    the streams are the hidden state itself, and a sublayer is ``h +
+    f(norm(h))`` bit for bit (the benchmark's ``axk1_ep16`` programs lower
+    to the same text as before: ``PERF.md`` section 4)."""
+    net, _w = build(tiny_cfg("float32"))
+    assert not [n for n in net._param_order if "hc_" in n or "bias" in n]
+    p = net._params_dict(net.param_leaves())
+    h = jax.random.normal(jax.random.PRNGKey(4), (5, 64), jnp.float32)
+    assert net._streams(h) is h and net._merged(h) is h
+    part = jnp.tanh(h)
+    seen = []
+    got = net._sublayer(p, 1, "ffn", h,
+                        lambda m: (seen.append(m) or part, 2 * part))
+    want = h + part + 2 * part
+    assert (np.asarray(got) == np.asarray(want)).all()
+    assert (np.asarray(seen[0]) == np.asarray(
+        latent_moe._rms(h, p["l1_norm_ffn"], 1e-6))).all()
+    with pytest.raises(ValueError, match="hc_mult"):
+        LatentMoELM(hc_mult=0)
+
+
+def test_shares_of_the_hyper_connected_expert_sublayer_add_up():
+    """Eight chips hold two experts each of 16: written back once, the
+    routed parts of all the shares with the shared expert counted once are
+    the uncut reference's expert sublayer on the four streams."""
+    full = hc_cfg(held=tuple(range(16)), bias_std=0.3)
+    w = hc_ref.weights(full, seed_key(11, stream=1))
+    lw = {k[len("layers.1."):]: v for k, v in w.items()
+          if k.startswith("layers.1.")}
+    X = jax.random.normal(jax.random.PRNGKey(2), (40, 4, 64), jnp.float32)
+    want = hc_ref._moe_sublayer(lw, X, cfg_key=hc_ref._freeze(full),
+                                precision="float32")
+    from mxnet_tpu.ops import hyper_connection as hc
+    h_pre, h_post, h_res = hc.hc_coefficients(
+        X, {"phi": lw["hc_ffn.phi"], "a": lw["hc_ffn.alpha"],
+            "b": lw["hc_ffn.bias"]}, 20, 1e-6, (-30.0, 30.0))
+    m = latent_moe._rms(hc.hc_read(X, h_pre),
+                        lw["post_attention_layernorm"], 1e-6)
+    total = latent_moe._swiglu(m, lw["mlp.shared_experts.gate_proj"],
+                               lw["mlp.shared_experts.up_proj"],
+                               lw["mlp.shared_experts.down_proj"])
+    one, n_rows = None, 0
+    for rank in range(8):
+        held = (2 * rank, 2 * rank + 1)
+        ids = np.asarray(held)
+        y, rows, n_assign = routed_expert_share(
+            m, lw["mlp.gate"], lw["mlp.experts.gate_proj"][ids],
+            lw["mlp.experts.up_proj"][ids], lw["mlp.experts.down_proj"][ids],
+            held, top_k=4, scale=2.0,
+            select_bias=lw["mlp.gate.e_score_correction_bias"])
+        one = total + y if one is None else one
+        total = total + y
+        n_rows += int(np.asarray(rows).sum())
+    scale = float(jnp.abs(want).max())
+    got = hc.hc_write(X, h_res, h_post, total)
+    assert float(jnp.abs(got - want).max()) <= 2e-5 * scale
+    assert n_rows == int(n_assign) == 40 * 4
+    # one share alone is NOT the sublayer
+    assert float(jnp.abs(hc.hc_write(X, h_res, h_post, one) - want).max()) \
+        > 0.01 * scale
