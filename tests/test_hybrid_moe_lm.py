@@ -47,13 +47,17 @@ from mxnet_tpu.serving.decode import (DecodeRuntime,  # noqa: E402
 from mxnet_tpu.serving.decode import hybrid_moe  # noqa: E402
 from mxnet_tpu.serving.decode.hybrid_moe import (  # noqa: E402
     routed_relu2_share)
+from decode_block_harness import (MAX_PAGES, PAGE, Kit,  # noqa: E402
+                                  decode_logits, new_cache, programs)
 from perf.harness.weights import seed_key  # noqa: E402
 from perf.reference import nemotron_h as ref  # noqa: E402
 from perf.systems import hybrid_moe_gateway as system_mod  # noqa: E402
 
 TOL = {"float32": 5e-5, "bfloat16": 5e-2}
-PAGE = 8
-MAX_PAGES = 8
+
+# built and compiled once a configuration: tests/decode_block_harness.py
+KIT = Kit(ref, system_mod, TOL)
+build = KIT.build
 
 
 def tiny_cfg(dtype="bfloat16", held=(0, 1, 2, 3, 8, 9), pattern="MEM*EME"):
@@ -72,70 +76,6 @@ def tiny_cfg(dtype="bfloat16", held=(0, 1, 2, 3, 8, 9), pattern="MEM*EME"):
             "norm_eps": 1e-5, "vocab_size": 97, "initializer_range": 0.2,
             "time_step_min": 0.01, "time_step_max": 0.5,
             "time_step_floor": 1e-4, "precision": {"weights": dtype}}
-
-
-def build(cfg, seed=8, max_length=64):
-    """``(block, reference weights)``: the block holds the reference's own
-    seeded tensors, loaded as the benchmark's system file loads them."""
-    w = ref.weights(cfg, seed_key(seed, stream=1))
-    # the loader empties what it is handed: a copy of the table, not of the
-    # arrays
-    return system_mod.block(cfg, max_length, dict(w), jax.devices()[0]), w
-
-
-def new_cache(net, max_slots=4):
-    return PagedKVCache(layout=net.cache_layout(), page_size=PAGE,
-                        num_pages=24, max_pages_per_seq=MAX_PAGES,
-                        max_slots=max_slots)
-
-
-def table_row(pages, slot_row):
-    row = np.zeros((MAX_PAGES + 1,), "int32")
-    row[:len(pages)] = pages
-    row[-1] = slot_row
-    return row
-
-
-def programs(net, pages):
-    """The block's prefill, commit and step as the runtime runs them:
-    compiled, the cache's page format closed over.  (Run op by op, the
-    conditionals of the products by expert are traced anew at every call
-    and a test takes minutes.)"""
-    return (jax.jit(net.prefill_math),
-            jax.jit(lambda *a: net.commit_program(*a, pages)),
-            jax.jit(lambda *a: net.step_program(*a, pages)))
-
-
-def decode_logits(net, tokens, n_prompt, pages, slot_row, batch=1, row=0,
-                  seq_pad=16, cache=None, pools=None):
-    """Prefill ``tokens[:n_prompt]`` (padded to ``seq_pad``) and decode the
-    rest, in row ``row`` of a batch of ``batch`` (the other rows are
-    padding) with the K/V in physical ``pages`` and the recurrent state in
-    state row ``slot_row``: logits of positions ``n_prompt - 1 ..
-    len(tokens) - 1``, and the pools as the last step left them."""
-    p = net._params_dict(net.param_leaves())
-    if cache is None:
-        cache = new_cache(net)
-        pools = cache.pools
-    prefill, commit, step = programs(net, cache.pages)
-    table = table_row(pages, slot_row)[None]
-    prompt = np.zeros((1, seq_pad), "int32")
-    prompt[0, :n_prompt] = tokens[:n_prompt]
-    lengths = jnp.asarray([n_prompt], "int32")
-    logits, *state = prefill(p, jnp.asarray(prompt), lengths)
-    pools = commit(tuple(state), lengths, jnp.asarray(table), pools)
-    out = [np.asarray(logits[0])]
-    tables = np.zeros((batch, MAX_PAGES + 1), "int32")
-    tables[row] = table[0]
-    extras = None
-    for t in range(n_prompt, len(tokens)):
-        tok = np.zeros((batch,), "int32")
-        pos = np.zeros((batch,), "int32")
-        tok[row], pos[row] = tokens[t], t
-        logits, pools, extras = step(
-            p, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables), pools)
-        out.append(np.asarray(logits[row]))
-    return np.stack(out), extras, pools
 
 
 # ------------------------------------------------- (a) against the reference
@@ -175,7 +115,7 @@ def test_prefill_hands_over_state_as_of_the_true_length():
     net, _w = build(tiny_cfg("float32"))
     p = net._params_dict(net.param_leaves())
     tokens = np.random.default_rng(5).integers(0, 97, 11)
-    prefill = jax.jit(net.prefill_math)
+    prefill = programs(net, new_cache(net).pages)[0]
     got = []
     for pad in (16, 24, 32):
         prompt = np.full((1, pad), 96, "int32")       # junk behind the prompt
@@ -305,8 +245,9 @@ def test_relu2_share_is_the_loop_over_experts():
     router = jax.random.normal(k[1], (32, 8))
     wu = jax.random.normal(k[2], (8, 32, 48)) * 0.2
     wd = jax.random.normal(k[3], (8, 48, 32)) * 0.2
-    y, rows, n = routed_relu2_share(x, router, wu, wd, tuple(range(8)),
-                                    top_k=3, scale=1.5)
+    # (compiled: op by op every conditional is traced and compiled anew)
+    y, rows, n = jax.jit(lambda *v: routed_relu2_share(
+        *v, tuple(range(8)), top_k=3, scale=1.5))(x, router, wu, wd)
     s = jax.nn.sigmoid(jnp.dot(x, router, precision="highest"))
     top = np.argsort(-np.asarray(s), axis=-1)[:, :3]
     want = np.zeros((24, 32))
@@ -334,9 +275,11 @@ def test_padding_is_routed_nowhere_and_an_unchosen_expert_is_skipped(real):
     wd = jax.random.normal(k[3], (5, 48, 32)) * 0.2
     held = (0, 3, 4, 9, 15)
     kw = dict(top_k=3, scale=2.5)
-    y0, _r, _n = routed_relu2_share(x, router, wu, wd, held, **kw)
-    y, rows, n = routed_relu2_share(x, router, wu, wd, held,
-                                    valid=jnp.arange(20) < real, **kw)
+    # (compiled: op by op every conditional is traced and compiled anew)
+    y0, _r, _n = jax.jit(lambda: routed_relu2_share(
+        x, router, wu, wd, held, **kw))()
+    y, rows, n = jax.jit(lambda valid: routed_relu2_share(
+        x, router, wu, wd, held, valid=valid, **kw))(jnp.arange(20) < real)
     np.testing.assert_allclose(y[:real], y0[:real], rtol=1e-5, atol=1e-6)
     assert float(jnp.abs(y[real:]).max()) == 0.0
     assert int(n) == 3 * real and int(rows.sum()) <= 3 * real
@@ -463,14 +406,14 @@ def test_cache_builds_paged_and_slot_pools_from_the_layout():
     ("drafter", {"drafter": "ngram"}, "cannot speculate"),
 ])
 def test_what_the_block_does_not_support_says_so(what, kwargs, match):
-    net, _w = build(tiny_cfg())
+    net, _w = build(tiny_cfg(), fresh=True)
     with pytest.raises(ValueError, match=match):
         DecodeSession(net, page_size=PAGE, batch_buckets=(1,),
                       seq_buckets=(8,), warm=False, start=False, **kwargs)
 
 
 def test_mesh_and_bad_patterns_say_so():
-    net, _w = build(tiny_cfg())
+    net, _w = build(tiny_cfg(), fresh=True)
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("model",))
     with pytest.raises(ValueError, match="slot pools, which are not sharded"):
         PagedKVCache(layout=net.cache_layout(), mesh=mesh)
@@ -485,7 +428,7 @@ def test_mesh_and_bad_patterns_say_so():
 
 
 def test_runtime_sizes_slots_and_tables_from_the_block():
-    net, _w = build(tiny_cfg(), max_length=48)
+    net, _w = build(tiny_cfg(), max_length=48, fresh=True)
     rt = DecodeRuntime(net, page_size=PAGE, batch_buckets=(1, 4),
                        seq_buckets=(8, 16), warm=False)
     assert rt.cache.context_length == 48 and rt.cache.max_pages_per_seq == 6
@@ -506,7 +449,7 @@ def test_runtime_sizes_slots_and_tables_from_the_block():
 # ------------------------------------------------- through the normal path
 @pytest.fixture(scope="module")
 def session():
-    net, w = build(tiny_cfg("float32"), seed=5)
+    net, w = build(tiny_cfg("float32"), seed=5, fresh=True)
     sess = DecodeSession(net, page_size=PAGE, batch_buckets=(1, 2, 4),
                          seq_buckets=(8, 16))
     yield sess, net, w

@@ -24,6 +24,7 @@ width, and the benchmark counts the served tokens it moves.  A lower
 precision than bfloat16 fails the comparison that decides ``correct``
 (``tests/perf/test_axk1_cell.py``).
 """
+import functools
 import gc
 import os
 import sys
@@ -45,17 +46,22 @@ from mxnet_tpu.serving.decode import (CausalLM, DecodeRuntime,  # noqa: E402
                                       DecodeSession, LatentMoELM,
                                       PagedKVCache, get_decode_model)
 from mxnet_tpu.serving.decode import latent_moe  # noqa: E402
+from decode_block_harness import PAGE, Kit, decode_logits  # noqa: E402
 from perf.harness.weights import seed_key  # noqa: E402
 from perf.reference import axk1 as ref  # noqa: E402
 from perf.reference import xing4 as hc_ref  # noqa: E402
-from perf.systems import hyper_latent_moe_gateway  # noqa: E402
-from perf.systems.latent_moe_gateway import program_name  # noqa: E402
+from perf.systems import (hyper_latent_moe_gateway,  # noqa: E402
+                          latent_moe_gateway)
 
 TOL = {"float32": 2e-5, "bfloat16": 4e-2}
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 32, "mscale": 1,
         "mscale_all_dim": 1, "original_max_position_embeddings": 16,
         "type": "yarn"}
-PAGE = 8
+
+# built and compiled once a configuration: tests/decode_block_harness.py
+KIT = Kit(ref, latent_moe_gateway, TOL, seed=3)
+HC = Kit(hc_ref, hyper_latent_moe_gateway, TOL, seed=3)
+build = KIT.build
 
 
 def tiny_cfg(dtype="bfloat16", held=(0, 1, 2, 3, 8, 9), n_layer=3):
@@ -75,71 +81,6 @@ def tiny_cfg(dtype="bfloat16", held=(0, 1, 2, 3, 8, 9), n_layer=3):
             "precision": {"weights": dtype}}
 
 
-def build(cfg, seed=3, max_length=64):
-    """``(block, reference weights)``: the block holds the reference's own
-    seeded tensors, loaded by name as the benchmark's system file does."""
-    w = ref.weights(cfg, seed_key(seed, stream=1))
-    net = LatentMoELM(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        num_layers=cfg["n_layer"], num_heads=cfg["num_attention_heads"],
-        q_lora_rank=cfg["q_lora_rank"], kv_lora_rank=cfg["kv_lora_rank"],
-        qk_nope_head_dim=cfg["qk_nope_head_dim"],
-        qk_rope_head_dim=cfg["qk_rope_head_dim"],
-        v_head_dim=cfg["v_head_dim"],
-        intermediate_size=cfg["intermediate_size"],
-        moe_intermediate_size=cfg["moe_intermediate_size"],
-        n_routed_experts=cfg["published"]["n_routed_experts"],
-        held_experts=cfg["held_experts"],
-        num_experts_per_tok=cfg["num_experts_per_tok"],
-        n_group=cfg["n_group"], topk_group=cfg["topk_group"],
-        routed_scaling_factor=cfg["routed_scaling_factor"],
-        first_k_dense_replace=cfg["first_k_dense_replace"],
-        rms_norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        rope_scaling=cfg["rope_scaling"], max_length=max_length,
-        dtype=cfg["precision"]["weights"])
-    params = net.collect_params()
-    params.setattr("grad_req", "null")
-    for name, arr in w.items():
-        params[net.prefix + program_name(name)]._load_init(
-            mx.nd.NDArray(arr), None)
-    return net, w
-
-
-def new_pools(net, num_pages=24, max_pages=8):
-    cache = PagedKVCache(layout=net.cache_layout(), page_size=PAGE,
-                         num_pages=num_pages, max_pages_per_seq=max_pages)
-    return cache, cache.pools
-
-
-def decode_logits(net, tokens, n_prompt, pages, batch=1, row=0, seq_pad=16):
-    """Prefill ``tokens[:n_prompt]`` and decode the rest through the paged
-    cache, in row ``row`` of a batch of ``batch`` (the other rows are
-    padding) with the sequence in physical ``pages``: logits of positions
-    ``n_prompt - 1 .. len(tokens) - 1``."""
-    p = net._params_dict(net.param_leaves())
-    cache, pools = new_pools(net)
-    table = np.zeros((1, 8), "int32")
-    table[0, :len(pages)] = pages
-    prompt = np.zeros((1, seq_pad), "int32")
-    prompt[0, :n_prompt] = tokens[:n_prompt]
-    lengths = jnp.asarray([n_prompt], "int32")
-    logits, rows = net.prefill_math(p, jnp.asarray(prompt), lengths)
-    pools = net.commit_program(rows, lengths, jnp.asarray(table), pools,
-                               cache.pages)
-    out = [np.asarray(logits[0])]
-    tables = np.zeros((batch, 8), "int32")
-    tables[row] = table[0]
-    for t in range(n_prompt, len(tokens)):
-        tok = np.zeros((batch,), "int32")
-        pos = np.zeros((batch,), "int32")
-        tok[row], pos[row] = tokens[t], t
-        logits, pools, extras = net.step_program(
-            p, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables),
-            pools, cache.pages)
-        out.append(np.asarray(logits[row]))
-    return np.stack(out), extras
-
-
 # ------------------------------------------------- (a) against the reference
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("n_prompt", [1, 9])
@@ -147,7 +88,7 @@ def test_prefill_then_paged_decode_matches_reference(dtype, n_prompt):
     cfg = tiny_cfg(dtype)
     net, w = build(cfg)
     tokens = np.random.default_rng(7).integers(0, 97, 22)
-    got, _ = decode_logits(net, tokens, n_prompt, pages=[3, 5, 7])
+    got, _x, _p = decode_logits(net, tokens, n_prompt, pages=[3, 5, 7])
     want = np.asarray(ref.forward(w, cfg, jnp.asarray(tokens)))[n_prompt - 1:]
     scale = np.abs(want).max()
     assert scale > 0.5          # logits of order 1, not a comparison of zeros
@@ -160,7 +101,7 @@ def test_float32_state_is_much_tighter_than_bfloat16():
     for dtype in ("float32", "bfloat16"):
         cfg = tiny_cfg(dtype)
         net, w = build(cfg, seed=4)
-        got, _ = decode_logits(net, tokens, 6, pages=[1, 2, 3])
+        got, _x, _p = decode_logits(net, tokens, 6, pages=[1, 2, 3])
         want = np.asarray(ref.forward(w, cfg, jnp.asarray(tokens)))[5:]
         err[dtype] = np.abs(got - want).max() / np.abs(want).max()
     assert err["float32"] < 2e-5 < 1e-3 < err["bfloat16"] < 4e-2
@@ -176,11 +117,14 @@ def test_absorbed_attention_agrees_with_expanded(dtype):
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (2, S))
     causal = jnp.tril(jnp.ones((S, S), bool))
     for layer in (0, 2):
-        full, rows = net.attend_expanded(p, layer, a, pos, causal)
+        # compiled, one program a form and layer: op by op the two forms
+        # are some hundred small programs, each compiled on its first use
+        expanded = jax.jit(functools.partial(net.attend_expanded, p, layer))
+        absorbed = jax.jit(functools.partial(net.attend_absorbed, p, layer))
+        full, rows = expanded(a, pos, causal)
         for t in (0, 5, S - 1):
             mask = jnp.arange(S)[None, :] <= jnp.full((2, 1), t)
-            one = net.attend_absorbed(p, layer, a[:, t], pos[:, t], rows,
-                                      mask)
+            one = absorbed(a[:, t], pos[:, t], rows, mask)
             scale = float(jnp.abs(full[:, t]).max())
             assert float(jnp.abs(one - full[:, t]).max()) <= \
                 TOL[dtype] * scale
@@ -241,8 +185,8 @@ def test_router_choice_follows_the_reference():
 def test_padding_rows_are_routed_nowhere():
     net, _w = build(tiny_cfg("float32"))
     tokens = np.random.default_rng(3).integers(0, 97, 12)
-    _logits, extras = decode_logits(net, tokens, 4, pages=[2, 4], batch=4,
-                                    row=1)
+    _logits, extras, _p = decode_logits(net, tokens, 4, pages=[2, 4],
+                                        batch=4, row=1)
     moe_rows = np.asarray(extras[0])
     # two expert layers, six held experts and the total; one real row of
     # four: 4 assignments a layer over all 16 experts, not 16
@@ -256,9 +200,9 @@ def test_padding_rows_are_routed_nowhere():
 def test_batched_and_replaced_row_equals_its_solo_run(dtype):
     net, _w = build(tiny_cfg(dtype))
     tokens = np.random.default_rng(9).integers(0, 97, 18)
-    solo, _ = decode_logits(net, tokens, 5, pages=[1, 2, 3])
-    moved, _ = decode_logits(net, tokens, 5, pages=[9, 4, 17], batch=4,
-                             row=2)
+    solo, _x, _p = decode_logits(net, tokens, 5, pages=[1, 2, 3])
+    moved, _x, _p = decode_logits(net, tokens, 5, pages=[9, 4, 17], batch=4,
+                                  row=2)
     assert np.abs(solo - moved).max() <= TOL[dtype] * np.abs(solo).max()
 
 
@@ -326,7 +270,7 @@ def test_cache_takes_its_pools_from_the_block():
     ("drafter", {"drafter": "ngram"}, "cannot speculate"),
 ])
 def test_what_the_block_does_not_support_says_so(what, kwargs, match):
-    net, _w = build(tiny_cfg())
+    net, _w = build(tiny_cfg(), fresh=True)
     with pytest.raises(ValueError, match=match):
         DecodeSession(net, page_size=PAGE, batch_buckets=(1,),
                       seq_buckets=(8,), warm=False, start=False, **kwargs)
@@ -340,7 +284,7 @@ def test_sharded_latent_pool_says_so():
 
 
 def test_runtime_takes_context_and_prefill_ladder_from_the_block():
-    net, _w = build(tiny_cfg(), max_length=48)
+    net, _w = build(tiny_cfg(), max_length=48, fresh=True)
     rt = DecodeRuntime(net, page_size=PAGE, batch_buckets=(1, 2, 4),
                        seq_buckets=(8, 16), warm=False)
     assert rt.cache.context_length == 48 and rt.cache.max_pages_per_seq == 6
@@ -363,7 +307,7 @@ def test_runtime_takes_context_and_prefill_ladder_from_the_block():
 # ------------------------------------------------- through the normal path
 @pytest.fixture(scope="module")
 def session():
-    net, w = build(tiny_cfg("float32"), seed=5)
+    net, w = build(tiny_cfg("float32"), seed=5, fresh=True)
     sess = DecodeSession(net, page_size=PAGE, batch_buckets=(1, 2, 4),
                          seq_buckets=(8, 16))
     yield sess, net, w
@@ -382,8 +326,12 @@ def test_session_serves_the_reference_greedy_stream(session):
     for prompt, fut in zip(prompts, futs):
         seq = list(prompt)
         for _ in range(6):
-            logits = ref.forward(w, cfg, jnp.asarray(seq, jnp.int32))
-            seq.append(int(jnp.argmax(logits[-1])))
+            # padded to one length (every layer is causal: what follows a
+            # position does not reach it), so the reference compiles once
+            padded = np.zeros((24,), "int32")
+            padded[:len(seq)] = seq
+            logits = ref.forward(w, cfg, jnp.asarray(padded))
+            seq.append(int(jnp.argmax(logits[len(seq) - 1])))
         assert fut.result(timeout=120).token_ids == seq[len(prompt):]
     s = sess.stats()
     assert s["pages_in_use"] == 0 and s["slots_in_use"] == 0
@@ -415,7 +363,7 @@ def test_served_weights_are_held_once(which):
     copied out of a prefill and rebound: after serving, every parameter is
     the very array it was loaded as, and the runtime's leaves are those."""
     if which == "latent_moe":
-        net, _w = build(tiny_cfg("float32"))
+        net, _w = build(tiny_cfg("float32"), fresh=True)
     else:
         net = CausalLM(vocab_size=50, units=32, num_layers=2, num_heads=2,
                        max_length=32)
@@ -466,12 +414,6 @@ def hc_cfg(dtype="float32", first_dense=1, bias_std=0.05,
     return cfg
 
 
-def hc_build(cfg, seed=3):
-    """``(block, reference weights)`` through the benchmark's system file."""
-    w = hc_ref.weights(cfg, seed_key(seed, stream=1))
-    return hyper_latent_moe_gateway.block(cfg, 64, w, jax.devices()[0]), w
-
-
 HC_TOKENS = np.random.default_rng(0).integers(0, 97, 12)
 
 
@@ -494,8 +436,8 @@ def test_hyper_connected_prefill_then_decode_matches_reference(dtype,
     layers and a wide selection bias move the answer, and the program
     follows the reference there too."""
     cfg = hc_cfg(dtype, **variant)
-    net, w = hc_build(cfg)
-    got, extras = decode_logits(net, HC_TOKENS, 9, pages=[3, 5])
+    net, w = HC.build(cfg)
+    got, extras, _p = decode_logits(net, HC_TOKENS, 9, pages=[3, 5])
     want = hc_reference(w, cfg)
     scale = np.abs(want).max()
     assert scale > 0.5

@@ -30,7 +30,6 @@ which no tolerance on logits covers and none is claimed
 scores are float32 so that it is rare at the real width, and the benchmark
 counts the served tokens it moves.
 """
-import functools
 import os
 import sys
 
@@ -53,13 +52,17 @@ from mxnet_tpu.serving.decode import (DecodeRuntime,  # noqa: E402
                                       PagedKVCache)
 from mxnet_tpu.serving.decode import linear_moe  # noqa: E402
 from mxnet_tpu.test_utils import counted  # noqa: E402
+from decode_block_harness import (MAX_PAGES, PAGE, Kit,  # noqa: E402
+                                  decode_logits, new_cache, programs)
 from perf.harness.weights import seed_key  # noqa: E402
 from perf.reference import solar_open2 as ref  # noqa: E402
 from perf.systems import linear_moe_gateway as system_mod  # noqa: E402
 
 TOL = {"float32": 5e-5, "bfloat16": 6e-2}
-PAGE = 8
-MAX_PAGES = 8
+
+# built and compiled once a configuration: tests/decode_block_harness.py
+KIT = Kit(ref, system_mod, TOL)
+build = KIT.build
 
 
 # ------------------------------------------------ (a) the three forms agree
@@ -210,72 +213,6 @@ def all_chosen(dtype):
     return tiny_cfg(dtype, held=(0, 1, 2, 3), published=4, top_k=4)
 
 
-def build(cfg, seed=8, max_length=64):
-    """``(block, reference weights)``: the block holds the reference's own
-    seeded tensors, loaded as the benchmark's system file loads them."""
-    w = ref.weights(cfg, seed_key(seed, stream=1))
-    # the loader empties what it is handed: a copy of the table, not of the
-    # arrays
-    return system_mod.block(cfg, max_length, dict(w), jax.devices()[0]), w
-
-
-def new_cache(net, max_slots=4):
-    return PagedKVCache(layout=net.cache_layout(), page_size=PAGE,
-                        num_pages=24, max_pages_per_seq=MAX_PAGES,
-                        max_slots=max_slots)
-
-
-def table_row(pages, slot_row):
-    row = np.zeros((MAX_PAGES + 1,), "int32")
-    row[:len(pages)] = pages
-    row[-1] = slot_row
-    return row
-
-
-@functools.lru_cache(maxsize=None)
-def programs(net):
-    """The block's prefill, commit and step as the runtime runs them:
-    compiled once a block, a cache's page format closed over (every cache
-    of these tests has the one geometry)."""
-    pages = new_cache(net).pages
-    return (jax.jit(net.prefill_math),
-            jax.jit(lambda *a: net.commit_program(*a, pages)),
-            jax.jit(lambda *a: net.step_program(*a, pages)))
-
-
-def decode_logits(net, tokens, n_prompt, pages, slot_row, batch=1, row=0,
-                  seq_pad=16, cache=None, pools=None):
-    """Prefill ``tokens[:n_prompt]`` (padded to ``seq_pad``) and decode the
-    rest, in row ``row`` of a batch of ``batch`` (the other rows are
-    padding) with the K/V in physical ``pages`` and the matrix state in
-    state row ``slot_row``: logits of positions ``n_prompt - 1 ..
-    len(tokens) - 1``, the last step's counts, and the pools as it left
-    them."""
-    p = net._params_dict(net.param_leaves())
-    if cache is None:
-        cache = new_cache(net)
-        pools = cache.pools
-    prefill, commit, step = programs(net)
-    table = table_row(pages, slot_row)[None]
-    prompt = np.zeros((1, seq_pad), "int32")
-    prompt[0, :n_prompt] = tokens[:n_prompt]
-    lengths = jnp.asarray([n_prompt], "int32")
-    logits, *state = prefill(p, jnp.asarray(prompt), lengths)
-    pools = commit(tuple(state), lengths, jnp.asarray(table), pools)
-    out = [np.asarray(logits[0])]
-    tables = np.zeros((batch, MAX_PAGES + 1), "int32")
-    tables[row] = table[0]
-    extras = None
-    for t in range(n_prompt, len(tokens)):
-        tok = np.zeros((batch,), "int32")
-        pos = np.zeros((batch,), "int32")
-        tok[row], pos[row] = tokens[t], t
-        logits, pools, extras = step(
-            p, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables), pools)
-        out.append(np.asarray(logits[row]))
-    return np.stack(out), extras, pools
-
-
 def reference_logits(w, cfg, tokens, precision="float32"):
     """The reference's full forward over ``tokens`` padded to 32 (every
     layer is causal), so that it compiles once."""
@@ -330,10 +267,10 @@ def test_bfloat16_is_near_and_float32_much_tighter(f32):
 
 
 def test_prompt_attention_by_query_blocks_is_the_whole_attention(
-        f32, monkeypatch):
+        monkeypatch):
     """Blocks of 8 queries over 20 keys (the last block padded) against all
     20 queries at once under the causal mask."""
-    _cfg, net, _w = f32
+    net, _w = build(tiny_cfg("float32"), fresh=True)
     p = net._params_dict(net.param_leaves())
     a = jax.random.normal(jax.random.PRNGKey(1), (1, 20, 64), jnp.float32)
     q, k, v = net._qkv(p, 0, a)
@@ -349,7 +286,7 @@ def test_prefill_hands_over_state_as_of_the_true_length(f32):
     _cfg, net, _w = f32
     p = net._params_dict(net.param_leaves())
     tokens = np.random.default_rng(5).integers(0, 97, 11)
-    prefill = programs(net)[0]
+    prefill = programs(net, new_cache(net).pages)[0]
     got = []
     for pad in (16, 24, 32):
         prompt = np.full((1, pad), 96, "int32")       # junk behind the prompt
@@ -483,12 +420,12 @@ def test_the_few_rows_share_is_the_grouped_share(real):
     assert float(jnp.abs(got[0][real:]).max() if real < 20 else 0.0) == 0.0
 
 
-def test_the_block_chooses_the_share_by_its_rows(f32):
+def test_the_block_chooses_the_share_by_its_rows():
     """The step program (2 rows here, 32 in the cell) has a conditional a
     held expert a layer and no grouped product; the prefill (16 rows a
     prompt here, hundreds in the cell: above ``few_rows``) has three
     grouped products a layer and no conditional."""
-    _cfg, net, _w = f32
+    net, _w = build(tiny_cfg("float32"), fresh=True)
     cache = new_cache(net)
     p = net._params_dict(net.param_leaves())
 
@@ -506,21 +443,16 @@ def test_the_block_chooses_the_share_by_its_rows(f32):
     # (the choice by platform of the recurrence and of the paged attention
     # is a conditional too in the traced program)
     assert count(step, "ragged_dot") == 0 and count(step, "cond") >= 5 * 6
-    old = net.few_rows
-    try:
-        net.few_rows = 8
-        # (a function of its own: the method's earlier traces are cached)
-        prefill = jax.make_jaxpr(lambda *a: net.prefill_math(*a))(
-            p, jnp.zeros((1, 16), "int32"), jnp.ones((1,), "int32")).jaxpr
-    finally:
-        net.few_rows = old
+    net.few_rows = 8                    # this test's own block
+    prefill = jax.make_jaxpr(net.prefill_math)(
+        p, jnp.zeros((1, 16), "int32"), jnp.ones((1,), "int32")).jaxpr
     assert count(prefill, "ragged_dot") == 5 * 3
     assert count(prefill, "cond") < 5 * 6
 
 
 # ------------------------------------ (f) the cache and runtime read the block
 def test_cache_builds_paged_and_slot_pools_from_the_layout():
-    net, _w = build(tiny_cfg())
+    net, _w = build(tiny_cfg(), fresh=True)
     layout = net.cache_layout()
     assert layout["layers"] == 2 and layout["state"]["layers"] == 3
     assert net.gqa_layers == (0, 4) and net.kda_layers == (1, 2, 3)
@@ -559,14 +491,14 @@ def test_cache_builds_paged_and_slot_pools_from_the_layout():
     ("drafter", {"drafter": "ngram"}, "cannot speculate"),
 ])
 def test_what_the_block_does_not_support_says_so(what, kwargs, match):
-    net, _w = build(tiny_cfg())
+    net, _w = build(tiny_cfg(), fresh=True)
     with pytest.raises(ValueError, match=match):
         DecodeSession(net, page_size=PAGE, batch_buckets=(1,),
                       seq_buckets=(8,), warm=False, start=False, **kwargs)
 
 
 def test_mesh_and_bad_layers_say_so():
-    net, _w = build(tiny_cfg())
+    net, _w = build(tiny_cfg(), fresh=True)
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("model",))
     with pytest.raises(ValueError, match="slot pools, which are not sharded"):
         DecodeSession(net, page_size=PAGE, batch_buckets=(1,),
@@ -595,7 +527,7 @@ def test_the_step_lowered_for_the_cpu_is_the_definition(f32):
 # ------------------------------------------------- through the normal path
 @pytest.fixture(scope="module")
 def session():
-    net, w = build(tiny_cfg("float32"), seed=5)
+    net, w = build(tiny_cfg("float32"), seed=5, fresh=True)
     sess = DecodeSession(net, page_size=PAGE, batch_buckets=(1, 2, 4),
                          seq_buckets=(8, 16))
     yield sess, net, w

@@ -5,7 +5,25 @@ squeezenet, inception, vgg — plus alexnet and resnet v2) must export to
 real ``.onnx`` bytes and import back with identical forward outputs.
 Reference flow: ``python/mxnet/contrib/onnx/mx2onnx/export_model.py`` on
 the zoo models.
+
+The claim is about bytes and outputs, not about ImageNet's image: each family
+runs at the smallest input it admits, stated beside its case.  A family that
+ends in global pooling takes 32 x 32 (five halvings to 1 x 1); ``vgg11`` too,
+its first dense layer then 512 wide and not 25,088; the others end in a pool
+of a fixed window, or in strides, that need the map they were drawn for, and
+one pixel less leaves the first dense layer no input (``(classes, 0)``).
+Every family and the tolerance are as they were, and every file is still
+over 10,000 bytes.
+
+The block runs once op by op before it is hybridized: a hybridized block's
+first call finishes deferred initialisation by a dry run in which every child
+block compiles a program of its own (246 for ``mobilenetv2_0.25``;
+``ROADMAP.md``, D19), which is the program's start-up cost and not this
+file's claim; ``tests/test_gluon.py`` and ``tests/test_gluon_deep.py`` hold
+that path.
 """
+import os
+
 import numpy as np
 import pytest
 
@@ -14,14 +32,14 @@ from mxnet_tpu.contrib import onnx as onnx_mod
 
 
 _CASES = [
-    ("squeezenet1.0", (1, 3, 224, 224)),
-    ("mobilenet0.25", (1, 3, 224, 224)),
-    ("mobilenetv2_0.25", (1, 3, 224, 224)),
-    ("densenet121", (1, 3, 224, 224)),
-    ("inceptionv3", (1, 3, 299, 299)),
-    ("vgg11", (1, 3, 224, 224)),
-    ("alexnet", (1, 3, 224, 224)),
-    ("resnet18_v2", (1, 3, 224, 224)),
+    ("squeezenet1.0", (1, 3, 213, 213)),     # floor: 13 x 13 into AvgPool(13)
+    ("mobilenet0.25", (1, 3, 32, 32)),
+    ("mobilenetv2_0.25", (1, 3, 32, 32)),
+    ("densenet121", (1, 3, 221, 221)),       # floor: 7 x 7 into AvgPool(7)
+    ("inceptionv3", (1, 3, 299, 299)),       # floor: 8 x 8 into AvgPool(8)
+    ("vgg11", (1, 3, 32, 32)),
+    ("alexnet", (1, 3, 63, 63)),             # floor: 1 x 1 out of the strides
+    ("resnet18_v2", (1, 3, 32, 32)),
 ]
 
 
@@ -50,19 +68,18 @@ def test_model_zoo_roundtrip_real_bytes(name, shape, tmp_path):
     net = mx.gluon.model_zoo.vision.get_model(name, classes=10)
     net.initialize()
     x = mx.nd.array(rng.rand(*shape).astype("float32"))
+    net(x)              # deferred initialisation, op by op (module docstring)
     net.hybridize()
-    net(x)
+    want = net(x).asnumpy()     # the block's own answer is the reference
     prefix = str(tmp_path / name.replace(".", "_"))
     net.export(prefix)
     sym = mx.sym.load(prefix + "-symbol.json")
     args, auxs = _load_checkpoint_params(prefix)
     params = dict(args)
     params.update(auxs)
-    want = _outputs(sym, params, x.asnumpy())[0]
 
     path = str(tmp_path / (name.replace(".", "_") + ".onnx"))
     onnx_mod.export_model(sym, params, shape, onnx_file_path=path)
-    import os
     assert os.path.getsize(path) > 10000
     sym2, arg2, aux2 = onnx_mod.import_model(path)
     got = _outputs(sym2, {**arg2, **aux2}, x.asnumpy())[0]

@@ -50,15 +50,20 @@ from mxnet_tpu.serving.decode import (DecodeRuntime,  # noqa: E402
                                       DecodeSession, PagedKVCache,
                                       WindowMoELM)
 from mxnet_tpu.serving.decode import window_moe  # noqa: E402
+from decode_block_harness import (MAX_PAGES, PAGE, Kit,  # noqa: E402
+                                  decode_logits, new_cache, programs,
+                                  relative_errors, table_row)
 from perf.harness.weights import seed_key  # noqa: E402
 from perf.reference import mimo_v2 as ref  # noqa: E402
 from perf.systems import window_moe_gateway as system_mod  # noqa: E402
 
 TOL = {"float32": 5e-5, "bfloat16": 5e-2}
-PAGE = 8
-MAX_PAGES = 8
 WINDOW = 8
 REF_PAD = 48            # the reference's sequences, whole blocks of 16
+
+# built and compiled once a configuration: tests/decode_block_harness.py
+KIT = Kit(ref, system_mod, TOL)
+build, assert_close = KIT.build, KIT.assert_close
 
 
 def tiny_cfg(dtype="bfloat16", held=(0, 1, 2, 3, 8, 9), window=WINDOW):
@@ -84,92 +89,12 @@ def tiny_cfg(dtype="bfloat16", held=(0, 1, 2, 3, 8, 9), window=WINDOW):
             "selection_bias_std": 0.1, "precision": {"weights": dtype}}
 
 
-def build(cfg, seed=8, max_length=64):
-    """``(block, reference weights)``: the block holds the reference's own
-    seeded tensors, loaded as the benchmark's system file loads them."""
-    w = ref.weights(cfg, seed_key(seed, stream=1))
-    # the loader empties what it is handed: a copy of the table, not of the
-    # arrays
-    return system_mod.block(cfg, max_length, dict(w), jax.devices()[0]), w
-
-
-def new_cache(net, max_slots=4):
-    return PagedKVCache(layout=net.cache_layout(), page_size=PAGE,
-                        num_pages=24, max_pages_per_seq=MAX_PAGES,
-                        max_slots=max_slots)
-
-
-def table_row(pages, slot_row):
-    row = np.zeros((MAX_PAGES + 1,), "int32")
-    row[:len(pages)] = pages
-    row[-1] = slot_row
-    return row
-
-
-def programs(net, pages):
-    """The block's prefill, commit and step as the runtime runs them:
-    compiled, the cache's page format closed over."""
-    return (jax.jit(net.prefill_math),
-            jax.jit(lambda *a: net.commit_program(*a, pages)),
-            jax.jit(lambda *a: net.step_program(*a, pages)))
-
-
-def decode_logits(net, tokens, n_prompt, pages, slot_row, batch=1, row=0,
-                  seq_pad=16, cache=None, pools=None):
-    """Prefill ``tokens[:n_prompt]`` (padded to ``seq_pad``) and decode the
-    rest, in row ``row`` of a batch of ``batch`` (the other rows are
-    padding) with the global K/V in physical ``pages`` and the rings in
-    state row ``slot_row``: logits of positions ``n_prompt - 1 ..
-    len(tokens) - 1``, the last step's counts, and the pools as the last
-    step left them."""
-    p = net._params_dict(net.param_leaves())
-    if cache is None:
-        cache = new_cache(net)
-        pools = cache.pools
-    prefill, commit, step = programs(net, cache.pages)
-    table = table_row(pages, slot_row)[None]
-    prompt = np.zeros((1, seq_pad), "int32")
-    prompt[0, :n_prompt] = tokens[:n_prompt]
-    lengths = jnp.asarray([n_prompt], "int32")
-    logits, *state = prefill(p, jnp.asarray(prompt), lengths)
-    pools = commit(tuple(state), lengths, jnp.asarray(table), pools)
-    out = [np.asarray(logits[0])]
-    tables = np.zeros((batch, MAX_PAGES + 1), "int32")
-    tables[row] = table[0]
-    extras = None
-    for t in range(n_prompt, len(tokens)):
-        tok = np.zeros((batch,), "int32")
-        pos = np.zeros((batch,), "int32")
-        tok[row], pos[row] = tokens[t], t
-        logits, pools, extras = step(
-            p, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables), pools)
-        out.append(np.asarray(logits[row]))
-    return np.stack(out), extras, pools
-
-
 def reference_logits(w, cfg, tokens, first, precision="float32"):
     """The reference's logits of positions ``first ..`` of ``tokens``."""
     padded = np.zeros((REF_PAD,), "int32")
     padded[:len(tokens)] = tokens
     return np.asarray(ref.forward(w, cfg, jnp.asarray(padded), precision,
                                   query_block=16))[first:len(tokens)]
-
-
-def relative_errors(got, want):
-    """The largest error of each position, as a share of the largest
-    logit."""
-    return np.abs(got - want).max(1) / np.abs(want).max()
-
-
-def assert_close(got, want, dtype):
-    """Every position within the tolerance; in bfloat16, but for the one
-    position in ten that an expert choice's flip may move (module
-    docstring)."""
-    assert np.abs(want).max() > 0.5     # logits of order 1, not zeros
-    err = relative_errors(got, want)
-    allowed = 0 if dtype == "float32" else -(-len(err) // 10)
-    assert (err > TOL[dtype]).sum() <= allowed, err
-    assert np.median(err) <= TOL[dtype] / 2
 
 
 # ------------------------------------------------- (a) against the reference
@@ -251,7 +176,7 @@ def test_prefill_hands_over_the_ring_as_of_the_true_length():
     net, _w = build(tiny_cfg("float32"))
     p = net._params_dict(net.param_leaves())
     tokens = np.random.default_rng(5).integers(0, 97, 11)
-    prefill = jax.jit(net.prefill_math)
+    prefill = programs(net, new_cache(net).pages)[0]
     got = []
     for pad in (16, 24, 32):
         prompt = np.full((1, pad), 96, "int32")       # junk behind the prompt
@@ -646,14 +571,14 @@ def test_cache_holds_two_kinds_of_attention_state_under_one_allocator():
     ("drafter", {"drafter": "ngram"}, "cannot speculate"),
 ])
 def test_what_the_block_does_not_support_says_so(what, kwargs, match):
-    net, _w = build(tiny_cfg())
+    net, _w = build(tiny_cfg(), fresh=True)
     with pytest.raises(ValueError, match=match):
         DecodeSession(net, page_size=PAGE, batch_buckets=(1,),
                       seq_buckets=(8,), warm=False, start=False, **kwargs)
 
 
 def test_mesh_and_bad_patterns_say_so():
-    net, _w = build(tiny_cfg())
+    net, _w = build(tiny_cfg(), fresh=True)
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("model",))
     with pytest.raises(ValueError, match="slot pools, which are not sharded"):
         PagedKVCache(layout=net.cache_layout(), mesh=mesh)
@@ -676,7 +601,7 @@ def test_mesh_and_bad_patterns_say_so():
 
 
 def test_runtime_sizes_slots_and_tables_from_the_block():
-    net, _w = build(tiny_cfg(), max_length=48)
+    net, _w = build(tiny_cfg(), max_length=48, fresh=True)
     rt = DecodeRuntime(net, page_size=PAGE, batch_buckets=(1, 4),
                        seq_buckets=(8, 16), warm=False)
     assert rt.cache.context_length == 48 and rt.cache.max_pages_per_seq == 6
@@ -691,7 +616,7 @@ def test_runtime_sizes_slots_and_tables_from_the_block():
 # ------------------------------------------------- through the normal path
 @pytest.fixture(scope="module")
 def session():
-    net, w = build(tiny_cfg("float32"), seed=5)
+    net, w = build(tiny_cfg("float32"), seed=5, fresh=True)
     sess = DecodeSession(net, page_size=PAGE, batch_buckets=(1, 2, 4),
                          seq_buckets=(8, 16, 32))
     yield sess, net, w
