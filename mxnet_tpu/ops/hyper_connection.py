@@ -21,6 +21,14 @@ themselves by a matrix that 20 Sinkhorn-Knopp rounds make doubly stochastic
 whatever the depth).  The flattened stream's norm has no gain (a gain folds
 into ``phi``).
 
+This module is the DEFINITION, and what a program lowered for the CPU runs.
+Where a program is lowered for the chip, a sublayer's mixing is two kernels,
+``ops.pallas_kernels.hc_pre`` (coefficients, every round, the read) and
+``hc_post`` (the write-back), behind ``ops.pallas_kernels.by_platform``
+(``serving.decode.latent_moe.LatentMoELM._sublayer`` is the caller); the
+two halves hand a token's coefficients over as one lane tile
+(:func:`coef_tile`, :func:`coef_parts`).
+
 Everything here is ``jax.numpy`` in float32 over leading axes of any shape
 (a decode step's ``(B,)``, a prefill's ``(B, S)``).  The ``x' phi`` product
 runs at the highest precision: it has ``n (n + 2)`` columns, so its cost is
@@ -37,7 +45,10 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["hc_coefficients", "sinkhorn", "hc_read", "hc_write",
-           "sinkhorn_residual"]
+           "sinkhorn_residual", "coef_tile", "coef_parts", "COEF_LANES"]
+
+#: a token's coefficients between a sublayer's two halves: one lane tile
+COEF_LANES = 128
 
 
 def sinkhorn(m, iters, eps):
@@ -113,3 +124,22 @@ def sinkhorn_residual(h_res):
     ``|rowsum - 1|`` or ``|colsum - 1|`` of each matrix, ``(...,)``."""
     return jnp.maximum(jnp.abs(h_res.sum(-1) - 1.0).max(-1),
                        jnp.abs(h_res.sum(-2) - 1.0).max(-1))
+
+
+def coef_tile(h_pre, h_post, h_res):
+    """The coefficients of ``T`` tokens as ``(T, COEF_LANES)``: a token's
+    ``Hpre | Hpost | Hres`` (row-major) in its first ``n (n + 2)`` lanes,
+    zeros after: what ``ops.pallas_kernels.hc_pre`` writes and ``hc_post``
+    reads."""
+    n = h_pre.shape[-1]
+    flat = jnp.concatenate([h_pre.reshape((-1, n)), h_post.reshape((-1, n)),
+                            h_res.reshape((-1, n * n))], axis=-1)
+    return jnp.pad(flat, ((0, 0), (0, COEF_LANES - n * (n + 2))))
+
+
+def coef_parts(coef, lead, n):
+    """``(Hpre (..., n), Hpost (..., n), Hres (..., n, n))`` of a
+    :func:`coef_tile` over the leading axes ``lead``."""
+    return (coef[:, :n].reshape(lead + (n,)),
+            coef[:, n:2 * n].reshape(lead + (n,)),
+            coef[:, 2 * n:n * (n + 2)].reshape(lead + (n, n)))

@@ -1148,3 +1148,261 @@ def by_platform(counter, *args, kernel, plain, **labels):
 
     return jax.lax.platform_dependent(*args, cpu=counted(plain, "plain"),
                                       default=counted(kernel, "kernel"))
+
+
+# --------------------------------------------------------------------------
+# fifth resident pair: the hyper-connections of one sublayer of a residual
+# path of ``n`` streams (``ops.hyper_connection`` is their definition),
+# two kernels around the sublayer's ``f`` on a grid of token blocks, so that
+# one pair of bodies serves a decode step's rows (one block) and a prefill's
+# tokens (128 a block).  ``hc_pre``: a block of streams read once; its mean
+# square; the ``n (n + 2)`` coefficients ``x phi`` to float32 accuracy
+# (multiply-accumulates on the vector unit for a step's rows, the MXU at the
+# highest precision for a prefill's block); sigmoids, the clipped
+# exponential and EVERY Sinkhorn round on a token a lane and an entry a
+# sublane, values that never leave the core; the read ``Hpre X`` from the
+# block still held.  ``hc_post``: ``Hres X + Hpost^T y``, the block read once
+# more and written where it lay.  Two launches and three crossings of the
+# streams a sublayer, where XLA made thirty fusions, a loop of two launches a
+# round, and five crossings.  ``serving.decode.latent_moe.LatentMoELM.
+# _sublayer`` is their one caller, through ``by_platform``.
+#
+# (Down here, past ``by_platform``, and not in the module's docstring or
+# among its imports: a kernel's lowered body carries the line numbers of the
+# frames that built it, and the programs of every block above must keep
+# lowering to the text they had.)
+
+from .hyper_connection import COEF_LANES  # noqa: E402
+
+__all__ += ["hc_pre", "hc_post"]
+
+_HC_BLOCK = 128     # tokens a block; fewer (a decode step's rows) are one
+
+
+def _hc_rounds(res, n, iters, eps):
+    """``iters`` Sinkhorn-Knopp rounds on ``res (n n, L)``, entry ``i, j`` of
+    a token's matrix at sublane ``i n + j`` and a token a lane, so that a
+    round is a dozen operations on whole registers whatever ``L``: the
+    columns first (each entry over its column's sum + eps), then the rows,
+    true divisions (``ops.hyper_connection.sinkhorn`` is the definition)."""
+    def one_round(_, rows):
+        col = sum(rows[1:], rows[0]) + eps
+        rows = [r / col for r in rows]
+        return tuple(r / (jnp.sum(r, axis=0, keepdims=True) + eps)
+                     for r in rows)
+
+    return jax.lax.fori_loop(
+        0, iters, one_round, tuple(res[i * n:(i + 1) * n] for i in range(n)))
+
+
+def _hc_row_groups(t, body):
+    """``body(first row, rows)`` for the ``t`` rows of a block eight at a
+    time (a register's sublanes), then the tail."""
+    if t >= 8:
+        def full(g, _):
+            body(pl.multiple_of(g * 8, 8), 8)
+        jax.lax.fori_loop(0, t // 8, full, None)
+    if t % 8:
+        body(t // 8 * 8, t % 8)
+
+
+def _hc_tile(c):
+    """Lanes ``c .. c + 128`` of a row (``c`` a multiple of 128)."""
+    return pl.ds(pl.multiple_of(c, 128), 128)
+
+
+def _hc_products(live_ref, x_ref, phit_ref, zt_ref, ss_ref):
+    """``phi^T x`` into ``zt_ref (K, L)`` and ``sum x^2`` into ``ss_ref (1,
+    L)`` for the tokens ``x_ref (T, n C)``, to float32 accuracy, a token a
+    lane.  A whole block of a prefill's tokens: one product on the MXU at
+    the highest precision, where the vector unit would take 24 passes over
+    the block.  Fewer (a decode step's rows): multiply-accumulates on the
+    vector unit, eight tokens at a time against the whole of ``phi^T``, 128
+    lanes a term, where the MXU would load 112 tiles six times for a few
+    rows; eight rows of which ``live_ref`` names none (the padding behind a
+    step's few rows) are passed over and read as a zero stream's."""
+    t, width = x_ref.shape
+    k = phit_ref.shape[0]
+    if t == _HC_BLOCK:
+        x = x_ref[...]
+        zt_ref[...] = jax.lax.dot_general(
+            phit_ref[...], x, _NT, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        ss_ref[...] = _col_to_row(jnp.sum(x * x, axis=1, keepdims=True))
+        return
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _HC_BLOCK), 1)
+    zeros = lambda r: jnp.zeros((r, 128), jnp.float32)
+    zt_ref[...] = jnp.zeros(zt_ref.shape, jnp.float32)
+    ss_ref[...] = jnp.ones(ss_ref.shape, jnp.float32)
+
+    @functools.partial(_hc_row_groups, t)
+    def group(base, rows):
+        def term(c, acc):
+            at = _hc_tile(c * 128)
+            xc, ph = x_ref[pl.ds(base, rows), at], phit_ref[:, at]
+            return tuple(acc[r] + ph * xc[r:r + 1] for r in range(rows)) \
+                + (acc[rows] + xc * xc,)
+
+        @pl.when(sum(live_ref[base + r] for r in range(rows)) > 0)
+        def _():
+            acc = jax.lax.fori_loop(
+                0, width // 128, term,
+                tuple(zeros(k) for _ in range(rows)) + (zeros(rows),))
+            for r in range(rows):
+                hit = lane == base + r
+                zt_ref[...] = jnp.where(
+                    hit, jnp.sum(acc[r], axis=1, keepdims=True), zt_ref[...])
+                ss_ref[...] = jnp.where(
+                    hit, jnp.sum(acc[rows][r:r + 1], axis=1, keepdims=True),
+                    ss_ref[...])
+
+
+def _hc_pre_kernel(a_ref, b_ref, live_ref, x_ref, phit_ref, u_ref, coef_ref,
+                   zt_ref, ss_ref, *, n, iters, eps, clamp):
+    """One block of tokens before ``f``: the coefficients of
+    ``ops.hyper_connection.hc_coefficients`` with every Sinkhorn round on
+    values that never leave the core, and the read ``u = Hpre X`` from the
+    block still held.  ``coef_ref (T, 128)``: a token's ``Hpre | Hpost |
+    Hres`` (row-major) in its first ``n (n + 2)`` lanes."""
+    t, width = x_ref.shape
+    c, k = width // n, n * (n + 2)
+    _hc_products(live_ref, x_ref, phit_ref, zt_ref, ss_ref)
+    inv = jax.lax.rsqrt(ss_ref[...] / width + eps)
+    row = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
+    scale = jnp.where(row < n, a_ref[0],
+                      jnp.where(row < 2 * n, a_ref[1], a_ref[2]))
+    bias = jnp.zeros((k, 1), jnp.float32)
+    for j in range(k):
+        bias = jnp.where(row == j, b_ref[j], bias)
+    z = zt_ref[...] * inv * scale + bias
+    gate = jax.nn.sigmoid(z[:2 * n])
+    gate = jnp.where(row[:2 * n] < n, gate, 2.0 * gate)
+    res = _hc_rounds(jnp.exp(jnp.clip(z[2 * n:], clamp[0], clamp[1])), n,
+                     iters, eps)
+    # a coefficient a sublane -> a token a sublane: a whole-tile transpose
+    coef_t = jnp.concatenate(
+        (gate,) + tuple(res)
+        + (jnp.zeros((COEF_LANES - k, _HC_BLOCK), jnp.float32),), axis=0)
+    coef_ref[...] = coef_t.T[:t]
+
+    @functools.partial(_hc_row_groups, t)
+    def read(base, rows):
+        at = pl.ds(base, rows)
+        own = coef_ref[at, :]
+        h = [jnp.broadcast_to(own[:, j:j + 1], (rows, 128)) for j in range(n)]
+
+        def tile(i, _):
+            u_ref[at, _hc_tile(i * 128)] = sum(
+                h[j] * x_ref[at, _hc_tile(j * c + i * 128)]
+                for j in range(n))
+
+        jax.lax.fori_loop(0, c // 128, tile, None)
+
+
+def _hc_post_kernel(coef_ref, y_ref, x_ref, o_ref, *, n):
+    """One block of tokens after ``f``: ``X'[i] = sum_j Hres[i, j] X[j] +
+    Hpost[i] y`` (``ops.hyper_connection.hc_write``), the block read once
+    and written where it lay; eight tokens' 20 coefficients stay in
+    registers while their streams go by, 128 lanes a turn."""
+    t, c = y_ref.shape
+
+    @functools.partial(_hc_row_groups, t)
+    def write(base, rows):
+        at = pl.ds(base, rows)
+        own = coef_ref[at, :]
+        h = [jnp.broadcast_to(own[:, j:j + 1], (rows, 128))
+             for j in range(n * (n + 2))]
+
+        def tile(l, _):
+            y = y_ref[at, _hc_tile(l * 128)].astype(jnp.float32)
+            x = [x_ref[at, _hc_tile(j * c + l * 128)] for j in range(n)]
+            for i in range(n):
+                o_ref[at, _hc_tile(i * c + l * 128)] = sum(
+                    (h[2 * n + i * n + j] * x[j] for j in range(n)),
+                    h[n + i] * y)
+
+        jax.lax.fori_loop(0, c // 128, tile, None)
+
+
+def _hc_call(kernel, name, t, width, **kw):
+    """``pallas_call`` on the grid (token blocks,): ``_HC_BLOCK`` tokens a
+    block (7.3 MB of float32 streams at 4 x 3,584; in, out and their second
+    buffers are four), the ragged last block's rows past the end read as
+    they come and never written; a step's rows are one block."""
+    block = min(t, _HC_BLOCK)
+    spec = lambda lanes: pl.BlockSpec((block, lanes), lambda i: (i, 0))
+    return spec, functools.partial(
+        pl.pallas_call, kernel, grid=(pl.cdiv(t, block),),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=5 * block * width * 4 + (16 << 20)),
+        name=name, **kw)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "iters", "eps", "clamp",
+                                             "interpret"))
+def _hc_pre_call(x, phi_t, a, b, live, *, n, iters, eps, clamp, interpret):
+    t, width = x.shape
+    spec, call = _hc_call(
+        functools.partial(_hc_pre_kernel, n=n, iters=iters, eps=eps,
+                          clamp=clamp), "hc_pre", t, width,
+        interpret=interpret)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return call(
+        in_specs=[smem, smem, smem, spec(width),
+                  pl.BlockSpec(phi_t.shape, lambda i: (0, 0))],
+        out_specs=(spec(width // n), spec(COEF_LANES)),
+        out_shape=(jax.ShapeDtypeStruct((t, width // n), jnp.float32),
+                   jax.ShapeDtypeStruct((t, COEF_LANES), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((phi_t.shape[0], _HC_BLOCK), jnp.float32),
+                        pltpu.VMEM((1, _HC_BLOCK), jnp.float32)],
+    )(a, b, live, x, phi_t)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
+def _hc_post_call(x, coef, y, *, n, interpret):
+    t, width = x.shape
+    spec, call = _hc_call(functools.partial(_hc_post_kernel, n=n), "hc_post",
+                          t, width, interpret=interpret)
+    return call(
+        in_specs=[spec(COEF_LANES), spec(width // n), spec(width)],
+        out_specs=spec(width),
+        out_shape=jax.ShapeDtypeStruct((t, width), jnp.float32),
+        input_output_aliases={2: 0},
+    )(coef, y, x)
+
+
+def hc_pre(X, phi_t, a, b, live=None, *, iters, eps, clamp, interpret=False):
+    """The first half of one sublayer's hyper-connections for streams ``X
+    (..., n, C)`` float32 (``ops.hyper_connection.hc_coefficients`` and
+    ``hc_read``, which are its definition): ``(u (..., C), coef (T, 128))``,
+    the sublayer's input ``Hpre X`` and each of the ``T`` tokens'
+    coefficients ``Hpre | Hpost | Hres`` in the first ``n (n + 2)`` lanes of
+    a lane tile (``ops.hyper_connection.coef_tile``), as :func:`hc_post`
+    reads them.  ``phi_t (n (n + 2), n C)`` is ``phi`` with its long axis
+    minor (as registered, its 24 columns tile to 128 lanes).  The streams
+    cross memory once, ``phi`` once a call, and the rounds run on registers.
+    ``C`` is whole lane tiles.  ``live (...)``, where given, marks the
+    tokens that count: among fewer than 128 (a step's rows) eight in a row
+    that are all padding get the coefficients of a zero stream, and their
+    share of the product is not computed."""
+    lead, (n, c) = X.shape[:-2], X.shape[-2:]
+    x = X.astype(jnp.float32).reshape((-1, n * c))
+    live = jnp.ones(x.shape[:1], jnp.int32) if live is None \
+        else live.reshape(-1).astype(jnp.int32)
+    u, coef = _hc_pre_call(
+        x, phi_t, a.astype(jnp.float32), b.astype(jnp.float32), live, n=n,
+        iters=int(iters), eps=float(eps),
+        clamp=(float(clamp[0]), float(clamp[1])), interpret=interpret)
+    return u.reshape(lead + (c,)), coef
+
+
+def hc_post(X, coef, y, *, interpret=False):
+    """The second half: the streams a sublayer leaves, ``Hres X + Hpost^T
+    y`` (``ops.hyper_connection.hc_write``), from :func:`hc_pre`'s ``coef``
+    and the sublayer's output ``y (..., C)``; ``X`` is read once more and
+    written where it lay."""
+    n, c = X.shape[-2:]
+    return _hc_post_call(X.astype(jnp.float32).reshape((-1, n * c)), coef,
+                         y.reshape((-1, c)), n=n,
+                         interpret=interpret).reshape(X.shape)

@@ -303,11 +303,21 @@ class LatentMoELM(HybridBlock):
         """Shape and dtype of the cache rows :meth:`prefill_math` emits."""
         return (self.num_layers, b, s, self.pool_width), self.dtype
 
+    def _served(self, name, array):
+        """A parameter's array as the programs take it: as registered, but
+        a hyper-connection's ``phi`` with its long axis minor (``(n (n + 2),
+        n U)``): as registered its 24 columns tile to 128 lanes on the chip,
+        7.3 MB a sublayer moved where 1.4 are meant."""
+        return array.T if name.endswith("_phi") else array
+
     def _params_dict(self, leaves):
         return dict(zip(self._param_order, leaves))
 
     def param_leaves(self):
-        return [self._reg_params[n].data()._data for n in self._param_order]
+        """The served weights, in ``_param_order`` (:meth:`_served`): made
+        once, when a runtime places them."""
+        return [self._served(n, self._reg_params[n].data()._data)
+                for n in self._param_order]
 
     # ------------------------------------------------------------ pure math
     def _rope(self, x, positions):
@@ -352,25 +362,68 @@ class LatentMoELM(HybridBlock):
         return p[f"l{i}_wkvb"].reshape(
             self.kv_lora_rank, self.num_heads, self.nope_dim + self.v_dim)
 
-    def _sublayer(self, p, i, sub, h, f, resid=None):
+    def _sublayer(self, p, i, sub, h, f, resid=None, live=None):
         """One sublayer (``sub`` is ``"attn"`` or ``"ffn"``) on the residual
         path: ``f`` maps the normed input ``(..., U)`` to the parts of the
         sublayer's output, and this is the one place that adds them to ``h
         (..., U)`` (``hc_mult == 1``) or writes their sum back to the streams
         ``h (..., n, U)``.  ``resid``, a list, receives the sublayer's
-        ``sinkhorn_residual`` a row."""
+        ``sinkhorn_residual`` a row; ``live (...)``, where given, marks the
+        rows that are not padding (the kernels skip what they can of the
+        others; their coefficients are then a zero stream's).
+
+        The mixing is two halves around ``f`` that hand the tokens'
+        coefficients over as one lane tile each (``hyper_connection.
+        coef_tile``): where the program is lowered for the chip, the kernels
+        ``ops.pallas_kernels.hc_pre`` (coefficients, every Sinkhorn round on
+        registers, the read; under the scope ``hc.coef``) and ``hc_post``
+        (the write-back where the streams lie; ``hc.mix``); where it is
+        lowered for the CPU, ``ops.hyper_connection``, which is their
+        definition (``by_platform``: nothing a caller sets chooses, and
+        ``decode.hc.lowered`` counts which was built, once a half).  A hidden
+        width that is not whole lane tiles (tiny blocks) is the definition's
+        on every platform."""
+        import jax
+        from ...ops.pallas_kernels import by_platform, hc_post, hc_pre
         gain = p[f"l{i}_norm_{sub}"]
         if self.hc_mult == 1:
             for part in f(_rms(h, gain, self.eps)):
                 h = h + part
             return h
-        hc = {k: p[f"l{i}_hc_{sub}_{k}"] for k in ("phi", "a", "b")}
-        h_pre, h_post, h_res = _hc.hc_coefficients(
-            h, hc, self.hc_iters, self.hc_eps, self.hc_clamp)
+        lead, n = h.shape[:-2], self.hc_mult
+        rounds = {"iters": self.hc_iters, "eps": self.hc_eps,
+                  "clamp": self.hc_clamp}
+
+        def read(h, phi_t, a, b, _live):
+            h_pre, h_post, h_res = _hc.hc_coefficients(
+                h, {"phi": phi_t.T, "a": a, "b": b}, **rounds)
+            return _hc.hc_read(h, h_pre), _hc.coef_tile(h_pre, h_post, h_res)
+
+        def read_kernel(*args):
+            with jax.named_scope("hc.coef"):
+                return hc_pre(*args, **rounds)
+
+        def write(h, coef, y):
+            _, h_post, h_res = _hc.coef_parts(coef, lead, n)
+            return _hc.hc_write(h, h_res, h_post, y)
+
+        def write_kernel(*args):
+            with jax.named_scope("hc.mix"):
+                return hc_post(*args)
+
+        if self.units % 128:
+            lowered = lambda *args, kernel, plain: plain(*args)
+        else:
+            lowered = functools.partial(by_platform, "decode.hc.lowered",
+                                        tokens=math.prod(lead))
+        u, coef = lowered(
+            h, *(p[f"l{i}_hc_{sub}_{k}"] for k in ("phi", "a", "b")), live,
+            kernel=read_kernel, plain=read)
         if resid is not None:
-            resid.append(_hc.sinkhorn_residual(h_res))
-        parts = f(_rms(_hc.hc_read(h, h_pre), gain, self.eps))
-        return _hc.hc_write(h, h_res, h_post, sum(parts))
+            resid.append(_hc.sinkhorn_residual(
+                _hc.coef_parts(coef, lead, n)[2]))
+        return lowered(h, coef, sum(f(_rms(u, gain, self.eps))),
+                       kernel=write_kernel, plain=write)
 
     def _ffn(self, p, i, h, valid, counts, resid=None):
         """``h + FFN(RMSNorm(h))`` over flat rows ``h (T, U)`` (streams ``(T,
@@ -378,7 +431,7 @@ class LatentMoELM(HybridBlock):
         ``perf/tools/route_flips.py`` follows the program through it."""
         return self._sublayer(
             p, i, "ffn", h,
-            lambda m: self._ffn_parts(p, i, m, valid, counts), resid)
+            lambda m: self._ffn_parts(p, i, m, valid, counts), resid, valid)
 
     def _ffn_parts(self, p, i, m, valid, counts):
         """The parts of ``FFN(m)`` over flat normed rows ``m (T, U)``: the
@@ -540,7 +593,7 @@ class LatentMoELM(HybridBlock):
                         scale=self._scale)
                     return (self._unfold(p, i, ctx),)
 
-            h = self._sublayer(p, i, "attn", h, attend, resid)
+            h = self._sublayer(p, i, "attn", h, attend, resid, valid)
             h = self._ffn(p, i, h, valid, counts, resid)
         hf = _rms(self._merged(h), p["norm_f"], self.eps)
         with jax.named_scope("head"):
@@ -587,7 +640,12 @@ class LatentMoELM(HybridBlock):
         leaves = [params[n] for n in self._param_order]
 
         def pure(tok, ln_, *leaf_vals):
-            return self.prefill_math(self._params_dict(leaf_vals), tok, ln_)
+            # the gluon path hands the parameters as registered: the
+            # prefill turns its phis itself, 9 MB a sublayer beside the
+            # hundreds its tokens' streams move
+            return self.prefill_math(self._params_dict(
+                self._served(n, v) for n, v in zip(self._param_order,
+                                                   leaf_vals)), tok, ln_)
 
         return tuple(invoke_fn(pure, [tokens, lengths] + leaves,
                                op_name="latent_moe_prefill"))

@@ -725,6 +725,103 @@ def test_hyper_connections_compile_as_a_loop_for_v5e(one_chip, rows):
     assert not padded, padded[:2]
 
 
+def _hyper_block(hc_mult=4):
+    """A declared (no array) one-layer ``LatentMoELM`` at Xing4.0's hidden
+    width: ``_sublayer`` is what is asked about."""
+    from mxnet_tpu.serving.decode import LatentMoELM
+    return LatentMoELM(vocab_size=512, hidden_size=3584, num_layers=1,
+                       num_heads=4, first_k_dense_replace=1,
+                       intermediate_size=256, hc_mult=hc_mult)
+
+
+@pytest.mark.parametrize("rows", [32, 2048])
+def test_hyper_connections_are_two_kernels_a_sublayer_for_v5e(one_chip, rows):
+    """The same two shapes through the path the chip runs
+    (``LatentMoELM._sublayer`` -> ``by_platform``): compiled for the
+    described chip one sublayer's mixing is two Mosaic calls, ``hc_pre``
+    under ``hc.coef`` and ``hc_post`` under ``hc.mix``, with no loop and next
+    to no fusion around them; neither the streams nor ``phi`` lie padded on
+    their 4- or 24-wide axis (``phi`` arrives with its long axis minor, the
+    streams as rows of ``4 x 3,584`` lanes); the written streams take the
+    buffer of the ones read; and ``decode.hc.lowered`` counts
+    ``kind="kernel"`` once a half."""
+    from mxnet_tpu.test_utils import counted
+    net, n, C = _hyper_block(), 4, 3584
+
+    def sublayer(X, phi_t, a, b, gain, y):
+        p = {"l0_hc_attn_phi": phi_t, "l0_hc_attn_a": a, "l0_hc_attn_b": b,
+             "l0_norm_attn": gain}
+        return net._sublayer(p, 0, "attn", X, lambda m: (m * y,))
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    args = (sds(rows, n, C), sds(n * (n + 2), n * C), sds(3),
+            sds(n * (n + 2)), sds(C), sds(rows, C))
+    fn = jax.jit(sublayer, donate_argnums=0)
+    assert counted("decode.hc.lowered", lambda: fn.lower(*args)) == \
+        {f'{{kind="kernel",tokens="{rows}"}}': 2}
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"(.*)$", text,
+                       re.M)
+    assert len(calls) == 2 and "/hc.coef/" in calls[0] \
+        and "hc_pre" in calls[0] and "/hc.mix/" in calls[1] \
+        and "hc_post" in calls[1], calls
+    assert not re.findall(r" while\(", text)
+    assert len(re.findall(r" fusion\(", text)) < 10
+    padded = re.findall(
+        rf"f32\[(?:{rows},4,3584|14336,24|{rows},4|{rows},4,4)\]\S*T\(8,128\)",
+        text)
+    assert not padded, padded[:2]
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        rows * n * C * 4
+
+
+def test_plain_residual_programs_name_no_hyper_connection_kernel(one_chip):
+    """``hc_mult == 1`` returns before any of it: the 32-row step and the
+    prefill of the latent block lower, for the described chip, to text that
+    names no ``hc`` kernel or scope and no ``decode.hc.lowered`` count, and
+    that is, character for character, the text they lower to with
+    ``_sublayer`` put back to the three lines it was before hyper-connections
+    (``h + part`` for each part of ``f(norm(h))``)."""
+    from mxnet_tpu.serving.decode import latent_moe
+    from mxnet_tpu.test_utils import counted
+    rt = _latent_runtime()
+    blk = rt.block
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                                    sharding=one_chip)
+
+    def texts():
+        fn, args = _latent_pool_program(rt, "step", 32, sds)
+        prefill = jax.jit(lambda leaves, tok, ln: blk.prefill_math(
+            blk._params_dict(leaves), tok, ln))
+        return (fn.lower(*args).as_text(), prefill.lower(
+            [sds(p.shape, p.dtype) for p in rt._params],
+            sds((1, 256), "int32"), sds((1,), "int32")).as_text())
+
+    got = []
+    assert counted("decode.hc.lowered", lambda: got.extend(texts())) == {}
+
+    def before(self, p, i, sub, h, f, resid=None, live=None):
+        for part in f(latent_moe._rms(h, p[f"l{i}_norm_{sub}"], self.eps)):
+            h = h + part
+        return h
+
+    was = latent_moe.LatentMoELM._sublayer
+    latent_moe.LatentMoELM._sublayer = before
+    try:
+        rt._step_fns.clear()
+        want = texts()
+    finally:
+        latent_moe.LatentMoELM._sublayer = was
+        rt._step_fns.clear()
+    for text, ref in zip(got, want):
+        assert "hc_pre" not in text and "hc_post" not in text \
+            and "hc." not in text
+        assert text == ref
+
+
 # ------------------------------------------- the window / global block's
 # the cell's geometry (mimo_v2_5_ep16.mixed_lengths): 18,433 pages, 288 a
 # row (4,608 tokens), 33 rings a window layer, the longest prefill bucket
