@@ -16,7 +16,10 @@ written back — the functional analog of the reference's in-place aux updates.
 buffer assignment subsumes the reference's memory planning
 (``src/nnvm/plan_memory.cc``).  The jitted callable is recorded on the
 autograd tape as ONE composite op — exactly how the reference registers
-``_CachedOp`` as an operator so it can be recorded and nested.
+``_CachedOp`` as an operator so it can be recorded and nested.  The block
+that is CALLED owns the program: the hybridized blocks below it run op by
+op inside its trace (as the reference's children run symbolically inside
+the parent's graph) and build a cached op only when called on their own.
 """
 from __future__ import annotations
 
@@ -131,6 +134,27 @@ def _regroup(args, fmt):
 # skip the O(tree) structure-signature walk on the hot path when no
 # registration has happened since their executable was traced
 _GLOBAL_STRUCTURE_COUNTER = 0
+
+
+class _OneProgram(threading.local):
+    """Entered on a thread while it finishes a block's deferred
+    initialisation or traces its ``CachedOp``: every block called meanwhile
+    is part of THAT program and runs op by op (``HybridBlock.__call__``), so
+    a first call builds, traces and compiles one program and not one more a
+    descendant.  A thread's own count, so no descendant's ``_active``,
+    ``_flags`` or ``_cached_op`` is touched and a block shared with a
+    hybridized parent on another thread is left alone."""
+
+    depth = 0
+
+    def __enter__(self):
+        self.depth += 1
+
+    def __exit__(self, *_exc):
+        self.depth -= 1
+
+
+_one_program = _OneProgram()
 
 
 class Block:
@@ -515,7 +539,8 @@ class CachedOp:
         def pure(*raw, __key__=None):
             in_raw, par_raw = raw[:n_in], raw[n_in:]
             old = [h._data for h in handles]
-            with autograd.pause(train_mode=training), _rnd.key_scope(__key__):
+            with autograd.pause(train_mode=training), \
+                    _rnd.key_scope(__key__), _one_program:
                 for h, r in zip(handles, par_raw):
                     h._data = r
                 try:
@@ -791,7 +816,7 @@ class HybridBlock(Block):
             return self.hybrid_forward(_sym_mod, x, *args, **params)
 
     def __call__(self, *args):
-        if self._active:
+        if self._active and not _one_program.depth:
             try:
                 flat_args, in_fmt = _flatten(list(args), "input")
             except AssertionError:
@@ -804,6 +829,21 @@ class HybridBlock(Block):
     def _call_cached_op(self, args, flat_args, in_fmt):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
+        op = self._built_cached_op(args)
+        self._in_sig = (len(flat_args), in_fmt)
+        out = op(*args)
+        for hook in self._forward_hooks.values():
+            hook(self, args, out)
+        return out
+
+    def _built_cached_op(self, args):
+        """This block's ``CachedOp``, built here where there is none or a
+        descendant's structure changed since it was traced: the one build
+        path (a call, ``compile_for``).  Parameters whose shapes were
+        deferred are finished by one op-by-op pass of ``forward`` over
+        ``args`` in which no descendant builds a cached op of its own
+        (``_OneProgram``): each would compile a program nothing calls
+        again."""
         if self._cached_op is not None and \
                 self._cached_counter != _GLOBAL_STRUCTURE_COUNTER:
             # some block somewhere registered a child: do the real (rare)
@@ -818,22 +858,16 @@ class HybridBlock(Block):
             else:
                 self._cached_counter = _GLOBAL_STRUCTURE_COUNTER
         if self._cached_op is None:
-            # ensure params are initialized (finishing deferred init
-            # eagerly) — only on the first, cache-building call
             try:
                 for p in self.collect_params().values():
                     p.data()
             except DeferredInitializationError:
-                with autograd.pause():
-                    self.forward(*args)  # dry-run finishes deferred init
+                with autograd.pause(), _one_program:
+                    self.forward(*args)
             self._cached_op = CachedOp(self, self._flags)
             self._cached_sig = self._structure_sig()
             self._cached_counter = _GLOBAL_STRUCTURE_COUNTER
-        self._in_sig = (len(flat_args), in_fmt)
-        out = self._cached_op(*args)
-        for hook in self._forward_hooks.values():
-            hook(self, args, out)
-        return out
+        return self._cached_op
 
     def hybrid_forward(self, F, x, *args, **kwargs):
         """Override to implement computation using ``F`` (reference
@@ -883,18 +917,7 @@ class HybridBlock(Block):
             return None
         if not flat or not all(isinstance(a, NDArray) for a in flat):
             return None
-        # mirror _call_cached_op's build path (deferred init + CachedOp)
-        if self._cached_op is None or \
-                self._cached_sig != self._structure_sig():
-            try:
-                for p in self.collect_params().values():
-                    p.data()
-            except DeferredInitializationError:
-                with autograd.pause():
-                    self.forward(*example_inputs)
-            self._cached_op = CachedOp(self, self._flags)
-            self._cached_sig = self._structure_sig()
-            self._cached_counter = _GLOBAL_STRUCTURE_COUNTER
+        op = self._built_cached_op(example_inputs)
         self._in_sig = (len(flat), in_fmt)
         shapes, dtypes = io_signature(flat)
         if cache_key is None:
@@ -903,17 +926,16 @@ class HybridBlock(Block):
         hit = cache.load(cache_key)
         if hit is not None:
             fn, extra = hit
-            self._cached_op.aot_install(
+            op.aot_install(
                 flat, in_fmt, fn, extra.get("out_fmt"),
                 aux_changed=extra.get("aux_changed"))
         else:
-            sig, compiled, out_fmt = \
-                self._cached_op.aot_compile(flat, in_fmt)
+            sig, compiled, out_fmt = op.aot_compile(flat, in_fmt)
             # an installed executable never traces: what tracing learned
             # of the graph's outputs is stored beside it
             cache.store(cache_key, compiled, extra={
                 "out_fmt": out_fmt,
-                "aux_changed": self._cached_op._aux_changed[sig[0]][0]})
+                "aux_changed": op._aux_changed[sig[0]][0]})
         return (shapes, dtypes)
 
     def compile_grid(self, make_example, buckets, cache=None):

@@ -58,7 +58,12 @@ __all__ = ["start_server", "stop_server", "server_port",
 # Conflating them is the classic outage amplifier: a breaker opening
 # under load flips readiness, and a liveness probe wired to the same
 # surface would have the orchestrator kill-looping a healthy process.
-_health_lock = threading.Lock()
+# A lock its holder may take again: a dead ``DecodeScheduler`` is cyclic
+# garbage whose ``__del__`` unregisters it, and the collector may run that on
+# the thread that holds this lock (``_register`` allocates a weakref under
+# it).  The sections below are dict operations, whole before and after any
+# point at which the collector can come in.
+_health_lock = threading.RLock()
 _health = {}        # name -> weakref to an object with .healthy
 _ready = {}         # name -> weakref to an object with .ready (or .healthy)
 

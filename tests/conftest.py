@@ -9,12 +9,22 @@ chip is reached only by ``python chip_smoke.py`` through the chip tool.
 import contextlib
 import os
 import signal
+import tempfile
 import threading
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
+# Where the suite's compile cache lives (turned on below), unless it is placed
+# from outside: a fixed name under the system's temporary directory, because a
+# whole run leaves 14,000 entries and 120 MB, too much to keep in a checkout
+# that is copied.  Through the environment, so that the children the tests
+# start share it, every program kept however quickly it compiled.
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR",
+    os.path.join(tempfile.gettempdir(), "mxnet_tpu_suite_jax_cache"))
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
 # setdefault leaves a JAX_PLATFORMS that names the chip in place (the chip
 # machine's own is "tpu,cpu") — force CPU through the config API too, so
@@ -26,18 +36,14 @@ jax.config.update("jax_platforms", "cpu")
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-import mxnet_tpu.telemetry.http as _http  # noqa: E402
+from mxnet_tpu.runtime import compile_cache  # noqa: E402
 
-# ROADMAP D20, the program's to mend: a dead ``DecodeScheduler`` is cyclic
-# garbage whose ``__del__`` takes the health registries' lock (``close`` ->
-# ``unregister_ready``), and the collector may run it on the thread that
-# HOLDS that lock (``_register`` allocates a weakref under it): a plain lock
-# then waits for itself, and a worker stood still for the rest of the run
-# (``test_decode.py::test_circuit_breaker_opens_and_probes``, twice of two
-# whole runs once a fixture moved the collector's phase).  No file under
-# ``mxnet_tpu/`` is this suite's to edit, so here the registries are held
-# under a lock the same thread may take again; delete this with the mend.
-_http._health_lock = threading.RLock()
+# A program is compiled once: every process of the suite (the six workers,
+# and the children they start) shares the program's own persistent compile
+# cache.  More than half of the suite's seconds were compiles, the same
+# seeding, broadcasting and elementwise programs once a process.  A module
+# whose programs must come from the compiler takes ``compiled_anew`` below.
+compile_cache()
 
 
 @pytest.fixture(autouse=True)
@@ -97,23 +103,33 @@ def _limit(request):
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def compiled_anew():
+    """The suite's persistent compilation cache off around a module whose
+    programs have to come from the compiler: a compile for a described chip
+    (``one_chip``) is written to the cache but cannot be read back without
+    one, and would warn; and on the CPU an executable that the cache LOADED
+    serializes without its kernels, so a ``serving.aot.ProgramCache`` entry
+    stored from one fails where it is loaded (``tests/test_aot_cache.py``;
+    ``ROADMAP.md`` D22)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(compiled_anew):
     """``SingleDeviceSharding`` on the first device of a described v5e 2x2
-    host (``tests/test_chip_compile*.py``), with the persistent compilation
-    cache off around the module: a compile for a described chip is written
-    to the cache but cannot be read back without one, and would warn."""
+    host (``tests/test_chip_compile*.py``)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # noqa: BLE001 — no TPU compiler: skip
         pytest.skip(f"cannot describe a v5e topology here: {e!r}"[:300])
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])

@@ -16,6 +16,9 @@ from mxnet_tpu.serving import aot
 from mxnet_tpu.serving.aot import (AOT_FORMAT, _MAGIC, ProgramCache,
                                    model_signature)
 
+# what this file stores are programs the compiler made in this process
+pytestmark = pytest.mark.usefixtures("compiled_anew")
+
 _M = len(_MAGIC)
 
 ITEM = (24,)

@@ -456,6 +456,48 @@ def test_circuit_breaker_opens_and_probes(runtime):
         s.close(drain=False, timeout=10.0)
 
 
+def test_dead_scheduler_collected_inside_a_registration(runtime, monkeypatch):
+    """A dead scheduler is cyclic garbage, and the collector may run its
+    ``__del__`` (``close`` -> ``unregister_ready``) on the thread that HOLDS
+    the registries' lock, at the weakref ``_register`` allocates under it:
+    that registration comes back and does not wait for its own lock."""
+    import gc
+    import types
+    import weakref
+
+    from mxnet_tpu.telemetry import http
+
+    class Probe:
+        ready = True
+
+    def ref_with_the_collector_in_it(obj):
+        gc.collect()
+        return weakref.ref(obj)
+
+    monkeypatch.setattr(
+        http, "weakref", types.SimpleNamespace(ref=ref_with_the_collector_in_it))
+    probe, gone = Probe(), []
+    gc.collect()
+    gc.disable()            # the dead scheduler waits for the collect above
+    try:
+        dead = DecodeScheduler(runtime, start=False)
+        dead.close(drain=False, timeout=10.0)
+        dead._itself = dead
+        weakref.finalize(dead, gone.append, True)
+        del dead
+        registering = threading.Thread(
+            target=http.register_ready, args=("probe:d20", probe), daemon=True)
+        registering.start()
+        registering.join(20.0)
+    finally:
+        gc.enable()
+    assert not registering.is_alive(), \
+        "register_ready waits for a lock its own thread holds"
+    assert gone == [True]
+    assert http.readiness()[1].get("probe:d20") is True
+    http.unregister_ready("probe:d20", probe)
+
+
 # ------------------------------------------------------------- telemetry
 def test_decode_telemetry_counters(runtime):
     telemetry.enable()

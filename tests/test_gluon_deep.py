@@ -492,3 +492,74 @@ def test_hybrid_stale_cache_nested_child_add():
     assert outer(mx.nd.ones((2, 3, 5))).shape == (2, 3, 10)
     inner.add(nn.Flatten())            # nested structural change
     assert outer(mx.nd.ones((2, 3, 5))).shape == (2, 30)
+
+
+# ------------------------------------------- one cached op for a first call
+def _three_levels():
+    """Dense layers with no ``in_units`` one, two and three blocks down: every
+    parameter's shape is deferred to the first call."""
+    inner = nn.HybridSequential()
+    inner.add(nn.Dense(6, activation="relu"), nn.Dense(5))
+    mid = nn.HybridSequential()
+    mid.add(inner, nn.Dense(4, activation="relu"))
+    net = nn.HybridSequential()
+    net.add(mid, nn.Dense(3))
+    net.initialize()
+    return net
+
+
+def _descendants(block):
+    for child in block._children.values():
+        yield child
+        yield from _descendants(child)
+
+
+@pytest.mark.parametrize("first", ["call", "compile_for", "compile_for_stored"])
+def test_first_call_with_deferred_shapes_builds_one_cached_op(first, tmp_path):
+    """A hybridized block's first call finishes deferred initialisation op by
+    op and then builds, traces and compiles ITS program: no descendant is
+    left holding a cached op of its own (each was a compile nothing called
+    again), through a call and through both forms of ``compile_for``; a
+    child called alone afterwards builds its own then."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.serving.aot import ProgramCache
+    net = _three_levels()
+    net.hybridize()
+    x = mx.nd.array(np.random.RandomState(3).rand(2, 7).astype("float32"))
+    telemetry.enable()
+    telemetry.reset()
+    try:
+        if first == "call":
+            got = net(x)
+        else:
+            stored = ProgramCache(str(tmp_path), "three_levels") \
+                if first == "compile_for_stored" else None
+            assert net.compile_for(x, cache=stored) == (((2, 7),),
+                                                        ("float32",))
+            with mx.autograd.pause(train_mode=False):
+                got = net(x)
+        after_first = dict(telemetry.snapshot()["counters"])
+        assert net._cached_op is not None
+        assert [b.name for b in _descendants(net)
+                if b._cached_op is not None] == []
+        assert all(b._active for b in _descendants(net))
+        assert net[0][0][0].weight.shape == (6, 7)
+        mid = net[0]
+        alone = mid(x)
+        assert mid._cached_op is not None
+        assert [b.name for b in _descendants(mid)
+                if b._cached_op is not None] == []
+        counters = telemetry.snapshot()["counters"]
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    # a stored program is installed, not traced: it counts as no recompile
+    assert after_first.get("cachedop.recompiles", 0) == \
+        (0 if first == "compile_for_stored" else 1)
+    assert counters["cachedop.recompiles"] == \
+        after_first.get("cachedop.recompiles", 0) + 1
+    net.hybridize(False)
+    np.testing.assert_allclose(got.asnumpy(), net(x).asnumpy(),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(alone.asnumpy(), net[0](x).asnumpy(),
+                               rtol=1e-6, atol=1e-6)

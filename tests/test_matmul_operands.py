@@ -83,14 +83,13 @@ def _run_tape(net, x, target):
 
 
 def _run_hybrid(net, x, target):
-    """CachedOp graphs: a hybridized block's call draws one key and opens
-    a scope on it, and that holds for each child in turn (``ffn_1``,
-    ``act``, ``ffn_2``, then ``drop``, inside whose scope the op draws)."""
+    """A CachedOp graph: the hybridized block's call draws one key and
+    opens a scope on it; its children are part of its program and open
+    none, so the Dropout op takes the scope's first key, as in
+    ``SPMDTrainer``'s step."""
     net.hybridize()
     loss, grads, key = _run_tape(net, x, target)
-    for _child in ("ffn_1", "act", "ffn_2", "drop"):
-        key, sub = jax.random.split(key)
-    return loss, grads, jax.random.split(sub)[1]
+    return loss, grads, jax.random.split(key)[1]
 
 
 def _run_spmd(net, x, target):

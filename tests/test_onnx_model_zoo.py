@@ -13,14 +13,7 @@ its first dense layer then 512 wide and not 25,088; the others end in a pool
 of a fixed window, or in strides, that need the map they were drawn for, and
 one pixel less leaves the first dense layer no input (``(classes, 0)``).
 Every family and the tolerance are as they were, and every file is still
-over 10,000 bytes.
-
-The block runs once op by op before it is hybridized: a hybridized block's
-first call finishes deferred initialisation by a dry run in which every child
-block compiles a program of its own (246 for ``mobilenetv2_0.25``;
-``ROADMAP.md``, D19), which is the program's start-up cost and not this
-file's claim; ``tests/test_gluon.py`` and ``tests/test_gluon_deep.py`` hold
-that path.
+over 10,000 bytes.  The block is hybridized cold, as a user's is.
 """
 import os
 
@@ -68,7 +61,6 @@ def test_model_zoo_roundtrip_real_bytes(name, shape, tmp_path):
     net = mx.gluon.model_zoo.vision.get_model(name, classes=10)
     net.initialize()
     x = mx.nd.array(rng.rand(*shape).astype("float32"))
-    net(x)              # deferred initialisation, op by op (module docstring)
     net.hybridize()
     want = net(x).asnumpy()     # the block's own answer is the reference
     prefix = str(tmp_path / name.replace(".", "_"))
