@@ -392,7 +392,7 @@ def check_kv_write_span(cache, slot, position, n_tokens):
     trash page.  Callers guard on ``sanitizer.slots``."""
     if not slots:
         return
-    ps = cache.page_size
+    ps = cache.page_tokens
     first = int(position) // ps
     last = (int(position) + max(int(n_tokens) - 1, 0)) // ps
     for idx in range(first, min(last, len(slot.pages) - 1) + 1):

@@ -44,6 +44,16 @@ discipline (PAPER.md design point #2) to that loop:
   alone pages; routed + shared SwiGLU experts in every layer as a chip's
   share.  No drafter, no quantized pools, no mesh; prefix sharing is
   skipped.
+- :class:`EvaLM` (``eva_lm.py``) — the ``evabyte`` family's block, dense and
+  byte-level: every layer attends exactly inside the query's own window of
+  ``window_size`` positions and, in the SAME softmax, over one learned
+  summary a ``chunk_size`` chunk of every window before it.  A slot keeps a
+  RING of the open window's keys and values (entry ``e`` live iff ``e <=
+  position mod window``); the summaries page, a ROW standing for
+  ``chunk_size`` tokens (the layout's ``row_tokens``, which the cache's
+  reservations and the scheduler's page arithmetic follow).  Eight
+  prediction heads, head 0 served.  No drafter, no quantized pools, no mesh;
+  prefix sharing is skipped.
 - :class:`PagedKVCache` (``kv_cache.py``) — device-resident page pools
   with a trash page for padding, generation-stamped slots (the ShmRing
   discipline: a post-free read raises ``StaleKVSlotError`` under
@@ -110,6 +120,7 @@ from .latent_moe import LatentMoELM  # noqa: F401
 from .hybrid_moe import HybridSSMMoELM  # noqa: F401
 from .window_moe import WindowMoELM  # noqa: F401
 from .linear_moe import LinearMoELM  # noqa: F401
+from .eva_lm import EvaLM  # noqa: F401
 from .runtime import DecodeRuntime, seq_bucket_ladder  # noqa: F401
 from .scheduler import (  # noqa: F401
     DecodeScheduler,
@@ -124,7 +135,7 @@ from .speculate import (  # noqa: F401
 )
 
 __all__ = ["CausalLM", "LatentMoELM", "HybridSSMMoELM", "WindowMoELM",
-           "LinearMoELM",
+           "LinearMoELM", "EvaLM",
            "get_decode_model", "rowdot",
            "sample_math",
            "kv_quantize_rows", "kv_dequantize",

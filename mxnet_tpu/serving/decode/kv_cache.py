@@ -92,13 +92,15 @@ __all__ = ["PagedKVCache", "KVSlot", "KVCacheExhausted", "pages_needed"]
 TRASH_PAGE = 0
 
 
-def pages_needed(prompt_len, max_new_tokens, page_size):
-    """Pages a request reserves at admission.  Written positions are the
-    prompt (``0..n-1``) plus every generated token that is fed back
-    (``n..n+max_new-2`` — the last sampled token is returned, never
-    re-encoded), so the reservation covers ``n + max_new - 1`` positions."""
+def pages_needed(prompt_len, max_new_tokens, page_tokens):
+    """Pages a request reserves at admission, of ``page_tokens`` tokens each
+    (``cache.page_tokens``: the page's rows, times the tokens a row stands
+    for).  Written positions are the prompt (``0..n-1``) plus every
+    generated token that is fed back (``n..n+max_new-2`` — the last sampled
+    token is returned, never re-encoded), so the reservation covers ``n +
+    max_new - 1`` positions."""
     written = int(prompt_len) + max(int(max_new_tokens) - 1, 0)
-    return -(-max(written, 1) // int(page_size))
+    return -(-max(written, 1) // int(page_tokens))
 
 
 class KVCacheExhausted(RuntimeError):
@@ -198,13 +200,14 @@ class PagedKVCache:
         ``kv_format.SlotState``), with ``layers`` then counting only the
         layers that page.
     page_size : int
-        Tokens per page.
+        Rows per page: tokens per page, but for a block whose layout states
+        ``row_tokens`` (``page_tokens`` is then ``page_size * row_tokens``).
     num_pages : int
         Total pages *including* the reserved trash page 0; usable
         capacity is ``num_pages - 1``.
     max_pages_per_seq : int
         Page-table length — fixes the decode step's gathered context at
-        ``max_pages_per_seq * page_size`` tokens (the model's effective
+        ``max_pages_per_seq * page_tokens`` tokens (the model's effective
         context window; constant shape = one program per batch bucket).
     max_slots : int
         Concurrent-sequence bound (the scheduler's max batch bucket).
@@ -251,10 +254,13 @@ class PagedKVCache:
         self.head_dim = pools[0][1] // self.num_heads if self.num_heads \
             else None
         self.page_size = int(page_size)
+        #: tokens a page stands for: its rows, times the tokens of a row
+        #: (1 but for a block whose layout states ``row_tokens``)
+        self.page_tokens = self.pages.page_tokens
         self.num_pages = int(num_pages)
         self.max_pages_per_seq = int(max_pages_per_seq)
         self.max_slots = int(max_slots)
-        self.context_length = self.max_pages_per_seq * self.page_size
+        self.context_length = self.max_pages_per_seq * self.page_tokens
         self.dtype = str(dtype)
         #: the per-sequence state pools' format, or None
         self.state = self.pages.state
@@ -342,7 +348,7 @@ class PagedKVCache:
     @property
     def page_bytes(self):
         """Device bytes one page costs (every pool, all layers)."""
-        return self.kv_bytes_per_token * self.page_size
+        return self.pages.row_bytes * self.page_size
 
     @property
     def pools(self):
@@ -397,7 +403,7 @@ class PagedKVCache:
         ``h_i = H(h_{i-1} || tokens_of_page_i)`` — equal hashes mean equal
         tokens at equal positions, which (row-stable math) means bitwise
         equal committed K/V."""
-        ps = self.page_size
+        ps = self.page_tokens
         out, h = [], b"kv-chain-0"
         for i in range(len(prompt) // ps):
             h = hashlib.sha1(
@@ -612,7 +618,7 @@ class PagedKVCache:
                 self._gauge_prefix_locked()
                 return
             tail = None
-            if prompt.size % self.page_size:
+            if prompt.size % self.page_tokens:
                 if not self._free_pages:
                     self._reclaim_locked(1)
                 if not self._free_pages:

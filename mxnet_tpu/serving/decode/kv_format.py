@@ -2,8 +2,9 @@
 that reach into one.
 
 A cache's attention state is a tuple of device arrays, the *pools*, each
-``(layers, num_pages, page_size, row width)``: the value pools the block's
-``cache_layout()`` states, in its order, then the format's float32
+``(layers, num_pages, page_size, row width)`` (the row by heads, ``(heads,
+head_dim)``, where the layout states a ``row_shape``): the value pools the
+block's ``cache_layout()`` states, in its order, then the format's float32
 *sidecars* ``(layers, num_pages, page_size)``, each value pool's side by
 side: ``(k, v)`` raw, ``(k, v, k_scale, k_mid, v_scale, v_mid)`` in int8,
 ``(k, v, k_scale, v_scale)`` in fp8_e4m3, ``(latent,)`` for a
@@ -263,26 +264,54 @@ class PageFormat:
                 f"supported")
         self.kv_dtype = kv_dtype
         self.quantized = codec is not None
+        #: ROWS a page holds; a row stands for ``row_tokens`` tokens, so a
+        #: page for ``page_tokens`` of them
         self.page_size = int(page_size)
+        self.page_tokens = self.tokens_a_page(layout, page_size)
+        self.row_tokens = self.page_tokens // self.page_size
         self.num_layers = int(layout["layers"])
         self._per = codec[3] if codec else 0
         self.num_sidecars = self._per * len(self.pool_layout)
         self._stored = tuple(jnp.dtype(codec[0] if codec else d)
                              for _n, _w, d in self.pool_layout)
-        #: device bytes one token position costs across every pool (all
-        #: layers), sidecars included
-        self.kv_bytes_per_token = self.num_layers * sum(
+        #: device bytes one row costs across every pool (all layers),
+        #: sidecars included, and one token position's part of it
+        self.row_bytes = self.num_layers * sum(
             w * dt.itemsize + 4 * self._per
             for (_n, w, _d), dt in zip(self.pool_layout, self._stored))
-        # a row that is a concatenation of heads reads back as (heads,
+        self.kv_bytes_per_token = self.row_bytes // self.row_tokens
+        # a row is stored flat, ``(width,)``, unless the layout states a
+        # ``row_shape`` for its pools (``(heads, head_dim)``: the chip tiles
+        # an array's two minor axes, and a flat row split into heads after
+        # the gather is a copy of everything gathered)
+        shape = tuple(int(d) for d in layout.get("row_shape") or ())
+        if shape and any(math.prod(shape) != w
+                         for _n, w, _d in self.pool_layout):
+            raise ValueError(
+                f"row_shape={shape} is not the {self.pool_layout} rows'")
+        self._stored_rows = tuple(shape or (w,)
+                                  for _n, w, _d in self.pool_layout)
+        # a flat row that is a concatenation of heads reads back as (heads,
         # head_dim): the axes a quantized format reduces over
         heads = layout.get("shard_heads")
-        self._row_shapes = tuple((heads, w // heads) if heads else (w,)
-                                 for _n, w, _d in self.pool_layout)
+        self._row_shapes = tuple(
+            (heads, w // heads) if heads else stored
+            for (_n, w, _d), stored in zip(self.pool_layout,
+                                           self._stored_rows))
         #: the per-sequence state pools behind the page pools, or None
         self.state = SlotState(
             layout["state"], len(self.pool_layout) + self.num_sidecars) \
             if layout.get("state") else None
+
+    @staticmethod
+    def tokens_a_page(layout, page_size):
+        """Tokens a page of ``page_size`` rows stands for under a block's
+        ``layout``: a row is one token's, or, where the layout states
+        ``row_tokens`` (a chunk summary: one row for every ``chunk_size``
+        tokens), that many tokens'.  The ONE place that reads the key; the
+        cache's reservations, the scheduler's page index of a position and
+        the runtime's context all go through it."""
+        return int(page_size) * int(layout.get("row_tokens", 1))
 
     def addresses(self, tables):
         """``(page tables (B, pages a row), state rows (B,) or None)`` of a
@@ -298,8 +327,8 @@ class PageFormat:
         format (the trash page, pages never written)."""
         import jax.numpy as jnp
         shape = (self.num_layers, int(num_pages), self.page_size)
-        return tuple(jnp.zeros(shape + (w,), dt) for (_n, w, _d), dt
-                     in zip(self.pool_layout, self._stored)) + \
+        return tuple(jnp.zeros(shape + row, dt) for row, dt
+                     in zip(self._stored_rows, self._stored)) + \
             tuple(jnp.zeros(shape, "float32")
                   for _ in range(self.num_sidecars))
 
@@ -316,16 +345,16 @@ class PageFormat:
         def put(j, x):
             pools[j] = pools[j].at[layer, page, offset].set(x)
 
-        def flat(x):
-            return x.reshape(page.shape + (-1,))
+        def flat(j, x):
+            return x.reshape(page.shape + self._stored_rows[j])
 
         if self._codec is None:
             for j, x in enumerate(rows):
-                put(j, flat(x).astype(pools[j].dtype))
+                put(j, flat(j, x).astype(pools[j].dtype))
             return tuple(pools)
         coded = [self._codec[1](x) for x in rows]
         for j, c in enumerate(coded):
-            put(j, flat(c[0]))
+            put(j, flat(j, c[0]))
         for j, c in enumerate(coded):
             for s, side in enumerate(c[1:]):
                 put(n + j * self._per + s, side)

@@ -42,6 +42,7 @@ from ...telemetry import bus as _tel
 from ..aot import as_program_cache
 from ..runtime import default_buckets, place_block
 from .kv_cache import PagedKVCache
+from .kv_format import PageFormat
 
 __all__ = ["DecodeRuntime", "StepFlight", "seq_bucket_ladder"]
 
@@ -177,9 +178,10 @@ class DecodeRuntime:
         # the context a rotary block was built for
         max_length = int(layout["max_length"])
         if cache is None:
-            # floor, not ceil: the derived context (max_pages * page_size)
-            # must never exceed the model's max_length
-            max_pages = max_length // int(page_size)
+            # floor, not ceil: the derived context (max_pages * the tokens
+            # a page stands for) must never exceed the model's max_length
+            max_pages = max_length // PageFormat.tokens_a_page(layout,
+                                                               page_size)
             if max_pages < 1:
                 raise ValueError(
                     f"page_size={page_size} exceeds the model's "

@@ -369,7 +369,7 @@ class DecodeScheduler:
             raise ValueError(
                 f"prompt ({prompt.size}) + max_new_tokens ({max_new}) "
                 f"exceeds the context window ({ctx})")
-        n_pages = pages_needed(prompt.size, max_new, self._cache.page_size)
+        n_pages = pages_needed(prompt.size, max_new, self._cache.page_tokens)
         # request base key: any deterministic uint32 pair works (the step
         # program folds the per-request token index into it); derived in
         # numpy so submit() never touches the jax dispatch path
@@ -990,7 +990,7 @@ class DecodeScheduler:
             # an invariant instead of an accident.
             for _r, req in live:
                 cache.ensure_writable(req.slot,
-                                      req.position // cache.page_size)
+                                      req.position // cache.page_tokens)
         if tokens is None:
             tokens = np.zeros((b,), "int32")
             for r, req in live:
@@ -1096,8 +1096,8 @@ class DecodeScheduler:
                 # the verify writes positions [position, position + k]:
                 # privatize EVERY page that span touches, not just the
                 # current one (a draft can cross a page boundary)
-                first = req.position // cache.page_size
-                last = (req.position + int(d.size)) // cache.page_size
+                first = req.position // cache.page_tokens
+                last = (req.position + int(d.size)) // cache.page_tokens
                 for idx in range(first, last + 1):
                     cache.ensure_writable(req.slot, idx)
             if _san.slots:

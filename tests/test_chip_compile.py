@@ -1169,3 +1169,131 @@ def test_linear_programs_touch_only_their_slots_and_pages(one_chip, kind, b):
     assert stats.temp_size_in_bytes < (24 << 20) + b * (1 << 20), \
         f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
         f"beside {pool_bytes / 1e9:.3f} GB of pools"
+
+
+# ------------------------------------------ the EVA-attention byte-level block's
+# the cell's geometry (evabyte_pp2.doc_bytes): 385 pages of 16 summary rows,
+# 48 a row (12,288 bytes at 16 bytes a row), 9 state rows (8 slots + trash)
+_E_PAGES, _E_ROW_PAGES, _E_SLOTS, _E_SEQ = 385, 48, 8, 2048
+
+
+def _eva_runtime():
+    """EvaByte's layer at every published width (hidden 4,096, 32 heads of
+    128, SwiGLU 11,008, a window of 2,048 and chunks of 16, eight heads of
+    320 logits), two layers."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.serving.decode import DecodeRuntime, EvaLM, PagedKVCache
+    net = EvaLM(vocab_size=320, hidden_size=4096, num_layers=2,
+                num_attention_heads=32, intermediate_size=11008,
+                window_size=2048, chunk_size=16, num_pred_heads=8,
+                max_length=_E_ROW_PAGES * _PAGE * 16)
+    for p in net.collect_params().values():
+        p._load_init(mx.nd.zeros(p.shape, dtype=p.dtype), None)
+    cache = PagedKVCache(layout=net.cache_layout(), page_size=_PAGE,
+                         num_pages=2, max_pages_per_seq=_E_ROW_PAGES,
+                         max_slots=1)
+    return DecodeRuntime(net, cache=cache, batch_buckets=(1,),
+                         seq_buckets=(_E_SEQ,), warm=False)
+
+
+def _eva_program(rt, kind, b, sds):
+    """``(jitted program, its arguments with the pools last, the pools)``;
+    the pools at the cell's size."""
+    i32, u32, f32 = "int32", "uint32", "float32"
+    blk, n_paged = rt.block, len(rt.cache.pool_layout)
+    pools = tuple(
+        sds(p.shape[:1] + ((_E_PAGES,) if j < n_paged else (_E_SLOTS + 1,))
+            + p.shape[2:], p.dtype) for j, p in enumerate(rt.cache.pools))
+    params = [sds(p.shape, p.dtype) for p in rt._params]
+    if kind == "prefill":
+        fn = jax.jit(lambda leaves, tok, ln: blk.prefill_math(
+            blk._params_dict(leaves), tok, ln))
+        return fn, (params, sds((b, _E_SEQ), i32), sds((b,), i32)), pools
+    rows = (sds((b, _E_ROW_PAGES + 1), i32), sds((b, 2), u32),
+            sds((b,), i32), sds((b,), f32))     # tables, keys, steps, temps
+    if kind == "step":
+        return rt._build_step(), \
+            (params, sds((b,), i32), sds((b,), i32)) + rows + pools, pools
+    state = tuple(sds(shape, dtype)
+                  for shape, dtype in blk.prefill_state(b, _E_SEQ))
+    return rt._build_commit(), \
+        (params, state, sds((b, blk.vocab_size), f32), sds((b,), i32)) \
+        + rows + pools, pools
+
+
+def test_eva_prefill_holds_no_heads_by_s_by_s_array(one_chip):
+    """A prompt of one window, 2,048 bytes: the float32 scores of ONE layer
+    as a ``(heads, S, S)`` array would be 32 x 2,048^2 x 4 B = 537 MB.  The
+    program goes by blocks of 512 queries over the window's keys and the
+    chunks' summaries (32 x 512 x (2,048 + 128) float32, 143 MB) and the
+    SwiGLU by windows of rows: no array is half of it, and all its
+    temporaries together are less than it."""
+    import numpy as np
+    rt = _eva_runtime()
+    assert rt.cache.context_length == 12288 and rt.cache.page_tokens == 256
+    fn, args, _pools = _eva_program(
+        rt, "prefill", 1, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    compiled = fn.lower(*args).compile()
+    heads, S = 32, _E_SEQ
+    weights = {tuple(p.shape) for p in args[0]}
+    for op, dtype, dims in _materialised(compiled.as_text()):
+        if dims in weights:
+            continue
+        assert int(np.prod(dims)) <= heads * S * S // 2, \
+            f"eva prefill: {op} writes {dtype}{list(dims)}"
+        assert len(dims) < 3 or sum(d == S for d in dims) < 2, \
+            f"eva prefill: {op} writes {dtype}{list(dims)}: S x S"
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        heads * S * S * 4
+
+
+@pytest.mark.parametrize("kind,b", [("step", 1), ("step", 8), ("commit", 1)])
+def test_eva_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
+    """A ring a slot (9 x 33.5 MB a layer) and summary pages in one donated
+    tuple.  No step or commit program copies a pool, and each gives every
+    pool back in the buffer it came in.  A step reads a row's ring WHERE IT
+    LIES, sliced out of the pool by the row's slot: it holds no gathered
+    ring (``b x 2,048`` entries of 32 x 128), widened or laid out anew,
+    and of the summaries the rows' reserved pages (``b x 48 x 16`` rows,
+    6.3 MB a row and pool) and nothing beyond."""
+    import numpy as np
+    rt = _eva_runtime()
+    fn, args, pools = _eva_program(
+        rt, kind, b, lambda shape, dtype: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one_chip))
+    kbar, vbar, ring_k, ring_v = pools
+    assert kbar.shape == vbar.shape == (2, _E_PAGES, _PAGE, 32, 128)
+    assert ring_k.shape == ring_v.shape == (2, _E_SLOTS + 1, 2048, 32, 128)
+    compiled = fn.lower(*args).compile()
+    what = f"eva {kind}-b{b}"
+    own = {int(np.prod(p.shape)) for p in pools}
+    weights = {tuple(p.shape) for p in args[0]}
+    for op, dtype, dims in _materialised(compiled.as_text()):
+        if op in ("parameter", "get-tuple-element", "bitcast") or \
+                dims in weights:
+            continue
+        n = int(np.prod(dims))
+        if dtype == "bf16" and n in own:
+            assert op != "copy", f"{what}: copies a whole pool {dtype}{dims}"
+            continue
+        if kind == "step":
+            # no ring, in any dtype, and nothing larger than the rows'
+            # gathered summaries (b x 768 rows of 4,096) or a streamed weight
+            assert not (2048 in dims and dims[-2:] == (32, 128)), \
+                f"{what}: {op} writes {dtype}{list(dims)}: a ring"
+            assert n <= max(b * 768 * 4096, 4096 * 11008), \
+                f"{what}: {op} writes {dtype}{list(dims)}"
+    stats = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    assert stats.alias_size_in_bytes >= pool_bytes, what
+    if kind == "commit":
+        assert stats.temp_size_in_bytes < (8 << 20), what
+        return
+    # the rows' gathered summaries (12.6 MB a row), the rows' vectors,
+    # weights streamed ahead of their use: 3.0 MB at b = 1, 55.6 at b = 8
+    # (sandbox compiles, PR 43).  Gathered side by side and handed to the
+    # MXU the rings alone were 841 MB of temporaries at b = 8
+    assert stats.temp_size_in_bytes < (8 << 20) + b * (7 << 20), \
+        f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
+        f"beside {pool_bytes / 1e9:.3f} GB of pools"

@@ -88,6 +88,57 @@ def test_pages_needed():
     assert pages_needed(1, 16, 8) == 2
 
 
+@pytest.mark.parametrize("prompt,max_new", [
+    (1, 1), (255, 2), (256, 2), (2048, 64), (5120, 192), (10240, 768),
+    (12288 - 767, 768)])
+def test_a_row_of_16_tokens_reserves_a_page_every_256(prompt, max_new):
+    """A block whose layout states ``row_tokens`` (a chunk summary: one row
+    for every 16 tokens) reserves ``ceil(written positions / 256)`` pages of
+    16 rows: 48 for a context of 12,288, where a row a token takes 768; the
+    cache's context, its page arithmetic and an allocation follow."""
+    from mxnet_tpu.serving.decode import EvaLM
+    net = EvaLM(window_size=2048, chunk_size=16, max_length=12288)
+    cache = PagedKVCache(layout=net.cache_layout(), page_size=16,
+                         num_pages=49, max_pages_per_seq=48, max_slots=1)
+    assert (cache.page_size, cache.page_tokens) == (16, 256)
+    assert cache.context_length == 12288
+    written = prompt + max_new - 1
+    n = pages_needed(prompt, max_new, cache.page_tokens)
+    assert n == -(-written // 256) <= 48
+    assert pages_needed(prompt, max_new, cache.page_size) == -(-written // 16)
+    slot = cache.alloc(n)
+    assert len(slot.pages) == n and len(slot.page_table) == 48 + 1
+    cache.free(slot)
+    assert cache.stats()["pages_in_use"] == 0
+
+
+@pytest.mark.parametrize("block", ["CausalLM", "LatentMoELM",
+                                   "HybridSSMMoELM", "WindowMoELM",
+                                   "LinearMoELM"])
+def test_every_other_block_reserves_a_row_a_token_as_it_did(block):
+    """The stride is 1 for every block that states none: a page holds
+    ``page_size`` tokens, the context is ``pages x page_size`` and a request
+    reserves exactly the pages it did."""
+    from mxnet_tpu.serving import decode
+    from mxnet_tpu.serving.decode import PageFormat
+    net = get_decode_model("decode_small", vocab_size=VOCAB) \
+        if block == "CausalLM" else getattr(decode, block)()
+    layout = net.cache_layout()
+    assert "row_tokens" not in layout and "row_shape" not in layout
+    assert PageFormat.tokens_a_page(layout, 16) == 16
+    cache = PagedKVCache(layout=layout, page_size=8, num_pages=9,
+                         max_pages_per_seq=4, max_slots=2)
+    assert cache.page_tokens == cache.page_size == 8
+    assert cache.context_length == 32
+    assert cache.page_bytes == cache.kv_bytes_per_token * 8
+    for prompt, max_new in ((3, 1), (8, 2), (9, 8), (1, 16), (20, 13)):
+        assert pages_needed(prompt, max_new, cache.page_tokens) == \
+            -(-(prompt + max_new - 1) // 8)
+    # every pool's row is flat, as the block states its width
+    assert [p.shape[3:] for p in cache.pools[:len(cache.pool_layout)]] == \
+        [(w,) for _n, w, _d in cache.pool_layout]
+
+
 def test_seq_bucket_ladder():
     assert seq_bucket_ladder(64) == (8, 16, 32, 64)
     assert seq_bucket_ladder(48) == (8, 16, 32, 48)

@@ -67,7 +67,7 @@ def main():
     n, steps = args.rows, args.steps
     b = rt.batch_bucket_for(n)
     slots = [cache.alloc(pages_needed(args.context, 3 * steps + 2,
-                                      cache.page_size)) for _ in range(n)]
+                                      cache.page_tokens)) for _ in range(n)]
     rng = np.random.RandomState(args.seed % (2 ** 31))
     first = np.zeros((b,), "int32")
     first[:n] = rng.randint(1, cell.config["vocab_size"], n)
