@@ -1406,3 +1406,226 @@ def hc_post(X, coef, y, *, interpret=False):
     return _hc_post_call(X.astype(jnp.float32).reshape((-1, n * c)), coef,
                          y.reshape((-1, c)), n=n,
                          interpret=interpret).reshape(X.shape)
+
+
+# --------------------------------------------------------------------------
+# sixth resident kernel: one query token a row over TWO operand pairs under
+# ONE softmax, each read where it lies and only where it is live: the OPEN
+# window's exact keys and values in the slot's rings (entries ``0 .. position
+# mod window``; "same window as the query", not "the last W") and the chunk
+# summaries of the CLOSED windows in the row's pages (rows ``0 .. position //
+# window * window / row_tokens - 1``).  ``paged_attention``'s walk with a
+# second operand: one batch row a grid step, blocks of ``block`` columns
+# double-buffered through two blocks of VMEM a pool, a ring block ONE
+# contiguous DMA a pool and a summary block a DMA a page, the second buffer
+# filling across the seam between the two; a running max, denominator and
+# accumulator in float32 carried through all of them, ``o = acc / l`` once.
+# The scores are joined, never the keys.  ``serving.decode.kv_format.
+# PageFormat.attend_window`` is its one caller, through ``by_platform``.
+#
+# Rows and ring entries are stored by head, ``(heads, head_dim)``, and one
+# query a K/V head is no shape for the MXU as it lies.  A block in VMEM is
+# read as ``(block * heads, head_dim)`` (a free view: the heads are whole
+# sublane tiles) and every head's query meets every (column, head) row in
+# ONE product, ``q (heads, head_dim) . rows^T -> (heads, block * heads)``, of
+# which a head keeps the columns that are its own (``column mod heads ==
+# head``; the others are masked out of the softmax like dead columns) and
+# the context is ``p . rows -> (heads, head_dim)``: ``heads`` times the
+# arithmetic of a step whose arithmetic is small, as ``paged_attention``'s
+# block-diagonal ``q`` is, on lane-dense tiles with no reduction across
+# lanes and nothing laid out anew.
+#
+# (Down here for the reason the fifth pair is: the line numbers above stay.)
+
+__all__ += ["eva_attention"]
+
+
+def _eva_kernel(layer_ref, tables_ref, rows_ref, pos_ref, q_ref, sk_hbm,
+                sv_hbm, rk_hbm, rv_hbm, o_ref, k_buf, v_buf, sems, *,
+                pages_a_row, block_pages, per_window, scale):
+    """One batch row a grid step.  Blocks ``0 .. ring_blocks - 1`` are the
+    ring's (``ring[layer, state row, i block : (i + 1) block]``, fetched
+    whole: the entries of the last one past ``position mod window`` hold
+    what the closed window or the slot's last owner left and are masked),
+    the blocks behind them the summaries' (``block_pages`` pages each, page
+    ``j`` from ``pool[layer, tables[row, j]]``, only those that hold a row
+    of a closed window).  A row in its first window has no summary block; a
+    padded row (its first page the trash page) asks for nothing and gives
+    zeros."""
+    b = pl.program_id(0)
+    layer, first, state_row = layer_ref[0], b * pages_a_row, rows_ref[b]
+    _slots, block, heads, width = k_buf.shape
+    page, window = block // block_pages, rk_hbm.shape[2]
+    entries = jax.lax.rem(pos_ref[b], window) + 1
+    summaries = jax.lax.div(pos_ref[b], window) * per_window
+    ring_blocks = pl.cdiv(entries, block)
+    blocks = ring_blocks + pl.cdiv(summaries, block)
+    pairs = ((rk_hbm, sk_hbm, k_buf), (rv_hbm, sv_hbm, v_buf))
+    precision = jax.lax.Precision.HIGHEST if k_buf.dtype == jnp.float32 \
+        else None
+
+    def live_of(i):
+        # the columns of block ``i`` that the query may read
+        return jnp.minimum(block, jnp.where(
+            i < ring_blocks, entries - i * block,
+            summaries - (i - ring_blocks) * block))
+
+    def each_copy(i, slot, what):
+        @pl.when(i < ring_blocks)
+        def _():
+            at = pl.ds(pl.multiple_of(i * block, block), block)
+            for n, (ring, _pool, buf) in enumerate(pairs):
+                what(pltpu.make_async_copy(ring.at[layer, state_row, at],
+                                           buf.at[slot], sems.at[n, slot]))
+
+        @pl.when(i >= ring_blocks)
+        def _():
+            def one(j, _):
+                at = tables_ref[first + (i - ring_blocks) * block_pages + j]
+                rows = pl.ds(pl.multiple_of(j * page, page), page)
+                for n, (_ring, pool, buf) in enumerate(pairs):
+                    what(pltpu.make_async_copy(pool.at[layer, at],
+                                               buf.at[slot, rows],
+                                               sems.at[n, slot]))
+            jax.lax.fori_loop(0, pl.cdiv(live_of(i), page), one, None)
+
+    # column ``j`` of a block's scores is (column j // heads, head j mod
+    # heads): a head's own are those of its number
+    column = jax.lax.broadcasted_iota(jnp.int32, (heads, block * heads), 1)
+    own = jax.lax.rem(column, heads) == jax.lax.broadcasted_iota(
+        jnp.int32, (heads, block * heads), 0)
+
+    def attend(i, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < blocks)
+        def _():
+            each_copy(i + 1, 1 - slot, lambda c: c.start())
+
+        each_copy(i, slot, lambda c: c.wait())
+        live = live_of(i)
+
+        # a column past the live ones holds what the ring held there, or
+        # what the buffer held before: its scores are masked, and its values
+        # are made zeros, which a probability of zero leaves zeros
+        def forget(e, _):
+            v_buf[slot, e] = jnp.zeros((heads, width), v_buf.dtype)
+        jax.lax.fori_loop(live, block, forget, None)
+        k = k_buf[slot].reshape(block * heads, width)
+        v = v_buf[slot].reshape(block * heads, width)
+        s = jax.lax.dot_general(q_ref[0], k, _NT, precision=precision,
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(jnp.logical_and(own, column < live * heads), s, _NEG)
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - new_m)
+        corr = jnp.exp(m - new_m)
+        pv = jnp.dot(p.astype(v.dtype), v, precision=precision,
+                     preferred_element_type=jnp.float32)
+        return (new_m, l * corr + jnp.sum(p, axis=-1, keepdims=True),
+                acc * corr + pv)
+
+    @pl.when(tables_ref[first] != 0)
+    def _():
+        each_copy(0, 0, lambda c: c.start())
+        _m, l, acc = jax.lax.fori_loop(
+            0, blocks, attend,
+            (jnp.full((heads, 1), _NEG, jnp.float32),
+             jnp.zeros((heads, 1), jnp.float32),
+             jnp.zeros((heads, width), jnp.float32)))
+        o_ref[0] = acc / l
+
+    @pl.when(tables_ref[first] == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("row_tokens", "block_pages",
+                                             "interpret"))
+def _eva_call(layer, tables, rows, positions, q, sk_pool, sv_pool, ring_k,
+              ring_v, *, row_tokens, block_pages, interpret):
+    """``pallas_call`` on the grid (batch rows,) with the layer, the flat
+    page tables, the rows' state rows and the positions as prefetched
+    scalars and the four WHOLE pools left where they are (``pl.ANY``): the
+    kernel fetches what it needs.  One jitted function of its arrays, the
+    layer among them: every layer of every step program lowers this once and
+    the chip compiles one body a shape.  Two blocks a pool of VMEM: 128
+    columns of 32 heads of 128 in bfloat16 are 1 MB, so 4 MB, and the
+    scores, probabilities and masks of a block 0.5 MB each beside them."""
+    b, heads, width = q.shape
+    page, window = sk_pool.shape[2], ring_k.shape[2]
+    block = block_pages * page
+    buffers = lambda pool: pltpu.VMEM((2, block, heads, width), pool.dtype)
+    by_row = pl.BlockSpec((1, heads, width), lambda i, *_: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_eva_kernel, pages_a_row=tables.shape[1],
+                          block_pages=block_pages,
+                          per_window=window // row_tokens,
+                          scale=width ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(b,),
+            in_specs=[by_row] + [pl.BlockSpec(memory_space=pl.ANY)] * 4,
+            out_specs=by_row,
+            scratch_shapes=[buffers(sk_pool), buffers(sv_pool),
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=4 * block * heads * width
+            * sk_pool.dtype.itemsize + 12 * block * heads * heads * 4
+            + (8 << 20)),
+        interpret=interpret, name="eva_attention",
+    )(layer, tables.reshape(-1), rows, positions, q, sk_pool, sv_pool,
+      ring_k, ring_v)
+
+
+def eva_attention(q, sk_pool, sv_pool, ring_k, ring_v, layer, tables, rows,
+                  positions, *, row_tokens, block_pages=None,
+                  interpret=False):
+    """Attention of ONE query token a row, ``q (b, heads, head_dim)``
+    float32 at ``positions (b,)``, under ONE softmax (scale ``head_dim **
+    -0.5``) over two kinds of column, each where it lies:
+
+    - the exact keys and values of the query's OWN window: entries ``0 ..
+      position mod window`` of the row's rings, ``ring_k``, ``ring_v
+      (layers, state rows, window, heads, head_dim)`` at ``[layer,
+      rows[i]]`` (the entry this step wrote among them);
+    - the summaries of the windows BEFORE it: rows ``0 .. position // window
+      * (window // row_tokens) - 1`` of the row's pages of ``sk_pool``,
+      ``sv_pool (layers, pages, page size, heads, head_dim)``, page ``j`` of
+      row ``i`` at ``pool[layer, tables[i, j]]``, a row standing for
+      ``row_tokens`` positions.  The row this step wrote belongs to the open
+      window and is never read here.
+
+    ``layer`` a scalar (traced: one kernel for every layer).  Returns ``(b,
+    heads, head_dim)`` float32.
+
+    Only blocks of the ring that hold a live entry and pages that hold a
+    closed window's row cross memory, once; ring entries past ``position
+    mod window`` inside the last block are fetched with it and masked (as
+    values, made zeros); ring blocks and pages past those, pages no table
+    names, other slots' rings and everything of a padded row (``tables[i,
+    0] == 0``, the trash page; its output is zeros) are not read.  The pools
+    are read where they are and keep their bits.
+
+    Products take operands in the pools' dtype (``q`` and the probabilities
+    are cast before them) and accumulate in float32; the softmax is float32,
+    online over blocks of ``block_pages`` pages' rows, which must divide the
+    window: 128 columns where nothing is said (on the chip, EvaByte's
+    widths, three live rows of eight: 97.0 us a layer against 104.7 at 256
+    columns and 119.3 at 512, a shorter first block to wait for and less of
+    the ring's last block read dead; my chip run, PR 44).  The heads must
+    fill whole sublane tiles on the chip (16 in bfloat16)."""
+    page, window = sk_pool.shape[2], ring_k.shape[2]
+    if block_pages is None:
+        block_pages = max(1, math.gcd(window, 128) // page)
+    if window % (block_pages * page):
+        raise ValueError(
+            f"a block of {block_pages} pages of {page} rows does not divide "
+            f"the window of {window}: a ring block is one slice of the ring")
+    i32 = lambda x: x.astype(jnp.int32)
+    return _eva_call(jnp.asarray(layer, jnp.int32).reshape(1), i32(tables),
+                     i32(rows), i32(positions), q.astype(sk_pool.dtype),
+                     sk_pool, sv_pool, ring_k, ring_v,
+                     row_tokens=int(row_tokens), block_pages=block_pages,
+                     interpret=interpret)

@@ -43,8 +43,14 @@ column.
   from the chunk's ``c`` ring entries: the last such write, at ``t mod c = c -
   1``, is the whole chunk's, and no earlier one can be seen.
 
-The step gathers the row's ring and its reserved summary rows and takes one
-masked softmax over both.  **Prefill never holds a ``(heads, S, S)`` array**:
+The step's attention is one call a layer through the page format's door
+(``kv_format.PageFormat.attend_window``): lowered for the chip, ONE kernel
+(``ops.pallas_kernels.eva_attention``) that reads the live rows' ring entries
+``0 .. t mod W`` and the summary pages of their closed windows out of the
+whole pools where they lie, under one softmax, and nothing of a padded row;
+lowered for the CPU, the definition: the row's whole ring and its reserved
+summary rows gathered and :meth:`EvaLM.attend_row`'s one masked softmax over
+both.  **Prefill never holds a ``(heads, S, S)`` array**:
 it goes by query blocks of at most one window (:attr:`query_block` queries at
 a time), each over its own window's keys and the summaries of the windows
 before; the commit hands the slot the open window's keys and values at their
@@ -156,9 +162,12 @@ class EvaLM(HybridBlock):
         (``row_tokens``: a fact of the layout, not a setting), one layer of
         the ``kbar`` and ``vbar`` pools a layer of the block; and a slot's
         rings of the open window's keys and values.  Rows and ring entries
-        are stored by head, ``(heads, head_dim)``: the step reads them so,
-        and splitting a flat row of a gathered ring into heads is a copy of
-        the ring (sandbox compile, PR 43).  Not quantizable, not sharded."""
+        are stored by head, ``(heads, head_dim)``: the step's kernel reads a
+        block of them as ``(columns x heads, head_dim)`` rows, a view and
+        not a copy because the heads are whole sublane tiles, and the CPU's
+        form multiplies and reduces them so (splitting a flat row of a
+        gathered ring into heads was a copy of the ring: sandbox compile,
+        PR 43).  Not quantizable, not sharded."""
         hd = (self.heads, self.head_dim)
         return {"layers": self.num_layers,
                 "pools": (("kbar", self.units, self.dtype),
@@ -258,14 +267,15 @@ class EvaLM(HybridBlock):
         """:meth:`attend` for ONE row's one query, ``q (heads, head_dim)``
         float32 over its ring ``k``, ``v (L, heads, head_dim)`` where ``live
         (L,)`` and its summaries ``sk``, ``sv (J, heads, head_dim)`` where
-        ``seen (J,)``: the step's form.  A product of one row a head is no
-        work for the MXU, and handed to it the rows' rings are first gathered
-        side by side, widened to float32 and laid out anew, five copies of
-        every key and value they hold (sandbox compile, PR 43); so scores and
-        context are multiply-and-reduce over the slot's ring WHERE IT LIES in
-        the pool and over the gathered summaries, each read once in the
-        stored precision, the scores laid out ``(columns, heads)`` as the
-        keys are.  Returns ``(heads, head_dim)`` float32."""
+        ``seen (J,)``: the DEFINITION of the step's attention, and its form
+        where a step program is lowered for the CPU (``PageFormat.
+        attend_window`` hands it a row's whole ring and every reserved
+        summary row; lowered for the chip the step is ``ops.pallas_kernels.
+        eva_attention``, which reads the live ones alone and is held to this
+        by ``tests/test_eva_attention_kernel.py``).  Scores and context are
+        multiply-and-reduce by head, each operand read once in the stored
+        precision and every sum float32, the scores laid out ``(columns,
+        heads)`` as the keys are.  Returns ``(heads, head_dim)`` float32."""
         import jax
         import jax.numpy as jnp
         f32 = jnp.float32
@@ -388,11 +398,15 @@ class EvaLM(HybridBlock):
         """Pure fused decode step, one byte a row.  ``tables`` ends with each
         row's state slot (``pages.addresses``).  Every layer writes the
         byte's K/V into entry ``position mod W`` of the slot's ring, pools
-        the chunk that holds the position from its ring entries into summary
+        the chunk that holds the position from its ``chunk_size`` ring
+        entries (sliced out of the pool: no ring is read for it) into summary
         row ``position // chunk_size`` of the row's pages, and attends over
-        the ring (entries ``<= position mod W``) and the row's gathered
-        summaries (those of closed windows) under one softmax.  Padded rows
-        (page table all trash) use the trash slot and the trash page.
+        the ring (entries ``<= position mod W``) and the row's summaries
+        (those of closed windows) under one softmax: ``pages.attend_window``
+        on the pools the two writes returned, one kernel a layer on the chip
+        and :meth:`attend_row` over the gathered state on the CPU.  Padded
+        rows (page table all trash) write to the trash slot and the trash
+        page and attend over nothing.
         Returns ``(logits (B, vocab) [head 0], pools, (drafts (B, heads - 1)
         int32: the other heads' first choices, counts (4,) int32: live ring
         entries, live summary rows, windows closed, live rows))``."""
@@ -409,27 +423,21 @@ class EvaLM(HybridBlock):
                                  axis=1)[:, 0]
         woff = sidx % pages.page_size
         closed = positions // W * (W // c)
-        live = jnp.arange(W)[None, :] <= went[:, None]
-        seen = jnp.arange(ptab.shape[1] * pages.page_size)[None, :] \
-            < closed[:, None]
         for i in range(self.num_layers):
             a = self._norm(h, p[f"l{i}_norm_attn"])
             q, k, v = self._qkv(p, i, a, positions)
             with jax.named_scope("attn.eva"):
                 pools = pages.state.write_at(pools, i, srow, went, (k, v))
-                # a row's ring is read where it lies: sliced out of the
-                # pool by the row's slot, not gathered beside the others'
-                rings = [pages.state.read(pools, i, srow[b])
-                         for b in range(B)]
+                # the chunk's entries are sliced out of the pool by the
+                # row's slot and the chunk's first entry: no ring is read
                 chunk = [[jax.lax.dynamic_slice_in_dim(x, first[b], c)
-                          for x in rings[b]] for b in range(B)]
+                          for x in pages.state.read(pools, i, srow[b])]
+                         for b in range(B)]
             summary = self.pool(p, i, *(jnp.stack(x) for x in zip(*chunk)))
             with jax.named_scope("attn.eva"):
                 pools = pages.write(pools, i, wp, woff, summary)
-                sk, sv = pages.read(pools, i, ptab)
-            o = jnp.stack([self.attend_row(q[b], *rings[b], live[b], sk[b],
-                                           sv[b], seen[b])
-                           for b in range(B)])
+                o = pages.attend_window(pools, i, ptab, srow, positions, q,
+                                        self.attend_row)
             h = self._mlp(p, i, h + _dot(o.reshape(B, -1), p[f"l{i}_wo"]))
         logits = self.head_logits(p, h)
         valid = ptab[:, 0] != 0

@@ -1,5 +1,5 @@
-"""The KV page format: what a page pool stores, and the only three functions
-that reach into one.
+"""The KV page format: what a page pool stores, and the only doors (three,
+and a fourth for a block whose slots hold rings) that reach into one.
 
 A cache's attention state is a tuple of device arrays, the *pools*, each
 ``(layers, num_pages, page_size, row width)`` (the row by heads, ``(heads,
@@ -73,9 +73,9 @@ the trash slot, as page 0 is the trash page.  A block whose
 ``cache_layout()`` has a ``state`` section gets them behind the page pools
 in the same donated tuple, and :class:`SlotState` (``pages.state``) is
 their only ``read`` and ``write``, and the one door (``in_place``) through
-which a kernel gets a whole state pool with the layer and the rows to find
-its blocks by.  A sequence's row of ``tables`` then
-ends with its state row: :meth:`PageFormat.addresses` splits the two.
+which a kernel gets a whole state pool to WRITE.  A sequence's row of
+``tables`` then ends with its state row (:meth:`PageFormat.addresses`).
+:meth:`PageFormat.attend_window` is the fourth door: rings and pages read.
 """
 from __future__ import annotations
 
@@ -243,7 +243,7 @@ class PageFormat:
     Built by :class:`~mxnet_tpu.serving.decode.kv_cache.PagedKVCache` from
     the block's ``cache_layout()`` and ``kv_dtype``; the commit, step and
     verify programs of a block receive it as ``pages`` and reach the pools
-    only through :meth:`write`, :meth:`read` and :meth:`attend`."""
+    only through ``write``, ``read``, ``attend`` and ``attend_window``."""
 
     def __init__(self, layout, kv_dtype=None, page_size=16):
         import jax.numpy as jnp
@@ -436,3 +436,61 @@ class PageFormat:
 
         return by_platform("decode.attn.paged.lowered", *pools[:n],
                            kernel=kernel, plain=gathered, rows=q.shape[0])
+
+    def attend_window(self, pools, layer, tables, rows, positions, q, plain):
+        """Attention of ONE query token a row at ``positions (B,)`` for a
+        block that keeps the OPEN window's exact keys and values in a ring a
+        slot (the layout's ``state``: two arrays ``(window,) + row``) and,
+        in its K and V pages, one row for every ``row_tokens`` positions of
+        the windows before: ONE softmax over the ring's entries ``0 ..
+        position mod window`` of state row ``rows (B,)`` and the page rows
+        ``0 .. position // window * (window // row_tokens) - 1`` of
+        ``tables (B, pages a row)`` (hand over the pools that the step's
+        ``state.write_at`` and :meth:`write` returned).  ``q (B, heads,
+        head_dim)`` float32; returns ``(B, heads, head_dim)`` float32.  The
+        window and ``row_tokens`` are the layout's; nothing is an argument.
+
+        Where the program is lowered for the chip this is ONE kernel
+        (``ops.pallas_kernels.eva_attention``) that reads the live rows'
+        live ring blocks and live pages out of the whole pools where they
+        lie and nothing else.  Where it is lowered for the CPU it is the
+        definition: a row's whole ring (indexed out of the pool once, as
+        ``state.read`` does), :meth:`read` of the reserved pages and the
+        block's own ``plain(q (heads, head_dim),
+        ring_k, ring_v, live (window,), summary keys, summary values, seen
+        (reserved rows,))`` a row.  The counter ``decode.attn.eva.lowered``
+        (``kind="kernel" | "plain"``) says which was lowered; nothing a
+        caller sets chooses."""
+        import jax.numpy as jnp
+        from ...ops.pallas_kernels import by_platform, eva_attention
+        n, state = len(self.pool_layout), self.state
+        rings = [shape for _n, shape, _d in state.arrays] if state else []
+        if self._codec is not None or n != 2 or len(rings) != 2 or any(
+                ring[1:] != row or ring[0] % self.row_tokens
+                for ring, row in zip(rings, self._stored_rows)):
+            raise ValueError(
+                f"attend_window reads raw K and V pools of rows by head and "
+                f"a slot's two rings of such rows, a window of whole rows' "
+                f"tokens; not {self.kv_dtype} pools of {self.pool_layout} "
+                f"with the state {state and state.arrays}")
+        window = rings[0][0]
+
+        def kernel(*arrays):
+            return eva_attention(q, *arrays, layer, tables, rows, positions,
+                                 row_tokens=self.row_tokens)
+
+        def gathered(*arrays):
+            went = positions % window
+            closed = positions // window * (window // self.row_tokens)
+            live = jnp.arange(window)[None, :] <= went[:, None]
+            sk, sv = self.read(arrays[:n], layer, tables)
+            seen = jnp.arange(sk.shape[1])[None, :] < closed[:, None]
+            return jnp.stack([
+                plain(q[b], *(ring[layer, rows[b]] for ring in arrays[n:]),
+                      live[b], sk[b], sv[b], seen[b])
+                for b in range(q.shape[0])])
+
+        return by_platform(
+            "decode.attn.eva.lowered", *pools[:n],
+            *pools[state.first:state.first + 2], kernel=kernel,
+            plain=gathered, rows=q.shape[0])

@@ -1252,11 +1252,12 @@ def test_eva_prefill_holds_no_heads_by_s_by_s_array(one_chip):
 def test_eva_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
     """A ring a slot (9 x 33.5 MB a layer) and summary pages in one donated
     tuple.  No step or commit program copies a pool, and each gives every
-    pool back in the buffer it came in.  A step reads a row's ring WHERE IT
-    LIES, sliced out of the pool by the row's slot: it holds no gathered
-    ring (``b x 2,048`` entries of 32 x 128), widened or laid out anew,
-    and of the summaries the rows' reserved pages (``b x 48 x 16`` rows,
-    6.3 MB a row and pool) and nothing beyond."""
+    pool back in the buffer it came in.  A step's attention is ONE kernel a
+    layer (``eva_attention`` under the scope ``attn.eva``) that is handed
+    the four WHOLE pools and fetches what is live: the program holds no
+    ring (``b x 2,048`` entries of 32 x 128), in any dtype, and no gathered
+    summaries (``b x 768`` rows of 4,096 were 6.3 MB a row and pool): its
+    largest array is a streamed weight."""
     import numpy as np
     rt = _eva_runtime()
     fn, args, pools = _eva_program(
@@ -1278,11 +1279,12 @@ def test_eva_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
             assert op != "copy", f"{what}: copies a whole pool {dtype}{dims}"
             continue
         if kind == "step":
-            # no ring, in any dtype, and nothing larger than the rows'
-            # gathered summaries (b x 768 rows of 4,096) or a streamed weight
-            assert not (2048 in dims and dims[-2:] == (32, 128)), \
-                f"{what}: {op} writes {dtype}{list(dims)}: a ring"
-            assert n <= max(b * 768 * 4096, 4096 * 11008), \
+            # no ring and no row's reserved summaries, in any dtype, and
+            # nothing larger than a streamed weight
+            assert not ({2048, 768} & set(dims) and dims[-2:] == (32, 128)), \
+                f"{what}: {op} writes {dtype}{list(dims)}: a ring or a " \
+                f"row's summaries"
+            assert n <= 4096 * 11008, \
                 f"{what}: {op} writes {dtype}{list(dims)}"
     stats = compiled.memory_analysis()
     pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
@@ -1290,10 +1292,65 @@ def test_eva_programs_touch_only_their_rings_and_pages(one_chip, kind, b):
     if kind == "commit":
         assert stats.temp_size_in_bytes < (8 << 20), what
         return
-    # the rows' gathered summaries (12.6 MB a row), the rows' vectors,
-    # weights streamed ahead of their use: 3.0 MB at b = 1, 55.6 at b = 8
-    # (sandbox compiles, PR 43).  Gathered side by side and handed to the
-    # MXU the rings alone were 841 MB of temporaries at b = 8
-    assert stats.temp_size_in_bytes < (8 << 20) + b * (7 << 20), \
+    # the kernel once a layer, under the scope the benchmark reads, each
+    # handed the four pools whole
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"(.*)$",
+                       compiled.as_text(), re.M)
+    assert len(calls) == rt.block.num_layers and all(
+        "/attn.eva/" in c and "eva_attention" in c for c in calls), \
+        [c[:300] for c in calls]
+    # the rows' vectors and weights streamed ahead of their use: 2.9 MB at
+    # b = 1, 2.4 at b = 8 (sandbox compiles, PR 44).  With the summaries
+    # gathered it was 3.0 and 55.6 (PR 43); with the rings gathered side by
+    # side and handed to the MXU, 841 MB at b = 8
+    assert stats.temp_size_in_bytes < (8 << 20) + b * (1 << 20), \
         f"{what}: {stats.temp_size_in_bytes / 1e6:.1f} MB of temporaries " \
         f"beside {pool_bytes / 1e9:.3f} GB of pools"
+
+
+def test_eva_step_counts_the_kernel_once_a_layer(one_chip):
+    """``decode.attn.eva.lowered``: lowering the 8-row step for the
+    described chip counts ``kind="kernel"`` once a layer and ``plain``
+    never; lowering the same program for the CPU, the other way round.
+    Nothing but the platform differs between the two."""
+    from mxnet_tpu.test_utils import counted
+    rt = _eva_runtime()
+    for sharding, kind in ((one_chip, "kernel"), (None, "plain")):
+        fn, args, _pools = _eva_program(
+            rt, "step", 8, lambda shape, dtype: jax.ShapeDtypeStruct(
+                tuple(shape), dtype, sharding=sharding))
+        assert counted("decode.attn.eva.lowered",
+                       lambda: fn.lower(*args)) == \
+            {f'{{kind="{kind}",rows="8"}}': rt.block.num_layers}
+
+
+@pytest.mark.parametrize("block", ["base", "latent", "hybrid", "window",
+                                   "linear"])
+def test_other_blocks_programs_name_no_eva_kernel(one_chip, block):
+    """The EVA kernel and its door are one block's: the step and the commit
+    program of each of the five other block kinds lower, for the described
+    chip, to text that names no ``eva_attention``, and count no
+    ``decode.attn.eva.lowered``.  (That their text is the text they
+    lowered to before the kernel existed no test can hold: ``PERF.md``
+    section 6, PR 44, has the comparison with the parent's tree.)"""
+    from mxnet_tpu.test_utils import counted
+    rt, program, b = {
+        "base": (lambda: _gpt2_medium_runtime(2, "float32"), _pool_program,
+                 8),
+        "latent": (_latent_runtime, _latent_pool_program, 32),
+        "hybrid": (_hybrid_runtime, _hybrid_program, 32),
+        "window": (_window_runtime, _window_program, 32),
+        "linear": (_linear_runtime, _linear_program, 1)}[block]
+    rt = rt()
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                                    sharding=one_chip)
+    texts = []
+
+    def lower():
+        for kind, rows in (("step", b), ("commit", 1)):
+            fn, args, *_pools = program(rt, kind, rows, sds)
+            texts.append(fn.lower(*args).as_text())
+
+    assert counted("decode.attn.eva.lowered", lower) == {}
+    for text in texts:
+        assert "eva_attention" not in text

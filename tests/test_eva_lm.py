@@ -387,3 +387,14 @@ def test_step_counters_ride_the_fetch(session):
     assert "decode.prefix_hits" not in c and "decode.prefix_misses" not in c
     assert g["decode.state_slots_live"] == 0
     assert g["decode.state_bytes"] == sess.cache.state_bytes
+    # on the CPU the step's attention is the definition, ``attend_row`` over
+    # the gathered ring and summaries, counted once a layer where the
+    # program is lowered (for the chip it is the kernel:
+    # ``tests/test_chip_compile.py``)
+    from mxnet_tpu.test_utils import counted
+    step = programs(net, sess.cache.pages)[2]
+    rows = jnp.zeros((2,), "int32")
+    lowered = counted("decode.attn.eva.lowered", lambda: step.lower(
+        net._params_dict(net.param_leaves()), rows, rows,
+        jnp.zeros((2, sess.cache.table_width), "int32"), sess.cache.pools))
+    assert lowered == {'{kind="plain",rows="2"}': net.num_layers}
