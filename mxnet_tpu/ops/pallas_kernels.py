@@ -1629,3 +1629,195 @@ def eva_attention(q, sk_pool, sv_pool, ring_k, ring_v, layer, tables, rows,
                      sk_pool, sv_pool, ring_k, ring_v,
                      row_tokens=int(row_tokens), block_pages=block_pages,
                      interpret=interpret)
+
+
+# --------------------------------------------------------------------------
+# seventh resident kernel: the causal attention of a latent-attention block's
+# whole padded prompt in the EXPANDED form (``serving.decode.latent_moe.
+# LatentMoELM.attend_expanded`` is its definition), on the grid (prompt, pair
+# of heads, query block, key block) with the score tile in VMEM only: no
+# ``(heads, S, S)`` array is written, where XLA's chain (two einsums added, a
+# ``where``, a softmax, a cast) passes one through device memory five times a
+# layer.  The operands lie as the projections leave them, heads side by side
+# in a row: the unrotated queries ``(S, H nope)``, the rotated ones ``(S, H
+# rope)``, the per-head keys and values out of ``W_kvb`` as ONE array ``(S, H
+# (nope + v))`` (a head's ``k_nope`` and ``v`` side by side), and the rotated
+# key ``(S, rope)`` that every head shares, so a tile's scores are two
+# products into one float32 tile, ``q_nope . k_nope^T + q_rope . k_r^T`` (one
+# product over their 192-wide concatenation takes the same time to the
+# microsecond: my chip run, PR 45).  Key blocks wholly past a query block's
+# diagonal are neither fetched (their index is the last live block's, and a
+# block that is named again is not moved) nor computed; in the block the
+# diagonal crosses only the keys up to the query block's last are read, and
+# only there is the mask built; the running max, denominator and accumulator
+# are float32 scratch and the division is made once, at the query block's
+# last live key block.  Forward only, no bias, no dropout, no log-sum-exp: a
+# prompt's padding needs none (keys past every valid query, queries nobody
+# reads).  ``LatentMoELM.prefill_math`` is its one caller, through
+# ``by_platform``.
+#
+# (Down here for the reason the fifth pair is: the line numbers above stay.)
+
+__all__ += ["mla_prefill_attention"]
+
+
+def _mla_last_live(qi, bq, bk):
+    """The last key block a query of block ``qi`` may read."""
+    return (qi * bq + bq - 1) // bk
+
+
+def _mla_prefill_kernel(qn_ref, qr_ref, kv_ref, kr_ref, o_ref, m_sc, l_sc,
+                        acc_sc, *, scale, heads, nope, rope, width):
+    """One (prompt, group of ``heads`` heads, q block, k block) step.
+
+    Blocks: qn (1, BQ, heads nope); qr (1, BQ, heads rope); kv (1, BK, heads
+    (nope + width)), a head's keys then its values; kr (1, BK, rope); o (1,
+    BQ, heads width).  Scratch: m, l (heads, BQ, 1) and acc (BQ, heads
+    width), float32."""
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    bq, bk = qn_ref.shape[1], kv_ref.shape[1]
+    last = _mla_last_live(qi, bq, bk)
+    precision = jax.lax.Precision.HIGHEST if kv_ref.dtype == jnp.float32 \
+        else None
+    dot = functools.partial(jax.lax.dot_general, precision=precision,
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(ki == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def tile(keys, crossed):
+        """The block's first ``keys`` keys under the queries; ``crossed``:
+        some of them lie past some query, and the mask is built."""
+        kr = kr_ref[0, :keys]
+        if crossed:
+            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, keys),
+                                                       1)
+            seen = q_pos >= k_pos
+        for a in range(heads):
+            at = a * (nope + width)
+            s = (dot(qn_ref[0, :, a * nope:(a + 1) * nope],
+                     kv_ref[0, :keys, at:at + nope], _NT)
+                 + dot(qr_ref[0, :, a * rope:(a + 1) * rope], kr, _NT)) \
+                * scale
+            if crossed:
+                s = jnp.where(seen, s, _NEG)
+            m = m_sc[a]
+            new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - new_m)
+            corr = jnp.exp(m - new_m)
+            l_sc[a] = l_sc[a] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_sc[a] = new_m
+            v = kv_ref[0, :keys, at + nope:at + nope + width]
+            lanes = slice(a * width, (a + 1) * width)
+            acc_sc[:, lanes] = acc_sc[:, lanes] * corr + dot(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
+
+    # every key of the block at or before every query of the block: no mask
+    whole = ki * bk + bk - 1 <= qi * bq
+    pl.when(whole)(functools.partial(tile, bk, False))
+    # a block the diagonal crosses.  Where it holds more keys than the query
+    # block queries, the queries begin ``j bq`` keys into it and may read its
+    # first ``(j + 1) bq``: a case a ``j``, each of a static width
+    crossed = jnp.logical_and(jnp.logical_not(whole), ki <= last)
+    if bk <= bq:
+        pl.when(crossed)(functools.partial(tile, bk, True))
+    else:
+        for j in range(bk // bq):
+            pl.when(jnp.logical_and(crossed, qi * bq - ki * bk == j * bq))(
+                functools.partial(tile, (j + 1) * bq, True))
+
+    @pl.when(ki == last)
+    def _():
+        for a in range(heads):
+            lanes = slice(a * width, (a + 1) * width)
+            o_ref[0, :, lanes] = (acc_sc[:, lanes] / l_sc[a]).astype(
+                o_ref.dtype)
+
+
+def _mla_blocks(s):
+    """(BQ, BK) for a prompt of ``s`` positions (a multiple of 128): 256
+    queries a block where that divides ``s`` and as many keys as divide it,
+    up to 2,048: a prompt of up to 2,048 positions is ONE key block and a
+    query block one step.  On the chip, 32 heads (microseconds a layer at 512
+    / 1,024 / 1,536 / 2,048 positions, my chip runs, PR 45): 113 / 223 / 377
+    / 585 so, 113 / 261 / 473 / 750 with 512 keys a block, 139 / 373 / 731 /
+    1,214 with 256: what a step pays whatever its keys (the accumulator read
+    and written, the statistics a lane a row) is paid once."""
+    bq = 256 if s % 256 == 0 else 128
+    return bq, max(bk for bk in range(bq, 2048 + 1, bq) if s % bk == 0)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "heads", "block_q",
+                                             "block_k", "interpret"))
+def _mla_prefill_call(q_nope, q_rope, kv, kr, *, scale, heads, block_q,
+                      block_k, interpret):
+    """``pallas_call`` on the grid (prompts, pairs of heads, q blocks, k
+    blocks).  One jitted function of its arrays: every layer of a prefill
+    program lowers this once and the chip compiles one body a bucket.  At
+    256 x 2,048 a pair's keys and values are 2 MB a buffer and a score tile
+    2 MB of float32, the probabilities as much again."""
+    b, s, _ = q_nope.shape
+    rope = kr.shape[2]
+    nope = q_nope.shape[2] // heads
+    width = kv.shape[2] // heads - nope
+    group = 2               # two rotated queries of 64 fill a lane tile
+    bq, bk = block_q, block_k
+    live = lambda j, kk: jnp.minimum(kk, _mla_last_live(j, bq, bk))
+    by_q = lambda lanes: pl.BlockSpec((1, bq, group * lanes),
+                                      lambda i, h, j, kk: (i, j, h))
+    return pl.pallas_call(
+        functools.partial(_mla_prefill_kernel, scale=scale, heads=group,
+                          nope=nope, rope=rope, width=width),
+        grid=(b, heads // group, s // bq, s // bk),
+        in_specs=[by_q(nope), by_q(rope),
+                  pl.BlockSpec((1, bk, group * (nope + width)),
+                               lambda i, h, j, kk: (i, live(j, kk), h)),
+                  pl.BlockSpec((1, bk, rope),
+                               lambda i, h, j, kk: (i, live(j, kk), 0))],
+        out_specs=by_q(width),
+        out_shape=jax.ShapeDtypeStruct((b, s, heads * width), kv.dtype),
+        scratch_shapes=[pltpu.VMEM((group, bq, 1), jnp.float32),
+                        pltpu.VMEM((group, bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, group * width), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        interpret=interpret, name="mla_prefill_attention",
+    )(q_nope, q_rope, kv, kr)
+
+
+def mla_prefill_attention(q_nope, q_rope, kv, kr, *, scale, block_q=None,
+                          block_k=None, interpret=False):
+    """Causal attention of whole padded prompts in a latent-attention
+    block's expanded form: position ``t`` of a prompt attends to positions
+    ``0 .. t`` of it with scores ``scale (q_nope . k_nope + q_rope . k_r)``.
+
+    ``q_nope (B, S, H, nope)`` and ``q_rope (B, S, H, rope)`` are the heads'
+    queries (the second rotated), ``kv (B, S, H, nope + v)`` a head's
+    unrotated keys and its values side by side as ``W_kvb`` gives them, and
+    ``kr (B, S, rope)`` the rotated key all heads share.  Returns ``(B, S, H
+    v)`` in ``kv``'s dtype.  Nothing of ``(H, S, S)`` is written: a score
+    tile lives in VMEM, key blocks past a query block's diagonal are neither
+    fetched nor computed.  Operands enter the products in ``kv``'s dtype (the
+    queries and the probabilities are cast to it), accumulation and the
+    softmax's statistics are float32.  ``S`` is a multiple of the blocks
+    (``_mla_blocks`` where none is named; one of them divides the other),
+    ``nope`` and ``v`` are whole lane tiles and a pair of heads' ``rope``
+    one, and the heads are even in number."""
+    b, s, h, _ = q_nope.shape
+    dt = kv.dtype
+    bq, bk = _mla_blocks(s)
+    bq, bk = block_q or bq, block_k or bk
+    if s % bq or s % bk or max(bq, bk) % min(bq, bk):
+        raise ValueError(f"mla_prefill_attention: blocks of {bq} queries "
+                         f"and {bk} keys for {s} positions")
+    flat = lambda x: x.astype(dt).reshape(b, s, -1)
+    return _mla_prefill_call(
+        flat(q_nope), flat(q_rope), flat(kv), kr.astype(dt),
+        scale=float(scale), heads=h, block_q=bq, block_k=bk,
+        interpret=interpret)

@@ -16,6 +16,10 @@ What differs from :class:`~mxnet_tpu.serving.decode.model.CausalLM`:
   (``W_kvb`` folded into the query and the output, scores and context taken
   straight over the cached latent rows), so no per-head K/V is ever
   materialised for a cached token.  Same mathematics, two contractions.
+  :meth:`LatentMoELM.attend_expanded` is the expanded form's definition
+  (every head's ``(S, S)`` scores, one softmax); a prefill program lowered
+  for the chip runs it by blocks, one kernel a layer and no such array
+  (:meth:`LatentMoELM._scores_lowered`).
 - **A dense FFN in the first ``first_k_dense_replace`` layers, then routed +
   shared experts.**  The routed part is
   :func:`mxnet_tpu.parallel.moe.routed_expert_share`: this block holds the
@@ -283,11 +287,11 @@ class LatentMoELM(HybridBlock):
         self._param_order = sorted(self._reg_params)
 
     # ------------------------------------------------- what the runtime reads
-    #: one prompt a prefill call.  The expanded attention's float32 scores
-    #: ``(H, S, S)`` and per-head keys and values are per row (0.78 GB of
-    #: temporaries at 1 x 1536 and the published widths), and a prompt of a
-    #: hundred tokens already fills the MXU's rows, so a second prompt in
-    #: the call buys memory and no time.
+    #: one prompt a prefill call.  A prompt of a hundred tokens already
+    #: fills the MXU's rows, so a second prompt in the call buys no time
+    #: (it is no longer memory that forbids it: lowered for the chip the
+    #: attention goes by blocks in one kernel a layer and a prompt's
+    #: temporaries are its per-head keys and values, tens of MB).
     max_prefill_batch = 1
 
     def cache_layout(self):
@@ -469,27 +473,72 @@ class LatentMoELM(HybridBlock):
         """The residual path's end: the streams summed."""
         return h if self.hc_mult == 1 else h.sum(-2)
 
-    def attend_expanded(self, p, i, a, positions, causal):
-        """Expanded attention over a whole sequence ``a (B, S, U)``:
-        per-head keys and values from the (stored-precision) latent rows.
-        Returns ``(attention output (B, S, U) float32, rows (B, S,
-        pool_width) in the cache dtype)``."""
-        import jax
-        import jax.numpy as jnp
-        B, S, _ = a.shape
-        H, dt = self.num_heads, self.dtype
+    def _attend_whole(self, scores, p, i, a, positions, causal):
+        """Expanded attention over a whole sequence ``a (B, S, U)`` with
+        ``scores(q_nope (B, S, H, nope), q_rope (B, S, H, rope), kv (B, S,
+        H, nope + v) float32, k_r (B, S, rope), causal) -> (B, S, H v)`` in
+        the middle: the queries, the latent rows in the cache dtype, the
+        per-head keys and values made from those (stored-precision) rows,
+        and ``W_o`` behind it."""
+        dt = self.dtype
         q_nope, q_rope = self._queries(p, i, a, positions)
         rows = self._latent_row(p, i, a, positions).astype(dt)
         ckv = rows[..., :self.kv_lora_rank]
         kr = rows[..., self.kv_lora_rank:self.row_width]
         kv = _einsum("bsc,chd->bshd", ckv, self._wkvb(p, i), dt)
+        return _dot(scores(q_nope, q_rope, kv, kr, causal),
+                    p[f"l{i}_wo"]), rows
+
+    def _scores_chain(self, q_nope, q_rope, kv, kr, causal):
+        """The definition of the expanded attention's middle under the mask
+        ``causal (S, S)``: every head's ``(S, S)`` float32 scores, one
+        softmax, ``p . v``."""
+        import jax
+        import jax.numpy as jnp
+        dt = self.dtype
         k_nope, v = kv[..., :self.nope_dim], kv[..., self.nope_dim:]
         s = (_einsum("bqhd,bkhd->bhqk", q_nope, k_nope, dt)
              + _einsum("bqhr,bkr->bhqk", q_rope, kr, dt)) * self._scale
         s = jnp.where(causal[None, None], s, -1e30)
         pr = jax.nn.softmax(s, axis=-1)
-        o = _einsum("bhqk,bkhd->bqhd", pr, v, dt).reshape(B, S, -1)
-        return _dot(o, p[f"l{i}_wo"]), rows
+        o = _einsum("bhqk,bkhd->bqhd", pr, v, dt)
+        return o.reshape(o.shape[:2] + (-1,))
+
+    def _scores_lowered(self, q_nope, q_rope, kv, kr, causal):
+        """:meth:`_scores_chain` as the prefill program runs it: where the
+        program is lowered for the chip ONE kernel
+        (``ops.pallas_kernels.mla_prefill_attention``: by blocks, the causal
+        order its only mask, no ``(H, S, S)`` array; ``causal`` is then not
+        read), where it is lowered for the CPU the definition
+        (``by_platform``: nothing a caller sets chooses, and
+        ``decode.mla.prefill.lowered`` counts which was built, once a
+        layer).  Widths that are not whole lane tiles, an odd number of
+        heads or a length that is not whole blocks (tiny blocks) are the
+        definition's on every platform."""
+        from ...ops.pallas_kernels import by_platform, mla_prefill_attention
+        S = q_nope.shape[1]
+        if self.nope_dim % 128 or self.v_dim % 128 or \
+                2 * self.rope_dim % 128 or self.num_heads % 2 or S % 128:
+            return self._scores_chain(q_nope, q_rope, kv, kr, causal)
+
+        def kernel(q_nope, q_rope, kv, kr, _causal):
+            return mla_prefill_attention(
+                q_nope, q_rope, kv.astype(self.dtype), kr,
+                scale=self._scale).astype("float32")
+
+        return by_platform("decode.mla.prefill.lowered", q_nope, q_rope, kv,
+                           kr, causal, kernel=kernel,
+                           plain=self._scores_chain, tokens=S)
+
+    def attend_expanded(self, p, i, a, positions, causal):
+        """Expanded attention over a whole sequence ``a (B, S, U)``:
+        per-head keys and values from the (stored-precision) latent rows.
+        Returns ``(attention output (B, S, U) float32, rows (B, S,
+        pool_width) in the cache dtype)``.  The definition: what a prefill
+        program lowered for the CPU runs, and what the kernel of one lowered
+        for the chip is held to."""
+        return self._attend_whole(self._scores_chain, p, i, a, positions,
+                                  causal)
 
     def _fold(self, p, i, a, positions):
         """One query per row ``a (B, U)`` in the latent rows' own space,
@@ -546,7 +595,8 @@ class LatentMoELM(HybridBlock):
         for i in range(self.num_layers):
             def attend(a, i=i):
                 with jax.named_scope("mla.attend"):
-                    o, rows = self.attend_expanded(p, i, a, pos, causal)
+                    o, rows = self._attend_whole(self._scores_lowered, p, i,
+                                                 a, pos, causal)
                 out_rows.append(rows)
                 return (o,)
 

@@ -1354,3 +1354,147 @@ def test_other_blocks_programs_name_no_eva_kernel(one_chip, block):
     assert counted("decode.attn.eva.lowered", lower) == {}
     for text in texts:
         assert "eva_attention" not in text
+
+
+# ------------------------------- the latent block's prefill, by blocks (PR 45)
+_X_SEQ = 2048
+
+
+@functools.lru_cache(maxsize=1)
+def _xing4_prefill_block(layers=2):
+    """A declared (no array) ``LatentMoELM`` at every attention width of
+    ``xing4_29b_ep8`` (hidden 3,584, 32 heads of 128 + 64 and 128, ranks 768
+    and 512, YaRN over 4,096) with a plain residual path and a narrow dense
+    FFN in every layer: the prompt's attention is what is asked about, and
+    four float32 streams of a 2,048-token prompt (117 MB an array) or its
+    routed rows would hide it."""
+    from mxnet_tpu.serving.decode import LatentMoELM
+    return LatentMoELM(
+        vocab_size=512, hidden_size=3584, num_layers=layers, num_heads=32,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=256,
+        first_k_dense_replace=layers,
+        rope_scaling={"factor": 64, "original_max_position_embeddings": 4096,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                      "mscale_all_dim": 1}, max_length=_X_SEQ + 256)
+
+
+def _latent_prefill(net, s, sharding):
+    """``(jitted prefill, its arguments)`` of one prompt of ``s``
+    positions."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                                    sharding=sharding)
+    # a plain residual path: every parameter is served as it is registered
+    params = [sds(net._reg_params[n].shape, net._reg_params[n].dtype)
+              for n in net._param_order]
+    fn = jax.jit(lambda leaves, tok, ln: net.prefill_math(
+        net._params_dict(leaves), tok, ln))
+    return fn, (params, sds((1, s), "int32"), sds((1,), "int32"))
+
+
+def test_latent_prefill_holds_no_heads_by_s_by_s_array(one_chip):
+    """A prompt of 2,048 at ``xing4``'s attention widths: the float32 scores
+    of ONE layer as a ``(heads, S, S)`` array are 32 x 2,048^2 x 4 B = 537
+    MB, and the definition's chain passes such an array through device
+    memory some five times a layer (3.2 ms; my chip run, PR 45).  Lowered for the chip the attention
+    is one ``mla_prefill_attention`` call a layer under ``mla.attend``: no
+    array has two axes of ``S``, the largest is a layer's per-head keys and
+    values (``(S, 32 x 256)`` bfloat16, 34 MB), and all the program's
+    temporaries together are under an eighth of that one array of scores."""
+    import numpy as np
+    net = _xing4_prefill_block()
+    fn, args = _latent_prefill(net, _X_SEQ, one_chip)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    heads, S = net.num_heads, _X_SEQ
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"(.*)$", text,
+                       re.M)
+    assert len(calls) == net.num_layers and all(
+        "/mla.attend/" in c and "mla_prefill_attention" in c for c in calls)
+    weights = {tuple(p.shape) for p in args[0]}
+    for op, dtype, dims in _materialised(text):
+        if dims in weights:
+            continue
+        assert int(np.prod(dims)) <= S * heads * 256, \
+            f"latent prefill: {op} writes {dtype}{list(dims)}"
+        # 32 heads' rotated queries side by side are 2,048 lanes wide, an
+        # ``(S, S)`` that is no score: 8 MB, one sixty-fourth of the scores
+        assert sum(d == S for d in dims) < 2 or dims == (1, S, heads * 64), \
+            f"latent prefill: {op} writes {dtype}{list(dims)}: S x S"
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        heads * S * S * 4 // 8
+
+
+@pytest.mark.parametrize("s", [256, 2048])
+def test_latent_prefill_counts_the_kernel_once_a_layer(one_chip, s):
+    """``decode.mla.prefill.lowered``: lowering the prefill for the
+    described chip counts ``kind="kernel"`` once a layer and ``plain``
+    never; lowering the same program for the CPU, the other way round.
+    Nothing but the platform differs between the two."""
+    from mxnet_tpu.test_utils import counted
+    net = _xing4_prefill_block()
+    for sharding, kind in ((one_chip, "kernel"), (None, "plain")):
+        fn, args = _latent_prefill(net, s, sharding)
+        assert counted("decode.mla.prefill.lowered",
+                       lambda: fn.lower(*args)) == \
+            {f'{{kind="{kind}",tokens="{s}"}}': net.num_layers}
+
+
+@pytest.mark.parametrize("heads,s", [(32, 512), (32, 2048), (64, 256),
+                                     (64, 1536)])
+def test_mla_prefill_kernel_compiles_for_v5e(one_chip, heads, s):
+    """The kernel alone at the two cells' head counts and their shortest and
+    longest buckets, in the blocks the program picks: Mosaic takes the
+    unaligned half-tile slices of the rotated queries and the scratch fits
+    the core's memory."""
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+    fn = jax.jit(functools.partial(pallas_kernels.mla_prefill_attention,
+                                   scale=0.1))
+    compiled = fn.lower(sds(1, s, heads, 128), sds(1, s, heads, 64),
+                        sds(1, s, heads, 256), sds(1, s, 64)).compile()
+    assert "mla_prefill_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("block", ["base", "hybrid", "window", "linear",
+                                   "eva", "latent-step", "bert"])
+def test_other_blocks_programs_name_no_mla_prefill_kernel(one_chip, block):
+    """The prefill kernel and its door are one block's one program's: the
+    step, the commit and (where the helper builds it) the prefill program of
+    each of the five other block kinds, the latent block's own step and
+    commit, and the BERT training step (one layer of it) lower, for the
+    described chip, to text that names no ``mla_prefill_attention``, and
+    count no ``decode.mla.prefill.lowered``."""
+    from mxnet_tpu.test_utils import counted
+    texts = []
+    if block == "bert":
+        from test_chip_compile_bert_step import _bert_base_step
+        lower = lambda: texts.append(_bert_base_step(one_chip, 1).as_text())
+    else:
+        rt, program, b, kinds = {
+            "base": (lambda: _gpt2_medium_runtime(2, "float32"),
+                     _pool_program, 8, ("step", "commit")),
+            "hybrid": (_hybrid_runtime, _hybrid_program, 32,
+                       ("step", "commit", "prefill")),
+            "window": (_window_runtime, _window_program, 32,
+                       ("step", "commit", "prefill")),
+            "linear": (_linear_runtime, _linear_program, 1,
+                       ("step", "commit", "prefill")),
+            "eva": (_eva_runtime, _eva_program, 8,
+                    ("step", "commit", "prefill")),
+            "latent-step": (_latent_runtime, _latent_pool_program, 32,
+                            ("step", "commit"))}[block]
+        rt = rt()
+        sds = lambda shape, dtype: jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                                        sharding=one_chip)
+
+        def lower():
+            for kind in kinds:
+                fn, args, *_pools = program(rt, kind,
+                                            b if kind == "step" else 1, sds)
+                texts.append(fn.lower(*args).as_text())
+
+    assert counted("decode.mla.prefill.lowered", lower) == {}
+    assert texts
+    for text in texts:
+        assert "mla_prefill_attention" not in text
